@@ -4,16 +4,14 @@ The columnar fast path: a BatchSource produces TupleBatches (struct of
 numpy arrays), WinSeqTPU folds them into per-key pane accumulators at
 ingest and launches batched window reductions on the device (the
 Win_Seq_GPU re-design -- win_seq_gpu.hpp:391-645 -- as XLA programs).
-With no reachable accelerator the same graph runs on the host XLA
-backend unchanged.
+Under ``JAX_PLATFORMS=cpu`` the same graph runs on XLA's CPU backend
+unchanged.
 """
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from examples._common import CountingSink, maybe_force_host, scale  # noqa: E402
-
-maybe_force_host()
+from examples._common import CountingSink, scale  # noqa: E402
 
 import numpy as np  # noqa: E402
 

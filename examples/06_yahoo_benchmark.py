@@ -8,9 +8,7 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from examples._common import CountingSink, maybe_force_host, scale  # noqa: E402
-
-maybe_force_host()
+from examples._common import CountingSink, scale  # noqa: E402
 
 import windflow_tpu as wf  # noqa: E402
 from windflow_tpu.core import Mode  # noqa: E402

@@ -7,17 +7,15 @@ at all -- the columnar twin of the record plane's set_synth lowering).
 Everything else in the graph is unchanged, and any non-chunk-aware
 consumer transparently receives materialized batches.
 
-This is the benchmark's headline configuration; on the bench box it
-sustains >170M tuples/s end to end on one host core + one chip.
+This is the synthesis lane of the benchmark's headline shape (its
+rate is not measured on the current machine; see PERF.md).
 """
 import sys
 import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from examples._common import CountingSink, maybe_force_host, scale  # noqa: E402
-
-maybe_force_host()
+from examples._common import CountingSink, scale  # noqa: E402
 
 import windflow_tpu as wf  # noqa: E402
 from windflow_tpu.core import Mode  # noqa: E402

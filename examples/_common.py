@@ -1,15 +1,7 @@
-"""Shared helpers for the examples: size/backends knobs and a counting
-sink, so each walkthrough stays focused on the feature it shows."""
+"""Shared helpers for the examples: the size knob and a counting sink,
+so each walkthrough stays focused on the feature it shows."""
 import os
 import threading
-
-
-def maybe_force_host():
-    """Honour WINDFLOW_FORCE_HOST=1 BEFORE anything touches jax (env
-    var JAX_PLATFORMS alone does not beat an installed PJRT plugin)."""
-    if os.environ.get("WINDFLOW_FORCE_HOST") == "1":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
 
 
 def scale(n: int) -> int:

@@ -143,7 +143,8 @@ def test_decision_monotone_in_host_rate():
 
 def test_small_launches_behind_long_rtt_go_host():
     """The VERDICT scenario: application-family configs whose windows
-    fire in dribbles behind a ~70 ms tunnel must not stay on device."""
+    fire in dribbles behind a ~70 ms launch floor must not stay on
+    device."""
     inp = PlacementInputs(rtt_floor_ms=70.0, host_rate_tps=60e6,
                           tuples_per_launch=256 * 16,  # tiny batches
                           bytes_per_launch=4_000)
@@ -248,7 +249,9 @@ def test_placements_and_device_time_in_stats_json():
     assert rec["Device_time_ms"] > 0
     assert rec["Device_ms_per_launch"] > 0
     assert rec["Device_bytes_per_launch"] > 0
-    assert "Device_roofline_frac" in rec
+    # the roofline estimate needs the device's published peak: the CPU
+    # backend's device_kind is not in the table, so the field is omitted
+    assert "Device_roofline_frac" not in rec
 
 
 def test_host_lane_reports_engine_time_too():

@@ -14,8 +14,8 @@
 * the online re-planner flips a lane mid-run with zero lost tuples,
   records a ``replacement`` flight event and the doctor explains it.
 
-Runs on the JAX CPU backend (cpu-fallback XLA); the same programs
-compile for TPU unchanged.  Green on both channel planes (the
+Runs on the CPU backend; the same programs compile for TPU
+unchanged.  Green on both channel planes (the
 WINDFLOW_NATIVE=0 CI job).
 """
 import collections
@@ -438,8 +438,8 @@ def _window_count(n, n_keys, win, slide):
 class TestReplanFlip:
     def test_scripted_load_shift_flips_lane_zero_loss(self):
         """The acceptance scenario: auto resolves 'device' from the
-        tiny pinned RTT floor, the measured cpu-fallback launch walls
-        contradict the projection, and the re-planner flips the lane
+        tiny pinned RTT floor, the launch walls measured on the CPU
+        backend contradict the projection, and the re-planner flips the lane
         mid-run -- zero lost/duplicated windows (ledger balanced
         across the flip), values equal to the integer oracle on both
         sides of the flip, flip visible as a ``replacement`` flight
@@ -596,8 +596,8 @@ class TestResidentDurability:
         return g, wins, counts
 
     def test_crash_restart_verify_resident_ffat(self, tmp_path):
-        """Kill-restart-verify with the device-resident (cpu-fallback
-        XLA) FFAT engine: epoch snapshots carry the resident forest,
+        """Kill-restart-verify with the device-resident FFAT engine
+        (on the CPU backend): epoch snapshots carry the resident forest,
         the restored run is bitwise equal to an uninterrupted one."""
         from windflow_tpu.resilience.faults import FaultPlan
         N = 5000

@@ -689,6 +689,27 @@ def test_fleet_places_8_tenants_and_survives_worker_death():
             <= set(names)
 
 
+def test_fleet_one_device_worker_per_visible_chip(monkeypatch):
+    """A chip belongs to one process: the fleet counts the chips its
+    children could open without touching JAX, hands each device worker
+    one of them through the environment, and refuses to start more
+    device workers than chips -- before spawning anything."""
+    from windflow_tpu.scheduler import FleetServer
+    from windflow_tpu.scheduler.fleet import chip_env, visible_chips
+    # the CPU backend is shared by any number of processes
+    assert visible_chips({"JAX_PLATFORMS": "cpu"}) is None
+    assert visible_chips({"JAX_PLATFORMS": "tpu,cpu",
+                          "TPU_VISIBLE_CHIPS": "2,3"}) == ["2", "3"]
+    env = chip_env({"PATH": "/bin"}, "3", 1)
+    assert env["TPU_VISIBLE_CHIPS"] == "3" and env["PATH"] == "/bin"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0")
+    with pytest.raises(SchedulerError, match="2 workers with device "
+                                             "lanes but 1 visible"):
+        FleetServer(workers=2)
+
+
 def test_fleet_single_tenant_completes_unthrottled(tmp_path):
     """A tenant alone on its worker runs under fair_share=True yet
     never waits in the gate (pay-for-what-you-use)."""

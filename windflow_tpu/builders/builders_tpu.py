@@ -362,7 +362,8 @@ class WinSeqFFATTPUBuilder(_WinBuilderBase, _TPUBuilderMixin):
             return self.combine
         if isinstance(self.combine, str) \
                 and self.combine in self._BUILTIN_COMBINES:
-            import jax.numpy as jnp
+            from ..ops.backend import jax_modules
+            _, jnp = jax_modules()
             fn = {"sum": jnp.add, "max": jnp.maximum,
                   "min": jnp.minimum}[self.combine]
             return fn, self._BUILTIN_COMBINES[self.combine][1]

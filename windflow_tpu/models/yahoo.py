@@ -109,8 +109,8 @@ def make_step(n_campaigns: int, n_windows: int, win_len: int):
     static shapes, no data-dependent control flow; XLA fuses the
     filter/join/gather chain.
     """
-    import jax
-    import jax.numpy as jnp
+    from ..ops.backend import jax_modules
+    jax, jnp = jax_modules()
 
     @jax.jit
     def step(campaign_of_ad, ad_id, event_type, ts, counts):

@@ -104,7 +104,8 @@ class WinSeqFFATResidentLogic(NodeLogic):
 
     def _grow_forest(self) -> None:
         """Double the key capacity, copying the resident trees."""
-        import jax.numpy as jnp
+        from ...ops.backend import jax_modules
+        _, jnp = jax_modules()
         old = self.forest.tree
         from ...ops.flatfat_jax import BatchedFlatFAT
         self.forest = BatchedFlatFAT(self.combine, self.neutral,
@@ -396,7 +397,8 @@ class WinSeqFFATResidentLogic(NodeLogic):
                 "capacity": self.capacity}
 
     def load_state(self, state):
-        import jax.numpy as jnp
+        from ...ops.backend import jax_modules
+        _, jnp = jax_modules()
         from ...ops.flatfat_jax import BatchedFlatFAT
         tree = state["tree"]
         self.capacity = state.get("capacity", self.capacity)

@@ -42,9 +42,9 @@ DEFAULT_BATCH_LEN = 256
 # host staging-buffer capacity (elements) before a forced flush
 DEFAULT_MAX_BUFFER_ELEMS = 1 << 19
 # device launches kept in flight before the oldest is flushed.  8 deep
-# (was 4): over a high-latency transport the pipeline must hold enough
-# programs that one RTT amortizes over several launches; the adaptive
-# batch resize below keeps per-launch latency bounded regardless
+# (was 4): the pipeline must hold enough programs that one launch round
+# trip amortizes over several launches; the adaptive batch resize below
+# keeps per-launch latency bounded regardless
 DEFAULT_INFLIGHT_DEPTH = 8
 # partial-batch launch trigger (latency bound), milliseconds
 DEFAULT_MAX_BATCH_DELAY_MS = 10.0
@@ -134,9 +134,9 @@ class _AsyncDispatcher:
     and hands them off; this thread pays the host->device transfer
     latency, keeps ``inflight_depth`` programs in flight, and emits
     completed results.  The reference overlaps CUDA streams with host
-    batching on ONE thread (win_seq_gpu.hpp:267-297); over a
-    high-latency PJRT transport the dispatch itself blocks for a round
-    trip, so it must come off the ingest thread entirely."""
+    batching on ONE thread (win_seq_gpu.hpp:267-297); here staging a
+    launch's buffers onto the device blocks the caller, so dispatch
+    comes off the ingest thread entirely."""
 
     __slots__ = ("logic", "work", "thread", "error", "aborting")
 
@@ -420,19 +420,19 @@ class WinSeqTPULogic(NodeLogic):
                 and cfg.n_outer == 1 and cfg.n_inner == 1
                 and cfg.id_outer == 0 and cfg.id_inner == 0
                 and value_of is None and resident is not True):
-            try:
-                from ...runtime.native import (NativeWindowEngine,
-                                               native_available)
-                if native_available():
-                    # renumbering = per-key arrival-order ids, which the
-                    # engine implements natively (ids implicit, always
-                    # on the dense lane)
-                    self._native = NativeWindowEngine(
-                        win_len, slide_len, win_type == WinType.TB,
-                        triggering_delay, renumber=renumbering,
-                        kind=win_kind)
-            except Exception:
-                self._native = None
+            # no library (no toolchain, WINDFLOW_NATIVE=0): the Python
+            # staging lanes below are the supported fallback, and
+            # runtime/native.py has said why on stderr
+            from ...runtime.native import (NativeWindowEngine,
+                                           native_available)
+            if native_available():
+                # renumbering = per-key arrival-order ids, which the
+                # engine implements natively (ids implicit, always on
+                # the dense lane)
+                self._native = NativeWindowEngine(
+                    win_len, slide_len, win_type == WinType.TB,
+                    triggering_delay, renumber=renumbering,
+                    kind=win_kind)
         if resident is True:
             self._enable_resident(required=True)
 

@@ -25,11 +25,12 @@ from typing import Callable
 
 import numpy as np
 
+from .backend import jax_modules
+
 
 @functools.lru_cache(maxsize=None)
 def _programs(combine: Callable, neutral: float, n: int):
-    import jax
-    import jax.numpy as jnp
+    jax, jnp = jax_modules()
 
     levels = int(np.log2(n))
     assert 1 << levels == n, "FlatFAT capacity must be a power of two"
@@ -92,8 +93,7 @@ def _batched_programs(combine: Callable, neutral: float, n: int):
     stays on the device between batches and only touched paths are
     recomputed (UpdateTreeLevel_Kernel, flatfat_gpu.hpp:68-82) --
     vectorized here as log n scatter rounds over the update batch."""
-    import jax
-    import jax.numpy as jnp
+    jax, jnp = jax_modules()
 
     levels = int(np.log2(n))
     assert 1 << levels == n, "FlatFAT capacity must be a power of two"
@@ -103,13 +103,8 @@ def _batched_programs(combine: Callable, neutral: float, n: int):
     # successor, and donation lets XLA reuse the buffer in place --
     # the double-buffered carry of the reference's rebuild=false mode
     # (win_seqffat_gpu.hpp:150) without a second tree's footprint.
-    # CPU (the test backend) does not implement donation, so the gate
-    # keeps it off there; WINDFLOW_DONATE_FOREST=0 opts a device
-    # backend out (e.g. a transport not yet exercised with donation).
-    import os
-    donate = ((0,) if jax.default_backend() != "cpu"
-              and os.environ.get("WINDFLOW_DONATE_FOREST", "1") != "0"
-              else ())
+    # The CPU backend deletes a donated argument just as the TPU does,
+    # so tests run the aliasing the chip will.
 
     # the level sweeps are lax.fori_loop, not Python-unrolled: every
     # iteration carries fixed shapes, and unrolling 2 x levels rounds
@@ -143,7 +138,7 @@ def _batched_programs(combine: Callable, neutral: float, n: int):
         tree, _ = jax.lax.fori_loop(0, levels, level, (tree, idx))
         return tree
 
-    update_sparse = functools.partial(jax.jit, donate_argnums=donate)(
+    update_sparse = functools.partial(jax.jit, donate_argnums=(0,))(
         _update_body)
 
     def _query_body(tree, keys, starts, ends, valid):
@@ -173,7 +168,7 @@ def _batched_programs(combine: Callable, neutral: float, n: int):
 
     query_ranges = jax.jit(_query_body)
 
-    @functools.partial(jax.jit, donate_argnums=donate)
+    @functools.partial(jax.jit, donate_argnums=(0,))
     def update_and_query(tree, keys, positions, values, valid,
                          q_keys, q_starts, q_ends, q_valid):
         """The fused per-launch program of the resident lane: scatter
@@ -185,7 +180,7 @@ def _batched_programs(combine: Callable, neutral: float, n: int):
         out = _query_body(tree, q_keys, q_starts, q_ends, q_valid)
         return tree, out
 
-    @functools.partial(jax.jit, donate_argnums=donate)
+    @functools.partial(jax.jit, donate_argnums=(0,))
     def update_runs_and_query(tree, run_rows, run_starts, run_lens,
                               values, q_keys, q_starts, q_ends,
                               q_valid):
@@ -234,7 +229,7 @@ class BatchedFlatFAT:
         (self._update, self._query, self._update_query,
          self._update_runs_query) = _batched_programs(combine, neutral,
                                                       n)
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         self.tree = jnp.full((n_keys, 2 * n), neutral, dtype)
         # leaves [n, 2n) start as neutral; internal nodes of a
         # neutral-filled tree are neutral (monoid identity), so no
@@ -251,7 +246,7 @@ class BatchedFlatFAT:
 
     def update(self, keys, ids, values) -> None:
         """Insert values at ring positions ids % n for their keys."""
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         keys = np.asarray(keys)
         b = 1
         while b < max(512, len(keys)):  # floored bucket (see above)
@@ -302,7 +297,7 @@ class BatchedFlatFAT:
 
     def _combine_pieces(self, out: np.ndarray, wraps: np.ndarray,
                         B: int) -> np.ndarray:
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         head, tail = out[:B], out[B:2 * B]
         if not wraps.any():
             return head
@@ -318,7 +313,7 @@ class BatchedFlatFAT:
         Returns ``(dev_out, wraps, B)``: the un-blocked device result
         (2B wrap pieces) for async dispatch plus what
         :meth:`finish_query` needs to resolve it on host."""
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         keys = np.asarray(keys)
         # floor the update bucket: padding is cheap device work, and
         # collapsing the distinct pad shapes to a handful means
@@ -350,7 +345,7 @@ class BatchedFlatFAT:
         ships values + 12 bytes per run instead of 8 bytes per leaf.
         ``starts`` may be absolute ids (pre-reduced mod n on host, so
         int32 device arithmetic can never overflow)."""
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         rows = np.asarray(rows, np.int64)
         lens = np.asarray(lens, np.int64)
         total = int(lens.sum())
@@ -400,7 +395,7 @@ class BatchedFlatFAT:
         """Window results for extents [starts, ends) in id space (end -
         start <= n); wrapping ranges are combined as (tail, head) to
         keep time order."""
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         k2, s2, e2, ok, wraps, B = self._pack_queries(keys, starts, ends)
         out = np.asarray(self._query(self.tree, jnp.asarray(k2),
                                      jnp.asarray(s2), jnp.asarray(e2),
@@ -425,17 +420,17 @@ class FlatFATJax:
         self.dtype = dtype
         self._build, self._update, self._query = _programs(
             combine, neutral, n)
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         self.tree = self._build(jnp.full((n,), neutral, dtype))
 
     def build(self, leaves: np.ndarray) -> None:
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         padded = np.full(self.n, self.neutral, self.dtype)
         padded[: len(leaves)] = leaves
         self.tree = self._build(jnp.asarray(padded))
 
     def update(self, positions: np.ndarray, values: np.ndarray) -> None:
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         b = next_pow2 = 1
         while next_pow2 < max(1, len(positions)):
             next_pow2 <<= 1
@@ -450,7 +445,7 @@ class FlatFATJax:
 
     def query_ranges(self, starts: np.ndarray,
                      ends: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         b = 1
         while b < max(1, len(starts)):
             b <<= 1

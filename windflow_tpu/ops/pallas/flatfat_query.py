@@ -3,10 +3,11 @@
 The TPU twin of the reference's ``ComputeResults_Kernel``
 (flatfat_gpu.hpp:92-135): there, one CUDA thread per window walks the
 device-resident aggregator tree with the bit-trick range decomposition;
-here, one grid program per window performs the same O(log n) walk with
-the whole heap-layout tree resident in VMEM (it is at most 2 x t_pad
-floats -- far under VMEM capacity for every bucketed batch shape the
-window engine produces).
+here, one grid program per block of ``ROWS`` windows performs the same
+O(log n) walk with the whole heap-layout tree resident in VMEM (it is
+at most 2 x t_pad floats; the engine's gate caps it at 4 MiB).  The
+output is blocked ``(ROWS, 128)`` per program, so its VMEM footprint
+does not grow with the batch.
 
 The walk keeps separate left/right partial accumulators so the combine
 order is preserved oldest->newest, which makes the kernel correct for
@@ -16,7 +17,10 @@ ops/flatfat_jax.py, against which the tests diff this kernel.
 Tree layout: flat [2n] heap (root at 1, leaves at [n, 2n)), reshaped to
 (2n / 128, 128) lane-rows.  Scalar tree loads become a dynamic-sublane
 row load plus a one-hot lane extract -- the TPU-shaped substitute for
-the scalar ``fat[i]`` indexing of the CUDA kernel.
+the scalar ``fat[i]`` indexing of the CUDA kernel.  Node values travel
+as lane-uniform (1, 128) vectors: the combine runs on the VPU, and the
+walk's scalar predicates reach it as int32 splats (Mosaic splats an
+int32 scalar, not a bool).
 
 Build/update stay XLA level sweeps (flatfat_jax.py): they are
 bandwidth-bound strided combines XLA already fuses optimally; only the
@@ -29,68 +33,73 @@ from typing import Callable
 
 import numpy as np
 
-LANES = 128
+from ..backend import jax_modules
+from .window_sum import LANES, ROWS, interpret_off_tpu, pad_extents
 
 
 @functools.lru_cache(maxsize=None)
 def _build(n_leaves: int, n_windows: int, combine: Callable,
            neutral: float, interpret: bool):
-    import jax
-    import jax.numpy as jnp
+    jax, jnp = jax_modules()
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     levels = int(np.log2(n_leaves))
     assert 1 << levels == n_leaves, "FlatFAT capacity must be a power of two"
+    assert n_windows % ROWS == 0
 
     def kernel(starts_ref, ends_ref, tree_ref, out_ref):
-        b = pl.program_id(0)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (LANES,), 0)
+        g = pl.program_id(0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        neutral_row = jnp.full((1, LANES), neutral, jnp.float32)
 
         def tload(idx):
-            """tree[idx] via dynamic row load + one-hot lane extract."""
-            row = idx // LANES
-            col = idx % LANES
-            rowvec = tree_ref[row, :]
-            return jnp.sum(jnp.where(lane == col, rowvec, 0.0))
+            """tree[idx] on every lane: dynamic row load, one-hot lane
+            extract, lane reduce."""
+            rowvec = tree_ref[pl.ds(idx // LANES, 1), :]
+            hit = jnp.where(lane == idx % LANES, rowvec, 0.0)
+            return jnp.broadcast_to(
+                jnp.sum(hit, axis=1, keepdims=True), (1, LANES))
+
+        def select(pred, a, b):
+            return jnp.where(
+                jnp.full((1, LANES), pred.astype(jnp.int32)) != 0, a, b)
 
         def body(_, carry):
             lo, hi, left, right = carry
             take_l = (lo < hi) & ((lo & 1) == 1)
-            lval = tload(lo)
-            left = jnp.where(take_l, combine(left, lval), left)
+            # an empty extent at the buffer's end starts at heap slot 2n:
+            # clamp the (discarded) load inside the tree
+            lval = tload(jax.lax.min(lo, 2 * n_leaves - 1))
+            left = select(take_l, combine(left, lval), left)
             lo = jnp.where(take_l, lo + 1, lo)
             take_r = (lo < hi) & ((hi & 1) == 1)
             rval = tload(jax.lax.max(hi - 1, 0))
-            right = jnp.where(take_r, combine(rval, right), right)
+            right = select(take_r, combine(rval, right), right)
             hi = jnp.where(take_r, hi - 1, hi)
             return lo >> 1, hi >> 1, left, right
 
-        lo = starts_ref[b] + n_leaves
-        hi = ends_ref[b] + n_leaves
-        valid = hi > lo
-        lo, hi, left, right = jax.lax.fori_loop(
-            0, levels + 1, body,
-            (lo, hi, jnp.float32(neutral), jnp.float32(neutral)))
-        out = combine(left, right)
-        # one lane-row per window (1x1 output blocks are not lowerable;
-        # the host/caller reads column 0)
-        out_ref[b, :] = jnp.full((LANES,), jnp.where(valid, out, neutral),
-                                 jnp.float32)
+        for r in range(ROWS):
+            lo = starts_ref[g * ROWS + r] + n_leaves
+            hi = ends_ref[g * ROWS + r] + n_leaves
+            _lo, _hi, left, right = jax.lax.fori_loop(
+                0, levels + 1, body, (lo, hi, neutral_row, neutral_row))
+            # one lane-row per window (the caller reads column 0)
+            out_ref[r:r + 1, :] = select(hi > lo, combine(left, right),
+                                         neutral_row)
 
-    n_out_rows = ((n_windows + 7) // 8) * 8
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_windows,),
+        grid=(n_windows // ROWS,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda g, s, e: (g, 0)),
     )
 
     @jax.jit
     def run(starts, ends, tree2d):
         return pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct((n_out_rows, LANES), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((n_windows, LANES), jnp.float32),
             grid_spec=grid_spec,
             interpret=interpret,
         )(starts, ends, tree2d)
@@ -101,7 +110,7 @@ def _build(n_leaves: int, n_windows: int, combine: Callable,
 def pad_tree_rows(tree, neutral: float):
     """Pad a [2n] heap tree to a LANES multiple and reshape to the
     (rows, LANES) layout the kernel expects.  jnp-traceable."""
-    import jax.numpy as jnp
+    _, jnp = jax_modules()
     tree = jnp.asarray(tree, jnp.float32)
     two_n = tree.shape[0]
     if two_n % LANES:
@@ -119,15 +128,13 @@ def flatfat_query_ranges(tree, starts, ends, combine: Callable,
     ``combine`` must be a jax-traceable binary fn forming a monoid with
     ``neutral``; starts/ends index the leaf axis.  Returns float32 [B].
     """
-    import jax
-    import jax.numpy as jnp
-
+    _, jnp = jax_modules()
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = interpret_off_tpu()
     tree = jnp.asarray(tree, jnp.float32)
     n_leaves = tree.shape[0] // 2
-    B = len(starts)
-    run = _build(n_leaves, B, combine, float(neutral), bool(interpret))
-    out = run(jnp.asarray(starts, jnp.int32), jnp.asarray(ends, jnp.int32),
-              pad_tree_rows(tree, neutral))
-    return np.asarray(out)[:B, 0]
+    se = pad_extents(starts, ends)
+    run = _build(n_leaves, se.shape[1], combine, float(neutral),
+                 bool(interpret))
+    out = run(se[0], se[1], pad_tree_rows(tree, neutral))
+    return np.asarray(out)[:len(starts), 0]

@@ -25,8 +25,9 @@ def make_mesh(n_devices: Optional[int] = None,
     ``win_axis`` chips cooperate on each window (psum over 'win'); the
     remaining devices shard the key space.
     """
-    import jax
     import numpy as np
+    from ..ops.backend import jax_modules
+    jax, _ = jax_modules()
     from jax.sharding import Mesh
 
     devices = jax.devices()
@@ -55,7 +56,8 @@ def make_multihost_mesh(win_axis: int = 1,
     Multi-host runs require ``jax.distributed.initialize()`` first (one
     process per host, standard JAX multi-host bootstrap).
     """
-    import jax
+    from ..ops.backend import jax_modules
+    jax, _ = jax_modules()
 
     n_procs = jax.process_count()
     if n_procs == 1:

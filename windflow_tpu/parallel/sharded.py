@@ -28,6 +28,8 @@ from typing import Any, Dict
 
 import numpy as np
 
+from ..ops.backend import jax_modules
+
 
 @functools.lru_cache(maxsize=None)
 def _sharded_programs(mesh_id: int, win_len: int, slide_len: int):
@@ -39,8 +41,7 @@ def _sharded_programs(mesh_id: int, win_len: int, slide_len: int):
       2. psum-combined striped window sums   [B2]             (WMR path)
       3. pane partials + gathered window combine              (PF path)
     """
-    import jax
-    import jax.numpy as jnp
+    jax, jnp = jax_modules()
     from jax.sharding import PartitionSpec as P
 
     def shard_map(f, mesh, in_specs, out_specs):
@@ -137,10 +138,10 @@ def _resolve_kind(kind):
                 "('ffat', lift, combine, neutral)")
         return "ffat", combine, float(neutral), lift
     if kind == "max":
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         return "max", jnp.maximum, float("-inf"), None
     if kind == "min":
-        import jax.numpy as jnp
+        _, jnp = jax_modules()
         return "min", jnp.minimum, float("inf"), None
     if kind in ("sum", "count", "mean"):
         return kind, None, 0.0, None
@@ -198,8 +199,7 @@ class ShardedWindowEngine:
         ('key', 'win') on axis 0/1.  Returns [K, W_shards * P_loc // spp]
         window sums, 'key'-sharded, windows in global time order.
         """
-        import jax
-        import jax.numpy as jnp
+        jax, jnp = jax_modules()
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         wpp = max(1, self.win_len // pane_len)    # panes per window
@@ -284,14 +284,13 @@ class ShardedWindowEngine:
         pmax / pmin for the builtins, or an all_gather + log-depth
         pairwise combine for a custom FFAT fold.  Returns [K_rows, B]
         full window results."""
-        import jax
+        jax, jnp = jax_modules()
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if self.kind == "mean":
             raise ValueError("WinMapReduceMesh does not support 'mean' "
                              "(stripe partials carry no count channel)")
         if not hasattr(self, "_wmr_only"):
-            import jax.numpy as jnp
             kind, comb, neutral = self.kind, self.combine, self.neutral
 
             def wmr_shard(stripe):
@@ -322,11 +321,10 @@ class ShardedWindowEngine:
         used by operators.tpu.mesh_farm).  ``values`` is [K_shards, T]
         (T a power of two), extents are [K_shards, B]; everything
         sharded over 'key'."""
-        import jax
+        jax, jnp = jax_modules()
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if not hasattr(self, "_kf_only"):
-            import jax.numpy as jnp
             kind, comb, neutral = self.kind, self.combine, self.neutral
 
             def kf_shard(v, s, e):
@@ -363,7 +361,7 @@ class ShardedWindowEngine:
                        stripe_w: int = 8, panes_per_shard: int = 4,
                        pane_len: int = 4):
         """Tiny correctly-sharded inputs for compile checks/dry runs."""
-        import jax
+        jax, _ = jax_modules()
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         K = self.n_key_shards

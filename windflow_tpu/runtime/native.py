@@ -1,9 +1,9 @@
 """ctypes bindings to the native C++ host runtime (native/windflow_native.cpp).
 
 Builds the shared library on first use with g++ (no pip/pybind11
-dependency), caches it next to the sources, and degrades gracefully to
-the pure-Python plane when a toolchain is unavailable
-(RuntimeConfig.use_native_runtime gates usage).
+dependency), caches it next to the sources under a content stamp, and
+degrades to the pure-Python plane -- saying so on stderr -- when the
+toolchain is unavailable (RuntimeConfig.use_native_runtime gates usage).
 
 Object hand-off across the native channel: the producer increfs the
 Python object and passes its address; the consumer rebuilds the object
@@ -13,8 +13,10 @@ released (ctypes drops it around foreign calls).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Any, Optional
 
@@ -34,13 +36,46 @@ _SO = os.path.join(_NATIVE_DIR, "libwindflow_native.so")
 # add separately; FMA contraction in the lowered planes would differ
 # by 1 ULP at exact filter thresholds (lowering must never change
 # results)
-_CMD = ["g++", "-O3", "-march=native", "-ffp-contract=off",
-        "-std=c++17", "-shared", "-fPIC", "-pthread", *_SRCS,
-        "-o", _SO]
-_STAMP = _SO + ".cmd"
+_FLAGS = ["-O3", "-march=native", "-ffp-contract=off", "-std=c++17",
+          "-shared", "-fPIC", "-pthread"]
+_STAMP = _SO + ".stamp"
+
+# "built" | "reused" once this process has a library, else None
+build_state: Optional[str] = None
+
+
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolved against: the first processor
+    block of /proc/cpuinfo without its per-core and clock lines.  A
+    library copied from a host with another CPU must not be loaded."""
+    volatile = ("processor", "cpu MHz", "bogomips", "BogoMIPS", "core id",
+                "apicid", "initial apicid", "physical id")
+    try:
+        with open("/proc/cpuinfo") as f:
+            block = f.read().split("\n\n", 1)[0]
+    except OSError:
+        import platform
+        return f"{platform.machine()} {platform.processor()}"
+    return "\n".join(ln for ln in block.splitlines()
+                     if ln.split(":")[0].strip() not in volatile)
+
+
+def _build_stamp() -> str:
+    """Hash of everything the library is a function of: the three
+    sources' contents, the flags, the host CPU.  No mtimes and no
+    paths, so a checkout copied elsewhere keeps its library and a
+    library copied from elsewhere does not pass for this host's."""
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_cpu_identity().encode())
+    return h.hexdigest()
 
 
 def _build() -> Optional[str]:
+    global build_state
     # fault-injection hook (resilience/faults.py): tests force the
     # toolchain probe to fail to exercise the pure-Python fallback
     from ..resilience.faults import native_build_forced_to_fail
@@ -48,23 +83,34 @@ def _build() -> Optional[str]:
         return None
     if os.environ.get("WINDFLOW_NATIVE", "1") == "0":
         return None  # CI pure-Python job: skip the toolchain entirely
-    cmd_str = " ".join(_CMD)
-    fresh = os.path.exists(_SO) and all(
-        os.path.getmtime(_SO) >= os.path.getmtime(src) for src in _SRCS)
+    stamp = _build_stamp()
     try:
         with open(_STAMP) as f:
-            same_cmd = f.read() == cmd_str
+            fresh = os.path.exists(_SO) and f.read() == stamp
     except OSError:
-        same_cmd = False
-    if fresh and same_cmd:
+        fresh = False
+    if fresh:
+        build_state = "reused"
         return _SO
+    # build beside the target and rename: processes that start together
+    # (fleet workers) must never load a half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
-        subprocess.run(_CMD, check=True, capture_output=True, timeout=180)
+        subprocess.run(["g++", *_FLAGS, *_SRCS, "-o", tmp], check=True,
+                       capture_output=True, timeout=180)
+        os.replace(tmp, _SO)
         with open(_STAMP, "w") as f:
-            f.write(cmd_str)
-        return _SO
-    except (OSError, subprocess.SubprocessError):
+            f.write(stamp)
+    except (OSError, subprocess.SubprocessError) as e:
+        # the pure-Python plane is a supported mode, but never a silent
+        # one (get_lib caches the outcome: once per process)
+        detail = getattr(e, "stderr", b"") or b""
+        print(f"[windflow] native library not built ({e}); running the "
+              f"pure-Python plane\n{detail.decode(errors='replace')[-2000:]}",
+              file=sys.stderr)
         return None
+    build_state = "built"
+    return _SO
 
 
 def get_lib():

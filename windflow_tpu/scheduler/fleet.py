@@ -44,6 +44,30 @@ FRAME_MAX_BYTES = 1 << 26
 _TERMINAL = ("COMPLETED", "STOPPED", "FAILED")
 
 
+def visible_chips(env) -> Optional[List[str]]:
+    """Ids of the TPU chips a child started with ``env`` could open,
+    found without touching JAX (a parent that initializes JAX holds
+    the chips, and its children then fail or hang).  None when ``env``
+    pins JAX to the CPU backend, which any number of processes share."""
+    if env.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        return None
+    if env.get("TPU_VISIBLE_CHIPS"):
+        return [c for c in env["TPU_VISIBLE_CHIPS"].split(",") if c]
+    import glob
+    nodes = glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*")
+    return [str(i) for i in range(len(nodes))]
+
+
+def chip_env(env, chip: str, wid: int) -> dict:
+    """``env`` narrowed to one chip: libtpu opens only ``chip`` and
+    treats the process as a one-chip host of its own."""
+    return dict(env, TPU_VISIBLE_CHIPS=chip,
+                TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_BOUNDS="1,1,1",
+                TPU_MESH_CONTROLLER_ADDRESS=f"localhost:{8476 + wid}",
+                TPU_MESH_CONTROLLER_PORT=str(8476 + wid))
+
+
 def send_frame(sock, doc: dict) -> None:
     payload = json.dumps(doc).encode()
     sock.sendall(FRAME_HEADER.pack(len(payload)) + payload)
@@ -125,6 +149,17 @@ class FleetServer:
                  python: Optional[str] = None) -> None:
         if workers < 1:
             raise ValueError("FleetServer needs at least one worker")
+        # one process per chip: a worker that hosts device lanes gets a
+        # chip of its own through its environment, or the fleet
+        # refuses; a worker without device lanes is held to the CPU
+        # backend, so it can never open a chip another process owns
+        chips = visible_chips(os.environ) if device_lanes > 0 else None
+        if chips is not None and workers > len(chips):
+            raise SchedulerError(
+                f"{workers} workers with device lanes but "
+                f"{len(chips)} visible TPU chip(s): a chip belongs to "
+                "one process", hint="start at most one device worker "
+                "per chip, or workers with device_lanes=0")
         from ..distributed.observe import ClusterObserver
         from ..distributed.runtime import free_ports
         from ..telemetry import FlightRecorder
@@ -149,7 +184,13 @@ class FleetServer:
                     "--observer",
                     f"{self.observer.host}:{self.observer.port}",
                     "--push-interval", str(push_interval_s)]
-            proc = subprocess.Popen(argv, cwd=os.getcwd())
+            if device_lanes <= 0:
+                env = dict(os.environ, JAX_PLATFORMS="cpu")
+            elif chips is not None:
+                env = chip_env(os.environ, chips[wid], wid)
+            else:
+                env = None
+            proc = subprocess.Popen(argv, cwd=os.getcwd(), env=env)
             self._workers[wid] = _Worker(wid, ports[wid], proc)
         try:
             self._connect_all(spawn_timeout_s)

@@ -25,11 +25,8 @@ _impl = None  # resolved on first launch_span call
 def _resolve():
     if os.environ.get("WINDFLOW_JAX_PROFILE", "0") == "0":
         return lambda label: nullcontext()
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation
-    except ImportError:
-        return lambda label: nullcontext()
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
 
 def launch_span(label: str):
