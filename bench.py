@@ -436,8 +436,7 @@ def run_planner_feed(n_events, feeders=2, placement="auto",
             if r["Device_launches"]:
                 dev = {"launches": r["Device_launches"],
                        "device_time_ms": r["Device_time_ms"],
-                       "bytes_per_launch": r.get("Device_bytes_per_launch"),
-                       "roofline_frac": r.get("Device_roofline_frac")}
+                       "bytes_per_launch": r.get("Device_bytes_per_launch")}
     logic = find_logic(g, lambda lg: isinstance(lg, WinSeqTPULogic))
     if logic is not None:
         dev["final_batch_len"] = logic.batch_len

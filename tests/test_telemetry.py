@@ -426,8 +426,9 @@ def test_adaptive_resize_records_flight_event():
             return True
 
     for _ in range(2):  # launches near the floor -> x2 after patience
-        lg._finish((_Handle(), [], time.perf_counter(),
-                    time.perf_counter(), 1, 0), lambda x: None)
+        t_sub = time.perf_counter()
+        lg._finish((_Handle(), [], t_sub, t_sub, 1, 0,
+                    lg._launches.open(0, 0, t_sub)), lambda x: None)
     assert lg.batch_len == 512
     assert any(e["kind"] == "batch_resize" and e["new_len"] == 512
                for e in lg.flight.snapshot())
@@ -800,10 +801,3 @@ def test_to_json_safe_under_concurrent_trace_closures():
     finally:
         stop.set()
         t.join(timeout=5)
-
-
-def test_launch_span_default_noop():
-    from windflow_tpu.telemetry.profiler import launch_span, reset
-    reset()
-    with launch_span("windflow/test"):
-        pass  # default: null context, no jax import

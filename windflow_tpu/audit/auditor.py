@@ -135,18 +135,26 @@ class GraphAuditor(threading.Thread):
 
     # -- audit passes --------------------------------------------------
     def run(self) -> None:
+        # every pass is a span on this thread's track (telemetry/
+        # spans.py): what names the auditor when a slow_span asks what
+        # the graph's other threads had open
+        from ..telemetry import spans
+        tr = spans.bind(self.graph.flight.spans)
         while not self._stop_evt.wait(self.interval_s):
             g = self.graph
             if g._ended or g._cancel.cancelled:
-                return
+                break
             pause = g._pause_ctl
             if pause is not None and pause.pausing:
                 continue  # checkpoint/rescale barrier: books are moving
+            tr.begin("wf/audit/pass")
             try:
                 self.audit_once()
             except Exception:  # pragma: no cover - never kill the graph
                 import traceback
                 traceback.print_exc()
+            finally:
+                tr.end()
 
     def audit_once(self) -> None:
         """One full pass: ledger, frontiers, census, publication."""

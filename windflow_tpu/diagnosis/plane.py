@@ -99,11 +99,18 @@ class DiagnosisPlane:
             if not force and now - self._last_tick < self.interval_s:
                 return False
             self._last_tick = now
+            # a span on whichever thread the tick rides (the auditor's,
+            # the monitor's, an explain() caller's): telemetry/spans.py
+            from ..telemetry import spans
+            tr = spans.track()
+            tr.begin("wf/diagnosis/tick")
             try:
                 self._tick(now)
             except Exception:  # pragma: no cover - diagnosis must
                 import traceback  # never take the graph down
                 traceback.print_exc()
+            finally:
+                tr.end()
         return True
 
     def _rtt_floor_ms(self) -> Optional[float]:

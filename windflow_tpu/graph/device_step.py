@@ -152,8 +152,8 @@ class DeviceStepLogic(FusedLogic):
             self._flush_boundary()
 
         try:
-            for k, seg in enumerate(self.segments):
-                seg.logic.eos_flush(step_exit if k == 0
+            for k in range(len(self.segments)):
+                self._flush_segment(k, step_exit if k == 0
                                     else self._exits[k])
         except _FusedDownstreamError as w:
             raise w.error

@@ -247,6 +247,11 @@ class PipeGraph:
                     f"MultiPipe {p.name} has no sink; terminate every "
                     "branch before run()")
         self._started = True
+        # span layer (telemetry/spans.py): a fresh registry entry, one
+        # of the same name from an earlier run is dropped; always on
+        from ..telemetry import spans
+        self.flight.spans = self.stats.span_graph = spans.start_graph(
+            self.name, self.flight)
         if self.config.tracing:
             from ..monitoring.monitor import MonitoringThread
             self._monitor = MonitoringThread(self)
@@ -559,6 +564,8 @@ class PipeGraph:
 
     def wait_end(self) -> None:
         errors, stuck = self._join_all()
+        from ..telemetry import spans
+        spans.end_graph(self.flight.spans)  # kept for readers, may age out
         if self._supervisor is not None:
             # a heal in flight holds the sources paused, so _join_all
             # cannot return mid-heal; stopping here just retires the

@@ -17,6 +17,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from ..telemetry import spans
 from ..telemetry.histogram import LogHistogram
 
 # Stats-JSON schema version (the top-level ``Schema_version`` field).
@@ -48,10 +49,15 @@ from ..telemetry.histogram import LogHistogram
 # plane is on) and replica records may carry Sched_wait_s (seconds a
 # consume loop spent gated by the fair-share lease; emitted only when
 # nonzero).
+# 12 = adds the optional Spans block (the span layer,
+# telemetry/spans.py: seconds and shares busy / idle / blocked per
+# operator replica and thread, launch stages per window operator);
+# the replica records' roofline fraction is gone (it divided bytes by a
+# host wall under a device's name).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 11
+SCHEMA_VERSION = 12
 
 
 @dataclass
@@ -83,11 +89,13 @@ class StatsRecord:
     num_launches: int = 0
     bytes_to_device: int = 0
     bytes_from_device: int = 0
-    # per-launch device timing (docs/PLANNER.md): cumulative wall time
-    # from program submit to result-on-host, summed over launches by
-    # the engine's dispatcher.  With the launch RTT floor this splits
-    # a launch's fixed cost from its compute: est. fixed = launches x
-    # floor, est. compute = the rest.
+    # per-launch timing (docs/PLANNER.md): a HOST WALL, not device
+    # time -- from the dispatcher picking a launch up to its result on
+    # the host (dispatch + ready wait + block of the span layer's launch
+    # record, telemetry/spans.py), summed over launches.  JAX gives the
+    # host no device timestamp outside a profiler session.  With the
+    # launch RTT floor this splits a launch's fixed cost from its
+    # compute: est. fixed = launches x floor, est. compute = the rest.
     device_time_ms: float = 0.0
     # resident-lane gauge (docs/PLANNER.md "Resident state"): bytes of
     # per-key window state living in device memory ACROSS launches
@@ -206,24 +214,12 @@ class StatsRecord:
         if self.join_state_keys:
             d["Join_state_keys"] = self.join_state_keys
         if self.num_launches:
-            # per-launch derivations + the roofline estimate: achieved
-            # bytes/s over the launch wall time as a fraction of the
-            # device's peak HBM bandwidth (ops/backend.HBM_PEAK_GBPS,
-            # keyed by device_kind; omitted for a device not listed).
-            # An estimate -- wall time includes the host<->device copy,
-            # so this UNDERSTATES the on-chip HBM fraction and is honest
-            # as a lower bound
+            # per-launch derivations (host wall and bytes a launch)
             d["Device_ms_per_launch"] = round(
                 self.device_time_ms / self.num_launches, 3)
             d["Device_bytes_per_launch"] = int(
                 (self.bytes_to_device + self.bytes_from_device)
                 / self.num_launches)
-            from ..ops.backend import hbm_peak_gbps
-            peak = hbm_peak_gbps()
-            if self.device_time_ms > 0 and peak:
-                achieved = (self.bytes_to_device + self.bytes_from_device) \
-                    / (self.device_time_ms / 1e3) / 1e9
-                d["Device_roofline_frac"] = round(achieved / peak, 4)
         if self.ingest_batch_size:     # ingest source replicas only
             d["Ingest_credits"] = self.credits_available
             d["Ingest_queue_depth"] = self.ingest_queue_depth
@@ -319,6 +315,9 @@ class GraphStats:
         # scheduler"): which worker hosts this tenant, its fair-share
         # weight, its device leases; None when the plane is off
         self.scheduler: Optional[dict] = None
+        # span layer (telemetry/spans.py): this graph's entry in the
+        # span registry, set at PipeGraph.start
+        self.span_graph = None
 
     def register(self, operator_name: str, replica_id: str) -> StatsRecord:
         rec = StatsRecord(operator_name, replica_id)
@@ -561,6 +560,12 @@ class GraphStats:
             # "Global scheduler"): hosting worker, fair-share weight,
             # device leases; None when the plane is off
             "Scheduler": scheduler,
+            # span layer (telemetry/spans.py; docs/OBSERVABILITY.md
+            # "Spans"): per operator replica and thread seconds and
+            # shares busy / idle / blocked since start and over the last
+            # ten seconds, per window operator the mean and longest of
+            # each launch stage; None before the graph has started
+            "Spans": spans.report(self.span_graph),
             "Memory_usage_KB": get_mem_usage_kb(),
             "Operator_number": len(ops),
             "Operators": ops,

@@ -16,6 +16,7 @@ key_farm_gpu.hpp.
 """
 from __future__ import annotations
 
+import time as _time
 from typing import Any, Dict, List
 
 import numpy as np
@@ -25,6 +26,7 @@ from ...core.tuples import BasicRecord, TupleBatch
 from ...core import win_assign as wa
 from ...runtime.emitters import StandardEmitter
 from ...runtime.node import EOSMarker, NodeLogic
+from ...telemetry import spans
 from ..base import Operator, StageSpec
 
 
@@ -56,6 +58,14 @@ class KeyFarmMeshLogic(NodeLogic):
         self.keys: Dict[Any, _ShardKeyState] = {}
         self.ready: List = []  # (key, gwid, start, end)
         self.launched_batches = 0
+        self._launches = spans.LaunchRing("key_farm_mesh")
+
+    def svc_init(self) -> None:
+        # the launch ring of the span layer (telemetry/spans.py), filed
+        # under the graph once the runtime has named the replica
+        sg = getattr(self.flight, "spans", None)
+        if sg is not None:
+            self._launches = sg.ring(self.span_op or "key_farm_mesh")
 
     def _ingest_key(self, key, ids, vals):
         st = self.keys.get(key)
@@ -190,7 +200,17 @@ class KeyFarmMeshLogic(NodeLogic):
             starts[sh, slot] = base + np.searchsorted(ids, s_key, "left")
             ends[sh, slot] = base + np.searchsorted(ids, e_key, "left")
             placement.append((key, lwid, sh, slot))
-        out = np.asarray(self.engine.compute_kf(values, starts, ends))
+        # the launch is synchronous here: submitted, picked up and
+        # dispatched on this thread, block() entered without a ready()
+        rec = self._launches.open(
+            0, values.nbytes + starts.nbytes + ends.nbytes,
+            _time.perf_counter())
+        rec.t_picked = rec.t_submitted
+        handle = self.engine.compute_kf(values, starts, ends)
+        rec.t_dispatched = rec.t_ready_seen = _time.perf_counter()
+        out = np.asarray(handle)
+        rec.t_on_host = _time.perf_counter()
+        rec.bytes_out = out.nbytes
         self.launched_batches += 1
         if self.emit_batches:
             n = len(placement)
@@ -206,6 +226,7 @@ class KeyFarmMeshLogic(NodeLogic):
             for key, lwid, sh, slot in placement:
                 r = BasicRecord(key, lwid, 0, float(out[sh, slot]))
                 emit(r)
+        rec.t_emitted = _time.perf_counter()
         self._evict_consumed(involved)
 
     def eos_flush(self, emit):

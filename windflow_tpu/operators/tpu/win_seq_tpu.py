@@ -35,7 +35,7 @@ from ...core import win_assign as wa
 from ...ops.window_compute import WindowComputeEngine
 from ...runtime.emitters import StandardEmitter
 from ...runtime.node import EOSMarker, NodeLogic
-from ...telemetry.profiler import launch_span
+from ...telemetry import spans
 from ..base import Operator, StageSpec
 
 DEFAULT_BATCH_LEN = 256
@@ -148,7 +148,7 @@ class _AsyncDispatcher:
         self.error: Optional[BaseException] = None
         self.aborting = False
         self.thread = _t.Thread(target=self._run, daemon=True,
-                                name="winseq-tpu-dispatch")
+                                name=f"winseq-tpu-dispatch:{logic._span_op}")
         self.thread.start()
 
     def submit(self, item) -> None:
@@ -156,15 +156,20 @@ class _AsyncDispatcher:
         # bounded put re-checking for a dead/failed dispatcher: a plain
         # blocking put could hang forever if the thread errors out while
         # the queue is full (nothing would ever drain it)
-        while True:
-            if self.error is not None:
-                raise RuntimeError("window dispatch thread failed") \
-                    from self.error
-            try:
-                self.work.put(item, timeout=0.25)
-                return
-            except _q.Full:
-                continue
+        tr = spans.track()
+        tr.begin(self.logic._n_submit_wait)
+        try:
+            while True:
+                if self.error is not None:
+                    raise RuntimeError("window dispatch thread failed") \
+                        from self.error
+                try:
+                    self.work.put(item, timeout=0.25)
+                    return
+                except _q.Full:
+                    continue
+        finally:
+            tr.end()
 
     def drain(self) -> None:
         """EOS barrier: launch everything staged, flush every handle."""
@@ -192,12 +197,24 @@ class _AsyncDispatcher:
         self.thread.join(timeout=30)
 
     def _run(self) -> None:
+        tr = spans.bind(getattr(self.logic.flight, "spans", None))
+        try:
+            self._serve(tr)
+        finally:
+            tr.close_all()
+
+    def _serve(self, tr) -> None:
         from collections import deque
         import queue as _q
         logic = self.logic
         pending = deque()
         last_emit = None
         while True:
+            # ready_wait while a launch is in flight (the poll below is
+            # how this thread learns a result is ready), work_wait with
+            # nothing to wait for but the next launch
+            tr.begin(logic._n_ready_wait if pending
+                     else logic._n_work_wait)
             try:
                 # fine-grained poll while batches are in flight: their
                 # async D2H lands mid-stream and must be emitted then,
@@ -205,38 +222,36 @@ class _AsyncDispatcher:
                 # with the launch interval)
                 item = self.work.get(timeout=0.005 if pending else 0.25)
             except _q.Empty:
+                tr.end()
                 if self.aborting:
                     return
                 while (pending and self.error is None
-                       and not self.aborting and pending[0][0].ready()):
+                       and not self.aborting and _ready(pending[0])):
                     try:
                         logic._finish(pending.popleft(), last_emit)
                     except BaseException as e:
                         self.error = e
                 continue
+            tr.end()
             if item is None:
                 break
             if self.aborting or self.error is not None:
                 continue  # failed/aborted: drain the queue, launch nothing
             (engine, cols, starts, ends, gwids, descs, birth, emit,
-             nbytes_in) = item
+             nbytes_in, rec) = item
             last_emit = emit
             try:
-                t_sub = _time.perf_counter()
-                # jax.profiler capture hook (telemetry/profiler.py):
-                # a no-op unless WINDFLOW_JAX_PROFILE=1
-                with launch_span("windflow/window_launch"):
-                    handle = engine.compute(cols, starts, ends, gwids)
-                logic.launched_batches += 1
+                handle, t_sub = logic._dispatch(
+                    tr, engine, cols, starts, ends, gwids, rec)
                 pending.append((handle, descs, birth, t_sub,
-                                len(pending) + 1, nbytes_in))
+                                len(pending) + 1, nbytes_in, rec))
                 # flush at depth (backpressure) AND any batch whose
                 # async D2H already landed -- otherwise results wait
                 # for the pipeline to fill and latency grows with
                 # inflight_depth instead of shrinking
                 while (pending and not self.aborting
                        and (len(pending) >= logic.inflight_depth
-                            or pending[0][0].ready())):
+                            or _ready(pending[0]))):
                     logic._finish(pending.popleft(), emit)
             except BaseException as e:  # surfaced on next submit / drain
                 self.error = e
@@ -245,6 +260,17 @@ class _AsyncDispatcher:
                 logic._finish(pending.popleft(), last_emit)
             except BaseException as e:
                 self.error = e
+
+
+def _ready(entry) -> bool:
+    """Whether an in-flight launch's result is ready; the first time it
+    reads true is the launch record's ``t_ready_seen``."""
+    if not entry[0].ready():
+        return False
+    rec = entry[-1]
+    if rec.t_ready_seen is None:
+        rec.t_ready_seen = _time.perf_counter()
+    return True
 
 
 class _TPUKeyState:
@@ -288,6 +314,13 @@ class WinSeqTPULogic(NodeLogic):
     # returns: the runtime must not hand this logic a buffered emit
     # (set per instance in __init__; inline dispatch is synchronous)
     sync_emit = False
+
+    @property
+    def spans_itself(self) -> bool:
+        """On the native lane every chunk is under this logic's own
+        ``fold`` span, so a FusedLogic adds no ``svc`` span round it
+        (runtime/node.py); the Python staging lanes take one."""
+        return self._native is not None
 
     def __init__(self, win_kind: Any, win_len: int, slide_len: int,
                  win_type: WinType, *, batch_len: int = DEFAULT_BATCH_LEN,
@@ -356,7 +389,7 @@ class WinSeqTPULogic(NodeLogic):
         self._dispatcher: Optional[_AsyncDispatcher] = None
         self.ignored_tuples = 0
         self.launched_batches = 0
-        self.last_launch_ms = 0.0  # newest submit->result wall (ms)
+        self.last_launch_ms = 0.0  # newest picked-up->result host wall (ms)
         # launch also when this much unshipped data is buffered, even if
         # the window batch is not full -- bounds host memory and keeps
         # device transfers pipelined (the adaptive resize analogue,
@@ -383,6 +416,13 @@ class WinSeqTPULogic(NodeLogic):
         # gauge-grade for sampled traces, like the depth gauges
         self._trace_ctx = None
         self._trace_name = "win_seq_tpu"
+        # span layer (telemetry/spans.py): the names of this operator's
+        # spans and its launch ring, re-resolved in svc_init once the
+        # runtime has named the replica; ``_chunk_seq`` counts the
+        # chunks ingested, so a launch record can say which one fired it
+        self._chunk_seq = 0
+        self._track = self._track_of = None
+        self._name_spans("win_seq_tpu", None)
         # whole-partition device step (graph/device_step.py): while a
         # chunk is traversing the fused chain the step logic holds all
         # intra-chunk launch triggers and calls flush_chunk() once at
@@ -540,9 +580,28 @@ class WinSeqTPULogic(NodeLogic):
         return (self._resident.state_bytes
                 if self._resident is not None else 0)
 
+    def _name_spans(self, op: str, graph) -> None:
+        self._span_op = op
+        for phase in ("fold", "flush", "stage", "submit_wait", "dispatch",
+                      "ready_wait", "work_wait", "block", "emit"):
+            setattr(self, "_n_" + phase, f"wf/{op}/{phase}")
+        self._launches = (graph.ring(op) if graph is not None
+                          else spans.LaunchRing(op))
+
+    def _ingest_track(self):
+        """The calling thread's span track, kept while the same thread
+        calls (a node's thread does for its life; direct feeders take
+        turns under the feed lock)."""
+        tid = _threading.get_ident()
+        if tid != self._track_of:
+            self._track, self._track_of = spans.track(), tid
+        return self._track
+
     def svc_init(self) -> None:
         if self.stats is not None and self.stats.operator_name:
             self._trace_name = self.stats.operator_name
+        self._name_spans(self.span_op or self._trace_name,
+                         getattr(self.flight, "spans", None))
         # adaptive x2 / /2 batch resize (win_seq_gpu.hpp:574-592): only
         # meaningful against a launch floor, so the device lane measures
         # one (planner-provided, else probed once per process)
@@ -566,6 +625,7 @@ class WinSeqTPULogic(NodeLogic):
                             "ts": np.asarray(ts, np.int64),
                             "value": np.asarray(vals)})
         with self._feed_lock:
+            self._chunk_seq += 1
             self._svc_batch(batch, emit)
 
     def feed_eos(self, emit) -> None:
@@ -625,12 +685,22 @@ class WinSeqTPULogic(NodeLogic):
 
     # -- batch plane -------------------------------------------------------
     def _finish(self, entry, emit) -> None:
-        """Flush one in-flight batch: block on its handle, record the
-        per-launch device time (submit -> result on host), sample the
-        window-result latency, feed the adaptive batch resize, emit."""
-        handle, descs, birth, t_sub, depth, nbytes_in = entry
-        results = handle.block()
-        now = _time.perf_counter()
+        """Flush one in-flight batch: block on its handle, add the
+        launch's host wall (picked up -> result on host: dispatch,
+        ready wait and block; no device clock is read) to
+        ``Device_time_ms``, sample the window-result latency, feed the
+        adaptive batch resize, emit.  Stamps the launch record."""
+        handle, descs, birth, t_sub, depth, nbytes_in, rec = entry
+        if rec.t_ready_seen is None:   # block() entered without a ready()
+            rec.t_ready_seen = _time.perf_counter()
+        tr = spans.track()
+        tr.begin(self._n_block)
+        try:
+            results = handle.block()
+        finally:
+            tr.end()
+        now = rec.t_on_host = _time.perf_counter()
+        rec.bytes_out = results.nbytes
         launch_ms = (now - t_sub) * 1e3
         self.last_launch_ms = launch_ms
         if len(self.latency_samples) < 100_000:
@@ -677,7 +747,28 @@ class WinSeqTPULogic(NodeLogic):
                              "bytes_out": int(results.nbytes)})
             else:
                 tr.hop(name, t_sub, now)
-        self._emit_results(results, descs, emit, trace=tr)
+        sp = spans.track()
+        sp.begin(self._n_emit)
+        try:
+            self._emit_results(results, descs, emit, trace=tr)
+        finally:
+            sp.end()
+        rec.t_emitted = _time.perf_counter()
+
+    def _dispatch(self, tr, eng, cols, starts, ends, gwids, rec):
+        """``engine.compute`` under the ``dispatch`` span, whose
+        annotation carries the launch sequence number so that a program
+        on the device line of a profiler trace can be joined to the
+        launch that caused it.  Returns (handle, t_picked)."""
+        t_sub = rec.t_picked = _time.perf_counter()
+        tr.begin(self._n_dispatch, {"launch": rec.seq})
+        try:
+            handle = eng.compute(cols, starts, ends, gwids)
+        finally:
+            tr.end()
+        rec.t_dispatched = _time.perf_counter()
+        self.launched_batches += 1
+        return handle, t_sub
 
     def _submit(self, cols, starts, ends, gwids, descs, birth, emit,
                 engine=None) -> None:
@@ -690,20 +781,20 @@ class WinSeqTPULogic(NodeLogic):
             self.stats.num_launches += 1
             self.stats.bytes_to_device += nbytes_in
             self.stats.inputs_ignored = self.ignored_tuples
+        rec = self._launches.open(self._chunk_seq, nbytes_in,
+                                  _time.perf_counter())
         if self.async_dispatch:
             if self._dispatcher is None:
                 self._dispatcher = _AsyncDispatcher(self)
             self._dispatcher.submit(
                 (eng, cols, starts, ends, gwids, descs, birth, emit,
-                 nbytes_in))
+                 nbytes_in, rec))
         else:
             self._flush_pending(emit)  # waitAndFlush of the previous
-            t_sub = _time.perf_counter()
-            with launch_span("windflow/window_launch"):
-                handle = eng.compute(cols, starts, ends, gwids)
-            self.launched_batches += 1
+            handle, t_sub = self._dispatch(spans.track(), eng, cols,
+                                           starts, ends, gwids, rec)
             self.pending.append((handle, descs, birth, t_sub,
-                                 len(self.pending) + 1, nbytes_in))
+                                 len(self.pending) + 1, nbytes_in, rec))
         self._buffered_since_launch = 0
         self._last_launch_t = _time.perf_counter()
 
@@ -713,7 +804,7 @@ class WinSeqTPULogic(NodeLogic):
         landed, or all when draining (inline-dispatch mode only)."""
         while self.pending and (drain
                                 or len(self.pending) >= self.inflight_depth
-                                or self.pending[0][0].ready()):
+                                or _ready(self.pending[0])):
             self._finish(self.pending.popleft(), emit)
 
     def _drain_all(self, emit) -> None:
@@ -857,8 +948,18 @@ class WinSeqTPULogic(NodeLogic):
         return out
 
     def _launch(self, emit) -> None:
+        """Stage the fired windows from the Python store and launch:
+        one ``stage`` span, with ``submit_wait`` its child."""
         if not self.descriptors:
             return
+        tr = spans.track()
+        tr.begin(self._n_stage)
+        try:
+            self._stage_and_submit(emit)
+        finally:
+            tr.end()
+
+    def _stage_and_submit(self, emit) -> None:
         descs = self.descriptors
         self.descriptors = []
         # group descriptors per key (preserving order)
@@ -1062,7 +1163,16 @@ class WinSeqTPULogic(NodeLogic):
     # the reference feeding batches straight from pinned staging) --------
     def _native_launch(self, emit, max_windows=None):
         """Stage ready windows from the C++ engine and launch one XLA
-        program over the pane-partial buffer."""
+        program over the pane-partial buffer: one ``flush`` span, with
+        ``submit_wait`` its child."""
+        tr = spans.track()
+        tr.begin(self._n_flush)
+        try:
+            self._flush_and_submit(emit, max_windows)
+        finally:
+            tr.end()
+
+    def _flush_and_submit(self, emit, max_windows) -> None:
         out = self._native.flush(max_windows or max(self.batch_len, 4096))
         if out is None:
             return
@@ -1097,11 +1207,15 @@ class WinSeqTPULogic(NodeLogic):
 
     def _svc_batch_native(self, batch: TupleBatch, emit):
         ids = batch.id if self.win_type == WinType.CB else batch.ts
-        ready = self._native.ingest(batch.key, ids, batch.ts,
-                                    batch["value"])
+        self._folded(self._native.ingest(batch.key, ids, batch.ts,
+                                         batch["value"]), len(batch), emit)
+
+    def _folded(self, ready: int, n: int, emit) -> None:
+        """After a native ingest of ``n`` events left ``ready`` windows:
+        the age clock, and a launch where one is due."""
         if ready and self._batch_birth is None:
             self._batch_birth = _time.perf_counter()
-        self._buffered_since_launch += len(batch)
+        self._buffered_since_launch += n
         if (ready and not self.chunk_hold
                 and (ready >= self.batch_len
                      or self._buffered_since_launch >= self.max_buffer_elems
@@ -1110,7 +1224,14 @@ class WinSeqTPULogic(NodeLogic):
 
     def _svc_batch(self, batch: TupleBatch, emit):
         if self._native is not None:
-            self._svc_batch_native(batch, emit)
+            # one ``fold`` span a chunk round the native ingest and this
+            # operator's Python about it (a launch is its child ``flush``)
+            tr = self._ingest_track()
+            tr.begin(self._n_fold)
+            try:
+                self._svc_batch_native(batch, emit)
+            finally:
+                tr.end()
             return
         keys = batch.key
         ids = batch.id if self.win_type == WinType.CB else batch.ts
@@ -1184,24 +1305,22 @@ class WinSeqTPULogic(NodeLogic):
             if tr is not None:   # crosses the dispatcher (see _finish)
                 self._trace_ctx = tr
         if isinstance(item, TupleBatch):
+            self._chunk_seq += 1
             self._svc_batch(item, emit)
             return
         if isinstance(item, SynthChunk):
+            self._chunk_seq += 1
             # declared synthetic stream: the native engine generates and
             # folds the chunk in one pass (no host column materializes)
             if self._native is not None:
-                ready = self._native.synth_ingest(
-                    item.start, item.n, item.n_keys, item.vmod,
-                    item.vscale, item.voff)
-                if ready and self._batch_birth is None:
-                    self._batch_birth = _time.perf_counter()
-                self._buffered_since_launch += item.n
-                if (ready and not self.chunk_hold
-                        and (ready >= self.batch_len
-                             or self._buffered_since_launch
-                             >= self.max_buffer_elems
-                             or self._launch_due())):
-                    self._native_launch(emit)
+                tr = self._ingest_track()
+                tr.begin(self._n_fold)
+                try:
+                    self._folded(self._native.synth_ingest(
+                        item.start, item.n, item.n_keys, item.vmod,
+                        item.vscale, item.voff), item.n, emit)
+                finally:
+                    tr.end()
             else:
                 self._svc_batch(item.materialize(), emit)
             return

@@ -19,13 +19,6 @@ from typing import Optional
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-# HBM bandwidth per chip (GB/s), keyed by ``jax.Device.device_kind``.
-# Source: Google Cloud documentation, "TPU v5e" system architecture
-# page (819 GB/s of HBM per chip).  A device that is not listed has no
-# roofline estimate -- there is no default.
-HBM_PEAK_GBPS = {"TPU v5 lite": 819.0}
-
-
 def compile_cache_dir() -> str:
     """Directory of JAX's persistent compilation cache for this
     program: ``JAX_COMPILATION_CACHE_DIR`` when the environment places
@@ -74,12 +67,3 @@ def open_tpu(min_devices: int = 1, out=None) -> dict:
     if device["count"] < min_devices:
         raise SystemExit(f"{device['count']} device(s), need {min_devices}")
     return device
-
-
-def hbm_peak_gbps() -> Optional[float]:
-    """Peak HBM bandwidth of the default device, None when the device
-    is not in the table or this process never loaded JAX."""
-    if not jax_modules.cache_info().currsize:
-        return None
-    jax, _ = jax_modules()
-    return HBM_PEAK_GBPS.get(jax.devices()[0].device_kind)

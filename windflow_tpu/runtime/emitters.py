@@ -14,6 +14,7 @@ import numpy as np
 
 from ..core.meta import default_hash
 from ..core.tuples import TupleBatch
+from ..telemetry import spans
 from .node import EOSMarker
 from .queues import Watermark
 
@@ -22,6 +23,9 @@ SendTo = Callable[[int, Any], None]
 
 class Emitter:
     n_dest: int = 1
+    # name of the span KEYBY partitioning runs under (telemetry/
+    # spans.py), set by the emitting RtNode once it knows its operator
+    span_keyby = "wf/-/keyby"
     # per-graph ColumnPool for partition sub-batches (attached by the
     # graph compile pass at start; None = allocate fresh columns)
     pool = None
@@ -78,7 +82,8 @@ class StandardEmitter(Emitter):
                     sk.offer_batch(item.key)
                 # vectorized KEYBY: partition the batch by key hash
                 dests = np.abs(item.key) % self.n_dest
-                for d, sub in partition_batch(item, dests, self.pool):
+                for d, sub in keyby_parts(item, dests, self.pool,
+                                          self.span_keyby):
                     send_to(d, sub)
         elif self.keyed:
             rec = item.record if isinstance(item, EOSMarker) else item
@@ -135,7 +140,8 @@ class StandardEmitter(Emitter):
                     if sk is not None:
                         sk.offer_batch(item.key)
                     dests = np.abs(item.key) % n
-                    for d, sub in partition_batch(item, dests, pool):
+                    for d, sub in keyby_parts(item, dests, pool,
+                                              self.span_keyby):
                         buckets.setdefault(int(d), []).append(sub)
             elif self.keyed:
                 rec = item.record if isinstance(item, EOSMarker) else item
@@ -149,6 +155,19 @@ class StandardEmitter(Emitter):
                 buckets.setdefault(d, []).append(item)
         for d, run in buckets.items():
             send_many_to(d, run)
+
+
+def keyby_parts(batch, dests, pool, name: str) -> list:
+    """:func:`partition_batch` as a list, under a ``keyby`` span of its
+    own (a child of the emitting thread's ``put_wait``), so that the
+    partitioning is told from the waiting on a full channel that
+    follows it (telemetry/spans.py)."""
+    tr = spans.track()
+    tr.begin(name)
+    try:
+        return list(partition_batch(batch, dests, pool))
+    finally:
+        tr.end()
 
 
 def partition_batch(batch, dests, pool=None):

@@ -15,11 +15,17 @@ import time as _time
 import traceback
 from typing import Any, Callable, Optional, Sequence
 
-from ..core.tuples import SynthChunk
+from ..core.tuples import SynthChunk, TupleBatch
 from ..resilience.cancel import GraphCancelled
 from ..resilience.policies import POLICY_DEAD_LETTER, POLICY_FAIL
+from ..telemetry import spans
 from ..telemetry.trace import attach_if_absent
 from .queues import Channel, CHANNEL_TIMEOUT, GET_MANY_MAX, Watermark
+
+
+# the containers a span is taken per: one clock pair a chunk, never
+# one a record (telemetry/spans.py)
+_CHUNKS = (TupleBatch, SynthChunk)
 
 
 class EOSMarker:
@@ -43,6 +49,10 @@ class NodeLogic:
     # device window engines), the graph TelemetryHub
     flight = None
     telemetry = None
+    # the operator name this logic's spans carry (telemetry/spans.py):
+    # the node's, or inside a FusedLogic the segment's; set per thread
+    # by RtNode.run / FusedLogic.svc_init
+    span_op = None
 
     # True (the default) promises every ``emit`` happens before the
     # ``svc``/``eos_flush`` call that received the callback returns.
@@ -138,6 +148,7 @@ class ChainedLogic(NodeLogic):
         # fused map chain on timed gets for nothing
         if hasattr(a, "idle_tick") or hasattr(b, "idle_tick"):
             self.idle_tick = self._idle_tick
+        self._n_svc = None   # span name, set in svc_init
 
     def _idle_tick(self, emit):
         ta = getattr(self.a, "idle_tick", None)
@@ -152,6 +163,9 @@ class ChainedLogic(NodeLogic):
         # logic only; forward it so fused stages report device metrics
         self.a.stats = self.stats
         self.b.stats = self.stats
+        self.a.span_op = self.b.span_op = self.span_op
+        self._n_svc = f"wf/{self.span_op}/svc" \
+            if isinstance(self.a, SourceLoopLogic) else None
         self.a.svc_init()
         self.b.svc_init()
 
@@ -164,6 +178,17 @@ class ChainedLogic(NodeLogic):
             if hook is not None:
                 hook(x, emit)
             emit(x)
+            return
+        if self._n_svc is not None and isinstance(x, _CHUNKS):
+            # chained onto a source: the loop's span is the source's
+            # ``body``, so what the chain does with a chunk is a child
+            # span ``svc`` of its own (telemetry/spans.py)
+            tr = spans.track()
+            tr.begin(self._n_svc)
+            try:
+                self.b.svc(x, 0, emit)
+            finally:
+                tr.end()
             return
         self.b.svc(x, 0, emit)
 
@@ -361,6 +386,15 @@ class FusedLogic(NodeLogic):
         # consume thread's in-flight context (the engine carries its
         # context across the dispatcher itself -- win_seq_tpu.py)
         inherit = getattr(seg.logic, "sync_emit", True)
+        # one span a chunk and segment (telemetry/spans.py): the first
+        # segment's time is the node's own span (a consume loop's svc, a
+        # source loop's body and its chain's svc), records are never
+        # timed singly (their time stays in the enclosing span), and a
+        # logic that takes its own spans per chunk (the window engine's
+        # fold, flush, stage) gets none round them here: on the thread
+        # that paces a graph every span costs what it evicts
+        n_svc = None if first or getattr(seg.logic, "spans_itself", False) \
+            else f"wf/{seg.name}/svc"
 
         def entry(item, cid):
             if isinstance(item, Watermark):
@@ -399,6 +433,12 @@ class FusedLogic(NodeLogic):
                     live = self._live
                     prev = getattr(live, "ctx", None)
                     live.ctx = ctx
+            tr = None
+            if n_svc is not None and isinstance(item, _CHUNKS):
+                # the calling thread's track: an async segment upstream
+                # runs this entry on its dispatcher thread
+                tr = spans.track()
+                tr.begin(n_svc)
             try:
                 svc(item, cid, exit_)
             except Exception as e:
@@ -413,6 +453,8 @@ class FusedLogic(NodeLogic):
                         and seg.dead_letters is not None:
                     seg.dead_letters.add(seg.name, item, e)
             finally:
+                if tr is not None:
+                    tr.end()
                 if ctx is not None:
                     if inherit:
                         live.ctx = prev
@@ -430,6 +472,7 @@ class FusedLogic(NodeLogic):
         for seg in self.segments:
             # device logics write launch metrics into their own record
             seg.logic.stats = seg.stats
+            seg.logic.span_op = seg.name
             seg.logic.svc_init()
 
     def svc(self, item, channel_id, emit):
@@ -462,11 +505,25 @@ class FusedLogic(NodeLogic):
         except _FusedDownstreamError as w:
             raise w.error
 
+    def _flush_segment(self, k: int, exit_) -> None:
+        """Segment ``k``'s ``eos_flush`` under its own svc span (the
+        first segment's is the source loop's or the node's)."""
+        seg = self.segments[k]
+        if not k:
+            seg.logic.eos_flush(exit_)
+            return
+        tr = spans.track()
+        tr.begin(f"wf/{seg.name}/svc")
+        try:
+            seg.logic.eos_flush(exit_)
+        finally:
+            tr.end()
+
     def eos_flush(self, emit):
         self._emit_out = emit
         try:
-            for k, seg in enumerate(self.segments):
-                seg.logic.eos_flush(self._exits[k])
+            for k in range(len(self.segments)):
+                self._flush_segment(k, self._exits[k])
         except _FusedDownstreamError as w:
             raise w.error
 
@@ -787,6 +844,12 @@ class RtNode(threading.Thread):
         self.watermarks_out = 0
         self._accepts_chunks = False  # resolved per thread (durable path)
         self._sync_emit = True
+        # span names (telemetry/spans.py), resolved per thread in run():
+        # a fused node waits and works under its first segment's name
+        # and puts under its last one's
+        self._n_get = f"wf/{name}/get_wait"
+        self._n_svc = f"wf/{name}/svc"
+        self._n_put = f"wf/{name}/put_wait"
 
     def bind_outlet_faults(self) -> None:
         """Propagate put-level fault state (FaultPlan drop_put /
@@ -824,6 +887,18 @@ class RtNode(threading.Thread):
             self.stats.outputs_sent += 1
         if self.faults is not None:
             self.faults.before_put()
+        if isinstance(item, _CHUNKS):
+            # one put_wait span a chunk, on the emitting thread's track
+            # (an async logic emits from its dispatcher thread); a
+            # record's put stays inside the span that covers its batch
+            tr = spans.track()
+            tr.begin(self._n_put)
+            try:
+                for o in self.outlets:
+                    o.send(item)
+            finally:
+                tr.end()
+            return
         for o in self.outlets:
             o.send(item)
 
@@ -875,8 +950,13 @@ class RtNode(threading.Thread):
             return
         if self.stats is not None:
             self.stats.outputs_sent += len(buf)
-        for o in self.outlets:
-            o.send_many(buf)
+        tr = spans.track()
+        tr.begin(self._n_put)        # one span a buffered run
+        try:
+            for o in self.outlets:
+                o.send_many(buf)
+        finally:
+            tr.end()
 
     def _svc_batch(self, got, accepts_chunks: bool, faults, pool) -> None:
         """Process one get_many batch with buffered emissions: outputs
@@ -1025,10 +1105,7 @@ class RtNode(threading.Thread):
         # logics with an idle_tick hook (time-bounded device launches on
         # stalled streams) take timed gets so the tick fires without input
         tick = getattr(self.logic, "idle_tick", None)
-        accepts_chunks = getattr(self.logic, "accepts_synth_chunks", False)
-        faults = self.faults
         channel = self.channel
-        pool = self.pool
         get_many = getattr(channel, "get_many", None)
         # buffered emissions require the logic's emits to happen inside
         # the svc call (sync_emit); the async window engines opt out.
@@ -1036,94 +1113,114 @@ class RtNode(threading.Thread):
         # (fence results, forward the barrier) in stream order, which
         # buffered emission runs would reorder around the barrier.
         sync_emit = getattr(self.logic, "sync_emit", True)
-        aligner = self.epochs
-        buffered = get_many is not None and sync_emit and aligner is None
-        tele = self.telemetry
+        buffered = get_many is not None and sync_emit \
+            and self.epochs is None
         # event-time hook resolved once per thread (None on logics
         # without it -- watermarks then just merge-and-forward)
         self._wm_hook = getattr(self.logic, "on_watermark", None)
-        self._accepts_chunks = accepts_chunks
+        self._accepts_chunks = getattr(self.logic, "accepts_synth_chunks",
+                                       False)
         self._sync_emit = sync_emit
-        # fair-share gate resolved once per thread: a lease-less graph
-        # (the default) pays a single None check per batch
-        lease = self.sched_lease
-        stats = self.stats
         timeout = 0.025 if tick else None
+        # the triad (telemetry/spans.py): get_wait inside the channel
+        # get, svc round what one batch taken from the channel costs
+        # (put_wait and the logic's own spans are its children)
+        tr = spans.track()
+        n_get, n_svc = self._n_get, self._n_svc
         while True:
-            if get_many is not None:
-                got = get_many(GET_MANY_MAX, timeout)
-            else:  # duck-typed channel without the bulk surface
-                got = channel.get(timeout) if tick else channel.get()
-                if isinstance(got, tuple):
-                    got = [got]
-            if got is CHANNEL_TIMEOUT:
-                if not (self.pause_ctl is not None
-                        and self.pause_ctl.pausing):
-                    tick(self._emit)
-                continue
+            tr.begin(n_get)
+            try:
+                if get_many is not None:
+                    got = get_many(GET_MANY_MAX, timeout)
+                else:  # duck-typed channel without the bulk surface
+                    got = channel.get(timeout) if tick else channel.get()
+                    if isinstance(got, tuple):
+                        got = [got]
+            finally:
+                tr.end()
             if got is None:
                 break
-            if lease is not None:
-                # weighted fair share across co-resident tenants:
-                # charge the batch, block while over-share (solo
-                # tenants never wait -- scheduler/leases.py)
-                waited = lease.acquire(len(got))
-                if waited and stats is not None:
-                    stats.sched_wait_s += waited
-            if buffered and len(got) > 1:
-                self._svc_batch(got, accepts_chunks, faults, pool)
-                continue
-            if aligner is not None:
-                # durable dispatch: barriers route to the aligner
-                # (alignment, epoch cut, holdback replay); everything
-                # else takes the factored per-item body
-                process = self._process_one
-                for cid, item in got:
-                    if not aligner.offer(cid, item, process):
-                        process(cid, item)
-                continue
+            tr.begin(n_svc)
+            try:
+                self._serve(got, tick, buffered)
+            finally:
+                tr.end()
+
+    def _serve(self, got, tick, buffered: bool) -> None:
+        """One batch taken from the channel (or an idle tick), inside
+        the consume loop's svc span."""
+        lease, aligner, faults = self.sched_lease, self.epochs, self.faults
+        accepts_chunks, sync_emit = self._accepts_chunks, self._sync_emit
+        pool, tele = self.pool, self.telemetry
+        if got is CHANNEL_TIMEOUT:
+            if not (self.pause_ctl is not None
+                    and self.pause_ctl.pausing):
+                tick(self._emit)
+            return
+        if lease is not None:
+            # weighted fair share across co-resident tenants:
+            # charge the batch, block while over-share (solo
+            # tenants never wait -- scheduler/leases.py)
+            waited = lease.acquire(len(got))
+            if waited and self.stats is not None:
+                self.stats.sched_wait_s += waited
+        if buffered and len(got) > 1:
+            self._svc_batch(got, accepts_chunks, faults, pool)
+            return
+        if aligner is not None:
+            # durable dispatch: barriers route to the aligner
+            # (alignment, epoch cut, holdback replay); everything
+            # else takes the factored per-item body
+            process = self._process_one
             for cid, item in got:
-                if isinstance(item, Watermark):
-                    self._handle_watermark(cid, item, self._emit)
-                    continue
-                if not accepts_chunks and isinstance(item, SynthChunk):
-                    item = item.materialize(pool)  # plane boundary
-                self.taken += 1
-                if faults is not None:
-                    faults.on_tuple(self.taken)  # may raise InjectedFailure
-                ctx = None if tele is None else getattr(item, "trace",
-                                                        None)
+                if not aligner.offer(cid, item, process):
+                    process(cid, item)
+            return
+        for cid, item in got:
+            if isinstance(item, Watermark):
+                self._handle_watermark(cid, item, self._emit)
+                continue
+            if not accepts_chunks and isinstance(item, SynthChunk):
+                item = item.materialize(pool)  # plane boundary
+            self.taken += 1
+            if faults is not None:
+                faults.on_tuple(self.taken)  # may raise InjectedFailure
+            ctx = None if tele is None else getattr(item, "trace",
+                                                    None)
+            if ctx is not None:
+                t_in = _time.perf_counter()
+                rec = self._hop_rec
+                if rec is not None and rec.residency_hist is not None:
+                    rec.residency_hist.observe(
+                        (t_in - ctx.last) * 1e6)
+                if sync_emit:
+                    # same-thread inheritance only: an async-
+                    # emitting logic's dispatcher thread calls
+                    # _emit concurrently and must not pick up the
+                    # consume thread's in-flight context (the
+                    # engine carries its own across the dispatcher)
+                    self._live_trace = ctx
+            try:
+                self._svc_guarded(item, cid)
+            finally:
+                # count failed tuples as done too: the quiesce
+                # barrier's in-flight detection must not see a
+                # skipped tuple as forever in flight
+                self.done += 1
                 if ctx is not None:
-                    t_in = _time.perf_counter()
-                    rec = self._hop_rec
-                    if rec is not None and rec.residency_hist is not None:
-                        rec.residency_hist.observe(
-                            (t_in - ctx.last) * 1e6)
-                    if sync_emit:
-                        # same-thread inheritance only: an async-
-                        # emitting logic's dispatcher thread calls
-                        # _emit concurrently and must not pick up the
-                        # consume thread's in-flight context (the
-                        # engine carries its own across the dispatcher)
-                        self._live_trace = ctx
-                try:
-                    self._svc_guarded(item, cid)
-                finally:
-                    # count failed tuples as done too: the quiesce
-                    # barrier's in-flight detection must not see a
-                    # skipped tuple as forever in flight
-                    self.done += 1
-                    if ctx is not None:
-                        self._live_trace = None
-                        t_done = _time.perf_counter()
-                        if not self._fused:
-                            # fused nodes stamp per-SEGMENT hops inline
-                            # and close traces in their last segment
-                            ctx.hop(self.name, t_in, t_done)
-                            if self._terminal:
-                                tele.close(ctx, self._e2e_rec, t_done)
+                    self._live_trace = None
+                    t_done = _time.perf_counter()
+                    if not self._fused:
+                        # fused nodes stamp per-SEGMENT hops inline
+                        # and close traces in their last segment
+                        ctx.hop(self.name, t_in, t_done)
+                        if self._terminal:
+                            tele.close(ctx, self._e2e_rec, t_done)
 
     def run(self) -> None:
+        # this thread's span track, filed under the graph's entry (the
+        # FlightRecorder every node holds carries it)
+        tr = spans.bind(getattr(self.flight, "spans", None))
         try:
             # logics that track device metrics (launches, staged bytes)
             # write them into the replica's record directly
@@ -1137,8 +1234,22 @@ class RtNode(threading.Thread):
                 # own entries -- the consume loops must NOT observe too
                 # (it would double-count every traced arrival)
                 self._hop_rec = self._e2e_rec = None
+                op_in = self.logic.segments[0].name
+                op_out = self.logic.segments[-1].name
             else:
                 self._hop_rec = self._e2e_rec = self.stats
+                op_in = op_out = self.name
+            self._n_get = f"wf/{op_in}/get_wait"
+            self._n_svc = f"wf/{op_in}/svc"
+            self._n_put = f"wf/{op_out}/put_wait"
+            self.logic.span_op = op_in
+            for o in self.outlets:
+                # a TreeEmitter partitions in its root or its children
+                em = o.emitter
+                for e in (em, getattr(em, "root", None),
+                          *getattr(em, "children", ())):
+                    if e is not None:
+                        e.span_keyby = f"wf/{op_out}/keyby"
             self._terminal = self.telemetry is not None \
                 and not self.outlets
             self._outlet_put_faults = any(o.faults is not None
@@ -1148,7 +1259,15 @@ class RtNode(threading.Thread):
             self.logic.svc_init()
             if self.channel is not None:
                 self._consume_loop()
-            self.logic.eos_flush(self._emit)
+                tr.begin(self._n_svc)
+                try:
+                    self.logic.eos_flush(self._emit)
+                finally:
+                    tr.end()
+            else:
+                # a source: eos_flush IS the generation loop, which
+                # takes its own spans (SourceLoopLogic)
+                self.logic.eos_flush(self._emit)
             if self.epoch_coord is not None:
                 # durability plane: hand the coordinator this replica's
                 # final state (it backfills epochs this node will never
@@ -1187,6 +1306,7 @@ class RtNode(threading.Thread):
                 if self.cancel_token is not None:
                     self.cancel_token.cancel(e, origin=self.name)
         finally:
+            tr.close_all()
             if not self._supervised_handoff:
                 # svc_end BEFORE closing outlets: teardown hooks (e.g.
                 # the device dispatcher abort) must stop emitting before
@@ -1239,15 +1359,28 @@ class SourceLoopLogic(NodeLogic):
         raise RuntimeError("source has no inputs")
 
     def eos_flush(self, emit):
-        while True:
-            tok = self.cancel_token
-            if tok is not None and tok.cancelled:
-                raise GraphCancelled("source cancelled")
-            inj = self.epoch_injector
-            if inj is not None:
-                inj.maybe_inject()
-            ctl = self.pause_control
-            if ctl is not None:
-                ctl.gate()
-            if not self.step(emit):
-                break
+        # ONE span round the whole loop, the source's ``body``
+        # (telemetry/spans.py): its self time is the user's function
+        # and the loop's own few microseconds a step, taken by
+        # subtraction; what the program does with a chunk lies under
+        # child spans (put_wait, a chain's svc, fused segments
+        # downstream).  No span a step: on the thread that paces a graph
+        # every span costs what it evicts, and a record source reads no
+        # clock per record
+        tr = spans.track()
+        tr.begin(f"wf/{self.span_op or type(self).__name__}/body")
+        try:
+            while True:
+                tok = self.cancel_token
+                if tok is not None and tok.cancelled:
+                    raise GraphCancelled("source cancelled")
+                inj = self.epoch_injector
+                if inj is not None:
+                    inj.maybe_inject()
+                ctl = self.pause_control
+                if ctl is not None:
+                    ctl.gate()
+                if not self.step(emit):
+                    break
+        finally:
+            tr.end()

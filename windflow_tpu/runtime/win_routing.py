@@ -14,7 +14,7 @@ from typing import Any, Callable, Dict, List
 from ..core.basic import Role, WinType
 from ..core.meta import default_hash
 from ..core.win_assign import wf_destinations, window_range_of
-from .emitters import Emitter, partition_batch
+from .emitters import Emitter, keyby_parts
 from .node import EOSMarker, NodeLogic
 
 
@@ -174,7 +174,8 @@ class KFEmitter(Emitter):
                     (self.routing(int(k) if k >= 0 else -int(k),
                                   self.pardegree) for k in item.key),
                     np.int64, len(item.key))
-            for d, sub in partition_batch(item, dests, self.pool):
+            for d, sub in keyby_parts(item, dests, self.pool,
+                                      self.span_keyby):
                 send_to(d, sub)
             return
         rec = item.record if isinstance(item, EOSMarker) else item

@@ -134,6 +134,21 @@ def render_openmetrics(apps: dict) -> str:
         if resident:
             out.append(f"windflow_device_state_bytes_resident"
                        f"{_labels(**lab)} {resident}")
+    # span layer (telemetry/spans.py): the triad per operator replica,
+    # summed over the threads that ran its spans
+    for kind in ("busy", "idle", "blocked"):
+        metric = f"windflow_operator_{kind}_seconds"
+        family(metric, "counter",
+               f"seconds the operator's threads spent {kind} (span layer)")
+        for rep, lab in per_graph():
+            secs: dict = {}
+            for row in (rep.get("Spans") or {}).get("Operators", []):
+                op = row.get("Operator", "")
+                secs[op] = secs.get(op, 0.0) \
+                    + float(row.get(kind.capitalize() + "_s", 0.0) or 0.0)
+            for op, s in sorted(secs.items()):
+                out.append(f"{metric}_total"
+                           f"{_labels(operator=op, **lab)} {s:.6f}")
     family("windflow_queue_depth", "gauge",
            "tuples parked in the operator's inbound channels")
     for _op, reps, lab in per_op():

@@ -33,12 +33,16 @@ class FlightRecorder:
     acknowledged seq) and the cross-worker merge dedups overlapping
     tails by ``(worker, seq)`` (distributed/observe.py)."""
 
-    __slots__ = ("_ring", "enabled", "dumped_path", "_seq")
+    __slots__ = ("_ring", "enabled", "dumped_path", "_seq", "spans")
 
     def __init__(self, capacity: int = 512):
         self.enabled = capacity > 0
         self._ring: deque = deque(maxlen=max(1, capacity))
         self.dumped_path: Optional[str] = None
+        # the graph's entry in the span registry (telemetry/spans.py),
+        # set at PipeGraph.start: every node and logic already holds
+        # the recorder, so a thread files its spans through it
+        self.spans = None
         self._seq = count(1)  # itertools.count: GIL-atomic next()
 
     def record(self, kind: str, **fields) -> None:

@@ -1,0 +1,596 @@
+"""The span layer inside the program (docs/OBSERVABILITY.md "Spans").
+
+A span has a name ``wf/<operator>/<phase>``, a start and an end on
+``time.perf_counter`` (read as integer nanoseconds), the thread it ran
+on and its parent: the span open on that thread when it began.  Spans
+are taken per *chunk*, per *batch taken from a channel* or per *launch*,
+never per event or per tuple.  They are always on; there is no switch.
+
+* Each thread has one :class:`Track` (thread-local), written by that
+  thread alone, with one :class:`Cell` per span name: count, total,
+  self and longest nanoseconds, and a bounded timeline of self
+  nanoseconds per 100 ms bucket, so that any two instants can be cut
+  out afterwards (:func:`self_seconds`).  Time is attributed to the
+  innermost open span: a cell's self time is its spans' time minus what
+  their child spans cover, exactly, accounted as the spans open and
+  close.
+* Tracks and launch rings hang under a :class:`SpanGraph` in one
+  process-wide registry that outlives the graph (threads are the
+  process's, and a reader may come after the graph is gone).  A graph's
+  entry is dropped when a graph of the same name starts again.
+* While a ``jax.profiler`` session runs, every span is also a
+  ``TraceAnnotation`` of the same name, so the ``.xplane.pb`` holds the
+  program's spans on the trace's clock beside the device's ops.  Whether
+  a session runs is asked of the profiler itself (``TraceMe.is_enabled``,
+  a flag test) as each span begins; JAX is never imported from here.
+* A wait span over 30 ms and any working span over 100 ms goes to the
+  graph's :class:`~windflow_tpu.telemetry.recorder.FlightRecorder` as
+  ``slow_span``, with what the graph's other threads had open when it
+  began.
+* :class:`LaunchRing` keeps one :class:`Launch` record per device
+  launch: six host stamps whose differences are the stages of a
+  launch's round trip.
+"""
+from __future__ import annotations
+
+import sys
+import threading
+import time
+from collections import deque
+from typing import Dict, List, Optional
+
+_now = time.perf_counter_ns
+
+BUCKET_NS = 100_000_000          # timeline bucket: 100 ms
+TIMELINE_BUCKETS = 4096          # per cell: 6.8 minutes of buckets
+LAUNCH_RING = 8192               # launches kept per window operator
+MAX_ENDED_GRAPHS = 32            # ended graphs the registry keeps
+
+# phases by what the thread is doing in them (the triad of
+# choosing-metrics, stream processing: busy, idle, blocked because the
+# next operator cannot accept output).  Anything else is busy, except a
+# source's ``body``: the user's generator is not the program's work.
+IDLE_PHASES = frozenset(("get_wait", "work_wait", "ready_wait", "block"))
+BLOCKED_PHASES = frozenset(("put_wait", "submit_wait"))
+BODY_PHASE = "body"
+# never a slow_span however long: the user's generator, and a dispatcher
+# with nothing in flight waiting for work
+QUIET_PHASES = frozenset((BODY_PHASE, "work_wait"))
+# waits that are recorded as ``slow_span`` and read as stalls
+STALL_PHASES = frozenset(("get_wait", "put_wait", "submit_wait",
+                          "ready_wait"))
+SLOW_WAIT_NS = 30_000_000
+SLOW_ANY_NS = 100_000_000
+SLOW_EVERY_NS = 1_000_000_000    # at most one slow_span a second a cell
+RECENT_NS = 1_000_000            # closed spans this long are remembered
+#                                  as context for another thread's slow_span
+
+
+# -- the profiler's flag, without importing JAX ----------------------------
+
+_annotation = None
+
+
+def _never():
+    return False
+
+
+def _probe():
+    """Until ``jax.profiler`` is in the process no session can run; once
+    it is, the flag test is the profiler's own.  Nothing is imported
+    here: another thread may be in the middle of importing JAX."""
+    global _annotation, _session_on
+    ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    if ann is None:
+        return False
+    _annotation = ann
+    _session_on = getattr(ann, "is_enabled", _never)
+    return _session_on()
+
+
+_session_on = _probe
+
+
+def session_active() -> bool:
+    """Whether a ``jax.profiler`` session is recording right now."""
+    return _session_on()
+
+
+# -- cells, tracks ---------------------------------------------------------
+
+class Cell:
+    """One (thread, span name): written by that thread alone."""
+
+    __slots__ = ("name", "operator", "phase", "wait", "count", "total_ns",
+                 "self_ns", "longest_ns", "timeline", "lo", "hi", "ns",
+                 "peaks", "last_slow_ns", "suppressed")
+
+    def __init__(self, name: str):
+        self.name = name
+        head, _, self.phase = name.rpartition("/")
+        self.operator = head[3:] if head.startswith("wf/") else head
+        self.wait = self.phase in STALL_PHASES
+        self.count = 0
+        self.total_ns = 0        # inclusive
+        self.self_ns = 0         # minus what child spans cover
+        self.longest_ns = 0
+        self.timeline: Dict[int, int] = {}   # closed buckets -> self ns
+        self.lo = self.hi = self.ns = 0      # the current bucket, its self ns
+        self.peaks: Dict[int, int] = {}      # waits: end bucket -> longest ns
+        self.last_slow_ns = 0
+        self.suppressed = 0
+
+
+def _trim(d: dict) -> None:
+    # buckets are inserted in time order, so the first key is the oldest
+    while len(d) > TIMELINE_BUCKETS:
+        del d[next(iter(d))]
+
+
+class Track:
+    """The spans of one thread, accounted as they open and close: a
+    stack of open frames ``[cell, t_begin, t_segment, longest own
+    segment, annotation]`` and the thread's cells.  Written by the owner
+    thread alone; what another thread reads of it is current."""
+
+    __slots__ = ("thread", "graph", "cells", "stack", "recent", "born_ns",
+                 "last_ns")
+
+    def __init__(self, thread_name: str):
+        self.thread = thread_name
+        self.graph: Optional["SpanGraph"] = None
+        self.cells: Dict[str, Cell] = {}
+        self.stack: List[list] = []
+        self.recent: deque = deque(maxlen=8)   # (name, t0, t1), >= RECENT_NS
+        self.born_ns: Optional[int] = None
+        self.last_ns = 0
+
+    def begin(self, name: str, meta: Optional[dict] = None) -> None:
+        cell = self.cells.get(name)
+        if cell is None:
+            cell = self.cells[name] = Cell(name)
+        ann = None
+        if _session_on():
+            ann = _annotation(name, **meta) if meta else _annotation(name)
+            ann.__enter__()
+        now = _now()
+        stack = self.stack
+        if stack:
+            # the parent's own segment ends here (adding [seg, now) to its
+            # self time: into its current bucket, else through _spread)
+            top = stack[-1]
+            parent, seg = top[0], top[2]
+            d = now - seg
+            if d > 0:
+                parent.self_ns += d
+                if parent.lo <= seg and now < parent.hi:
+                    parent.ns += d
+                else:
+                    _spread(parent, seg, now)
+                if d > top[3]:
+                    top[3] = d
+        elif self.born_ns is None:
+            self.born_ns = now
+        # [cell, t_begin, t_segment, its longest segment without a child]
+        stack.append([cell, now, now, 0, ann])
+
+    def end(self) -> None:
+        """Close the innermost open span."""
+        now = _now()
+        stack = self.stack
+        cell, t0, seg, own, ann = stack.pop()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        d = now - seg
+        if d > 0:
+            cell.self_ns += d
+            if cell.lo <= seg and now < cell.hi:
+                cell.ns += d
+            else:
+                _spread(cell, seg, now)
+            if d > own:
+                own = d
+        dur = now - t0
+        cell.count += 1
+        cell.total_ns += dur
+        if dur > cell.longest_ns:
+            cell.longest_ns = dur
+        if cell.wait:
+            b = now // BUCKET_NS
+            peaks = cell.peaks
+            if dur > peaks.get(b, 0):
+                peaks[b] = dur
+                if len(peaks) > TIMELINE_BUCKETS:
+                    _trim(peaks)
+        if stack:
+            stack[-1][2] = now
+        self.last_ns = now
+        if dur >= RECENT_NS:
+            self._long(cell, t0, now, own)
+
+    def close_all(self) -> None:
+        """Thread end or unwinding: close whatever is still open."""
+        while self.stack:
+            self.end()
+
+    def _long(self, cell: Cell, t0: int, now: int, own: int) -> None:
+        """A span of a millisecond or more has closed; ``own`` is the
+        longest stretch it ran without a child span, so that one stall
+        is recorded once, under the innermost span that holds it, and a
+        span that is long by many short stretches (a source's loop) is
+        not slow."""
+        self.recent.append((cell.name, t0, now))
+        if own < SLOW_WAIT_NS or not (
+                cell.wait or (own >= SLOW_ANY_NS
+                              and cell.phase not in QUIET_PHASES)):
+            return
+        g = self.graph
+        flight = g.flight if g is not None else None
+        if flight is None:
+            return
+        if now - cell.last_slow_ns < SLOW_EVERY_NS:
+            cell.suppressed += 1
+            return
+        cell.last_slow_ns = now
+        flight.record("slow_span", name=cell.name, thread=self.thread,
+                      start_s=t0 / 1e9, ms=round((now - t0) / 1e6, 3),
+                      own_ms=round(own / 1e6, 3),
+                      suppressed=cell.suppressed,
+                      others=g.open_at(t0, but=self))
+        cell.suppressed = 0
+
+    # -- reading (any thread; gauge-grade against a live writer) --------
+    def open_spans(self) -> list:
+        """``(name, t_begin, t_segment)`` of the spans open now,
+        outermost first."""
+        return [(f[0].name, f[1], f[2]) for f in list(self.stack)]
+
+    def life_ns(self, now: Optional[int] = None):
+        """(first begin, last end or ``now`` while a span is open)."""
+        if self.stack:
+            return self.born_ns, now or _now()
+        return self.born_ns, self.last_ns
+
+
+def _spread(cell: Cell, a: int, b: int) -> None:
+    """The segment [a, b) of the cell's self time does not lie in the
+    cell's current bucket: close that bucket into the timeline, give
+    every bucket the segment crosses its part, and open the last one."""
+    tl = cell.timeline
+    if cell.ns:
+        k = cell.lo // BUCKET_NS
+        tl[k] = tl.get(k, 0) + cell.ns
+    ba, bb = a // BUCKET_NS, b // BUCKET_NS
+    if ba != bb:
+        tl[ba] = tl.get(ba, 0) + (ba + 1) * BUCKET_NS - a
+        for k in range(ba + 1, bb):
+            tl[k] = tl.get(k, 0) + BUCKET_NS
+        a = bb * BUCKET_NS
+    cell.lo, cell.hi = bb * BUCKET_NS, (bb + 1) * BUCKET_NS
+    cell.ns = tl.pop(bb, 0) + b - a
+    if len(tl) > TIMELINE_BUCKETS:
+        _trim(tl)
+
+
+def timeline(cell: Cell) -> Dict[int, int]:
+    """{bucket: self ns}: the closed buckets and the current one."""
+    tl = cell.timeline.copy()
+    if cell.ns:
+        k = cell.lo // BUCKET_NS
+        tl[k] = tl.get(k, 0) + cell.ns
+    return tl
+
+
+_tls = threading.local()
+
+
+def track() -> Track:
+    """The calling thread's track."""
+    try:
+        return _tls.track
+    except AttributeError:
+        t = _tls.track = Track(threading.current_thread().name)
+        return t
+
+
+def bind(graph: Optional["SpanGraph"]) -> Track:
+    """The calling thread's track, filed under ``graph``.  A thread
+    that served another graph before starts a fresh track."""
+    t = track()
+    if graph is None or t.graph is graph:
+        return t
+    if t.graph is not None or t.cells:
+        t = _tls.track = Track(threading.current_thread().name)
+    t.thread = threading.current_thread().name
+    t.graph = graph
+    with _lock:
+        graph.tracks.append(t)
+    return t
+
+
+# -- launches --------------------------------------------------------------
+
+class Launch:
+    """One device launch: host stamps on ``time.perf_counter`` (seconds).
+    ``t_submitted`` is taken on the ingest thread before the hand-off,
+    the others by whoever dispatches (the dispatcher thread, or the
+    ingest thread on the inline lane).  ``queue_wait``, ``dispatch``,
+    ``ready_wait``, ``block`` and ``emit`` are their differences."""
+
+    __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "t_submitted",
+                 "t_picked", "t_dispatched", "t_ready_seen", "t_on_host",
+                 "t_emitted")
+
+    def __init__(self, seq: int, chunk_seq: int, bytes_in: int,
+                 t_submitted: float):
+        self.seq = seq
+        self.chunk_seq = chunk_seq
+        self.bytes_in = bytes_in
+        self.bytes_out = 0
+        self.t_submitted = t_submitted
+        self.t_picked = self.t_dispatched = self.t_ready_seen = None
+        self.t_on_host = self.t_emitted = None
+
+    def as_row(self) -> dict:
+        """An emitted launch for the stats JSON: which launch, the chunk
+        whose arrival fired it, what it moved, and its stages."""
+        return {"Seq": self.seq, "Chunk_seq": self.chunk_seq,
+                "Bytes_in": int(self.bytes_in),
+                "Bytes_out": int(self.bytes_out),
+                "Picked_s": round(self.t_picked, 6),
+                **{k: round(v, 4) for k, v in self.stages_ms().items()}}
+
+    def stages_ms(self) -> Optional[dict]:
+        """The five stages in milliseconds; None until emitted."""
+        if self.t_emitted is None:
+            return None
+        return {
+            "queue_wait": 1e3 * (self.t_picked - self.t_submitted),
+            "dispatch": 1e3 * (self.t_dispatched - self.t_picked),
+            "ready_wait": 1e3 * (self.t_ready_seen - self.t_dispatched),
+            "block": 1e3 * (self.t_on_host - self.t_ready_seen),
+            "emit": 1e3 * (self.t_emitted - self.t_on_host),
+        }
+
+
+STAGES = ("queue_wait", "dispatch", "ready_wait", "block", "emit")
+
+
+class LaunchRing:
+    """The last :data:`LAUNCH_RING` launches of one window operator,
+    keyed by its launch sequence number."""
+
+    __slots__ = ("operator", "records", "seq")
+
+    def __init__(self, operator: str):
+        self.operator = operator
+        self.records: deque = deque(maxlen=LAUNCH_RING)
+        self.seq = 0
+
+    def open(self, chunk_seq: int, bytes_in: int, t_submitted: float
+             ) -> Launch:
+        self.seq += 1
+        rec = Launch(self.seq, chunk_seq, bytes_in, t_submitted)
+        self.records.append(rec)
+        return rec
+
+    def finished(self, t0: Optional[float] = None,
+                 t1: Optional[float] = None) -> List[Launch]:
+        """Emitted launches whose result reached the host in (t0, t1]."""
+        return [r for r in list(self.records)
+                if r.t_emitted is not None
+                and (t0 is None or r.t_on_host > t0)
+                and (t1 is None or r.t_on_host <= t1)]
+
+    def summary(self, t0: Optional[float] = None,
+                t1: Optional[float] = None) -> dict:
+        """Mean and longest of each stage over :meth:`finished`, and
+        the launch whose round trip (picked up to emitted) was the
+        longest, whole: the one to look for in a trace or a log."""
+        done = self.finished(t0, t1)
+        rows = [r.stages_ms() for r in done]
+        out = {"Operator": self.operator, "Launches": len(rows)}
+        for s in STAGES:
+            vals = [r[s] for r in rows]
+            out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
+                      "max_ms": round(max(vals), 4)} if vals else None
+        out["Slowest"] = max(
+            done, key=lambda r: r.t_emitted - r.t_picked).as_row() \
+            if done else None
+        return out
+
+
+# -- graphs and the registry -----------------------------------------------
+
+class SpanGraph:
+    """The tracks and launch rings of one graph."""
+
+    def __init__(self, name: str, flight=None):
+        self.name = name
+        self.flight = flight
+        self.tracks: List[Track] = []
+        self.rings: Dict[str, LaunchRing] = {}
+        self.ended = False
+
+    def ring(self, operator: str) -> LaunchRing:
+        with _lock:
+            r = self.rings.get(operator)
+            if r is None:
+                r = self.rings[operator] = LaunchRing(operator)
+            return r
+
+    def open_at(self, t_ns: int, but: Optional[Track] = None) -> list:
+        """What the graph's other threads had open at ``t_ns``: frames
+        still open that began before it, and remembered spans (1 ms or
+        longer) that covered it."""
+        out = []
+        for tr in list(self.tracks):
+            if tr is but:
+                continue
+            for name, t0, _seg in tr.open_spans():
+                if t0 <= t_ns:
+                    out.append({"thread": tr.thread, "name": name,
+                                "open_ms": round((t_ns - t0) / 1e6, 3)})
+            for name, t0, t1 in list(tr.recent):
+                if t0 <= t_ns <= t1:
+                    out.append({"thread": tr.thread, "name": name,
+                                "open_ms": round((t_ns - t0) / 1e6, 3),
+                                "ms": round((t1 - t0) / 1e6, 3)})
+        return out
+
+    def cells(self):
+        """(track, cell) of every cell of the graph."""
+        return [(tr, c) for tr in list(self.tracks)
+                for c in list(tr.cells.values())]
+
+
+_lock = threading.Lock()
+_graphs: Dict[str, SpanGraph] = {}
+
+
+def start_graph(name: str, flight=None) -> SpanGraph:
+    """A fresh entry for ``name``; one of the same name is dropped."""
+    g = SpanGraph(name, flight)
+    with _lock:
+        _graphs.pop(name, None)
+        _graphs[name] = g
+        ended = [n for n, x in _graphs.items() if x.ended]
+        for n in ended[:max(0, len(ended) - MAX_ENDED_GRAPHS)]:
+            del _graphs[n]
+    return g
+
+
+def end_graph(graph: Optional[SpanGraph]) -> None:
+    if graph is not None:
+        graph.ended = True
+
+
+def graph(name: str) -> Optional[SpanGraph]:
+    return _graphs.get(name)
+
+
+# -- cutting the timelines -------------------------------------------------
+
+def _bucket_sum(tl: dict, t0: int, t1: int) -> float:
+    """Nanoseconds of a bucket timeline between two instants: whole
+    buckets inside, and of the two edge buckets the covered part."""
+    total = 0.0
+    for b, ns in tl.items():
+        lo, hi = b * BUCKET_NS, (b + 1) * BUCKET_NS
+        if hi <= t0 or lo >= t1:
+            continue
+        part = (min(hi, t1) - max(lo, t0)) / BUCKET_NS
+        total += ns * part
+    return total
+
+
+def self_seconds(tr: Track, cell: Cell, t0_s: Optional[float] = None,
+                 t1_s: Optional[float] = None) -> float:
+    """The cell's self time in seconds, since start or between two
+    ``perf_counter`` instants (good to one bucket at each end).  A span
+    of the cell that is open now counts up to now."""
+    now = _now()
+    open_ns = 0
+    frames = tr.open_spans()
+    if frames and frames[-1][0] == cell.name:
+        seg = frames[-1][2]
+        lo = seg if t0_s is None else max(seg, int(t0_s * 1e9))
+        hi = now if t1_s is None else min(now, int(t1_s * 1e9))
+        open_ns = max(0, hi - lo)
+    if t0_s is None and t1_s is None:
+        return (cell.self_ns + open_ns) / 1e9
+    t0 = 0 if t0_s is None else int(t0_s * 1e9)
+    t1 = now if t1_s is None else int(t1_s * 1e9)
+    return (_bucket_sum(timeline(cell), t0, t1) + open_ns) / 1e9
+
+
+def longest_wait_ms(graph: SpanGraph, t0_s: float, t1_s: float) -> float:
+    """The longest single wait span (:data:`STALL_PHASES`) of any thread
+    of the graph that ended in the buckets of [t0_s, t1_s]."""
+    b0, b1 = int(t0_s * 1e9) // BUCKET_NS, int(t1_s * 1e9) // BUCKET_NS
+    longest = 0
+    for _tr, c in graph.cells():
+        if c.wait:
+            for b, ns in c.peaks.copy().items():
+                if b0 <= b <= b1 and ns > longest:
+                    longest = ns
+    return longest / 1e6
+
+
+def triad(graph: SpanGraph, t0_s: Optional[float] = None,
+          t1_s: Optional[float] = None) -> List[dict]:
+    """Per (operator, thread): seconds busy, idle, blocked and in the
+    source's body, and the thread's life in the same cut.  Busy is self
+    time of every phase that is neither a wait nor the body."""
+    now = _now()
+    rows: Dict[tuple, dict] = {}
+    for tr in list(graph.tracks):
+        born, last = tr.life_ns(now)
+        if born is None:
+            continue
+        lo = born if t0_s is None else max(born, int(t0_s * 1e9))
+        hi = last if t1_s is None else min(last, int(t1_s * 1e9))
+        life = max(0, hi - lo) / 1e9
+        for c in list(tr.cells.values()):
+            row = rows.get((c.operator, tr.thread, id(tr)))
+            if row is None:
+                row = rows[(c.operator, tr.thread, id(tr))] = {
+                    "operator": c.operator, "thread": tr.thread,
+                    "track": id(tr), "busy_s": 0.0, "idle_s": 0.0,
+                    "blocked_s": 0.0, "body_s": 0.0, "life_s": life,
+                    "phases": {}}
+            s = self_seconds(tr, c, t0_s, t1_s)
+            row["phases"][c.phase] = s
+            if c.phase in IDLE_PHASES:
+                row["idle_s"] += s
+            elif c.phase in BLOCKED_PHASES:
+                row["blocked_s"] += s
+            elif c.phase == BODY_PHASE:
+                row["body_s"] += s
+            else:
+                row["busy_s"] += s
+    return list(rows.values())
+
+
+def report(g: Optional[SpanGraph]) -> Optional[dict]:
+    """The ``Spans`` block of the stats JSON: per operator replica and
+    thread seconds and shares busy / idle / blocked since start and over
+    the last ten seconds, with every phase's closed spans counted (how
+    many, their self and whole seconds, the longest single one), and per
+    window operator the mean and longest of each launch stage and its
+    slowest launch."""
+    if g is None:
+        return None
+    now_s = _now() / 1e9
+
+    def shares(row):
+        life = row["life_s"]
+        return {k[:-2] + "_share": round(row[k] / life, 4) if life else 0.0
+                for k in ("busy_s", "idle_s", "blocked_s")}
+
+    recent = {(r["operator"], r["track"]): r
+              for r in triad(g, now_s - 10.0, now_s)}
+    counted: Dict[tuple, dict] = {}
+    for tr, c in g.cells():
+        counted.setdefault((c.operator, id(tr)), {})[c.phase] = {
+            "Count": c.count, "Self_s": round(c.self_ns / 1e9, 6),
+            "Total_s": round(c.total_ns / 1e9, 6),
+            "Longest_ms": round(c.longest_ns / 1e6, 3)}
+    ops = []
+    for row in triad(g):
+        out = {"Operator": row["operator"], "Thread": row["thread"],
+               "Busy_s": round(row["busy_s"], 6),
+               "Idle_s": round(row["idle_s"], 6),
+               "Blocked_s": round(row["blocked_s"], 6),
+               "Life_s": round(row["life_s"], 6)}
+        if row["body_s"]:
+            out["Body_s"] = round(row["body_s"], 6)
+        out["Phases"] = counted.get((row["operator"], row["track"]), {})
+        out.update({k.capitalize(): v for k, v in shares(row).items()})
+        last = recent.get((row["operator"], row["track"]))
+        if last is not None:
+            out["Last_10s"] = {k.capitalize(): v
+                               for k, v in shares(last).items()}
+        ops.append(out)
+    return {"Operators": ops,
+            "Launches": [r.summary() for r in list(g.rings.values())]}
