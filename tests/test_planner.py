@@ -381,6 +381,7 @@ def test_finish_normalizes_launch_wall_by_inflight_depth():
     # submit->result wall of a depth-8 entry reads ~8x the per-launch
     # service, which must not register as a shrink vote
     import time as _t
+    from windflow_tpu.telemetry import spans
 
     class _H:
         def block(self):
@@ -390,7 +391,8 @@ def test_finish_normalizes_launch_wall_by_inflight_depth():
     logic._adaptive = AdaptiveBatcher(256, floor_ms=10.0, patience=1)
     t_sub = _t.perf_counter() - 0.080  # 80 ms wall, 8 deep => 10 ms each
     logic._finish((_H(), [], t_sub, t_sub, 8, 0,
-                   logic._launches.open(0, 0, t_sub)), lambda *_: None)
+                   logic._launches.open(0, 0, t_sub)), lambda *_: None,
+                  spans.FORCED)
     # ~floor after normalization: a grow vote (raw 80 ms >= 8x floor
     # would have halved the batch)
     assert logic._adaptive.resizes == [("x2", 512)]

@@ -24,7 +24,7 @@ from windflow_tpu.operators.tpu.win_seq_tpu import (AdaptiveBatcher,
 from windflow_tpu.resilience import FaultPlan
 from windflow_tpu.telemetry import (FlightRecorder, LogHistogram,
                                     TraceContext, TraceSampler,
-                                    render_openmetrics)
+                                    render_openmetrics, spans)
 
 WAIT_S = 60
 
@@ -428,7 +428,8 @@ def test_adaptive_resize_records_flight_event():
     for _ in range(2):  # launches near the floor -> x2 after patience
         t_sub = time.perf_counter()
         lg._finish((_Handle(), [], t_sub, t_sub, 1, 0,
-                    lg._launches.open(0, 0, t_sub)), lambda x: None)
+                    lg._launches.open(0, 0, t_sub)), lambda x: None,
+                   spans.READY)
     assert lg.batch_len == 512
     assert any(e["kind"] == "batch_resize" and e["new_len"] == 512
                for e in lg.flight.snapshot())

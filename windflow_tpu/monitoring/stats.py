@@ -54,10 +54,13 @@ from ..telemetry.histogram import LogHistogram
 # operator replica and thread, launch stages per window operator);
 # the replica records' roofline fraction is gone (it divided bytes by a
 # host wall under a device's name).
+# 13 = Spans.Launches rows gain Collected (how many launches were
+# collected ready / waited / forced / flushed) and their Slowest row
+# its own Collected.
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 12
+SCHEMA_VERSION = 13
 
 
 @dataclass

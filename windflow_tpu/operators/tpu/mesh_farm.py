@@ -201,11 +201,12 @@ class KeyFarmMeshLogic(NodeLogic):
             ends[sh, slot] = base + np.searchsorted(ids, e_key, "left")
             placement.append((key, lwid, sh, slot))
         # the launch is synchronous here: submitted, picked up and
-        # dispatched on this thread, block() entered without a ready()
+        # dispatched on this thread, and collected at once (depth one)
         rec = self._launches.open(
             0, values.nbytes + starts.nbytes + ends.nbytes,
             _time.perf_counter())
         rec.t_picked = rec.t_submitted
+        rec.collected = spans.FORCED
         handle = self.engine.compute_kf(values, starts, ends)
         rec.t_dispatched = rec.t_ready_seen = _time.perf_counter()
         out = np.asarray(handle)

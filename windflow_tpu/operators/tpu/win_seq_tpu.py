@@ -132,11 +132,13 @@ def _key_groups(keys: np.ndarray):
 class _AsyncDispatcher:
     """Dedicated launch thread: the ingest thread stages numpy buffers
     and hands them off; this thread pays the host->device transfer
-    latency, keeps ``inflight_depth`` programs in flight, and emits
-    completed results.  The reference overlaps CUDA streams with host
-    batching on ONE thread (win_seq_gpu.hpp:267-297); here staging a
-    launch's buffers onto the device blocks the caller, so dispatch
-    comes off the ingest thread entirely."""
+    latency, keeps up to ``inflight_depth`` programs in flight, sleeps
+    on the oldest one's result whenever nothing is staged, and emits
+    each as the device finishes it.  The reference overlaps CUDA
+    streams with host batching on ONE thread
+    (win_seq_gpu.hpp:267-297); here staging a launch's buffers onto the
+    device blocks the caller, so dispatch comes off the ingest thread
+    entirely."""
 
     __slots__ = ("logic", "work", "thread", "error", "aborting")
 
@@ -193,7 +195,7 @@ class _AsyncDispatcher:
         try:
             self.work.put_nowait(None)
         except _q.Full:
-            pass  # the run loop polls `aborting` on empty reads
+            pass  # the run loop checks `aborting` when the queue reads empty
         self.thread.join(timeout=30)
 
     def _run(self) -> None:
@@ -210,29 +212,39 @@ class _AsyncDispatcher:
         pending = deque()
         last_emit = None
         while True:
-            # ready_wait while a launch is in flight (the poll below is
-            # how this thread learns a result is ready), work_wait with
-            # nothing to wait for but the next launch
-            tr.begin(logic._n_ready_wait if pending
-                     else logic._n_work_wait)
-            try:
-                # fine-grained poll while batches are in flight: their
-                # async D2H lands mid-stream and must be emitted then,
-                # not at the next launch (latency would otherwise grow
-                # with the launch interval)
-                item = self.work.get(timeout=0.005 if pending else 0.25)
-            except _q.Empty:
-                tr.end()
-                if self.aborting:
-                    return
-                while (pending and self.error is None
-                       and not self.aborting and _ready(pending[0])):
+            if pending:
+                # a launch is in flight.  Staged work is taken without
+                # waiting, so a backlog reaches the device (up to
+                # inflight_depth) before this thread sleeps; with none
+                # staged it sleeps on the oldest result itself and
+                # emits the moment the device is done, not at the next
+                # launch or the next tick of a timer.  A launch staged
+                # meanwhile is picked up when the wait returns: late by
+                # what the device still needed for the oldest one.
+                try:
+                    item = self.work.get_nowait()
+                except _q.Empty:
+                    if self.aborting:
+                        return
                     try:
-                        logic._finish(pending.popleft(), last_emit)
+                        self._collect(tr, pending, last_emit, spans.WAITED)
                     except BaseException as e:
+                        # surfaced on next submit / drain; nothing is
+                        # launched or collected after a failure
                         self.error = e
-                continue
-            tr.end()
+                        pending.clear()
+                    continue
+            else:
+                # work_wait: nothing to wait for but the next launch
+                tr.begin(logic._n_work_wait)
+                try:
+                    item = self.work.get(timeout=0.25)
+                except _q.Empty:
+                    if self.aborting:
+                        return
+                    continue
+                finally:
+                    tr.end()
             if item is None:
                 break
             if self.aborting or self.error is not None:
@@ -245,21 +257,43 @@ class _AsyncDispatcher:
                     tr, engine, cols, starts, ends, gwids, rec)
                 pending.append((handle, descs, birth, t_sub,
                                 len(pending) + 1, nbytes_in, rec))
-                # flush at depth (backpressure) AND any batch whose
-                # async D2H already landed -- otherwise results wait
+                # collect at depth (backpressure) AND any batch whose
+                # result is ready already -- otherwise results wait
                 # for the pipeline to fill and latency grows with
                 # inflight_depth instead of shrinking
-                while (pending and not self.aborting
-                       and (len(pending) >= logic.inflight_depth
-                            or _ready(pending[0]))):
-                    logic._finish(pending.popleft(), emit)
+                while pending and not self.aborting:
+                    how = _collectable(pending, logic.inflight_depth)
+                    if how is None:
+                        break
+                    self._collect(tr, pending, emit, how)
             except BaseException as e:  # surfaced on next submit / drain
                 self.error = e
+                pending.clear()
         while pending and self.error is None and not self.aborting:
             try:
-                logic._finish(pending.popleft(), last_emit)
+                self._collect(tr, pending, last_emit, spans.FLUSHED)
             except BaseException as e:
                 self.error = e
+
+    def _collect(self, tr, pending, emit, how) -> None:
+        """Finish the oldest in-flight launch.  Unless it was found
+        ready, this thread first sleeps on its result under the
+        ``ready_wait`` span (``handle.wait()`` releases the GIL) and
+        stamps ``t_ready_seen`` as the wait returns.  An abort that came
+        during the wait leaves the launch where it is."""
+        entry = pending[0]
+        rec = entry[-1]
+        if rec.t_ready_seen is None:
+            tr.begin(self.logic._n_ready_wait)
+            try:
+                entry[0].wait()
+            finally:
+                tr.end()
+            rec.t_ready_seen = _time.perf_counter()
+            if self.aborting:
+                return
+        pending.popleft()
+        self.logic._finish(entry, emit, how)
 
 
 def _ready(entry) -> bool:
@@ -271,6 +305,16 @@ def _ready(entry) -> bool:
     if rec.t_ready_seen is None:
         rec.t_ready_seen = _time.perf_counter()
     return True
+
+
+def _collectable(pending, depth: int) -> Optional[str]:
+    """How the oldest in-flight launch is to be collected now, if at
+    all: forced because the pipeline is at depth, or found ready."""
+    if len(pending) >= depth:
+        return spans.FORCED
+    if _ready(pending[0]):
+        return spans.READY
+    return None
 
 
 class _TPUKeyState:
@@ -684,14 +728,16 @@ class WinSeqTPULogic(NodeLogic):
             st.values = st.values[cut:]
 
     # -- batch plane -------------------------------------------------------
-    def _finish(self, entry, emit) -> None:
-        """Flush one in-flight batch: block on its handle, add the
-        launch's host wall (picked up -> result on host: dispatch,
-        ready wait and block; no device clock is read) to
-        ``Device_time_ms``, sample the window-result latency, feed the
-        adaptive batch resize, emit.  Stamps the launch record."""
+    def _finish(self, entry, emit, how: str) -> None:
+        """Flush one in-flight batch: copy its result to the host
+        (``block``), add the launch's host wall (picked up -> result on
+        host: dispatch, ready wait and block; no device clock is read)
+        to ``Device_time_ms``, sample the window-result latency, feed
+        the adaptive batch resize, emit.  Stamps the launch record;
+        ``how`` (``spans.COLLECTED``) says what brought the caller here."""
         handle, descs, birth, t_sub, depth, nbytes_in, rec = entry
-        if rec.t_ready_seen is None:   # block() entered without a ready()
+        rec.collected = how
+        if rec.t_ready_seen is None:   # inline lane: block() waits too
             rec.t_ready_seen = _time.perf_counter()
         tr = spans.track()
         tr.begin(self._n_block)
@@ -802,10 +848,12 @@ class WinSeqTPULogic(NodeLogic):
         """Emit completed in-flight batches: the oldest when the
         pipeline is at depth (waitAndFlush), any whose async D2H has
         landed, or all when draining (inline-dispatch mode only)."""
-        while self.pending and (drain
-                                or len(self.pending) >= self.inflight_depth
-                                or _ready(self.pending[0])):
-            self._finish(self.pending.popleft(), emit)
+        while self.pending:
+            how = spans.FLUSHED if drain else _collectable(
+                self.pending, self.inflight_depth)
+            if how is None:
+                break
+            self._finish(self.pending.popleft(), emit, how)
 
     def _drain_all(self, emit) -> None:
         if self._dispatcher is not None:

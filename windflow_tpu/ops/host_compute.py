@@ -44,6 +44,9 @@ class HostBatchHandle:
     def ready(self) -> bool:
         return True
 
+    def wait(self) -> None:
+        pass
+
     def block(self) -> np.ndarray:
         return self._arr
 

@@ -272,10 +272,12 @@ class DeviceBatchHandle:
     analogue of the reference's in-flight CUDA kernel).
 
     The device-to-host copy is started asynchronously at construction
-    (``copy_to_host_async``): the transfer rides under subsequent host
-    batching, so ``block()`` is near-free by the time the double-buffer
-    protocol flushes this batch -- the cudaMemcpyAsync-D2H analogue
-    (win_seq_gpu.hpp:610)."""
+    (``copy_to_host_async``), the cudaMemcpyAsync-D2H analogue
+    (win_seq_gpu.hpp:610).  A caller with nothing else to do sleeps in
+    ``wait()`` until the device computation has finished (the GIL is
+    released meanwhile); ``ready()`` asks the same without waiting.
+    After either, ``block()`` is the end of the copy to the host and
+    near-free; entered before, it waits for the computation too."""
 
     __slots__ = ("_dev", "_n")
 
@@ -288,6 +290,10 @@ class DeviceBatchHandle:
         """True when the device computation has finished (block() will
         not stall)."""
         return bool(self._dev.is_ready())
+
+    def wait(self) -> None:
+        """Sleep until ``ready()`` would read true."""
+        self._dev.block_until_ready()
 
     def block(self) -> np.ndarray:
         return np.asarray(self._dev)[: self._n]
@@ -309,6 +315,9 @@ class _ResidentPaneHandle:
 
     def ready(self) -> bool:
         return bool(self._dev.is_ready())
+
+    def wait(self) -> None:
+        self._dev.block_until_ready()
 
     def block(self) -> np.ndarray:
         out = np.asarray(self._dev)

@@ -315,11 +315,13 @@ class Launch:
     ``t_submitted`` is taken on the ingest thread before the hand-off,
     the others by whoever dispatches (the dispatcher thread, or the
     ingest thread on the inline lane).  ``queue_wait``, ``dispatch``,
-    ``ready_wait``, ``block`` and ``emit`` are their differences."""
+    ``ready_wait``, ``block`` and ``emit`` are their differences.
+    ``collected`` (one of :data:`COLLECTED`) says how the dispatcher
+    came to take the result."""
 
     __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "t_submitted",
                  "t_picked", "t_dispatched", "t_ready_seen", "t_on_host",
-                 "t_emitted")
+                 "t_emitted", "collected")
 
     def __init__(self, seq: int, chunk_seq: int, bytes_in: int,
                  t_submitted: float):
@@ -330,6 +332,7 @@ class Launch:
         self.t_submitted = t_submitted
         self.t_picked = self.t_dispatched = self.t_ready_seen = None
         self.t_on_host = self.t_emitted = None
+        self.collected = None
 
     def as_row(self) -> dict:
         """An emitted launch for the stats JSON: which launch, the chunk
@@ -338,6 +341,7 @@ class Launch:
                 "Bytes_in": int(self.bytes_in),
                 "Bytes_out": int(self.bytes_out),
                 "Picked_s": round(self.t_picked, 6),
+                "Collected": self.collected,
                 **{k: round(v, 4) for k, v in self.stages_ms().items()}}
 
     def stages_ms(self) -> Optional[dict]:
@@ -354,6 +358,13 @@ class Launch:
 
 
 STAGES = ("queue_wait", "dispatch", "ready_wait", "block", "emit")
+
+# how a launch's result was collected: found ready straight after a
+# dispatch; waited for, with nothing staged to dispatch meanwhile;
+# forced, because ``inflight_depth`` launches were in flight (the inline
+# lane and a synchronous launch are always at depth); flushed at EOS
+COLLECTED = ("ready", "waited", "forced", "flushed")
+READY, WAITED, FORCED, FLUSHED = COLLECTED
 
 
 class LaunchRing:
@@ -384,9 +395,10 @@ class LaunchRing:
 
     def summary(self, t0: Optional[float] = None,
                 t1: Optional[float] = None) -> dict:
-        """Mean and longest of each stage over :meth:`finished`, and
-        the launch whose round trip (picked up to emitted) was the
-        longest, whole: the one to look for in a trace or a log."""
+        """Mean and longest of each stage over :meth:`finished`, how
+        many of them were collected in each way, and the launch whose
+        round trip (picked up to emitted) was the longest, whole: the
+        one to look for in a trace or a log."""
         done = self.finished(t0, t1)
         rows = [r.stages_ms() for r in done]
         out = {"Operator": self.operator, "Launches": len(rows)}
@@ -394,6 +406,8 @@ class LaunchRing:
             vals = [r[s] for r in rows]
             out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
                       "max_ms": round(max(vals), 4)} if vals else None
+        out["Collected"] = {how: sum(r.collected == how for r in done)
+                            for how in COLLECTED}
         out["Slowest"] = max(
             done, key=lambda r: r.t_emitted - r.t_picked).as_row() \
             if done else None
