@@ -29,11 +29,13 @@
 // internal state; Python calls via ctypes release the GIL.
 
 #include <algorithm>
+#include <chrono>
 #include <cstdint>
 #include <cstring>
+#include <deque>
 #include <limits>
+#include <map>
 #include <numeric>
-#include <unordered_map>
 #include <vector>
 
 namespace {
@@ -41,6 +43,11 @@ namespace {
 using i64 = long long;
 
 constexpr double INF = std::numeric_limits<double>::infinity();
+
+inline i64 now_ns() {
+    return std::chrono::duration_cast<std::chrono::nanoseconds>(
+        std::chrono::steady_clock::now().time_since_epoch()).count();
+}
 
 struct KeyState {
     // pane-partial ring: pacc[j] is the combine partial of absolute
@@ -52,6 +59,7 @@ struct KeyState {
     std::vector<double> pacc;
     std::vector<i64> pcnt;
     std::vector<i64> plid, plts;
+    i64 key = 0;
     i64 pane_base = 0;        // absolute pane index of pacc[0]
     i64 next_fire = 0;        // next window (lwid) to fire
     i64 anchor = 0;           // first window that can ever fire for this
@@ -62,11 +70,29 @@ struct KeyState {
     i64 opened_max = -1;
     i64 max_id = -1;
     i64 arrivals = 0;         // renumber lane: running arrival count
-                              // (ids implicit, persists across eviction)
+                              // (ids implicit; such keys are never evicted)
+    i64 staged_upto = -1;     // last window flush() staged for the key
+    int32_t queued = 0;       // fired windows of the key waiting in `ready`
+    bool live = false;        // the pool slot holds a key
+    bool indexed = false;     // listed in `due` under next_fire
+
+    // back to a fresh slot; the rings keep their capacity, so a key
+    // opened in a reused slot allocates nothing
+    void reset() {
+        pacc.clear();
+        pcnt.clear();
+        plid.clear();
+        plts.clear();
+        pane_base = next_fire = anchor = arrivals = 0;
+        opened_max = max_id = staged_upto = -1;
+        queued = 0;
+        live = indexed = false;
+    }
 };
 
 struct Desc {
-    i64 key, lwid, start, end;
+    i64 key, lwid;
+    int32_t slot;
 };
 
 enum class Kind : int { SUM = 0, COUNT = 1, MAX = 2, MIN = 3, MEAN = 4 };
@@ -77,91 +103,209 @@ struct Engine {
     bool renumber;            // ids are implicit per-key arrival order
                               // (TS_RENUMBERING analogue): the id input
                               // is ignored
+    // THE FIRING RULE.  Window w of a key fires once the frontier has
+    // reached w*slide + win + delay and the key has opened w.  For TB
+    // windows on real stamps (`stream_rule`) the frontier is the
+    // engine's stream time, the largest stamp ingested over all keys:
+    // a key that goes quiet gets its rows when the stream passes them,
+    // not at EOS, and the input must be ordered per engine up to
+    // `delay` (docs/RUNTIME.md "When a window fires").  For CB windows
+    // and renumbered ids, which count a key's own arrivals, it is the
+    // key's own largest id.
+    bool stream_rule;
+    // `stream_rule` engines that are not `dense` emit a row only for a
+    // window that holds a tuple of the key and evict a key once its
+    // last opened window has been staged; a key that comes back is a
+    // new key.  `dense` engines (output ids are per-key counters, or a
+    // lowered record graph whose host twin emits them) emit every
+    // window from the key's anchor on and keep every key.
+    bool dense, sparse;
     Kind kind;
     i64 pane;                 // gcd(win, slide)
+    i64 ppw;                  // panes per window
     int pshift;               // log2(pane) when pane is a power of two
     double neutral;
-    std::unordered_map<i64, KeyState> keys;
-    std::vector<Desc> ready;
+    // key states live in a pool whose slots are reused; the table
+    // below maps key -> slot
+    std::deque<KeyState> pool;
+    std::vector<int32_t> free_slots;
+    i64 n_live = 0;
+    std::vector<Desc> ready;  // fired, unstaged; consumed from ready_head
+    std::size_t ready_head = 0;
     i64 ignored = 0;          // tuples dropped behind the fired frontier
+    // stream-time trigger: keys with an opened, unfired window are
+    // listed under the window they fire next, so a firing visits the
+    // keys that fire
+    i64 stream_time = -1;
+    i64 fired_upto = -1;      // highest window the stream has passed
+    std::map<i64, std::vector<int32_t>> due;
+    // what key churn costs, read by wfn_engine_stats (nanoseconds on a
+    // steady clock, taken per call and only when there is such work)
+    i64 open_ns = 0, trigger_ns = 0, evict_ns = 0;
+    i64 keys_opened = 0, keys_evicted = 0, keys_live_peak = 0;
+    i64 windows_fired = 0;
     // staging buffers (valid until the next flush)
     std::vector<double> st_vals, st_cnts;
     std::vector<i64> st_starts, st_ends, st_keys, st_gwids, st_rts;
-    // scatter-ingest machinery: an open-addressing table maps key ->
-    // (KeyState*, per-call dense index).  Pass 1 does ONE table probe
-    // per tuple and gathers per-key min/max; pass 2 folds each tuple
-    // into its pane through the cached state pointer.
+    std::vector<i64> f_prefix;        // flush(): count prefixes, flat
+    std::vector<int32_t> f_touched, f_dead;
+    i64 flush_id = 0;
+    // scatter-ingest machinery: an open-addressing table (linear
+    // probing from home_of) maps key -> (pool slot, per-call dense
+    // index); an entry leaves it by a backward shift, so it holds no
+    // tombstones and forgets as fast as it learns.  Pass 1 does ONE table
+    // probe per tuple and gathers per-key min/max; pass 2 folds each
+    // tuple into its pane through the cached state pointer.
     std::vector<i64> tab_key;
-    std::vector<KeyState*> tab_state;
+    std::vector<int32_t> tab_slot;    // -1: empty
     std::vector<i64> tab_stamp;
     std::vector<int32_t> tab_dense;
     i64 call_id = 0;
     // per-call dense arrays (index = order of first touch this call)
     std::vector<KeyState*> d_state;
     std::vector<i64> d_key, d_count, d_min, d_max, d_accept;
-    std::vector<int32_t> slot_of;  // per-tuple dense index
-    static constexpr i64 EMPTY = INT64_MIN;
+    std::vector<int32_t> d_slot;
+    std::vector<int32_t> opened_now;  // dense indices of keys opened this call
+    std::vector<int32_t> slot_of;     // per-tuple dense index
 
-    Engine(i64 w, i64 s, bool tb, i64 d, bool renum, Kind k)
+    Engine(i64 w, i64 s, bool tb, i64 d, bool renum, Kind k, bool dns)
         : win(w), slide(s), delay(tb ? d : 0), is_tb(tb), renumber(renum),
-          kind(k), pane(std::gcd(w, s)) {
+          stream_rule(tb && !renum), dense(dns),
+          sparse(tb && !renum && !dns), kind(k), pane(std::gcd(w, s)) {
+        ppw = win / pane;
         pshift = (pane & (pane - 1)) == 0 ? __builtin_ctzll(pane) : -1;
         neutral = kind == Kind::MAX ? -INF : kind == Kind::MIN ? INF : 0.0;
-        tab_key.assign(1024, EMPTY);
-        tab_state.assign(1024, nullptr);
-        tab_stamp.assign(1024, -1);
-        tab_dense.assign(1024, 0);
+        clear_table(1024);
+    }
+
+    void clear_table(std::size_t m) {
+        tab_key.assign(m, 0);
+        tab_slot.assign(m, -1);
+        tab_stamp.assign(m, -1);
+        tab_dense.assign(m, 0);
     }
 
     inline i64 pane_of(i64 id) const {
         return pshift >= 0 ? id >> pshift : id / pane;
     }
 
+    // the first window that holds `id`
+    inline i64 first_window_of(i64 id) const {
+        return id < win ? 0 : (id - win) / slide + 1;
+    }
+
+    // A key's home slot: its own low bits, doubled.  Keys that follow
+    // one another (auction ids, interned ids) then stay neighbours in
+    // the table, as under the identity hash, but leave every other slot
+    // empty: a run of them is no cluster, so a probe for a new key ends
+    // at once and evict()'s backward shift moves nothing.
+    inline std::size_t home_of(i64 key) const {
+        return ((std::size_t)key << 1) & (tab_key.size() - 1);
+    }
+
     void grow_table() {
-        std::size_t m = tab_key.size() * 4;
-        std::vector<i64> nk(m, EMPTY);
-        std::vector<KeyState*> ns(m, nullptr);
-        std::vector<i64> nst(m, -1);
-        std::vector<int32_t> nd(m, 0);
-        for (std::size_t s = 0; s < tab_key.size(); ++s) {
-            // occupancy = non-null state pointer, NOT the key sentinel:
-            // a real key may equal INT64_MIN
-            if (tab_state[s] == nullptr) continue;
-            std::size_t h = std::hash<i64>{}(tab_key[s]) & (m - 1);
-            while (ns[h] != nullptr) h = (h + 1) & (m - 1);
-            nk[h] = tab_key[s];
-            ns[h] = tab_state[s];
-            nst[h] = tab_stamp[s];
-            nd[h] = tab_dense[s];
+        std::vector<i64> ok, ost;
+        std::vector<int32_t> os, od;
+        ok.swap(tab_key);
+        os.swap(tab_slot);
+        ost.swap(tab_stamp);
+        od.swap(tab_dense);
+        clear_table(ok.size() * 4);
+        const std::size_t mask = tab_key.size() - 1;
+        for (std::size_t s = 0; s < ok.size(); ++s) {
+            if (os[s] < 0) continue;
+            std::size_t h = home_of(ok[s]);
+            while (tab_slot[h] >= 0) h = (h + 1) & mask;
+            tab_key[h] = ok[s];
+            tab_slot[h] = os[s];
+            tab_stamp[h] = ost[s];
+            tab_dense[h] = od[s];
         }
-        tab_key.swap(nk);
-        tab_state.swap(ns);
-        tab_stamp.swap(nst);
-        tab_dense.swap(nd);
+    }
+
+    // a pool slot for a key that has none: a reused one, else a new one
+    int32_t take_slot(i64 key) {
+        int32_t s;
+        if (!free_slots.empty()) {
+            s = free_slots.back();
+            free_slots.pop_back();
+        } else {
+            s = (int32_t)pool.size();
+            pool.emplace_back();
+        }
+        KeyState& st = pool[s];
+        st.key = key;
+        st.live = true;
+        ++keys_opened;
+        if (++n_live > keys_live_peak) keys_live_peak = n_live;
+        return s;
+    }
+
+    // the key's table entry, made (with a fresh slot) where it has
+    // none; `opened` says which
+    inline std::size_t locate(i64 key, bool& opened) {
+        std::size_t mask = tab_key.size() - 1;
+        std::size_t h = home_of(key);
+        opened = false;
+        while (true) {
+            if (tab_slot[h] < 0) {
+                if ((n_live + 1) * 4 >= (i64)tab_key.size()) {
+                    grow_table();
+                    return locate(key, opened);
+                }
+                tab_key[h] = key;
+                tab_slot[h] = take_slot(key);
+                tab_stamp[h] = -1;
+                opened = true;
+                return h;
+            }
+            if (tab_key[h] == key) return h;
+            h = (h + 1) & mask;
+        }
+    }
+
+    // forget a key: its table entry goes by a backward shift (no
+    // tombstone), its slot goes back to the pool
+    void evict(int32_t slot) {
+        KeyState& st = pool[slot];
+        const std::size_t mask = tab_key.size() - 1;
+        std::size_t i = home_of(st.key);
+        while (tab_slot[i] != slot) i = (i + 1) & mask;
+        while (true) {
+            tab_slot[i] = -1;
+            std::size_t j = i;
+            while (true) {
+                j = (j + 1) & mask;
+                if (tab_slot[j] < 0) goto shifted;
+                std::size_t k = home_of(tab_key[j]);
+                // entry j may stay where its home k lies in (i, j]
+                if (i <= j ? (i < k && k <= j) : (i < k || k <= j))
+                    continue;
+                break;
+            }
+            tab_key[i] = tab_key[j];
+            tab_slot[i] = tab_slot[j];
+            tab_stamp[i] = tab_stamp[j];
+            tab_dense[i] = tab_dense[j];
+            i = j;
+        }
+    shifted:
+        st.reset();
+        free_slots.push_back(slot);
+        --n_live;
+        ++keys_evicted;
     }
 
     inline int32_t dense_of(i64 key) {
-        std::size_t mask = tab_key.size() - 1;
-        std::size_t h = std::hash<i64>{}(key) & mask;
-        while (true) {
-            if (tab_state[h] != nullptr && tab_key[h] == key) break;
-            if (tab_state[h] == nullptr) {
-                if (keys.size() * 4 >= tab_key.size()) {
-                    grow_table();
-                    return dense_of(key);
-                }
-                tab_key[h] = key;
-                tab_state[h] = &keys[key];
-                tab_stamp[h] = -1;
-                break;
-            }
-            h = (h + 1) & mask;
-        }
+        bool opened;
+        std::size_t h = locate(key, opened);
         if (tab_stamp[h] != call_id) {
             tab_stamp[h] = call_id;
             tab_dense[h] = (int32_t)d_key.size();
-            d_key.push_back(tab_key[h]);
-            d_state.push_back(tab_state[h]);
+            if (opened) opened_now.push_back(tab_dense[h]);
+            d_key.push_back(key);
+            d_slot.push_back(tab_slot[h]);
+            d_state.push_back(&pool[tab_slot[h]]);
             d_count.push_back(0);
         }
         return tab_dense[h];
@@ -197,6 +341,138 @@ struct Engine {
         ++st.pcnt[p_rel];
     }
 
+    // -- firing -----------------------------------------------------------
+    // Whether window [start, start + win) of the key holds a tuple.
+    // `q` is a cursor over the ring (the first pane at or after the
+    // last window's start that holds one), so a run of windows costs
+    // one pass over their panes.
+    inline bool holds_tuple(const KeyState& st, i64 start, i64& q) const {
+        i64 ps = pane_of(start) - st.pane_base;
+        i64 pe = std::min<i64>(ps + ppw, (i64)st.pcnt.size());
+        if (q < ps) q = ps < 0 ? 0 : ps;
+        while (q < pe && st.pcnt[q] == 0) ++q;
+        return q < pe;
+    }
+
+    // Queue every window of the key that `front` has passed (the one
+    // place the rule is applied: stream time, a CB key's own largest
+    // id, or everything at EOS).
+    inline void fire_key(KeyState& st, int32_t slot, i64 front) {
+        i64 q = -1;
+        while (st.next_fire <= st.opened_max) {
+            const i64 start = st.next_fire * slide;
+            if (front - delay < start + win) break;
+            if (!sparse || holds_tuple(st, start, q)) {
+                ready.push_back(Desc{st.key, st.next_fire, slot});
+                ++st.queued;
+                ++windows_fired;
+            }
+            ++st.next_fire;
+        }
+    }
+
+    // list the key under the window it fires next
+    inline void index_key(KeyState& st, int32_t slot) {
+        if (st.indexed || st.next_fire > st.opened_max) return;
+        due[st.next_fire].push_back(slot);
+        st.indexed = true;
+    }
+
+    // The stream has moved: fire the windows it has passed, for the
+    // keys listed under them alone.
+    void trigger() {
+        const i64 t = stream_time - delay - win;
+        const i64 upto = t < 0 ? -1 : t / slide;
+        if (upto > fired_upto) fired_upto = upto;
+        if (due.empty() || due.begin()->first > fired_upto) return;
+        const i64 t0 = now_ns();
+        while (!due.empty() && due.begin()->first <= fired_upto) {
+            // taken out first: a key that still has a window opened is
+            // listed anew, under a later one
+            const auto node = due.extract(due.begin());
+            for (int32_t slot : node.mapped()) {
+                KeyState& st = pool[slot];
+                st.indexed = false;
+                fire_key(st, slot, stream_time);
+                index_key(st, slot);
+                if (sparse && st.queued == 0 && !st.indexed)
+                    evict(slot);  // every window it opened lay empty
+            }
+        }
+        trigger_ns += now_ns() - t0;
+    }
+
+    // the id below which a tuple is late for every key: the end of the
+    // last window the stream has passed (a key that was evicted must
+    // not open that window again)
+    inline i64 stream_accept() const {
+        return stream_rule && fired_upto >= 0 ? fired_upto * slide + win
+                                              : INT64_MIN;
+    }
+
+    // a key's first data (of this life): anchor the fire frontier at
+    // the first window containing the earliest tuple -- firing from 0
+    // on an epoch-scale first id/ts would emit ~id/slide empty windows
+    // (flood/OOM) -- and never at a window the stream has passed
+    inline void anchor_key(KeyState& st, i64 first) {
+        i64 a = first_window_of(first);
+        if (stream_rule && a <= fired_upto) a = fired_upto + 1;
+        st.anchor = st.next_fire = a;
+        st.pane_base = pane_of(a * slide);
+    }
+
+    // the acceptance boundary of a key: tuples below it are late (they
+    // fall in a window that has fired, the key's own last or the last
+    // the stream passed) and are counted; tuples below a new key's
+    // anchor lie in a hopping gap, below the ring, and are not
+    inline i64 accept_of(const KeyState& st) const {
+        i64 own = st.next_fire > st.anchor
+            ? (st.next_fire - 1) * slide + win : INT64_MIN;
+        return std::max(own, stream_accept());
+    }
+
+    // per key and call, before the fold: this batch's id range, the
+    // anchor of a new key, the acceptance boundary, the ring's room
+    inline void prepare(std::size_t d) {
+        KeyState& st = *d_state[d];
+        if (renumber) {
+            // implicit arrival-order ids: this batch appends ids
+            // [arrivals, arrivals + count)
+            d_min[d] = st.arrivals;
+            d_max[d] = st.arrivals + d_count[d] - 1;
+        }
+        if (st.max_id < 0) {
+            anchor_key(st, d_min[d]);
+        } else if (sparse && st.next_fire > st.opened_max) {
+            // every window the key opened has fired: the windows that
+            // lie empty before this batch's first tuple are skipped
+            // here, not one by one at the trigger
+            i64 w0 = first_window_of(d_min[d]);
+            if (w0 > st.next_fire) st.next_fire = w0;
+        }
+        d_accept[d] = accept_of(st);
+        // pre-grow the ring to this batch's frontier so the fold
+        // loop never reallocates
+        i64 hi_rel = pane_of(d_max[d]) - st.pane_base;
+        if (hi_rel >= 0) ensure_pane(st, hi_rel);
+    }
+
+    // per key and call, after the fold: the frontier it opened, and
+    // its part in the firing
+    inline void settle(KeyState& st, int32_t slot, i64 max_seen) {
+        if (max_seen > st.max_id) st.max_id = max_seen;
+        if (win >= slide && st.max_id >= 0) {
+            i64 last_w = (st.max_id + 1 + slide - 1) / slide - 1;
+            if (last_w > st.opened_max) st.opened_max = last_w;
+        }
+        if (stream_rule) {
+            if (st.max_id > stream_time) stream_time = st.max_id;
+            index_key(st, slot);
+        } else {
+            fire_key(st, slot, st.max_id);
+        }
+    }
+
     // TV = double or float: f32 sources fold without a host-side
     // widening copy (values widen at the accumulate)
     template <typename TV>
@@ -204,8 +480,10 @@ struct Engine {
                       const TV* vals, i64 n) {
         ++call_id;
         d_key.clear();
+        d_slot.clear();
         d_state.clear();
         d_count.clear();
+        opened_now.clear();
         if ((i64)slot_of.size() < n) slot_of.resize(n);
         if (renumber) {
             for (i64 j = 0; j < n; ++j) {
@@ -231,30 +509,21 @@ struct Engine {
         if (d_min.size() < nd) d_min.resize(nd);
         if (d_max.size() < nd) d_max.resize(nd);
         d_accept.resize(nd);
-        for (std::size_t d = 0; d < nd; ++d) {
-            KeyState& st = *d_state[d];
-            if (renumber) {
-                // implicit arrival-order ids: this batch appends ids
-                // [arrivals, arrivals + count)
-                d_min[d] = st.arrivals;
-                d_max[d] = st.arrivals + d_count[d] - 1;
+        if (opened_now.empty()) {
+            for (std::size_t d = 0; d < nd; ++d) prepare(d);
+        } else {
+            // `open`: the keys this call created, timed apart (their
+            // anchor and their ring); the others after them
+            const i64 t0 = now_ns();
+            for (int32_t d : opened_now) prepare(d);
+            open_ns += now_ns() - t0;
+            std::size_t o = 0;  // opened_now is ascending
+            for (std::size_t d = 0; d < nd; ++d) {
+                if (o < opened_now.size() && (std::size_t)opened_now[o] == d)
+                    ++o;
+                else
+                    prepare(d);
             }
-            if (st.max_id < 0) {
-                // first data for this key: anchor the fire frontier at
-                // the first window containing the earliest tuple --
-                // firing from 0 on an epoch-scale first id/ts would
-                // emit ~id/slide empty windows (flood/OOM)
-                i64 first = d_min[d];
-                st.anchor = first < win ? 0 : (first - win) / slide + 1;
-                st.next_fire = st.anchor;
-                st.pane_base = pane_of(st.anchor * slide);
-            }
-            d_accept[d] = st.next_fire > st.anchor
-                ? (st.next_fire - 1) * slide + win : st.anchor * slide;
-            // pre-grow the ring to this batch's frontier so the fold
-            // loop never reallocates
-            i64 hi_rel = pane_of(d_max[d]) - st.pane_base;
-            if (hi_rel >= 0) ensure_pane(st, hi_rel);
         }
         // hopping windows (win < slide): whether an id opens a window
         // depends on its position inside the slide period, so the
@@ -322,31 +591,18 @@ struct Engine {
             }
         }
         for (std::size_t d = 0; d < nd; ++d) {
-            KeyState& st = *d_state[d];
-            if (d_max[d] > st.max_id) st.max_id = d_max[d];
-            if (!hopping && st.max_id >= 0) {
-                i64 last_w = (st.max_id + 1 + slide - 1) / slide - 1;
-                if (last_w > st.opened_max) st.opened_max = last_w;
-            }
-            i64 key = d_key[d];
-            while (true) {
-                i64 end = st.next_fire * slide + win;
-                if (st.max_id < end + delay || st.next_fire > st.opened_max)
-                    break;
-                ready.push_back(Desc{key, st.next_fire,
-                                     st.next_fire * slide, end});
-                ++st.next_fire;
-            }
+            settle(*d_state[d], d_slot[d], d_max[d]);
             d_min[d] = INT64_MAX;
             d_max[d] = INT64_MIN;
         }
+        if (stream_rule) trigger();
     }
 
     // Fused synthesis + ingest: generate events [start, start+n) of the
     // declared synthetic law (key = e % K, id = ts = e / K,
     // value = (e % vmod) * vscale + voff -- operators/synth.py) and
     // fold them directly into the pane rings.  Grouping by key turns
-    // the per-tuple hash probe into one map lookup per key, and the
+    // the per-tuple hash probe into one table lookup per key, and the
     // generated columns never materialize in memory: the host feed for
     // a declared synthetic stream costs the fold alone, the columnar
     // twin of the record plane's set_synth lane.
@@ -354,13 +610,14 @@ struct Engine {
     // a declared value-predicate filter folds to it, since the
     // synthetic value of event e depends only on e % vmod.  A dropped
     // event behaves exactly as if a Filter removed it before the
-    // window op: it does not fold, does not advance max_id/arrivals,
-    // and cannot open or trigger windows (the record plane's EOS fires
-    // only up to the last SURVIVING tuple).  ``vtab``: optional
-    // per-residue value table (double[vmod]) computed by applying the
-    // declared map chain sequentially -- bit-identical floats to the
-    // per-event path, where composing the affines into one (vscale,
-    // voff) could differ by ULPs at filter boundaries.
+    // window op: it does not fold, does not advance max_id/arrivals or
+    // the stream time, and cannot open or trigger windows (the record
+    // plane's EOS fires only up to the last SURVIVING tuple).
+    // ``vtab``: optional per-residue value table (double[vmod])
+    // computed by applying the declared map chain sequentially --
+    // bit-identical floats to the per-event path, where composing the
+    // affines into one (vscale, voff) could differ by ULPs at filter
+    // boundaries.
     void synth_ingest(i64 start, i64 n, i64 K, i64 vmod,
                       double vscale, double voff,
                       const unsigned char* mask = nullptr,
@@ -373,13 +630,13 @@ struct Engine {
             // first event e >= start with e % K == k
             i64 e0 = start + (((k - start % K) % K) + K) % K;
             if (e0 >= endE) continue;
-            KeyState& st = keys[k];
+            bool opened;
+            const int32_t slot = tab_slot[locate(k, opened)];
+            KeyState& st = pool[slot];
             const i64 id0 = e0 / K;
             const i64 cnt = (endE - e0 + K - 1) / K;
             if (st.max_id < 0 && !mask) {
-                st.anchor = id0 < win ? 0 : (id0 - win) / slide + 1;
-                st.next_fire = st.anchor;
-                st.pane_base = pane_of(st.anchor * slide);
+                anchor_key(st, id0);
             } else if (st.max_id < 0 && mask) {
                 // anchor on the first SURVIVING id (a masked prefix
                 // must not open windows the record plane never sees)
@@ -391,87 +648,43 @@ struct Engine {
                     if (vm0 >= vmod) vm0 -= vmod;
                 }
                 if (first < 0) continue;  // whole chunk filtered out
-                st.anchor = first < win ? 0 : (first - win) / slide + 1;
-                st.next_fire = st.anchor;
-                st.pane_base = pane_of(st.anchor * slide);
+                anchor_key(st, first);
             }
             i64 hi_rel = pane_of(id0 + cnt - 1) - st.pane_base;
             if (hi_rel >= 0) ensure_pane(st, hi_rel);
-            const i64 accept = st.next_fire > st.anchor
-                ? (st.next_fire - 1) * slide + win : st.anchor * slide;
+            const i64 accept = accept_of(st);
             i64 vm = e0 % vmod;  // value index, advanced mod-free
-            if (!mask) {
-                // headline lane: every event survives, so arrivals and
-                // max_id hoist out of the per-event loop
-                for (i64 j = 0; j < cnt; ++j) {
-                    const i64 id = id0 + j;
-                    const double v = vtab ? vtab[vm]
-                                          : (double)vm * vscale + voff;
-                    vm += kmod;
-                    if (vm >= vmod) vm -= vmod;
-                    if (id < accept) {
-                        ++ignored;
-                        continue;
-                    }
-                    const i64 p = pane_of(id) - st.pane_base;
-                    if (p < 0) continue;
-                    if (hopping) {
-                        const i64 nn = id / slide;
-                        if (id >= nn * slide + win) continue;  // gap
-                        if (nn > st.opened_max) st.opened_max = nn;
-                    }
-                    fold(st, p, v);
-                    if (!is_tb && id >= st.plid[p]) {
-                        st.plid[p] = id;
-                        st.plts[p] = id;  // the law sets ts = id
-                    }
+            i64 last_ok = st.max_id;  // max SURVIVING id
+            for (i64 j = 0; j < cnt; ++j) {
+                const i64 id = id0 + j;
+                const double v = vtab ? vtab[vm]
+                                      : (double)vm * vscale + voff;
+                const bool dropped = mask && !mask[vm];
+                vm += kmod;
+                if (vm >= vmod) vm -= vmod;
+                if (dropped) continue;  // filtered pre-window
+                ++st.arrivals;  // renumber lane: survivors only
+                if (id > last_ok) last_ok = id;
+                if (id < accept) {
+                    ++ignored;
+                    continue;
                 }
-                st.arrivals += cnt;
-                if (id0 + cnt - 1 > st.max_id) st.max_id = id0 + cnt - 1;
-            } else {
-                i64 last_ok = st.max_id;  // max SURVIVING id
-                for (i64 j = 0; j < cnt; ++j) {
-                    const i64 id = id0 + j;
-                    const double v = vtab ? vtab[vm]
-                                          : (double)vm * vscale + voff;
-                    const bool dropped = !mask[vm];
-                    vm += kmod;
-                    if (vm >= vmod) vm -= vmod;
-                    if (dropped) continue;  // filtered pre-window
-                    ++st.arrivals;  // renumber lane: survivors only
-                    if (id > last_ok) last_ok = id;
-                    if (id < accept) {
-                        ++ignored;
-                        continue;
-                    }
-                    const i64 p = pane_of(id) - st.pane_base;
-                    if (p < 0) continue;
-                    if (hopping) {
-                        const i64 nn = id / slide;
-                        if (id >= nn * slide + win) continue;  // gap
-                        if (nn > st.opened_max) st.opened_max = nn;
-                    }
-                    fold(st, p, v);
-                    if (!is_tb && id >= st.plid[p]) {
-                        st.plid[p] = id;
-                        st.plts[p] = id;  // the law sets ts = id
-                    }
+                const i64 p = pane_of(id) - st.pane_base;
+                if (p < 0) continue;
+                if (hopping) {
+                    const i64 nn = id / slide;
+                    if (id >= nn * slide + win) continue;  // gap
+                    if (nn > st.opened_max) st.opened_max = nn;
                 }
-                if (last_ok > st.max_id) st.max_id = last_ok;
+                fold(st, p, v);
+                if (!is_tb && id >= st.plid[p]) {
+                    st.plid[p] = id;
+                    st.plts[p] = id;  // the law sets ts = id
+                }
             }
-            if (!hopping) {
-                const i64 last_w = (st.max_id + 1 + slide - 1) / slide - 1;
-                if (last_w > st.opened_max) st.opened_max = last_w;
-            }
-            while (true) {
-                const i64 end = st.next_fire * slide + win;
-                if (st.max_id < end + delay || st.next_fire > st.opened_max)
-                    break;
-                ready.push_back(Desc{k, st.next_fire,
-                                     st.next_fire * slide, end});
-                ++st.next_fire;
-            }
+            settle(st, slot, last_ok);
         }
+        if (stream_rule) trigger();
     }
 
     // pane accessors tolerant of extents beyond the retained ring
@@ -485,138 +698,192 @@ struct Engine {
         return (r >= 0 && r < (i64)st.pcnt.size()) ? st.pcnt[r] : 0;
     }
 
-    struct SpanInfo {
-        i64 off, base_key;
-        std::vector<i64> prefix;  // prefix tuple counts over the span
+    inline i64 n_ready() const { return (i64)(ready.size() - ready_head); }
+
+    // flush()'s note of one key's part in a take: the extent of its
+    // taken windows, where its panes and their count prefix were
+    // staged, how many windows.  Kept by pool slot beside the key
+    // states, not in them: the passes over the taken windows then read
+    // 48 bytes a key, and each key state is visited once.
+    struct Take {
+        i64 stamp = -1, lo = 0, hi = 0, off = 0, pf = 0;
+        int32_t n = 0;
     };
+    std::vector<Take> takes;
+
+    // A key's windows have been staged: drop the pane prefix nothing
+    // will read again -- never past the earliest window still queued in
+    // `ready` for the key (a partial take leaves fired-but-unstaged
+    // windows, all later than the last one staged, whose extents must
+    // stay resident) -- or, where it has nothing opened and nothing
+    // queued, note the key as done.
+    inline void retire(KeyState& st, int32_t slot) {
+        if (sparse && st.queued == 0 && st.next_fire > st.opened_max) {
+            f_dead.push_back(slot);
+            return;
+        }
+        i64 keep_from = (st.queued > 0 ? st.staged_upto + 1
+                                       : st.next_fire) * slide;
+        i64 cut = pane_of(keep_from) - st.pane_base;
+        i64 sz = (i64)st.pacc.size();
+        if (cut <= 0) return;
+        if (cut > sz) cut = sz;
+        st.pacc.erase(st.pacc.begin(), st.pacc.begin() + cut);
+        st.pcnt.erase(st.pcnt.begin(), st.pcnt.begin() + cut);
+        if (!is_tb) {
+            st.plid.erase(st.plid.begin(), st.plid.begin() + cut);
+            st.plts.erase(st.plts.begin(), st.plts.begin() + cut);
+        }
+        st.pane_base += cut;
+    }
 
     // Stage up to max_windows ready windows as pane partials.
-    // Returns the number staged.
+    // Returns the number staged.  A partial take of a long `ready`
+    // list costs the windows it takes.
     i64 flush(i64 max_windows) {
         st_vals.clear();
         st_cnts.clear();
-        st_starts.clear();
-        st_ends.clear();
-        st_keys.clear();
-        st_gwids.clear();
-        st_rts.clear();
-        if (ready.empty()) return 0;
-        i64 take = std::min<i64>(max_windows, (i64)ready.size());
-        // group taken descriptors per key (they were appended per key
-        // in order, but batches interleave keys)
-        std::unordered_map<i64, std::pair<i64, i64>> span;  // key->min,max
+        if (n_ready() == 0) return 0;
+        const i64 take = std::min<i64>(max_windows, n_ready());
+        const Desc* taken = ready.data() + ready_head;
+        ++flush_id;
+        f_touched.clear();
+        if (takes.size() < pool.size()) takes.resize(pool.size());
+        // the extent of each key's taken windows (a key's windows were
+        // appended in order, but batches interleave keys)
+        i64 n_vals = 0;
         for (i64 d = 0; d < take; ++d) {
-            const Desc& ds = ready[d];
-            auto it = span.find(ds.key);
-            if (it == span.end()) {
-                span[ds.key] = {ds.start, ds.end};
+            const Desc& ds = taken[d];
+            Take& t = takes[ds.slot];
+            const i64 s = ds.lwid * slide, e = s + win;
+            if (t.stamp != flush_id) {
+                t.stamp = flush_id;
+                t.lo = s;
+                t.hi = e;
+                t.n = 0;
+                f_touched.push_back(ds.slot);
+                n_vals += ppw;
             } else {
-                it->second.first = std::min(it->second.first, ds.start);
-                it->second.second = std::max(it->second.second, ds.end);
+                if (s < t.lo) { n_vals += (t.lo - s) / pane; t.lo = s; }
+                if (e > t.hi) { n_vals += (e - t.hi) / pane; t.hi = e; }
             }
+            ++t.n;
         }
-        std::unordered_map<i64, SpanInfo> info;
-        for (auto& [key, mm] : span) {
-            KeyState& st = keys[key];
-            i64 base_key = mm.first, max_end = mm.second;
-            i64 p0 = pane_of(base_key);
-            i64 n_panes = (max_end - base_key) / pane;
-            SpanInfo si;
-            si.off = (i64)st_vals.size();
-            si.base_key = base_key;
-            si.prefix.resize(n_panes + 1);
-            si.prefix[0] = 0;
+        st_vals.resize(n_vals);
+        if (kind == Kind::MEAN) st_cnts.resize(n_vals);
+        f_prefix.resize(n_vals + (i64)f_touched.size());
+        // each key once: its panes staged, its windows accounted, and
+        // (TB) its consumed prefix dropped while its state is at hand
+        f_dead.clear();
+        i64 off = 0, pf = 0;
+        for (int32_t slot : f_touched) {
+            KeyState& st = pool[slot];
+            Take& t = takes[slot];
+            const i64 p0 = pane_of(t.lo);
+            const i64 n_panes = (t.hi - t.lo) / pane;
+            t.off = off;
+            t.pf = pf;
+            i64 seen = 0;
+            f_prefix[pf++] = 0;
             for (i64 p = 0; p < n_panes; ++p) {
-                st_vals.push_back(pane_at(st, p0 + p));
-                si.prefix[p + 1] = si.prefix[p] + cnt_at(st, p0 + p);
-                if (kind == Kind::MEAN)
-                    st_cnts.push_back((double)cnt_at(st, p0 + p));
+                const i64 c = cnt_at(st, p0 + p);
+                st_vals[off] = pane_at(st, p0 + p);
+                if (kind == Kind::MEAN) st_cnts[off] = (double)c;
+                ++off;
+                f_prefix[pf++] = (seen += c);
             }
-            info.emplace(key, std::move(si));
+            st.staged_upto = (t.hi - win) / slide;
+            st.queued -= t.n;
+            if (is_tb) retire(st, slot);
         }
+        st_starts.resize(take);
+        st_ends.resize(take);
+        st_keys.resize(take);
+        st_gwids.resize(take);
+        st_rts.resize(take);
         for (i64 d = 0; d < take; ++d) {
-            const Desc& ds = ready[d];
-            const SpanInfo& si = info[ds.key];
-            st_keys.push_back(ds.key);
-            st_gwids.push_back(ds.lwid);
-            i64 ps = (ds.start - si.base_key) / pane;
-            i64 pe = (ds.end - si.base_key) / pane;
+            const Desc& ds = taken[d];
+            const Take& t = takes[ds.slot];
+            const i64* pfx = f_prefix.data() + t.pf;
+            st_keys[d] = ds.key;
+            st_gwids[d] = ds.lwid;
+            const i64 s = ds.lwid * slide;
+            i64 ps = (s - t.lo) / pane;
+            i64 pe = ps + ppw;
             // a fired window whose extent holds no tuples (gapped id
             // space) stages an EMPTY pane range (start==end) so the
             // device combine emits the masked neutral 0, exactly like
             // the Python/XLA path (window_compute.py `jnp.where`) --
             // otherwise max/min kinds would emit the +-inf pane fill
-            bool empty = si.prefix[pe] == si.prefix[ps];
-            st_starts.push_back(si.off + (empty ? 0 : ps));
-            st_ends.push_back(si.off + (empty ? 0 : pe));
+            bool empty = pfx[pe] == pfx[ps];
+            st_starts[d] = t.off + (empty ? 0 : ps);
+            st_ends[d] = t.off + (empty ? 0 : pe);
             if (is_tb) {
-                st_rts.push_back(ds.lwid * slide + win - 1);
+                st_rts[d] = s + win - 1;
             } else if (empty) {
-                st_rts.push_back(0);
+                st_rts[d] = 0;
             } else {
                 // CB: result ts = ts of the max-id tuple in the extent,
                 // which lives in the last non-empty pane of the range
                 // (binary search on the span's count prefix)
-                const auto& pf = si.prefix;
-                i64 q = std::lower_bound(pf.begin() + ps,
-                                         pf.begin() + pe + 1,
-                                         pf[pe]) - pf.begin();
-                KeyState& st = keys[ds.key];
-                i64 p_abs = pane_of(si.base_key) + (q - 1);
-                i64 r = p_abs - st.pane_base;
-                st_rts.push_back(
-                    (r >= 0 && r < (i64)st.plts.size()) ? st.plts[r] : 0);
+                const KeyState& st = pool[ds.slot];
+                i64 q = std::lower_bound(pfx + ps, pfx + pe + 1, pfx[pe])
+                    - pfx;
+                i64 r = pane_of(t.lo) + (q - 1) - st.pane_base;
+                st_rts[d] = (r >= 0 && r < (i64)st.plts.size())
+                    ? st.plts[r] : 0;
             }
         }
-        ready.erase(ready.begin(), ready.begin() + take);
-        // evict consumed pane prefixes -- but never past the earliest
-        // window still queued in `ready` for the key (a partial take
-        // leaves fired-but-unstaged windows whose extents must stay
-        // resident)
-        std::unordered_map<i64, i64> queued_floor;
-        for (const Desc& ds : ready) {
-            auto it = queued_floor.find(ds.key);
-            if (it == queued_floor.end() || ds.start < it->second)
-                queued_floor[ds.key] = ds.start;
+        ready_head += take;
+        if (ready_head == ready.size()) {
+            ready.clear();
+            ready_head = 0;
+        } else if (ready_head * 2 > ready.size()) {
+            ready.erase(ready.begin(), ready.begin() + ready_head);
+            ready_head = 0;
         }
-        for (auto& [key, mm] : span) {
-            KeyState& st = keys[key];
-            i64 keep_from = st.next_fire * slide;
-            auto qf = queued_floor.find(key);
-            if (qf != queued_floor.end() && qf->second < keep_from)
-                keep_from = qf->second;
-            i64 cut = pane_of(keep_from) - st.pane_base;
-            i64 sz = (i64)st.pacc.size();
-            if (cut <= 0) continue;
-            if (cut > sz) cut = sz;
-            st.pacc.erase(st.pacc.begin(), st.pacc.begin() + cut);
-            st.pcnt.erase(st.pcnt.begin(), st.pcnt.begin() + cut);
-            if (!is_tb) {
-                st.plid.erase(st.plid.begin(), st.plid.begin() + cut);
-                st.plts.erase(st.plts.begin(), st.plts.begin() + cut);
-            }
-            st.pane_base += cut;
+        if (!is_tb)  // the CB lane read the rings' stamps just above
+            for (int32_t slot : f_touched) retire(pool[slot], slot);
+        if (!f_dead.empty()) {
+            const i64 t0 = now_ns();
+            for (int32_t slot : f_dead) evict(slot);
+            evict_ns += now_ns() - t0;
         }
         return take;
     }
 
     void eos() {
-        for (auto& [key, st] : keys) {
-            while (st.next_fire <= st.opened_max) {
-                ready.push_back(Desc{key, st.next_fire,
-                                     st.next_fire * slide,
-                                     st.next_fire * slide + win});
-                ++st.next_fire;
-            }
+        const i64 t0 = now_ns();
+        for (std::size_t s = 0; s < pool.size(); ++s) {
+            KeyState& st = pool[s];
+            if (!st.live) continue;
+            fire_key(st, (int32_t)s, INT64_MAX);
+            st.indexed = false;
         }
+        due.clear();
+        trigger_ns += now_ns() - t0;
+    }
+
+    void clear() {
+        pool.clear();
+        free_slots.clear();
+        n_live = 0;
+        ready.clear();
+        ready_head = 0;
+        due.clear();
+        clear_table(tab_key.size());
+        stream_time = fired_upto = -1;
+        keys_opened = keys_evicted = keys_live_peak = windows_fired = 0;
     }
 
     // -- checkpoint / resume ------------------------------------------
-    // Versioned binary snapshot of all mutable state (per-key pane
-    // rings + fired-but-unstaged descriptors).  The reference has no
-    // checkpointing at all (SURVEY.md §5); this feeds the policy layer
-    // in utils/checkpoint.py through the Python state_dict hooks.
-    static constexpr i64 SNAP_MAGIC = 0x33'4E'46'57;  // "WFN3"
+    // Versioned binary snapshot of all mutable state (the live keys'
+    // pane rings, the fired-but-unstaged descriptors, the stream time
+    // and the churn counters; an evicted key is not in it).  The
+    // reference has no checkpointing at all (SURVEY.md §5); this feeds
+    // the policy layer in utils/checkpoint.py through the Python
+    // state_dict hooks.
+    static constexpr i64 SNAP_MAGIC = 0x34'4E'46'57;  // "WFN4"
 
     template <typename T>
     static void put(std::vector<unsigned char>& b, const T& v) {
@@ -659,47 +926,62 @@ struct Engine {
         put(b, win); put(b, slide); put(b, delay);
         put(b, (i64)(is_tb ? 1 : 0));
         put(b, (i64)(renumber ? 1 : 0));
+        put(b, (i64)(dense ? 1 : 0));
         put(b, (i64)kind);
-        put(b, (i64)keys.size());
-        for (const auto& [key, st] : keys) {
-            put(b, key);
+        put(b, stream_time); put(b, fired_upto);
+        put(b, keys_opened); put(b, keys_evicted);
+        put(b, keys_live_peak); put(b, windows_fired);
+        put(b, n_live);
+        for (const KeyState& st : pool) {
+            if (!st.live) continue;
+            put(b, st.key);
             put(b, st.next_fire); put(b, st.anchor);
             put(b, st.opened_max); put(b, st.max_id);
             put(b, st.pane_base); put(b, st.arrivals);
+            put(b, st.staged_upto);
             put_vec(b, st.pacc);
             put_vec(b, st.pcnt);
             put_vec(b, st.plid);
             put_vec(b, st.plts);
         }
-        put(b, (i64)ready.size());
-        for (const Desc& d : ready) {
-            put(b, d.key); put(b, d.lwid); put(b, d.start); put(b, d.end);
+        put(b, n_ready());
+        for (std::size_t i = ready_head; i < ready.size(); ++i) {
+            put(b, ready[i].key); put(b, ready[i].lwid);
         }
         return b;
     }
 
     bool deserialize(const unsigned char* p, i64 len) {
         const unsigned char* end = p + len;
-        i64 magic, w, s, d, tb, rn, kd, nk;
+        i64 magic, w, s, d, tb, rn, dn, kd, nk;
         if (!get(p, end, magic) || magic != SNAP_MAGIC) return false;
         if (!get(p, end, w) || !get(p, end, s) || !get(p, end, d)
-            || !get(p, end, tb) || !get(p, end, rn) || !get(p, end, kd))
+            || !get(p, end, tb) || !get(p, end, rn) || !get(p, end, dn)
+            || !get(p, end, kd))
             return false;
         // snapshot must match this engine's static configuration
         if (w != win || s != slide || d != delay
             || (tb != 0) != is_tb || (rn != 0) != renumber
-            || kd != (i64)kind)
+            || (dn != 0) != dense || kd != (i64)kind)
+            return false;
+        clear();
+        if (!get(p, end, stream_time) || !get(p, end, fired_upto)
+            || !get(p, end, keys_opened) || !get(p, end, keys_evicted)
+            || !get(p, end, keys_live_peak) || !get(p, end, windows_fired))
             return false;
         if (!get(p, end, nk) || nk < 0) return false;
-        keys.clear();
-        ready.clear();
+        const i64 opened = keys_opened, peak = keys_live_peak;
         for (i64 i = 0; i < nk; ++i) {
             i64 key;
-            KeyState st;
-            if (!get(p, end, key) || !get(p, end, st.next_fire)
-                || !get(p, end, st.anchor)
+            if (!get(p, end, key)) return false;
+            bool fresh;
+            const int32_t slot = tab_slot[locate(key, fresh)];
+            if (!fresh) return false;  // a key twice
+            KeyState& st = pool[slot];
+            if (!get(p, end, st.next_fire) || !get(p, end, st.anchor)
                 || !get(p, end, st.opened_max) || !get(p, end, st.max_id)
                 || !get(p, end, st.pane_base) || !get(p, end, st.arrivals)
+                || !get(p, end, st.staged_upto)
                 || !get_vec(p, end, st.pacc) || !get_vec(p, end, st.pcnt)
                 || !get_vec(p, end, st.plid) || !get_vec(p, end, st.plts))
                 return false;
@@ -711,21 +993,28 @@ struct Engine {
             // the pairwise checks above and then write out of bounds
             if (!is_tb && st.plid.size() != st.pacc.size())
                 return false;
-            keys.emplace(key, std::move(st));
+            if (stream_rule) index_key(st, slot);
         }
+        // locate() counted the restored keys as opened: the snapshot's
+        // own counts stand
+        keys_opened = opened;
+        keys_live_peak = std::max(peak, n_live);
         i64 nr;
         if (!get(p, end, nr) || nr < 0) return false;
         for (i64 i = 0; i < nr; ++i) {
             Desc ds;
-            if (!get(p, end, ds.key) || !get(p, end, ds.lwid)
-                || !get(p, end, ds.start) || !get(p, end, ds.end))
+            if (!get(p, end, ds.key) || !get(p, end, ds.lwid))
                 return false;
+            // a queued window belongs to a live key
+            std::size_t h = home_of(ds.key);
+            const std::size_t mask = tab_key.size() - 1;
+            while (tab_slot[h] >= 0 && tab_key[h] != ds.key)
+                h = (h + 1) & mask;
+            if (tab_slot[h] < 0) return false;
+            ds.slot = tab_slot[h];
+            ++pool[ds.slot].queued;
             ready.push_back(ds);
         }
-        // the scatter table caches KeyState pointers; rebuild lazily
-        tab_key.assign(tab_key.size(), EMPTY);
-        std::fill(tab_state.begin(), tab_state.end(), nullptr);
-        std::fill(tab_stamp.begin(), tab_stamp.end(), (i64)-1);
         return p == end;
     }
 };
@@ -735,9 +1024,9 @@ struct Engine {
 extern "C" {
 
 void* wfn_engine_new(i64 win, i64 slide, int is_tb, i64 delay,
-                     int renumber, int kind) {
+                     int renumber, int kind, int dense) {
     return new Engine(win, slide, is_tb != 0, delay, renumber != 0,
-                      static_cast<Kind>(kind));
+                      static_cast<Kind>(kind), dense != 0);
 }
 
 void wfn_engine_free(void* e) { delete static_cast<Engine*>(e); }
@@ -748,7 +1037,7 @@ i64 wfn_engine_ingest(void* ep, const i64* keys, const i64* ids,
                       const i64* tss, const double* vals, i64 n) {
     Engine& e = *static_cast<Engine*>(ep);
     e.ingest_batch(keys, ids, tss, vals, n);
-    return (i64)e.ready.size();
+    return e.n_ready();
 }
 
 // f32 value column variant (no widening copy on the host side).
@@ -756,7 +1045,7 @@ i64 wfn_engine_ingest_f32(void* ep, const i64* keys, const i64* ids,
                           const i64* tss, const float* vals, i64 n) {
     Engine& e = *static_cast<Engine*>(ep);
     e.ingest_batch(keys, ids, tss, vals, n);
-    return (i64)e.ready.size();
+    return e.n_ready();
 }
 
 // Fused synthesis + ingest of the declared synthetic law; returns the
@@ -765,7 +1054,7 @@ i64 wfn_engine_synth_ingest(void* ep, i64 start, i64 n, i64 n_keys,
                             i64 vmod, double vscale, double voff) {
     Engine& e = *static_cast<Engine*>(ep);
     e.synth_ingest(start, n, n_keys, vmod, vscale, voff);
-    return (i64)e.ready.size();
+    return e.n_ready();
 }
 
 // Masked/tabled variant: mask is uint8[vmod] (entry 0 drops the event
@@ -779,15 +1068,33 @@ i64 wfn_engine_synth_ingest_masked(void* ep, i64 start, i64 n,
                                    const double* vtab) {
     Engine& e = *static_cast<Engine*>(ep);
     e.synth_ingest(start, n, n_keys, vmod, vscale, voff, mask, vtab);
-    return (i64)e.ready.size();
+    return e.n_ready();
 }
 
 i64 wfn_engine_ready(void* ep) {
-    return (i64)static_cast<Engine*>(ep)->ready.size();
+    return static_cast<Engine*>(ep)->n_ready();
 }
 
 i64 wfn_engine_ignored(void* ep) {
     return static_cast<Engine*>(ep)->ignored;
+}
+
+// What key churn costs and how many keys there are, into out[9]:
+// nanoseconds spent creating key states (open), finding and queueing
+// fired windows (trigger) and evicting (evict), since the engine was
+// made; keys opened, keys evicted, keys live now and at their peak,
+// windows fired; the stream time (-1 before the first stamp).
+void wfn_engine_stats(void* ep, i64* out) {
+    const Engine& e = *static_cast<Engine*>(ep);
+    out[0] = e.open_ns;
+    out[1] = e.trigger_ns;
+    out[2] = e.evict_ns;
+    out[3] = e.keys_opened;
+    out[4] = e.keys_evicted;
+    out[5] = e.n_live;
+    out[6] = e.keys_live_peak;
+    out[7] = e.windows_fired;
+    out[8] = e.stream_time;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }
@@ -830,10 +1137,7 @@ i64 wfn_engine_serialize(void* ep, unsigned char* buf, i64 cap) {
 int wfn_engine_deserialize(void* ep, const unsigned char* buf, i64 len) {
     Engine& e = *static_cast<Engine*>(ep);
     bool ok = e.deserialize(buf, len);
-    if (!ok) {  // never leave partially-restored state behind
-        e.keys.clear();
-        e.ready.clear();
-    }
+    if (!ok) e.clear();  // never leave partially-restored state behind
     return ok ? 1 : 0;
 }
 

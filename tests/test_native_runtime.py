@@ -315,13 +315,15 @@ def test_engine_deserialize_rejects_huge_length_field():
               np.arange(10, dtype=np.int64), np.ones(10))
     blob = bytearray(e1.serialize())
     import struct
-    # parse the WFN3 snapshot framing (window_engine.cpp serialize()):
-    # the 8-i64 header (magic,win,slide,delay,tb,rn,kind,nkeys) and the
-    # first key's 7 fixed i64s (key,next_fire,anchor,opened_max,max_id,
-    # pane_base,arrivals), then walk the four per-key vectors
+    # parse the WFN4 snapshot framing (window_engine.cpp serialize()):
+    # the 15-i64 header (magic,win,slide,delay,tb,rn,dense,kind,
+    # stream_time,fired_upto,keys_opened,keys_evicted,keys_live_peak,
+    # windows_fired,nkeys) and the first live key's 8 fixed i64s
+    # (key,next_fire,anchor,opened_max,max_id,pane_base,arrivals,
+    # staged_upto), then walk the four per-key vectors
     # (pacc,pcnt,plid,plts) by their length headers and corrupt the
     # first non-empty one
-    off = 8 * 8 + 7 * 8
+    off = 15 * 8 + 8 * 8
     corrupted = False
     for _ in range(4):
         n = struct.unpack_from("<q", blob, off)[0]

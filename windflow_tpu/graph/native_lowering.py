@@ -219,8 +219,10 @@ def _run_columnar_synth(graph, plan, mask, vtab) -> bool:
     kind = w.win_kind_name
     # ids are dense from 0, so the renumber lane would assign the same
     # ids (no filters reach here with renumbering -- see the spec fn)
+    # dense: the record graph this lowers emits a key's empty windows,
+    # and lowering must never change results
     eng = NativeWindowEngine(win_len, slide_len, is_tb, 0,
-                             renumber=False, kind=kind)
+                             renumber=False, kind=kind, dense=True)
     sink_ctx = RuntimeContext(1, 0)
     sink_fn = with_context(plan["sink"].fn, 1, sink_ctx)
 

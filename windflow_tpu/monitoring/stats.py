@@ -57,10 +57,13 @@ from ..telemetry.histogram import LogHistogram
 # 13 = Spans.Launches rows gain Collected (how many launches were
 # collected ready / waited / forced / flushed) and their Slowest row
 # its own Collected.
+# 14 = Spans.Operators rows of a window operator on the native lane gain
+# Counters (keys_opened, keys_evicted, keys_live, keys_live_peak,
+# windows_fired) and their Phases the engine's open / trigger / evict.
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 13
+SCHEMA_VERSION = 14
 
 
 @dataclass

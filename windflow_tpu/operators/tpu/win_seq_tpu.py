@@ -21,6 +21,7 @@ Win_Seq_GPU does in the reference (win_farm_gpu.hpp:82-86).
 """
 from __future__ import annotations
 
+import heapq as _heapq
 import threading as _threading
 import time as _time
 from typing import Any, Callable, Dict, List, Optional
@@ -321,7 +322,7 @@ class _TPUKeyState:
     __slots__ = ("sort_keys", "ts", "values", "pending_sort", "pending_ts",
                  "pending_val", "pending_chunks", "next_fire", "opened_max",
                  "max_id", "renumber_next", "emit_counter", "anchor",
-                 "pane_synced", "min_new_id")
+                 "pane_synced", "min_new_id", "queued", "indexed")
 
     def __init__(self, emit_counter_start=0):
         # resident-lane sync state (ops/window_compute.ResidentPaneCarry):
@@ -347,6 +348,8 @@ class _TPUKeyState:
                                   # native engine's anchor)
         self.opened_max = -1      # highest lwid opened by any tuple
         self.max_id = -1
+        self.queued = 0           # fired windows not yet staged
+        self.indexed = False      # listed in the logic's ``_due`` heap
         self.renumber_next = 0
         self.emit_counter = emit_counter_start
 
@@ -418,6 +421,23 @@ class WinSeqTPULogic(NodeLogic):
         self.closing_func = closing_func
         self.emit_batches = emit_batches
         self.keys: Dict[Any, _TPUKeyState] = {}
+        # THE FIRING RULE (docs/RUNTIME.md "When a window fires"; the
+        # native engine has the same, native/window_engine.cpp): TB
+        # windows on real stamps fire on this replica's stream time, the
+        # largest stamp it has ingested over all keys, so a key that goes
+        # quiet gets its rows when the stream passes them; CB windows and
+        # renumbered ids count a key's own arrivals and fire on the key's
+        # own largest id.  Under the stream rule a SEQ replica (output
+        # ids are window ids) emits a row only for a window that holds a
+        # tuple of the key and drops a key whose last window is staged;
+        # the other roles number a key's windows densely for the next
+        # stage, so they emit every one and keep every key.
+        self._stream_rule = win_type == WinType.TB and not renumbering
+        self._sparse = self._stream_rule and role == Role.SEQ
+        self._stream_time = -1
+        self._fired_time = -1     # the stream time the last trigger saw
+        self._due: List = []      # heap of (fire at, n, key): keys with an
+        self._due_n = 0           # opened window, by when it fires next
         # batch under assembly: descriptors (key, gwid, start_key, end_key)
         self.descriptors: List = []
         # in-flight batches, oldest first: (handle, descriptors, birth).
@@ -516,7 +536,9 @@ class WinSeqTPULogic(NodeLogic):
                 self._native = NativeWindowEngine(
                     win_len, slide_len, win_type == WinType.TB,
                     triggering_delay, renumber=renumbering,
-                    kind=win_kind)
+                    kind=win_kind, dense=role != Role.SEQ)
+                # the engine's churn clock and counters as last read
+                self._churn = [0] * len(NativeWindowEngine.STATS)
         if resident is True:
             self._enable_resident(required=True)
 
@@ -627,10 +649,14 @@ class WinSeqTPULogic(NodeLogic):
     def _name_spans(self, op: str, graph) -> None:
         self._span_op = op
         for phase in ("fold", "flush", "stage", "submit_wait", "dispatch",
-                      "ready_wait", "work_wait", "block", "emit"):
+                      "ready_wait", "work_wait", "block", "emit",
+                      "open", "trigger", "evict"):
             setattr(self, "_n_" + phase, f"wf/{op}/{phase}")
         self._launches = (graph.ring(op) if graph is not None
                           else spans.LaunchRing(op))
+        self._counters = (graph.counters_of(op)
+                          if graph is not None and self._native is not None
+                          else spans.Counters(op))
 
     def _ingest_track(self):
         """The calling thread's span track, kept while the same thread
@@ -951,11 +977,12 @@ class WinSeqTPULogic(NodeLogic):
             out = self.result_factory()
             out.value = float(val)
             out.set_control_fields(key, gwid, rts)
-            st = self.keys[kd_key]
             if self.role == Role.MAP:
+                st = self.keys[kd_key]
                 out.set_control_fields(key, st.emit_counter, rts)
                 st.emit_counter += self.map_indexes[1]
             elif self.role == Role.PLQ:
+                st = self.keys[kd_key]
                 new_id = wa.plq_renumbered_id(default_hash(key),
                                               st.emit_counter, self.config)
                 out.set_control_fields(key, new_id, rts)
@@ -1074,11 +1101,13 @@ class WinSeqTPULogic(NodeLogic):
         self._submit({"value": flat_vals}, starts, ends, gwids, descs,
                      birth, emit, engine=eng)
         # the staged flat buffer is dispatcher-owned now: evict consumed
-        # prefixes
+        # prefixes, and the keys whose last window this was
         for k in keys_involved:
             st = self.keys[k]
-            self._evict(st, wa.initial_id_of_key(default_hash(k), self.config,
-                                                 self.role))
+            st.queued -= len(per_key[k])
+            if not self._drop_if_done(k, st):
+                self._evict(st, wa.initial_id_of_key(default_hash(k),
+                                                     self.config, self.role))
 
     def _launch_resident(self, descs, per_key, keys_involved, pane,
                          kind, emit) -> None:
@@ -1173,6 +1202,9 @@ class WinSeqTPULogic(NodeLogic):
         if self.stats is not None:  # single-writer: ingest thread
             self.stats.device_state_bytes = carry.state_bytes
         for k in keys_involved:
+            # a resident key keeps its row in the device forest, so its
+            # host state stays too
+            self.keys[k].queued -= len(per_key[k])
             self._evict(self.keys[k], spans[k][0])
 
     def _count_engine(self):
@@ -1182,29 +1214,117 @@ class WinSeqTPULogic(NodeLogic):
         return self._count_eng
 
     # -- descriptor generation (window assignment) -------------------------
-    def _fire_ready(self, key, st: _TPUKeyState, id_: int, hashcode: int,
-                    emit) -> None:
+    def _fire_key(self, key, st: _TPUKeyState, front, emit) -> None:
+        """Queue every window of the key that ``front`` has passed: the
+        one place the rule is applied (the stream time, a CB key's own
+        largest id, or infinity at EOS)."""
         cfg = self.config
+        hashcode = default_hash(key)
         first_gwid = wa.first_gwid_of_key(hashcode, cfg)
         initial_id = wa.initial_id_of_key(hashcode, cfg, self.role)
-        slack = self.triggering_delay if self.win_type == WinType.TB else 0
-        while True:
+        tb = self.win_type == WinType.TB
+        slack = self.triggering_delay if tb else 0
+        if self._sparse:
+            self._consolidate(st)
+        while st.next_fire <= st.opened_max:
             lwid = st.next_fire
             start = initial_id + lwid * self.slide_len
             end = start + self.win_len
-            # a window fires once a tuple beyond its extent (+delay) is seen
-            if st.max_id < end + slack or lwid > st.opened_max:
+            if front < end + slack:
                 break
+            st.next_fire += 1
+            if self._sparse:
+                lo, hi = np.searchsorted(st.sort_keys, (start, end))
+                if lo == hi:
+                    continue      # holds no tuple of the key: no row
             gwid = wa.gwid_of_lwid(first_gwid, lwid, cfg)
             rts = (gwid * self.slide_len + self.win_len - 1
-                   if self.win_type == WinType.TB else -1)  # CB: at launch
+                   if tb else -1)  # CB: resolved at launch
             if not self.descriptors:
-                        self._batch_birth = _time.perf_counter()
+                self._batch_birth = _time.perf_counter()
             self.descriptors.append((key, gwid, start, end, rts, key))
-            st.next_fire += 1
+            st.queued += 1
             if (len(self.descriptors) >= self.batch_len
                     and not self.chunk_hold):
                 self._launch(emit)
+
+    def _passed_lwid(self, initial_id: int) -> int:
+        """Local id of the last window the stream has passed for a key
+        whose windows start at ``initial_id``: fired for every key, so a
+        tuple below its end is late for every key, also for one whose
+        state is gone.  -1 where there is none (or no stream rule)."""
+        if not self._stream_rule:
+            return -1
+        t = (self._fired_time - self.triggering_delay - self.win_len
+             - initial_id)
+        return -1 if t < 0 else t // self.slide_len
+
+    def _admit(self, st: _TPUKeyState, first_rel: int, passed: int):
+        """Anchor a key on its first data, skip what lies empty before a
+        returning one's, and return the acceptance boundary (relative to
+        the key's initial id) with whether a tuple below it is late: it
+        is where a window has fired there, the key's own last or the
+        last the stream passed; below a new key's anchor lies a hopping
+        gap."""
+        first_w = ((first_rel - self.win_len) // self.slide_len + 1
+                   if first_rel >= self.win_len else 0)
+        if st.max_id < 0:
+            # first data: anchor the fire frontier at the first
+            # containing window (an epoch-scale first id must not fire
+            # ~id/slide empty windows), never at one the stream passed
+            st.anchor = st.next_fire = max(first_w, passed + 1)
+        elif (self._sparse and st.next_fire > st.opened_max
+              and first_w > st.next_fire):
+            st.next_fire = first_w
+        fired = st.next_fire > st.anchor
+        own = (self.win_len + (st.next_fire - 1) * self.slide_len
+               if fired else st.anchor * self.slide_len)
+        if passed >= 0:
+            return max(own, passed * self.slide_len + self.win_len), True
+        return own, fired
+
+    def _settle(self, key, st: _TPUKeyState, initial_id: int, emit) -> None:
+        """A key has new data: its part in the firing."""
+        if not self._stream_rule:
+            self._fire_key(key, st, st.max_id, emit)
+            return
+        if st.max_id > self._stream_time:
+            self._stream_time = st.max_id
+        self._index_key(key, st, initial_id)
+
+    def _index_key(self, key, st: _TPUKeyState, initial_id: int) -> None:
+        if st.indexed or st.next_fire > st.opened_max:
+            return
+        st.indexed = True
+        self._due_n += 1
+        _heapq.heappush(self._due, (
+            initial_id + st.next_fire * self.slide_len + self.win_len
+            + self.triggering_delay, self._due_n, key))
+
+    def _trigger(self, emit) -> None:
+        """The stream has moved: fire the windows it has passed, for the
+        keys that have them."""
+        now = self._fired_time = self._stream_time
+        due = self._due
+        while due and due[0][0] <= now:
+            key = _heapq.heappop(due)[2]
+            st = self.keys[key]
+            st.indexed = False
+            self._fire_key(key, st, now, emit)
+            self._index_key(key, st, wa.initial_id_of_key(
+                default_hash(key), self.config, self.role))
+            self._drop_if_done(key, st)
+
+    def _drop_if_done(self, key, st: _TPUKeyState) -> bool:
+        """Evict a key whose every opened window has fired and been
+        staged: a later tuple of it opens a new key."""
+        if (not self._sparse or st.queued or st.indexed
+                or st.next_fire <= st.opened_max
+                or self._resident is not None
+                or self.keys.get(key) is not st):
+            return False
+        del self.keys[key]
+        return True
 
     # -- columnar ingest (the zero-copy fast path: a whole TupleBatch is
     # partitioned by key and appended per key vectorized; the analogue of
@@ -1220,8 +1340,28 @@ class WinSeqTPULogic(NodeLogic):
         finally:
             tr.end()
 
+    def _account_churn(self) -> None:
+        """What the native engine timed and counted since the last look:
+        ``open``, ``trigger`` and ``evict`` become children of the span
+        open round the call, the counters go to the registry.  A look
+        that finds nothing new costs one native call."""
+        s = self._native.stats()
+        last = self._churn
+        if s[0] == last[0] and s[1] == last[1] and s[2] == last[2] \
+                and s[3] == last[3]:
+            return                # evict and fired move with these
+        tr = self._ingest_track()
+        for i, name in ((0, self._n_open), (1, self._n_trigger),
+                        (2, self._n_evict)):
+            if s[i] != last[i]:
+                tr.account(name, s[i] - last[i])
+        last[:] = s
+        self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
+                            last[3:8])
+
     def _flush_and_submit(self, emit, max_windows) -> None:
         out = self._native.flush(max_windows or max(self.batch_len, 4096))
+        self._account_churn()
         if out is None:
             return
         vals, starts, ends, d_keys, d_gwids, d_rts = out[:6]
@@ -1255,8 +1395,10 @@ class WinSeqTPULogic(NodeLogic):
 
     def _svc_batch_native(self, batch: TupleBatch, emit):
         ids = batch.id if self.win_type == WinType.CB else batch.ts
-        self._folded(self._native.ingest(batch.key, ids, batch.ts,
-                                         batch["value"]), len(batch), emit)
+        ready = self._native.ingest(batch.key, ids, batch.ts,
+                                    batch["value"])
+        self._account_churn()
+        self._folded(ready, len(batch), emit)
 
     def _folded(self, ready: int, n: int, emit) -> None:
         """After a native ingest of ``n`` events left ``ready`` windows:
@@ -1303,19 +1445,12 @@ class WinSeqTPULogic(NodeLogic):
                 k_ids = np.arange(st.renumber_next,
                                   st.renumber_next + (hi - lo))
                 st.renumber_next += hi - lo
-            if st.max_id < 0 and len(k_ids):
-                # first data: anchor the fire frontier at the first
-                # containing window (native-engine parity; an
-                # epoch-scale first id must not fire ~id/slide empty
-                # windows)
-                rel = int(k_ids.min()) - initial_id
-                if rel >= self.win_len:
-                    st.anchor = (rel - self.win_len) // self.slide_len + 1
-                    st.next_fire = st.anchor
+            if not len(k_ids):
+                continue
             # acceptance: drop tuples behind the already-fired frontier
-            min_boundary = (self.win_len + (st.next_fire - 1) * self.slide_len
-                            if st.next_fire > st.anchor
-                            else st.anchor * self.slide_len)
+            passed = self._passed_lwid(initial_id)
+            min_boundary, late = self._admit(
+                st, int(k_ids.min()) - initial_id, passed)
             keep = k_ids >= initial_id + min_boundary
             if self.win_len < self.slide_len:  # hopping: drop gap tuples
                 n = (k_ids - initial_id) // self.slide_len
@@ -1323,11 +1458,16 @@ class WinSeqTPULogic(NodeLogic):
                 keep &= (off >= n * self.slide_len) & \
                     (off < n * self.slide_len + self.win_len)
             n_drop = int((~keep).sum())
-            if n_drop and st.next_fire > st.anchor:
+            if n_drop and late:
                 self.ignored_tuples += n_drop
-            k_ids = k_ids[keep]
-            if len(k_ids) == 0:
+            if n_drop == len(k_ids):
+                if self._stream_rule:
+                    # late, yet the stream has come this far
+                    self._stream_time = max(self._stream_time,
+                                            int(k_ids.max()))
+                self._drop_if_done(key, st)
                 continue
+            k_ids = k_ids[keep]
             st.pending_chunks.append(
                 (k_ids.astype(np.int64), tss_s[lo:hi][keep],
                  vals_s[lo:hi][keep].astype(np.float64)))
@@ -1341,7 +1481,9 @@ class WinSeqTPULogic(NodeLogic):
                                        self.slide_len)
             if last_w >= 0:
                 st.opened_max = max(st.opened_max, last_w)
-            self._fire_ready(key, st, st.max_id, hashcode, emit)
+            self._settle(key, st, initial_id, emit)
+        if self._stream_rule:
+            self._trigger(emit)
         if (self.descriptors and not self.chunk_hold
                 and (self._buffered_since_launch >= self.max_buffer_elems
                      or self._launch_due())):
@@ -1364,9 +1506,11 @@ class WinSeqTPULogic(NodeLogic):
                 tr = self._ingest_track()
                 tr.begin(self._n_fold)
                 try:
-                    self._folded(self._native.synth_ingest(
+                    ready = self._native.synth_ingest(
                         item.start, item.n, item.n_keys, item.vmod,
-                        item.vscale, item.voff), item.n, emit)
+                        item.vscale, item.voff)
+                    self._account_churn()
+                    self._folded(ready, item.n, emit)
                 finally:
                     tr.end()
             else:
@@ -1402,21 +1546,17 @@ class WinSeqTPULogic(NodeLogic):
         cfg = self.config
         initial_id = wa.initial_id_of_key(hashcode, cfg, self.role)
         if not is_marker:
-            if st.max_id < 0:
-                rel = id_ - initial_id
-                if rel >= self.win_len:
-                    st.anchor = (rel - self.win_len) // self.slide_len + 1
-                    st.next_fire = st.anchor
-            min_boundary = (self.win_len + (st.next_fire - 1) * self.slide_len
-                            if st.next_fire > st.anchor
-                            else st.anchor * self.slide_len)
+            passed = self._passed_lwid(initial_id)
+            min_boundary, late = self._admit(st, id_ - initial_id, passed)
             if id_ < initial_id + min_boundary:
-                if st.next_fire > st.anchor:
+                if late:
                     self.ignored_tuples += 1
+                self._drop_if_done(key, st)
                 return
             last_w = wa.last_window_of(id_, initial_id, self.win_len,
                                        self.slide_len)
             if last_w < 0:
+                self._drop_if_done(key, st)
                 return  # hopping gap
             st.opened_max = max(st.opened_max, last_w)
             st.pending_sort.append(id_)
@@ -1426,7 +1566,9 @@ class WinSeqTPULogic(NodeLogic):
                     st.min_new_id is None or id_ < st.min_new_id):
                 st.min_new_id = id_
         st.max_id = max(st.max_id, id_)
-        self._fire_ready(key, st, id_, hashcode, emit)
+        self._settle(key, st, initial_id, emit)
+        if self._stream_rule:
+            self._trigger(emit)
         if (self.descriptors and self._launch_due()
                 and not self.chunk_hold):
             self._launch(emit)
@@ -1441,23 +1583,10 @@ class WinSeqTPULogic(NodeLogic):
                 self._native_launch(emit)
             self._drain_all(emit)
             return
-        for key, st in self.keys.items():
-            hashcode = default_hash(key)
-            cfg = self.config
-            first_gwid = wa.first_gwid_of_key(hashcode, cfg)
-            initial_id = wa.initial_id_of_key(hashcode, cfg, self.role)
-            for lwid in range(st.next_fire, st.opened_max + 1):
-                start = initial_id + lwid * self.slide_len
-                end = start + self.win_len
-                gwid = wa.gwid_of_lwid(first_gwid, lwid, cfg)
-                # CB: -1 sentinel -> _launch resolves the result ts to
-                # the last tuple in the extent (same as the fired path)
-                rts = (gwid * self.slide_len + self.win_len - 1
-                       if self.win_type == WinType.TB else -1)
-                self.descriptors.append((key, gwid, start, end, rts, key))
-                st.next_fire += 1
-                if len(self.descriptors) >= self.batch_len:
-                    self._launch(emit)
+        for key, st in list(self.keys.items()):
+            st.indexed = False
+            self._fire_key(key, st, float("inf"), emit)
+        self._due.clear()
         self._launch(emit)
         self._drain_all(emit)
 
@@ -1519,11 +1648,12 @@ class WinSeqTPULogic(NodeLogic):
                 "staging": len(self.descriptors)}
 
     def keyed_state_census(self):
-        """(key count, byte estimate) of the per-key window state.
-        Python path: sampled _TPUKeyState arrays; native path: key
-        count only (the engine owns the buffers)."""
+        """(key count, byte estimate) of the per-key window state: the
+        keys that are live, not every key ever seen.  Python path:
+        sampled _TPUKeyState arrays; native path: the engine's count of
+        live keys (it owns the buffers: no byte estimate)."""
         if self._native is not None:
-            n = len(self._plq_counters) or len(self._key_intern)
+            n = self._native.snapshot()["keys_live"]
             return (n, 0) if n else None
         keys = self.keys
         n = len(keys)
@@ -1554,6 +1684,8 @@ class WinSeqTPULogic(NodeLogic):
             "ignored_tuples": self.ignored_tuples,
             "launched_batches": self.launched_batches,
             "buffered": self._buffered_since_launch,
+            "stream_time": self._stream_time,
+            "fired_time": self._fired_time,
         }
         if self._native is not None:
             st["native"] = self._native.serialize()
@@ -1587,6 +1719,13 @@ class WinSeqTPULogic(NodeLogic):
                     "replica runs the native engine")
             import copy
             self.keys = copy.deepcopy(state["keys"])
+            self._stream_time = state.get("stream_time", -1)
+            self._fired_time = state.get("fired_time", -1)
+            self._due = []
+            for key, st in self.keys.items():
+                st.indexed = False
+                self._index_key(key, st, wa.initial_id_of_key(
+                    default_hash(key), self.config, self.role))
             # re-derive the non-integral-key flag from the restored
             # store (every descriptor's key is in it): the columnar
             # emit shortcut keys off the flag, and a fresh replica

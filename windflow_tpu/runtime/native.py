@@ -175,7 +175,7 @@ def get_lib():
         PLL = ctypes.POINTER(LL)
         PD = ctypes.POINTER(ctypes.c_double)
         lib.wfn_engine_new.restype = ctypes.c_void_p
-        lib.wfn_engine_new.argtypes = [LL, LL, ctypes.c_int, LL,
+        lib.wfn_engine_new.argtypes = [LL, LL, ctypes.c_int, LL, ctypes.c_int,
                                        ctypes.c_int, ctypes.c_int]
         lib.wfn_engine_free.argtypes = [ctypes.c_void_p]
         lib.wfn_engine_ingest.restype = LL
@@ -198,6 +198,7 @@ def get_lib():
         lib.wfn_engine_ready.argtypes = [ctypes.c_void_p]
         lib.wfn_engine_ignored.restype = LL
         lib.wfn_engine_ignored.argtypes = [ctypes.c_void_p]
+        lib.wfn_engine_stats.argtypes = [ctypes.c_void_p, PLL]
         lib.wfn_engine_eos.argtypes = [ctypes.c_void_p]
         lib.wfn_engine_flush.restype = LL
         lib.wfn_engine_flush.argtypes = [
@@ -577,21 +578,53 @@ class NativeRecordPipeline:
 
 class NativeWindowEngine:
     """ctypes wrapper over the C++ columnar window engine
-    (native/window_engine.cpp)."""
+    (native/window_engine.cpp).  TB windows on real stamps fire on the
+    engine's stream time (docs/RUNTIME.md "When a window fires"); such
+    an engine emits a row for every window that holds a tuple of the
+    key and evicts a key whose last window has been staged.  ``dense``
+    is for callers whose output ids count a key's windows (role PLQ) or
+    whose host twin emits the empty ones (graph/native_lowering.py):
+    every window from the key's anchor on is emitted and no key is
+    evicted."""
 
-    __slots__ = ("lib", "ptr")
+    __slots__ = ("lib", "ptr", "_stats", "_stats_p")
 
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4}
+    # what ``stats()`` returns, in order: nanoseconds creating key
+    # states, finding and queueing fired windows, evicting; keys opened,
+    # evicted, live now, live at their peak; windows fired; stream time
+    STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
+             "keys_evicted", "keys_live", "keys_live_peak",
+             "windows_fired", "stream_time")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
-                 delay: int = 0, renumber: bool = False, kind: str = "sum"):
+                 delay: int = 0, renumber: bool = False, kind: str = "sum",
+                 dense: bool = False):
         self.lib = get_lib()
         if self.lib is None:
             raise RuntimeError("native runtime unavailable")
         self.ptr = self.lib.wfn_engine_new(win_len, slide_len,
                                            1 if is_tb else 0, delay,
                                            1 if renumber else 0,
-                                           self.KINDS[kind])
+                                           self.KINDS[kind],
+                                           1 if dense else 0)
+        self._stats = (ctypes.c_longlong * len(self.STATS))()
+        self._stats_p = ctypes.cast(self._stats,
+                                    ctypes.POINTER(ctypes.c_longlong))
+
+    def stats(self):
+        """The engine's churn clock and counters (:data:`STATS`) as a
+        ctypes array the engine refills on every call: read it before
+        the next one."""
+        self.lib.wfn_engine_stats(self.ptr, self._stats_p)
+        return self._stats
+
+    def snapshot(self) -> dict:
+        """:data:`STATS` by name, through a buffer of its own: for a
+        thread that is not the one that feeds the engine."""
+        buf = (ctypes.c_longlong * len(self.STATS))()
+        self.lib.wfn_engine_stats(self.ptr, buf)
+        return dict(zip(self.STATS, buf))
 
     def ingest(self, keys, ids, ts, vals) -> int:
         import numpy as np

@@ -149,6 +149,28 @@ def render_openmetrics(apps: dict) -> str:
             for op, s in sorted(secs.items()):
                 out.append(f"{metric}_total"
                            f"{_labels(operator=op, **lab)} {s:.6f}")
+    # the native window engine's counters (spans.ENGINE_COUNTERS): the
+    # same operator's rows (one a thread) carry the same values
+    engines = [(lab, {row.get("Operator", ""): row["Counters"]
+                      for row in (rep.get("Spans") or {}).get("Operators", [])
+                      if "Counters" in row})
+               for rep, lab in per_graph()]
+    for name, kind, text in (
+            ("keys_opened", "counter", "key states the window engine "
+             "created"),
+            ("keys_evicted", "counter", "key states the window engine "
+             "evicted"),
+            ("keys_live", "gauge", "key states the window engine holds"),
+            ("keys_live_peak", "gauge", "most key states the window "
+             "engine held at once"),
+            ("windows_fired", "counter", "windows the engine fired")):
+        metric = f"windflow_engine_{name}"
+        family(metric, kind, text + " (span layer)")
+        for lab, seen in engines:
+            for op, vals in sorted(seen.items()):
+                out.append(f"{metric}{'_total' if kind == 'counter' else ''}"
+                           f"{_labels(operator=op, **lab)} "
+                           f"{int(vals.get(name, 0))}")
     family("windflow_queue_depth", "gauge",
            "tuples parked in the operator's inbound channels")
     for _op, reps, lab in per_op():

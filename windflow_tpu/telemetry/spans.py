@@ -30,6 +30,11 @@ never per event or per tuple.  They are always on; there is no switch.
 * :class:`LaunchRing` keeps one :class:`Launch` record per device
   launch: six host stamps whose differences are the stages of a
   launch's round trip.
+* Work that native code timed on its own steady clock (the window
+  engine's ``open``, ``trigger`` and ``evict``) is entered as a child of
+  the span open round the call (:meth:`Track.account`), with no clock
+  read here; what the engine counts (:data:`ENGINE_COUNTERS`) is kept
+  per operator in :class:`Counters`.
 """
 from __future__ import annotations
 
@@ -207,6 +212,35 @@ class Track:
         self.last_ns = now
         if dur >= RECENT_NS:
             self._long(cell, t0, now, own)
+
+    def account(self, name: str, ns: int) -> None:
+        """A span of ``ns`` nanoseconds that something else timed (native
+        code, on its own steady clock) inside the innermost open span,
+        since that span's last child: entered as its child, so the
+        parent's self time loses what the child gains.  No clock is read
+        here.  In a profiler session it shows as an instant of the same
+        name that carries ``ns``."""
+        cell = self.cells.get(name)
+        if cell is None:
+            cell = self.cells[name] = Cell(name)
+        if self.stack:
+            top = self.stack[-1]
+            a = top[2]
+            top[2] = a + ns      # the parent's segment goes on after it
+        else:
+            a = self.last_ns
+        cell.count += 1
+        cell.total_ns += ns
+        cell.self_ns += ns
+        if ns > cell.longest_ns:
+            cell.longest_ns = ns
+        if cell.lo <= a and a + ns < cell.hi:
+            cell.ns += ns
+        else:
+            _spread(cell, a, a + ns)
+        if _session_on():
+            with _annotation(name, ns=ns):
+                pass
 
     def close_all(self) -> None:
         """Thread end or unwinding: close whatever is still open."""
@@ -414,6 +448,51 @@ class LaunchRing:
         return out
 
 
+# -- counters --------------------------------------------------------------
+
+# what the native window engine counts (runtime/native.py
+# ``NativeWindowEngine.STATS[3:8]``): key states it created and evicted
+# since it was made, those live now and at their peak, windows it fired
+ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
+                   "keys_live_peak", "windows_fired")
+
+
+class Counters:
+    """The latest value of each counter of one operator, and of
+    ``keys_live`` the largest value noted in each 100 ms bucket, so that
+    its peak between two instants can be read afterwards.  Written by
+    the operator's ingest thread alone."""
+
+    __slots__ = ("operator", "values", "live")
+
+    def __init__(self, operator: str):
+        self.operator = operator
+        self.values: Dict[str, int] = dict.fromkeys(ENGINE_COUNTERS, 0)
+        self.live: Dict[int, int] = {}      # bucket -> largest keys_live
+
+    def note(self, at_ns: int, values) -> None:
+        """The counters' values, in :data:`ENGINE_COUNTERS`' order, as
+        read at ``at_ns``."""
+        self.values = v = dict(zip(ENGINE_COUNTERS, values))
+        b, live = at_ns // BUCKET_NS, v["keys_live"]
+        if live > self.live.get(b, -1):
+            self.live[b] = live
+            if len(self.live) > TIMELINE_BUCKETS:
+                _trim(self.live)
+
+    def live_peak(self, t0_s: float, t1_s: float) -> Optional[int]:
+        """The largest ``keys_live`` noted in the buckets of [t0_s,
+        t1_s]; where nothing was noted there (no key came or went), the
+        last value noted before."""
+        b0, b1 = int(t0_s * 1e9) // BUCKET_NS, int(t1_s * 1e9) // BUCKET_NS
+        live = self.live.copy()
+        inside = [n for b, n in live.items() if b0 <= b <= b1]
+        if inside:
+            return max(inside)
+        before = [b for b in live if b < b0]
+        return live[max(before)] if before else None
+
+
 # -- graphs and the registry -----------------------------------------------
 
 class SpanGraph:
@@ -424,6 +503,7 @@ class SpanGraph:
         self.flight = flight
         self.tracks: List[Track] = []
         self.rings: Dict[str, LaunchRing] = {}
+        self.counters: Dict[str, Counters] = {}
         self.ended = False
 
     def ring(self, operator: str) -> LaunchRing:
@@ -432,6 +512,13 @@ class SpanGraph:
             if r is None:
                 r = self.rings[operator] = LaunchRing(operator)
             return r
+
+    def counters_of(self, operator: str) -> Counters:
+        with _lock:
+            c = self.counters.get(operator)
+            if c is None:
+                c = self.counters[operator] = Counters(operator)
+            return c
 
     def open_at(self, t_ns: int, but: Optional[Track] = None) -> list:
         """What the graph's other threads had open at ``t_ns``: frames
@@ -570,7 +657,8 @@ def report(g: Optional[SpanGraph]) -> Optional[dict]:
     """The ``Spans`` block of the stats JSON: per operator replica and
     thread seconds and shares busy / idle / blocked since start and over
     the last ten seconds, with every phase's closed spans counted (how
-    many, their self and whole seconds, the longest single one), and per
+    many, their self and whole seconds, the longest single one) and, for
+    a window operator on the native lane, its engine's counters; per
     window operator the mean and longest of each launch stage and its
     slowest launch."""
     if g is None:
@@ -600,6 +688,9 @@ def report(g: Optional[SpanGraph]) -> Optional[dict]:
         if row["body_s"]:
             out["Body_s"] = round(row["body_s"], 6)
         out["Phases"] = counted.get((row["operator"], row["track"]), {})
+        kept = g.counters.get(row["operator"])
+        if kept is not None:
+            out["Counters"] = dict(kept.values)
         out.update({k.capitalize(): v for k, v in shares(row).items()})
         last = recent.get((row["operator"], row["track"]))
         if last is not None:
