@@ -15,15 +15,16 @@
 // engine only runs builtin associative combines, it never stores the
 // tuple stream at all.  Each key holds a small ring of pane
 // accumulators (pane = gcd(win, slide), so every window is an exact
-// pane range) and each tuple is folded into its pane on arrival -- one
-// load+combine+store on a hot cache line, instead of the scatter-copy
-// of the full value series that a CUDA staging design implies
-// (win_seq_gpu.hpp:552-596 archives tuples per key and re-reads them
-// per batch; on a TPU host that second pass is pure memory-bandwidth
-// waste).  Late tuples within the retained pane range fold in exactly
-// like the archive insert would; tuples behind the fired frontier are
-// dropped, matching the scalar path's acceptance rule
-// (win_seq.hpp:417-428).
+// pane range) and a chunk's tuples are folded into their panes as it
+// arrives -- by key: one table probe a tuple, one combine a key and
+// chunk where the key's tuples lie in one pane (Engine, "THE FOLD IS BY
+// KEY"), instead of the scatter-copy of the full value series that a
+// CUDA staging design implies (win_seq_gpu.hpp:552-596 archives tuples
+// per key and re-reads them per batch; on a TPU host that second pass
+// is pure memory-bandwidth waste).  Late tuples within the retained
+// pane range fold in exactly like the archive insert would; tuples
+// behind the fired frontier are dropped, matching the scalar path's
+// acceptance rule (win_seq.hpp:417-428).
 //
 // GIL-free: every entry point only touches caller-provided arrays and
 // internal state; Python calls via ctypes release the GIL.
@@ -120,6 +121,9 @@ struct Engine {
     // lowered record graph whose host twin emits them) emit every
     // window from the key's anchor on and keep every key.
     bool dense, sparse;
+    // the lanes whose pane state one combine a key can carry (see "THE
+    // FOLD IS BY KEY" below)
+    bool by_key_lane;
     Kind kind;
     i64 pane;                 // gcd(win, slide)
     i64 ppw;                  // panes per window
@@ -150,28 +154,59 @@ struct Engine {
     std::vector<i64> f_prefix;        // flush(): count prefixes, flat
     std::vector<int32_t> f_touched, f_dead;
     i64 flush_id = 0;
+    // what the fold did with the tuples it accepted, since the engine
+    // was made: folded with their key's other tuples of the call in one
+    // combine, or one by one (wfn_engine_stats)
+    i64 folded_by_key = 0, folded_singly = 0;
     // scatter-ingest machinery: an open-addressing table (linear
-    // probing from home_of) maps key -> (pool slot, per-call dense
-    // index); an entry leaves it by a backward shift, so it holds no
-    // tombstones and forgets as fast as it learns.  Pass 1 does ONE table
-    // probe per tuple and gathers per-key min/max; pass 2 folds each
-    // tuple into its pane through the cached state pointer.
-    std::vector<i64> tab_key;
-    std::vector<int32_t> tab_slot;    // -1: empty
-    std::vector<i64> tab_stamp;
-    std::vector<int32_t> tab_dense;
+    // probing from home_of) of one record a key: its pool slot and,
+    // under the stamp of the call that wrote it, its index into the
+    // per-call arrays.  An entry leaves the table by a backward shift,
+    // so it holds no tombstones and forgets as fast as it learns.
+    //
+    // THE FOLD IS BY KEY.  A call walks its tuples once: one probe a
+    // tuple, and the key's partial of this call (how many, the smallest
+    // and largest id, for MAX / MIN the extreme value) is brought up to
+    // date.  Then each key is visited once: prepare() fixes its anchor,
+    // acceptance boundary and ring room, and where the key's tuples all
+    // lie in one pane at or above that boundary the partial goes into
+    // the pane with one combine (fold_key).  Only the keys that fail
+    // that test -- the call straddles a pane edge, holds a late or
+    // hopping-gap tuple, or the lane keeps more than a combine can carry
+    // (SUM / MEAN add in arrival order, CB and renumbered ids stamp a
+    // pane's last tuple) -- have their tuples folded one by one in a
+    // second walk, which does not run where no key needs it.
+    struct Entry {
+        i64 key;
+        i64 stamp;            // call_id of the last ingest that met the key
+        int32_t slot;         // -1: empty
+        int32_t dense;
+    };
+    std::vector<Entry> tab;
     i64 call_id = 0;
-    // per-call dense arrays (index = order of first touch this call)
+    // per-call arrays (index = order of first touch this call)
+    struct Part {
+        i64 count;            // the key's tuples in this call
+        i64 lo, hi;           // their smallest and largest id
+        double ext;           // MAX / MIN: the extreme of their values
+    };
+    std::vector<Part> parts;
     std::vector<KeyState*> d_state;
-    std::vector<i64> d_key, d_count, d_min, d_max, d_accept;
+    std::vector<i64> d_accept;
     std::vector<int32_t> d_slot;
+    std::vector<unsigned char> d_single;  // the key's tuples fold one by one
+    i64 n_single = 0;                     // such keys in this call
     std::vector<int32_t> opened_now;  // dense indices of keys opened this call
     std::vector<int32_t> slot_of;     // per-tuple dense index
 
     Engine(i64 w, i64 s, bool tb, i64 d, bool renum, Kind k, bool dns)
         : win(w), slide(s), delay(tb ? d : 0), is_tb(tb), renumber(renum),
           stream_rule(tb && !renum), dense(dns),
-          sparse(tb && !renum && !dns), kind(k), pane(std::gcd(w, s)) {
+          sparse(tb && !renum && !dns),
+          by_key_lane(tb && !renum && w >= s
+                      && (k == Kind::COUNT || k == Kind::MAX
+                          || k == Kind::MIN)),
+          kind(k), pane(std::gcd(w, s)) {
         ppw = win / pane;
         pshift = (pane & (pane - 1)) == 0 ? __builtin_ctzll(pane) : -1;
         neutral = kind == Kind::MAX ? -INF : kind == Kind::MIN ? INF : 0.0;
@@ -179,10 +214,7 @@ struct Engine {
     }
 
     void clear_table(std::size_t m) {
-        tab_key.assign(m, 0);
-        tab_slot.assign(m, -1);
-        tab_stamp.assign(m, -1);
-        tab_dense.assign(m, 0);
+        tab.assign(m, Entry{0, -1, -1, 0});
     }
 
     inline i64 pane_of(i64 id) const {
@@ -200,26 +232,19 @@ struct Engine {
     // empty: a run of them is no cluster, so a probe for a new key ends
     // at once and evict()'s backward shift moves nothing.
     inline std::size_t home_of(i64 key) const {
-        return ((std::size_t)key << 1) & (tab_key.size() - 1);
+        return ((std::size_t)key << 1) & (tab.size() - 1);
     }
 
     void grow_table() {
-        std::vector<i64> ok, ost;
-        std::vector<int32_t> os, od;
-        ok.swap(tab_key);
-        os.swap(tab_slot);
-        ost.swap(tab_stamp);
-        od.swap(tab_dense);
-        clear_table(ok.size() * 4);
-        const std::size_t mask = tab_key.size() - 1;
-        for (std::size_t s = 0; s < ok.size(); ++s) {
-            if (os[s] < 0) continue;
-            std::size_t h = home_of(ok[s]);
-            while (tab_slot[h] >= 0) h = (h + 1) & mask;
-            tab_key[h] = ok[s];
-            tab_slot[h] = os[s];
-            tab_stamp[h] = ost[s];
-            tab_dense[h] = od[s];
+        std::vector<Entry> old;
+        old.swap(tab);
+        clear_table(old.size() * 4);
+        const std::size_t mask = tab.size() - 1;
+        for (const Entry& e : old) {
+            if (e.slot < 0) continue;
+            std::size_t h = home_of(e.key);
+            while (tab[h].slot >= 0) h = (h + 1) & mask;
+            tab[h] = e;
         }
     }
 
@@ -243,23 +268,24 @@ struct Engine {
 
     // the key's table entry, made (with a fresh slot) where it has
     // none; `opened` says which
-    inline std::size_t locate(i64 key, bool& opened) {
-        std::size_t mask = tab_key.size() - 1;
+    inline Entry& locate(i64 key, bool& opened) {
+        const std::size_t mask = tab.size() - 1;
         std::size_t h = home_of(key);
         opened = false;
         while (true) {
-            if (tab_slot[h] < 0) {
-                if ((n_live + 1) * 4 >= (i64)tab_key.size()) {
+            Entry& e = tab[h];
+            if (e.slot < 0) {
+                if ((n_live + 1) * 4 >= (i64)tab.size()) {
                     grow_table();
                     return locate(key, opened);
                 }
-                tab_key[h] = key;
-                tab_slot[h] = take_slot(key);
-                tab_stamp[h] = -1;
+                e.key = key;
+                e.slot = take_slot(key);
+                e.stamp = -1;
                 opened = true;
-                return h;
+                return e;
             }
-            if (tab_key[h] == key) return h;
+            if (e.key == key) return e;
             h = (h + 1) & mask;
         }
     }
@@ -268,25 +294,22 @@ struct Engine {
     // tombstone), its slot goes back to the pool
     void evict(int32_t slot) {
         KeyState& st = pool[slot];
-        const std::size_t mask = tab_key.size() - 1;
+        const std::size_t mask = tab.size() - 1;
         std::size_t i = home_of(st.key);
-        while (tab_slot[i] != slot) i = (i + 1) & mask;
+        while (tab[i].slot != slot) i = (i + 1) & mask;
         while (true) {
-            tab_slot[i] = -1;
+            tab[i].slot = -1;
             std::size_t j = i;
             while (true) {
                 j = (j + 1) & mask;
-                if (tab_slot[j] < 0) goto shifted;
-                std::size_t k = home_of(tab_key[j]);
+                if (tab[j].slot < 0) goto shifted;
+                std::size_t k = home_of(tab[j].key);
                 // entry j may stay where its home k lies in (i, j]
                 if (i <= j ? (i < k && k <= j) : (i < k || k <= j))
                     continue;
                 break;
             }
-            tab_key[i] = tab_key[j];
-            tab_slot[i] = tab_slot[j];
-            tab_stamp[i] = tab_stamp[j];
-            tab_dense[i] = tab_dense[j];
+            tab[i] = tab[j];
             i = j;
         }
     shifted:
@@ -296,19 +319,20 @@ struct Engine {
         ++keys_evicted;
     }
 
+    // the key's index into the per-call arrays, given at its first
+    // tuple of the call
     inline int32_t dense_of(i64 key) {
         bool opened;
-        std::size_t h = locate(key, opened);
-        if (tab_stamp[h] != call_id) {
-            tab_stamp[h] = call_id;
-            tab_dense[h] = (int32_t)d_key.size();
-            if (opened) opened_now.push_back(tab_dense[h]);
-            d_key.push_back(key);
-            d_slot.push_back(tab_slot[h]);
-            d_state.push_back(&pool[tab_slot[h]]);
-            d_count.push_back(0);
+        Entry& e = locate(key, opened);
+        if (e.stamp != call_id) {
+            e.stamp = call_id;
+            e.dense = (int32_t)parts.size();
+            if (opened) opened_now.push_back(e.dense);
+            parts.push_back(Part{0, INT64_MAX, INT64_MIN, neutral});
+            d_slot.push_back(e.slot);
+            d_state.push_back(&pool[e.slot]);
         }
-        return tab_dense[h];
+        return e.dense;
     }
 
     // grow the pane ring so relative pane p_rel is addressable
@@ -435,26 +459,54 @@ struct Engine {
     // anchor of a new key, the acceptance boundary, the ring's room
     inline void prepare(std::size_t d) {
         KeyState& st = *d_state[d];
+        Part& pt = parts[d];
         if (renumber) {
             // implicit arrival-order ids: this batch appends ids
             // [arrivals, arrivals + count)
-            d_min[d] = st.arrivals;
-            d_max[d] = st.arrivals + d_count[d] - 1;
+            pt.lo = st.arrivals;
+            pt.hi = st.arrivals + pt.count - 1;
         }
         if (st.max_id < 0) {
-            anchor_key(st, d_min[d]);
+            anchor_key(st, pt.lo);
         } else if (sparse && st.next_fire > st.opened_max) {
             // every window the key opened has fired: the windows that
             // lie empty before this batch's first tuple are skipped
             // here, not one by one at the trigger
-            i64 w0 = first_window_of(d_min[d]);
+            i64 w0 = first_window_of(pt.lo);
             if (w0 > st.next_fire) st.next_fire = w0;
         }
         d_accept[d] = accept_of(st);
         // pre-grow the ring to this batch's frontier so the fold
-        // loop never reallocates
-        i64 hi_rel = pane_of(d_max[d]) - st.pane_base;
+        // never reallocates
+        i64 hi_rel = pane_of(pt.hi) - st.pane_base;
         if (hi_rel >= 0) ensure_pane(st, hi_rel);
+        fold_key(d, st, pt, hi_rel);
+    }
+
+    // The key's partial of this call into its pane with one combine,
+    // where that gives the bits the one-by-one fold would: every tuple
+    // of the key accepted and in the one pane `hi_rel`, on a lane whose
+    // pane state is a count and an order-free value.  COUNT adds whole
+    // numbers (exact in a double), MAX / MIN keep the first of equal
+    // extremes as the one-by-one fold does.  Else the key is marked and
+    // its tuples take the second walk.
+    inline void fold_key(std::size_t d, KeyState& st, const Part& pt,
+                         i64 hi_rel) {
+        const bool whole = by_key_lane && pt.lo >= d_accept[d]
+            && hi_rel >= 0 && pane_of(pt.lo) - st.pane_base == hi_rel;
+        d_single[d] = !whole;
+        if (!whole) {
+            ++n_single;
+            return;
+        }
+        double& acc = st.pacc[hi_rel];
+        switch (kind) {
+            case Kind::COUNT: acc += (double)pt.count; break;
+            case Kind::MAX: if (pt.ext > acc) acc = pt.ext; break;
+            default: if (pt.ext < acc) acc = pt.ext; break;  // MIN
+        }
+        st.pcnt[hi_rel] += pt.count;
+        folded_by_key += pt.count;
     }
 
     // per key and call, after the fold: the frontier it opened, and
@@ -473,42 +525,55 @@ struct Engine {
         }
     }
 
+    // The one walk over a call's tuples: a probe each, and the key's
+    // partial brought up to date.  IDS: the ids are read (renumbered
+    // ids are implicit); EXT: 1 keeps the largest value, 2 the
+    // smallest, 0 reads no value.
+    template <bool IDS, int EXT, typename TV>
+    void gather(const i64* bkeys, const i64* ids, const TV* vals, i64 n) {
+        for (i64 j = 0; j < n; ++j) {
+            const int32_t d = dense_of(bkeys[j]);
+            slot_of[j] = d;
+            Part& pt = parts[d];
+            ++pt.count;
+            if (IDS) {
+                const i64 id = ids[j];
+                if (id < pt.lo) pt.lo = id;
+                if (id > pt.hi) pt.hi = id;
+            }
+            if (EXT == 1) {
+                const double v = (double)vals[j];
+                if (v > pt.ext) pt.ext = v;
+            } else if (EXT == 2) {
+                const double v = (double)vals[j];
+                if (v < pt.ext) pt.ext = v;
+            }
+        }
+    }
+
     // TV = double or float: f32 sources fold without a host-side
     // widening copy (values widen at the accumulate)
     template <typename TV>
     void ingest_batch(const i64* bkeys, const i64* ids, const i64* tss,
                       const TV* vals, i64 n) {
         ++call_id;
-        d_key.clear();
+        parts.clear();
         d_slot.clear();
         d_state.clear();
-        d_count.clear();
         opened_now.clear();
         if ((i64)slot_of.size() < n) slot_of.resize(n);
-        if (renumber) {
-            for (i64 j = 0; j < n; ++j) {
-                int32_t d = dense_of(bkeys[j]);
-                ++d_count[d];
-                slot_of[j] = d;
-            }
-        } else {
-            for (i64 j = 0; j < n; ++j) {
-                int32_t d = dense_of(bkeys[j]);
-                ++d_count[d];
-                slot_of[j] = d;
-                i64 id = ids[j];
-                if ((std::size_t)d >= d_min.size()) {
-                    d_min.resize(d + 1, INT64_MAX);
-                    d_max.resize(d + 1, INT64_MIN);
-                }
-                if (id < d_min[d]) d_min[d] = id;
-                if (id > d_max[d]) d_max[d] = id;
-            }
-        }
-        std::size_t nd = d_key.size();
-        if (d_min.size() < nd) d_min.resize(nd);
-        if (d_max.size() < nd) d_max.resize(nd);
+        if (renumber)
+            gather<false, 0>(bkeys, ids, vals, n);
+        else if (by_key_lane && kind == Kind::MAX)
+            gather<true, 1>(bkeys, ids, vals, n);
+        else if (by_key_lane && kind == Kind::MIN)
+            gather<true, 2>(bkeys, ids, vals, n);
+        else
+            gather<true, 0>(bkeys, ids, vals, n);
+        const std::size_t nd = parts.size();
         d_accept.resize(nd);
+        d_single.resize(nd);
+        n_single = 0;
         if (opened_now.empty()) {
             for (std::size_t d = 0; d < nd; ++d) prepare(d);
         } else {
@@ -525,77 +590,47 @@ struct Engine {
                     prepare(d);
             }
         }
+        if (n_single) fold_singly(ids, tss, vals, n);
+        for (std::size_t d = 0; d < nd; ++d)
+            settle(*d_state[d], d_slot[d], parts[d].hi);
+        if (stream_rule) trigger();
+    }
+
+    // The second walk, for the keys fold_key() left: each of their
+    // tuples against the acceptance boundary and into its own pane.
+    template <typename TV>
+    void fold_singly(const i64* ids, const i64* tss, const TV* vals, i64 n) {
         // hopping windows (win < slide): whether an id opens a window
         // depends on its position inside the slide period, so the
         // opened-window frontier must be tracked per accepted tuple --
         // the batch's final max_id alone misses windows opened by
         // mid-batch ids when the batch ends in a gap
         const bool hopping = win < slide;
-        if (renumber) {
-            for (i64 j = 0; j < n; ++j) {
-                int32_t d = slot_of[j];
-                KeyState& st = *d_state[d];
-                i64 id = st.arrivals++;
-                i64 p = pane_of(id) - st.pane_base;
-                if (p < 0) continue;  // hopping-gap arrival below the ring
-                if (hopping) {
-                    i64 nn = id / slide;
-                    if (id >= nn * slide + win) continue;  // gap arrival
-                    if (nn > st.opened_max) st.opened_max = nn;
-                }
-                fold(st, p, (double)vals[j]);
-                if (!is_tb && id >= st.plid[p]) {
-                    st.plid[p] = id;
-                    st.plts[p] = tss[j];
-                }
+        i64 folded = 0;
+        for (i64 j = 0; j < n; ++j) {
+            const int32_t d = slot_of[j];
+            if (!d_single[d]) continue;
+            KeyState& st = *d_state[d];
+            const i64 id = renumber ? st.arrivals++ : ids[j];
+            if (!renumber && id < d_accept[d]) {
+                ++ignored;
+                continue;
             }
-        } else if (is_tb) {
-            for (i64 j = 0; j < n; ++j) {
-                int32_t d = slot_of[j];
-                i64 id = ids[j];
-                if (id < d_accept[d]) {
-                    ++ignored;
-                    continue;
-                }
-                KeyState& st = *d_state[d];
-                i64 p = pane_of(id) - st.pane_base;
-                if (p < 0) continue;  // hopping-gap tuple below the ring
-                if (hopping) {
-                    i64 nn = id / slide;
-                    if (id >= nn * slide + win) continue;  // gap tuple
-                    if (nn > st.opened_max) st.opened_max = nn;
-                }
-                fold(st, p, (double)vals[j]);
+            const i64 p = pane_of(id) - st.pane_base;
+            if (p < 0) continue;  // hopping-gap tuple below the ring
+            if (hopping) {
+                const i64 nn = id / slide;
+                if (id >= nn * slide + win) continue;  // gap tuple
+                if (nn > st.opened_max) st.opened_max = nn;
             }
-        } else {
-            for (i64 j = 0; j < n; ++j) {
-                int32_t d = slot_of[j];
-                i64 id = ids[j];
-                if (id < d_accept[d]) {
-                    ++ignored;
-                    continue;
-                }
-                KeyState& st = *d_state[d];
-                i64 p = pane_of(id) - st.pane_base;
-                if (p < 0) continue;
-                if (hopping) {
-                    i64 nn = id / slide;
-                    if (id >= nn * slide + win) continue;  // gap tuple
-                    if (nn > st.opened_max) st.opened_max = nn;
-                }
-                fold(st, p, (double)vals[j]);
-                if (id >= st.plid[p]) {
-                    st.plid[p] = id;
-                    st.plts[p] = tss[j];
-                }
+            fold(st, p, (double)vals[j]);
+            ++folded;
+            if (!is_tb && id >= st.plid[p]) {
+                st.plid[p] = id;
+                st.plts[p] = tss[j];
             }
         }
-        for (std::size_t d = 0; d < nd; ++d) {
-            settle(*d_state[d], d_slot[d], d_max[d]);
-            d_min[d] = INT64_MAX;
-            d_max[d] = INT64_MIN;
-        }
-        if (stream_rule) trigger();
+        folded_singly += folded;
     }
 
     // Fused synthesis + ingest: generate events [start, start+n) of the
@@ -631,7 +666,7 @@ struct Engine {
             i64 e0 = start + (((k - start % K) % K) + K) % K;
             if (e0 >= endE) continue;
             bool opened;
-            const int32_t slot = tab_slot[locate(k, opened)];
+            const int32_t slot = locate(k, opened).slot;
             KeyState& st = pool[slot];
             const i64 id0 = e0 / K;
             const i64 cnt = (endE - e0 + K - 1) / K;
@@ -655,6 +690,7 @@ struct Engine {
             const i64 accept = accept_of(st);
             i64 vm = e0 % vmod;  // value index, advanced mod-free
             i64 last_ok = st.max_id;  // max SURVIVING id
+            i64 folded = 0;
             for (i64 j = 0; j < cnt; ++j) {
                 const i64 id = id0 + j;
                 const double v = vtab ? vtab[vm]
@@ -677,11 +713,13 @@ struct Engine {
                     if (nn > st.opened_max) st.opened_max = nn;
                 }
                 fold(st, p, v);
+                ++folded;
                 if (!is_tb && id >= st.plid[p]) {
                     st.plid[p] = id;
                     st.plts[p] = id;  // the law sets ts = id
                 }
             }
+            folded_singly += folded;
             settle(st, slot, last_ok);
         }
         if (stream_rule) trigger();
@@ -871,7 +909,7 @@ struct Engine {
         ready.clear();
         ready_head = 0;
         due.clear();
-        clear_table(tab_key.size());
+        clear_table(tab.size());
         stream_time = fired_upto = -1;
         keys_opened = keys_evicted = keys_live_peak = windows_fired = 0;
     }
@@ -975,7 +1013,7 @@ struct Engine {
             i64 key;
             if (!get(p, end, key)) return false;
             bool fresh;
-            const int32_t slot = tab_slot[locate(key, fresh)];
+            const int32_t slot = locate(key, fresh).slot;
             if (!fresh) return false;  // a key twice
             KeyState& st = pool[slot];
             if (!get(p, end, st.next_fire) || !get(p, end, st.anchor)
@@ -1007,11 +1045,11 @@ struct Engine {
                 return false;
             // a queued window belongs to a live key
             std::size_t h = home_of(ds.key);
-            const std::size_t mask = tab_key.size() - 1;
-            while (tab_slot[h] >= 0 && tab_key[h] != ds.key)
+            const std::size_t mask = tab.size() - 1;
+            while (tab[h].slot >= 0 && tab[h].key != ds.key)
                 h = (h + 1) & mask;
-            if (tab_slot[h] < 0) return false;
-            ds.slot = tab_slot[h];
+            if (tab[h].slot < 0) return false;
+            ds.slot = tab[h].slot;
             ++pool[ds.slot].queued;
             ready.push_back(ds);
         }
@@ -1079,11 +1117,13 @@ i64 wfn_engine_ignored(void* ep) {
     return static_cast<Engine*>(ep)->ignored;
 }
 
-// What key churn costs and how many keys there are, into out[9]:
-// nanoseconds spent creating key states (open), finding and queueing
-// fired windows (trigger) and evicting (evict), since the engine was
-// made; keys opened, keys evicted, keys live now and at their peak,
-// windows fired; the stream time (-1 before the first stamp).
+// What key churn costs, how many keys there are and how the fold went,
+// into out[11]: nanoseconds spent creating key states (open), finding
+// and queueing fired windows (trigger) and evicting (evict), since the
+// engine was made; keys opened, keys evicted, keys live now and at their
+// peak, windows fired; tuples folded with their key's others of the call
+// in one combine, tuples folded one by one; the stream time (-1 before
+// the first stamp).
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -1094,7 +1134,9 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[5] = e.n_live;
     out[6] = e.keys_live_peak;
     out[7] = e.windows_fired;
-    out[8] = e.stream_time;
+    out[8] = e.folded_by_key;
+    out[9] = e.folded_singly;
+    out[10] = e.stream_time;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }

@@ -60,10 +60,13 @@ from ..telemetry.histogram import LogHistogram
 # 14 = Spans.Operators rows of a window operator on the native lane gain
 # Counters (keys_opened, keys_evicted, keys_live, keys_live_peak,
 # windows_fired) and their Phases the engine's open / trigger / evict.
+# 15 = those Counters gain folded_by_key and folded_singly (tuples the
+# engine folded with their key's others of the call in one combine, and
+# one by one).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 14
+SCHEMA_VERSION = 15
 
 
 @dataclass

@@ -1344,7 +1344,9 @@ class WinSeqTPULogic(NodeLogic):
         """What the native engine timed and counted since the last look:
         ``open``, ``trigger`` and ``evict`` become children of the span
         open round the call, the counters go to the registry.  A look
-        that finds nothing new costs one native call."""
+        that finds no key opened and no clock moved costs one native
+        call: the fold's two counts, which move with every chunk, go
+        with the next look that does (every firing, and EOS)."""
         s = self._native.stats()
         last = self._churn
         if s[0] == last[0] and s[1] == last[1] and s[2] == last[2] \
@@ -1357,7 +1359,7 @@ class WinSeqTPULogic(NodeLogic):
                 tr.account(name, s[i] - last[i])
         last[:] = s
         self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
-                            last[3:8])
+                            last[3:10])
 
     def _flush_and_submit(self, emit, max_windows) -> None:
         out = self._native.flush(max_windows or max(self.batch_len, 4096))

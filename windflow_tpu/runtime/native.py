@@ -592,10 +592,13 @@ class NativeWindowEngine:
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4}
     # what ``stats()`` returns, in order: nanoseconds creating key
     # states, finding and queueing fired windows, evicting; keys opened,
-    # evicted, live now, live at their peak; windows fired; stream time
+    # evicted, live now, live at their peak; windows fired; tuples
+    # folded with their key's others of the call in one combine, tuples
+    # folded one by one; stream time
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
-             "windows_fired", "stream_time")
+             "windows_fired", "folded_by_key", "folded_singly",
+             "stream_time")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
                  delay: int = 0, renumber: bool = False, kind: str = "sum",

@@ -163,7 +163,11 @@ def render_openmetrics(apps: dict) -> str:
             ("keys_live", "gauge", "key states the window engine holds"),
             ("keys_live_peak", "gauge", "most key states the window "
              "engine held at once"),
-            ("windows_fired", "counter", "windows the engine fired")):
+            ("windows_fired", "counter", "windows the engine fired"),
+            ("folded_by_key", "counter", "tuples the engine folded with "
+             "their key's others of the call in one combine"),
+            ("folded_singly", "counter", "tuples the engine folded one "
+             "by one")):
         metric = f"windflow_engine_{name}"
         family(metric, kind, text + " (span layer)")
         for lab, seen in engines:

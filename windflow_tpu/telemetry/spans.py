@@ -451,24 +451,29 @@ class LaunchRing:
 # -- counters --------------------------------------------------------------
 
 # what the native window engine counts (runtime/native.py
-# ``NativeWindowEngine.STATS[3:8]``): key states it created and evicted
-# since it was made, those live now and at their peak, windows it fired
+# ``NativeWindowEngine.STATS[3:10]``): key states it created and evicted
+# since it was made, those live now and at their peak, windows it fired,
+# tuples it folded with their key's others of the call in one combine
+# and tuples it folded one by one
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
-                   "keys_live_peak", "windows_fired")
+                   "keys_live_peak", "windows_fired", "folded_by_key",
+                   "folded_singly")
 
 
 class Counters:
-    """The latest value of each counter of one operator, and of
-    ``keys_live`` the largest value noted in each 100 ms bucket, so that
-    its peak between two instants can be read afterwards.  Written by
-    the operator's ingest thread alone."""
+    """The latest value of each counter of one operator; of ``keys_live``
+    the largest value noted in each 100 ms bucket and of the fold's two
+    counts the last, so that the peak and the tuples folded between two
+    instants can be read afterwards.  Written by the operator's ingest
+    thread alone."""
 
-    __slots__ = ("operator", "values", "live")
+    __slots__ = ("operator", "values", "live", "folded")
 
     def __init__(self, operator: str):
         self.operator = operator
         self.values: Dict[str, int] = dict.fromkeys(ENGINE_COUNTERS, 0)
         self.live: Dict[int, int] = {}      # bucket -> largest keys_live
+        self.folded: Dict[int, tuple] = {}  # bucket -> (by key, singly)
 
     def note(self, at_ns: int, values) -> None:
         """The counters' values, in :data:`ENGINE_COUNTERS`' order, as
@@ -479,6 +484,9 @@ class Counters:
             self.live[b] = live
             if len(self.live) > TIMELINE_BUCKETS:
                 _trim(self.live)
+        self.folded[b] = (v["folded_by_key"], v["folded_singly"])
+        if len(self.folded) > TIMELINE_BUCKETS:
+            _trim(self.folded)
 
     def live_peak(self, t0_s: float, t1_s: float) -> Optional[int]:
         """The largest ``keys_live`` noted in the buckets of [t0_s,
@@ -491,6 +499,17 @@ class Counters:
             return max(inside)
         before = [b for b in live if b < b0]
         return live[max(before)] if before else None
+
+    def folded_between(self, t0_s: float, t1_s: float) -> tuple:
+        """(by key, singly): the tuples folded between the last note in
+        the buckets before ``t0_s``'s and the last in those up to
+        ``t1_s``'s (good to a bucket and a note at each end)."""
+        b0, b1 = int(t0_s * 1e9) // BUCKET_NS, int(t1_s * 1e9) // BUCKET_NS
+        folded = self.folded.copy()
+        at = [max((b for b in folded if b <= edge), default=None)
+              for edge in (b0 - 1, b1)]
+        lo, hi = (folded[b] if b is not None else (0, 0) for b in at)
+        return hi[0] - lo[0], hi[1] - lo[1]
 
 
 # -- graphs and the registry -----------------------------------------------
