@@ -693,44 +693,3 @@ def test_audit_overhead_results_identical():
     key = sorted((r.key, r.id, r.value) for r in sunk_on)
     assert key == sorted((r.key, r.id, r.value) for r in sunk_off)
     assert g_on.auditor.violations == []
-
-
-def test_census_device_tier_from_resident_forest():
-    """ROADMAP item 4 (device leg): the resident pane forest's device
-    bytes surface as the census ``device`` tier, flow through the
-    doctor's State_tiers block and prose, and render as
-    ``windflow_keyed_state_bytes{tier="device"}`` -- reporting only,
-    no behaviour change."""
-    from windflow_tpu.diagnosis import build_report, render_text
-    from windflow_tpu.graph.fuse import iter_logics
-    from windflow_tpu.models.nexmark import build_q5_hot_items
-    from windflow_tpu.operators.tpu.win_seq_tpu import WinSeqTPULogic
-
-    g = wf.PipeGraph("audit_dev_tier", wf.Mode.DEFAULT,
-                     config=RuntimeConfig(audit_interval_s=0.05))
-    out = []
-    build_q5_hot_items(g, 60_000, 1 << 12, 1 << 11, out.append,
-                       batch_size=4096, device_batch=512)
-    # python path: the resident pane carry is the planner-promoted lane
-    for _n, lg in iter_logics(g):
-        if hasattr(lg, "_native"):
-            lg._native = None
-    quiet_run(g)
-    eng = next(lg for _n, lg in iter_logics(g)
-               if isinstance(lg, WinSeqTPULogic))
-    res = eng.device_resident_bytes()
-    assert res > 0, "resident lane should be active on the device path"
-    rep = json.loads(g.stats.to_json())
-    row = next(r for r in rep["Skew"]["Census"]
-               if "q5_counts" in r["replica"])
-    assert row["tiers"]["device"] == [row["keys"], res]
-    assert row["keys"] > 0
-    # doctor: per-tier totals block + one line of prose
-    doc = build_report(rep)
-    assert doc["State_tiers"]["device"] == {"keys": row["keys"],
-                                            "bytes": res}
-    assert any("keyed-state tiers: device=" in ln
-               for ln in render_text(doc).splitlines())
-    # /metrics: the per-tier byte gauge picks the device tier up
-    text = render_openmetrics({1: {"active": True, "report": rep}})
-    assert f'tier="device"}} {res}' in text

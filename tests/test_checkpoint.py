@@ -208,7 +208,7 @@ def test_win_seq_tpu_restore_string_keys_python_path():
     blob = pickle.dumps(a.state_dict())
     b, out2 = make_logic(), []
     b.load_state(pickle.loads(blob))
-    assert b._saw_nonint_key  # derived from the restored store
+    assert b._store._saw_nonint_key  # derived from the restored store
     # launch WITHOUT any post-restore svc record (svc would re-set the
     # flag itself): eos_flush fires the restored keys' pending windows
     b.eos_flush(out2.append)
@@ -359,14 +359,14 @@ def test_native_snapshot_rejects_mismatched_config():
     e1 = NativeWindowEngine(32, 16, True)
     e1.ingest(np.zeros(10, np.int64), np.arange(10, dtype=np.int64),
               np.arange(10, dtype=np.int64), np.ones(10))
-    blob = e1.serialize()
+    blob = e1.serialize()["native"]
     e2 = NativeWindowEngine(64, 16, True)  # different window length
     with pytest.raises(ValueError):
-        e2.deserialize(blob)
+        e2.deserialize({"native": blob})
     e3 = NativeWindowEngine(32, 16, True)
-    e3.deserialize(blob)  # matching config restores fine
+    e3.deserialize({"native": blob})  # matching config restores fine
     with pytest.raises(ValueError):
-        e3.deserialize(blob[:20])  # truncated blob rejected
+        e3.deserialize({"native": blob[:20]})  # truncated blob rejected
 
 
 def test_run_with_recovery_restarts_on_node_failure(tmp_path):

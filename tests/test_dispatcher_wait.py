@@ -61,7 +61,7 @@ class Handle:
 
 class Rig:
     """A ``WinSeqTPULogic`` fed launches by hand: ``launch()`` is what
-    ``_native_launch`` does with a staged batch, ``emitted`` what the
+    ``_launch`` does with a staged batch, ``emitted`` what the
     sink would see."""
 
     def __init__(self, depth=3):
@@ -88,7 +88,7 @@ class Rig:
     def launch(self):
         n = self.logic._launches.seq + 1
         one = np.asarray([n], np.int64)
-        self.logic._submit({}, one, one, one, ("native", one, one, one),
+        self.logic._submit({}, one, one, one, (one, one, one),
                            time.perf_counter(), self._emit, engine=self)
 
     @property
@@ -226,8 +226,8 @@ def test_abort_during_a_wait_returns_once_the_handle_completes():
 
 
 @pytest.mark.parametrize("kwargs", [
-    {}, {"async_dispatch": False}, {"placement": "host"},
-    {"resident": True}], ids=["async", "inline", "host", "resident"])
+    {}, {"async_dispatch": False}, {"placement": "host"}],
+    ids=["async", "inline", "host"])
 def test_every_lane_counts_how_each_launch_was_collected(kwargs):
     """The real engines on the CPU backend: every launch of the ring is
     collected in exactly one of the four ways, in the stats JSON too."""

@@ -94,15 +94,16 @@ def drive(lane, kind, keys, ts, vals, chunking, look_at=None):
             out = eng.flush(1 << 30)
             if out is None:
                 return
-            for a in out:
+            cols, starts, ends, d_keys, gwids, rts, _ = out
+            for a in (cols["value"], *out[1:6], *list(cols.values())[1:]):
                 digest.update(np.ascontiguousarray(a).tobytes())
-            pv, starts, ends, d_keys, gwids, rts = out[:6]
+            pv = cols["value"]
             for i in range(len(d_keys)):
                 panes = pv[starts[i]:ends[i]]
                 if kind in ("count", "sum"):
                     v = panes.sum()
                 elif kind == "mean":
-                    c = out[6][starts[i]:ends[i]].sum()
+                    c = cols["count"][starts[i]:ends[i]].sum()
                     v = panes.sum() / c if c else 0.0
                 else:
                     v = (panes.max() if kind == "max" else panes.min()) \
@@ -247,7 +248,8 @@ def test_a_late_tuple_in_an_otherwise_one_pane_chunk():
     assert s["folded_singly"] - before["folded_singly"] == mine - 1
     assert s["folded_by_key"] - before["folded_by_key"] == 76 - mine
     eng.eos()
-    pv, starts, ends, d_keys, gwids, _rts = eng.flush(1 << 30)
+    cols, starts, ends, d_keys, gwids, _rts, _ = eng.flush(1 << 30)
+    pv = cols["value"]
     got = {(int(k), int(w)): pv[a:b].sum()
            for k, w, a, b in zip(d_keys, gwids, starts, ends)}
     kept = np.r_[ts, np.delete(ts2, 10)]

@@ -774,13 +774,14 @@ def test_string_keys_device_results_carry_original_keys():
     if logic._native is None:
         pytest.skip("native engine unavailable: intern round-trip "
                     "rides the native snapshot")
-    logic._intern_key("alpha")
-    logic._intern_key("beta")
+    logic._native.intern_key("alpha")
+    logic._native.intern_key("beta")
     st = logic.state_dict()
     fresh = WinSeqTPULogic("sum", 20, 10, WinType.CB)
     fresh.load_state(st)
-    assert fresh._key_intern == logic._key_intern
-    assert fresh._key_extern[logic._key_intern["beta"]] == "beta"
+    assert fresh._native.key_intern == logic._native.key_intern
+    assert fresh._native.key_extern[logic._native.key_intern["beta"]] \
+        == "beta"
 
 
 def test_mixed_int_and_string_keys_device_batches():

@@ -148,7 +148,7 @@ def test_engine_int64_min_key():
         out = eng.flush(1000)
         if out is None:
             break
-        vals, starts, ends, d_keys, gwids, _rts = out
+        vals, starts, ends, d_keys, gwids = out[0]["value"], *out[1:5]
         for i in range(len(d_keys)):
             got.setdefault(int(d_keys[i]), []).append(
                 vals[starts[i]:ends[i]].sum())
@@ -172,7 +172,7 @@ def test_engine_partial_flush_keeps_queued_window_data():
         out = eng.flush(10)
         if out is None:
             break
-        vals, starts, ends, _keys, gwids, _rts = out
+        vals, starts, ends, _keys, gwids = out[0]["value"], *out[1:5]
         for i in range(len(gwids)):
             assert vals[starts[i]:ends[i]].sum() == 4.0, int(gwids[i])
             seen += 1
@@ -199,7 +199,7 @@ def test_engine_gapped_window_stages_empty_extent():
             out = eng.flush(1000)
             if out is None:
                 break
-            vals, starts, ends, d_keys, gwids, _rts = out
+            vals, starts, ends, d_keys, gwids = out[0]["value"], *out[1:5]
             for i in range(len(gwids)):
                 w = int(gwids[i])
                 seg = vals[starts[i]:ends[i]]
@@ -237,7 +237,7 @@ def test_engine_renumber_hopping_gap_after_eviction():
     eng.ingest(np.zeros(n, np.int64), ids2, ids2, np.full(n, 3.0))
     eng.eos()
     out = eng.flush(10)
-    vals, starts, ends, _keys, gwids, _rts = out[:6]
+    vals, starts, ends, _keys, gwids = out[0]["value"], *out[1:5]
     assert list(gwids) == [1]
     assert vals[starts[0]:ends[0]].sum() == 6.0  # arrivals 10, 11 only
 
@@ -267,7 +267,7 @@ def test_engine_synth_ingest_matches_array_ingest(win, slide, kind,
             r = eng.flush(1 << 20)
             if r is None:
                 return
-            vals, starts, ends, keys, gwids, rts = r[:6]
+            vals, starts, ends, keys, gwids = r[0]["value"], *r[1:5]
             agg_of = {"sum": np.sum, "max": np.max, "min": np.min}[kind]
             for b in range(len(starts)):
                 seg = vals[starts[b]:ends[b]]
@@ -313,7 +313,7 @@ def test_engine_deserialize_rejects_huge_length_field():
     e1 = NativeWindowEngine(32, 16, True)
     e1.ingest(np.zeros(10, np.int64), np.arange(10, dtype=np.int64),
               np.arange(10, dtype=np.int64), np.ones(10))
-    blob = bytearray(e1.serialize())
+    blob = bytearray(e1.serialize()["native"])
     import struct
     # parse the WFN4 snapshot framing (window_engine.cpp serialize()):
     # the 15-i64 header (magic,win,slide,delay,tb,rn,dense,kind,
@@ -336,7 +336,7 @@ def test_engine_deserialize_rejects_huge_length_field():
     assert corrupted  # 10 ingested values: the pane ring is non-empty
     e2 = NativeWindowEngine(32, 16, True)
     with pytest.raises(ValueError):
-        e2.deserialize(bytes(blob))
+        e2.deserialize({"native": bytes(blob)})
 
 
 def test_engine_deserialize_corruption_fuzz():
@@ -350,9 +350,9 @@ def test_engine_deserialize_corruption_fuzz():
     eng = NativeWindowEngine(64, 32, False, 0)
     ids = np.arange(5000, dtype=np.int64)
     eng.ingest(ids % 8, ids // 8, ids // 8, np.ones(5000))
-    blob = eng.serialize()
+    blob = eng.serialize()["native"]
     # control: the pristine blob must load, or the fuzz is vacuous
-    NativeWindowEngine(64, 32, False, 0).deserialize(blob)
+    NativeWindowEngine(64, 32, False, 0).deserialize({"native": blob})
     rnd = random.Random(0)
     for _trial in range(200):
         b = bytearray(blob)
@@ -360,12 +360,12 @@ def test_engine_deserialize_corruption_fuzz():
             b[rnd.randrange(len(b))] ^= 1 << rnd.randrange(8)
         e2 = NativeWindowEngine(64, 32, False, 0)
         try:
-            e2.deserialize(bytes(b))
+            e2.deserialize({"native": bytes(b)})
         except Exception:
             pass  # clean rejection is a pass; only a crash fails
     for cut in range(0, len(blob), max(1, len(blob) // 40)):
         e2 = NativeWindowEngine(64, 32, False, 0)
         try:
-            e2.deserialize(bytes(blob[:cut]))
+            e2.deserialize({"native": bytes(blob[:cut])})
         except Exception:
             pass

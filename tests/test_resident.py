@@ -1,11 +1,8 @@
 """Device-resident keyed window state + online re-planning
-(docs/PLANNER.md "Resident state & online re-planning").
+(docs/PLANNER.md "Online re-planning").
 
 * the fused scatter+query forest program (one launch per chunk,
   donated carry) matches the sequential update/query pair;
-* the WinSeqTPULogic resident pane carry produces results BITWISE
-  identical to the rebuild lane while shipping a fraction of its
-  bytes, with the resident footprint on a separate gauge;
 * the FFAT resident lane ships >= 10x fewer bytes/launch than the
   rebuild lane on a sliding-window config;
 * resident engines stay checkpoint-, rescale- (keyed_state_dict
@@ -126,118 +123,6 @@ class TestFusedForest:
         from windflow_tpu.ops.flatfat_jax import BatchedFlatFAT
         f = BatchedFlatFAT(jnp.add, 0.0, 4, 64)
         assert f.state_bytes == 4 * 2 * 64 * 4  # K x 2n x f32
-
-
-# ---------------------------------------------------------------------------
-# WinSeqTPULogic resident pane carry
-# ---------------------------------------------------------------------------
-
-def _win_logic(resident, kind="sum", win=256, slide=32,
-               win_type=WinType.CB, batch_len=16):
-    # value_of defeats the native engine on BOTH lanes so the Python
-    # staging path (the one the resident carry extends) is compared
-    return WinSeqTPULogic(kind, win, slide, win_type,
-                          batch_len=batch_len, async_dispatch=False,
-                          resident=resident,
-                          value_of=lambda t: t.value)
-
-
-class TestResidentPaneCarry:
-    @pytest.mark.parametrize("kind", ["sum", "count", "max"])
-    def test_cb_bitwise_vs_rebuild(self, kind):
-        a = _run_logic(_win_logic(False, kind), 6000)
-        b = _run_logic(_win_logic(True, kind), 6000)
-        assert a and a == b
-
-    def test_tb_bitwise_vs_rebuild(self):
-        a = _run_logic(_win_logic(False, "sum", win_type=WinType.TB),
-                       6000)
-        b = _run_logic(_win_logic(True, "sum", win_type=WinType.TB),
-                       6000)
-        assert a and a == b
-
-    def test_resident_ships_fraction_of_rebuild_bytes(self):
-        from windflow_tpu.monitoring.stats import StatsRecord
-        shipped = {}
-        for resident in (False, True):
-            lg = _win_logic(resident, "sum", win=4096, slide=64,
-                            batch_len=8)
-            lg.stats = StatsRecord()
-            _run_logic(lg, 40_000)
-            assert lg.stats.num_launches > 4
-            shipped[resident] = (lg.stats.bytes_to_device
-                                 / lg.stats.num_launches)
-            if resident:
-                # the separate footprint gauge: state lives on device,
-                # not in the per-launch traffic
-                assert lg.stats.device_state_bytes > 0
-                assert lg.device_resident_bytes() \
-                    == lg.stats.device_state_bytes
-        assert shipped[True] < shipped[False] / 3, shipped
-
-    def test_checkpoint_restore_continues_identically(self):
-        ref = _run_logic(_win_logic(True), 8000)
-        a = _win_logic(True)
-        out = []
-        for c in range(0, 4000, 500):
-            a.svc(_int_batch(c, c + 500), 0, out.append)
-        a.quiesce(out.append)  # snapshot contract: nothing in flight
-        blob = a.state_dict()
-        b = _win_logic(True)
-        b.load_state(blob)
-        for c in range(4000, 8000, 500):
-            b.svc(_int_batch(c, c + 500), 0, out.append)
-        b.eos_flush(out.append)
-        got = {(r.key, r.id): (r.value, r.ts) for r in out}
-        assert got == ref
-
-    def test_lane_flip_drops_then_recovers_residency(self):
-        lg = _win_logic(True)
-        out = []
-        lg.svc(_int_batch(0, 2000), 0, out.append)
-        assert lg._resident is not None
-        lg.apply_placement("host")
-        assert lg._resident is None
-        lg.apply_placement("device")
-        assert lg.maybe_enable_resident()
-        lg.svc(_int_batch(2000, 6000), 0, out.append)
-        lg.eos_flush(out.append)
-        got = {(r.key, r.id): (r.value, r.ts) for r in out}
-        assert got == _run_logic(_win_logic(False), 6000)
-
-    def test_many_keys_grow_forest_empty_swap(self):
-        """Key count past the initial forest capacity swaps in a
-        bigger EMPTY forest (never a tree copy: queued launches still
-        scatter into the old object) and re-ships dirty partials --
-        results stay identical to the rebuild lane."""
-        a = _run_logic(_win_logic(False, win=64, slide=32), 20_000,
-                       n_keys=40)
-        lg = _win_logic(True, win=64, slide=32)
-        b = _run_logic(lg, 20_000, n_keys=40)
-        assert lg._resident.forest.n_keys >= 40
-        assert a and a == b
-
-    def test_forced_resident_rejects_ineligible_shapes(self):
-        with pytest.raises(ValueError, match="resident"):
-            _win_logic(True, "mean")          # no monoid pair form
-        with pytest.raises(ValueError, match="resident"):
-            _win_logic(True, "sum", win=24, slide=6)  # pane < 16
-
-    def test_planner_promotes_eligible_device_engines(self):
-        for opt_out, expect in ((False, True), (True, False)):
-            rows = []
-            g = wf.PipeGraph("resident_promo", wf.Mode.DEFAULT)
-            op = WinSeqTPU("sum", 256, 32, WinType.CB, batch_len=32,
-                           placement="device",
-                           value_of=lambda t: t.value,
-                           resident=(False if opt_out else None))
-            g.add_source(BatchSource(_counted_batches(20_000, 2000))) \
-                .add(op).add_sink(Sink(rows.append))
-            g.run()
-            entry = next(p for p in g.placements
-                         if p["operator"].endswith("win_seq_tpu.0"))
-            assert entry.get("resident", False) is expect
-            assert rows
 
 
 def _counted_batches(n, sb, n_keys=N_KEYS, pace_s=0.0):

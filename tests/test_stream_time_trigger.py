@@ -97,8 +97,7 @@ def settle(logic, out):
 
 
 def live_keys(logic):
-    return (logic._native.snapshot()["keys_live"]
-            if logic._native is not None else len(logic.keys))
+    return logic._store.snapshot()["keys_live"]
 
 
 # -- the rule ------------------------------------------------------------------
@@ -150,8 +149,7 @@ def test_triggering_delay_is_honoured(lane):
     tup(1, 250)                              # late: window 0 has fired
     logic.eos_flush(out)
     assert out.rows[(1, 0)] == 2.0
-    ignored = (logic._native.ignored() if lane == "native"
-               else logic.ignored_tuples)
+    ignored = logic._store.ignored()
     assert ignored == 1
 
 
@@ -212,8 +210,7 @@ def test_cross_key_laggard_is_late_and_counted(lane):
     logic.eos_flush(out)
     assert out.rows[(1, 0)] == 51.0
     assert {kw for kw in out.rows if kw[0] == 1} == {(1, 0)}
-    ignored = (logic._native.ignored() if lane == "native"
-               else logic.ignored_tuples)
+    ignored = logic._store.ignored()
     assert ignored == 49
 
 
@@ -294,7 +291,7 @@ def drain(eng, rows, max_windows=1 << 20):
         r = eng.flush(max_windows)
         if r is None:
             return
-        vals, starts, ends, keys, gwids, _rts = r[:6]
+        vals, starts, ends, keys, gwids = r[0]["value"], *r[1:5]
         for j in range(len(keys)):
             kw = (int(keys[j]), int(gwids[j]))
             assert kw not in rows
@@ -335,7 +332,7 @@ def test_snapshot_holds_live_keys_only_and_keeps_the_counters():
     drain(a, {})
     blob, before = a.serialize(), a.snapshot()
     assert before["keys_evicted"] > 1000
-    assert len(blob) < 64 * 1024             # a few dozen keys, not 1,200
+    assert len(blob["native"]) < 64 * 1024   # a few dozen keys, not 1,200
     b = NativeWindowEngine(WIN, SLIDE, True, 0, kind="sum")
     b.deserialize(blob)
     after = b.snapshot()

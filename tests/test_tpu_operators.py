@@ -530,8 +530,8 @@ def test_native_engine_renumber_mode_matches_explicit_ids():
         def take(o):
             if o is None:
                 return
-            _, st_, en_, dk, dg, dr = o
-            v = o[0]
+            cols, st_, en_, dk, dg, dr, _ = o
+            v = cols["value"]
             for i in range(len(dk)):
                 s, e = int(st_[i]), int(en_[i])
                 out[(int(dk[i]), int(dg[i]))] = (round(float(v[s:e].sum()), 6),

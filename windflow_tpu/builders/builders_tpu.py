@@ -28,23 +28,10 @@ class _TPUBuilderMixin:
     max_batch_delay_ms = DEFAULT_MAX_BATCH_DELAY_MS
     placement = "device"
     adaptive_batch = False
-    resident = None
 
     def with_batch(self, batch_len: int):
         self.batch_len = batch_len
         return self
-
-    def with_resident(self, on=True):
-        """Resident pane-partial state (docs/PLANNER.md "Resident
-        state"): per-key window carry stays device-resident across
-        launches and only new partials ship.  True forces the resident
-        lane (rejecting ineligible shapes loudly), False opts out;
-        the default (None) lets the placement planner promote
-        eligible device-lane engines automatically."""
-        self.resident = on
-        return self
-
-    withResident = with_resident
 
     def with_placement(self, placement: str):
         """Engine lane: 'device' (XLA launches -- the default, status
@@ -75,13 +62,11 @@ class _TPUBuilderMixin:
         """Builders whose operators cannot change lanes (FFAT trees,
         device MAP/REDUCE composites) reject non-default placement
         loudly instead of ignoring it."""
-        if self.placement != "device" or self.adaptive_batch \
-                or self.resident is not None:
+        if self.placement != "device" or self.adaptive_batch:
             raise ValueError(
                 f"{type(self).__name__} is device-pinned: "
-                "with_placement/with_adaptive_batch/with_resident are "
-                "not supported on this operator family (the FFAT "
-                "family's resident mode is with_rebuild(False))")
+                "with_placement/with_adaptive_batch are not supported "
+                "on this operator family")
 
     def with_max_buffer(self, elems: int):
         """Host staging-buffer capacity (elements) for the device
@@ -163,8 +148,7 @@ class WinSeqTPUBuilder(_WinBuilderBase, _TPUBuilderMixin):
                          inflight_depth=self.inflight_depth,
                          max_batch_delay_ms=self.max_batch_delay_ms,
                          placement=self.placement,
-                         adaptive_batch=self.adaptive_batch,
-                         resident=self.resident)
+                         adaptive_batch=self.adaptive_batch)
 
 
 @_alias_camel
@@ -334,7 +318,7 @@ class WinSeqFFATTPUBuilder(_WinBuilderBase, _TPUBuilderMixin):
         self.combine = combine
         self.batch_len = DEFAULT_BATCH_LEN
         self.device_index = 0
-        # None = auto (docs/PLANNER.md "Resident state"): CB windows
+        # None = auto (docs/PLANNER.md "Online re-planning"): CB windows
         # default onto the RESIDENT lane (rebuild=False) -- per-key
         # forests stay in HBM across launches and only new leaves
         # ship; TB windows default to rebuild (the resident ring's

@@ -363,3 +363,17 @@ class EOS:
 
     def __repr__(self):
         return "EOS()"
+
+
+def key_groups(keys: np.ndarray):
+    """Stable-group a key column: (order, keys_sorted, bounds) with
+    ``order`` None when the column is already sorted (saves the
+    re-index on the columnar hot path)."""
+    if len(keys) > 1 and not np.all(keys[:-1] <= keys[1:]):
+        order = np.argsort(keys, kind="stable")
+        keys_s = keys[order]
+    else:
+        order, keys_s = None, keys
+    edges = np.nonzero(np.diff(keys_s))[0] + 1
+    bounds = np.concatenate([[0], edges, [len(keys_s)]])
+    return order, keys_s, bounds

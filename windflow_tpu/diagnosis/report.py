@@ -63,8 +63,8 @@ def build_report(stats: dict, flight: Optional[list] = None) -> dict:
             entry["tier"] = tier
         hot.append(entry)
     # per-tier keyed-state totals (schema v9 census extras): tiered
-    # stores report hot/warm/cold, device-lane window engines report
-    # their resident forest bytes under "device" (audit/census.py;
+    # stores report hot/warm/cold, and a census hook may report device
+    # bytes under "device" (audit/census.py;
     # windflow_keyed_state_bytes{tier=...} renders the same rows)
     tier_tot: dict = {}
     for row in (skew.get("Census") or []):

@@ -6,11 +6,9 @@ with every window engine's lane resolved.  It is the logical end of
 LEVEL2: where fusion removed the *channel hop* between adjacent stages,
 this pass removes the *launch* between adjacent device work -- an
 entire device-placed segment (decode -> filter/map -> KEYBY partition
--> resident window update+query -> fired-result extraction) executes as
-ONE XLA program invocation per ingest chunk, with all window state
-living in the engines' donated carry (ops/window_compute.py resident
-lane).  Python touches the stream once per chunk, not once per
-operator-trigger.
+-> window update+query -> fired-result extraction) executes as
+ONE XLA program invocation per ingest chunk.  Python touches the stream
+once per chunk, not once per operator-trigger.
 
 Two steps, to a fixpoint:
 

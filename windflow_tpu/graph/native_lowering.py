@@ -16,8 +16,9 @@ expressions compile onto C++ descriptors; opaque Python stays on the
 interpreted plane.
 
 The reference architecture itself (one thread per operator over SPSC
-queues) is available as ``NativeRecordPipeline(mode="threaded")`` and
-is what bench.py measures as the honest baseline.
+queues) is available as ``NativeRecordPipeline(mode="threaded")``
+(BASELINE.md: the stand-in for the reference, which publishes no
+numbers).
 """
 from __future__ import annotations
 
@@ -231,11 +232,11 @@ def _run_columnar_synth(graph, plan, mask, vtab) -> bool:
             out = eng.flush(1 << 20)
             if out is None:
                 return
-            vals, starts, ends, d_keys, d_gwids, d_rts = out[:6]
-            cs = np.concatenate([[0.0], np.cumsum(vals)])
+            cols, starts, ends, d_keys, d_gwids, d_rts, _ = out
+            cs = np.concatenate([[0.0], np.cumsum(cols["value"])])
             wins = cs[ends] - cs[starts]
             if kind == "mean":
-                cc = np.concatenate([[0.0], np.cumsum(out[6])])
+                cc = np.concatenate([[0.0], np.cumsum(cols["count"])])
                 wins = wins / np.maximum(cc[ends] - cc[starts], 1.0)
             for j in range(len(d_keys)):
                 sink_fn(BasicRecord(int(d_keys[j]), int(d_gwids[j]),

@@ -851,9 +851,8 @@ class PipeGraph:
         epochs restores exactly-once, like a rescale), drain the
         pipeline to a quiescent cut -- channels empty, no device
         batches in flight -- then swap the engine and resume.  Keyed
-        window state lives in the host staging store on both lanes
-        (resident device state is derivable from it and dropped on a
-        host flip), so the swap migrates nothing and loses nothing.
+        window state lives in the host staging store on both lanes, so
+        the swap migrates nothing and loses nothing.
 
         Records a ``replacement`` flight event the doctor explains.
         Returns the event dict, or None when already on ``lane``."""
@@ -885,13 +884,6 @@ class PipeGraph:
                 self.quiesce(timeout)
                 try:
                     target.apply_placement(lane)
-                    if lane == "device":
-                        # re-promote eligible engines onto the
-                        # resident lane (the host flip dropped it)
-                        maybe = getattr(target,
-                                        "maybe_enable_resident", None)
-                        if maybe is not None:
-                            maybe()
                 finally:
                     self.resume()
             if dur is not None:
@@ -955,8 +947,8 @@ class PipeGraph:
                 # (runtime/queues.py:73 / native.py:209), exported here
                 rec.queue_high_watermark = getattr(ch,
                                                    "high_watermark", 0)
-            # resident-lane gauge (docs/PLANNER.md "Resident state"):
-            # bytes of per-key window state living in device memory --
+            # resident gauge (operators/tpu/ffat_resident.py): bytes of
+            # per-key window state living in device memory --
             # every fused segment's engine reports into its own record
             pairs = ([(seg.logic, seg.stats)
                       for seg in n.logic.segments]

@@ -10,13 +10,13 @@ configs ran *faster on the CPU backend than on device*.
 The planner decides per engine replica, from **measured** quantities:
 
 * ``rtt_floor_ms`` -- median round trip of one tiny launch, probed
-  once per process at the first auto-placed graph start (the same
-  probe bench.py reads against p99; override:
+  once per process at the first auto-placed graph start (override:
   ``WINDFLOW_RTT_FLOOR_MS``);
 * ``host_rate_tps`` -- the host/native engine's sustained fold rate,
   micro-calibrated once per box (~1M synthetic tuples through
   ``NativeWindowEngine``; numpy fallback) and cached in
-  ``bench_runs/host_calibration.json``; override:
+  ``host_calibration.json`` beside the compile cache
+  (``ops/backend.compile_cache_dir``); override:
   ``WINDFLOW_HOST_RATE_TPS``;
 * ``tuples_per_launch`` / ``bytes_per_launch`` -- derived from the
   operator's window parameters (batch_len windows x slide tuples each;
@@ -49,6 +49,8 @@ import time
 from dataclasses import dataclass
 from typing import List, Optional
 
+from ..ops.backend import compile_cache_dir
+
 # device must beat the measured host rate by this factor to win an
 # 'auto' placement: the host number is measured on this box, the device
 # number is a projection
@@ -58,11 +60,10 @@ DEVICE_MARGIN = 1.2
 # measured (MB/s); deliberately conservative
 DEFAULT_TRANSFER_MBPS = 200.0
 
-_CALIB_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "bench_runs",
-    "host_calibration.json")
-_DEV_CALIB_PATH = os.path.join(os.path.dirname(_CALIB_PATH),
+# the two per-box calibrations live beside the compile cache: both are
+# what this box measured, kept for its next process
+_CALIB_PATH = os.path.join(compile_cache_dir(), "host_calibration.json")
+_DEV_CALIB_PATH = os.path.join(compile_cache_dir(),
                                "device_calibration.json")
 
 _probe_lock = threading.Lock()
@@ -132,8 +133,9 @@ def _calibrate_host_rate() -> float:
 
 def host_rate_tps() -> float:
     """Host-engine sustained rate, cached per box in
-    ``bench_runs/host_calibration.json`` (keyed by hostname + core
-    count, so a checkout moved between boxes re-calibrates)."""
+    ``host_calibration.json`` beside the compile cache (keyed by
+    hostname + core count, so a checkout moved between boxes
+    re-calibrates)."""
     global _host_rate_tps
     env = os.environ.get("WINDFLOW_HOST_RATE_TPS")
     if env:
@@ -174,7 +176,7 @@ def device_compute_ms_per_launch() -> float:
     against the evidence.  Sources, in priority order: the
     ``WINDFLOW_DEVICE_COMPUTE_MS`` env override, the in-process value
     the re-planner recorded this run, the per-box cache file
-    (``bench_runs/device_calibration.json``, written alongside
+    (``device_calibration.json``, written alongside
     host_calibration.json whenever a device lane's attribution is
     measured).  0.0 when never measured -- the original free-compute
     projection, unchanged."""
@@ -400,15 +402,6 @@ def plan_graph(graph) -> List[dict]:
             else:
                 entry = {"placement": pinned, "reason": "pinned"}
                 logic.apply_placement(pinned)
-            # resident promotion (docs/PLANNER.md "Resident state"):
-            # eligible device-lane engines keep their per-key pane
-            # partials resident in device memory across launches --
-            # the default lane; .with_resident(False) opts out
-            if entry["placement"] == "device" \
-                    and getattr(logic, "maybe_enable_resident",
-                                None) is not None \
-                    and logic.maybe_enable_resident():
-                entry["resident"] = True
             rid = replica_ids.get(name, 0)
             replica_ids[name] = rid + 1
             if holder.stats is None:
@@ -416,10 +409,7 @@ def plan_graph(graph) -> List[dict]:
             entry["operator"] = name
             # the lease's Resident bit marks NON-demotable lanes: a
             # custom/FFAT combine has no host program, so the arbiter
-            # must never pick it for a device->host demotion.  A
-            # promoted-resident window engine stays demotable -- its
-            # device state is derivable from the host staging store
-            # and replace_lane drops it losslessly.
+            # must never pick it for a device->host demotion.
             _lease(entry, name,
                    resident=not isinstance(
                        getattr(logic.engine, "kind", None), str))

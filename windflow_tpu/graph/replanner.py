@@ -1,6 +1,5 @@
 """Online device<->host re-planning: the placement decision as a
-running hypothesis (docs/PLANNER.md "Resident state & online
-re-planning").
+running hypothesis (docs/PLANNER.md "Online re-planning").
 
 The start-time planner (graph/planner.py) projects a device rate from
 the probed RTT floor, the calibrated host rate and the operator's

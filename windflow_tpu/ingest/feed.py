@@ -11,7 +11,7 @@ per batch.  This module closes the gap from the feed side:
   **through a shared ColumnPool arena** (`core/tuples.ColumnPool`):
   buffers recycle by refcount, so steady state allocates nothing, and
   the emitted TupleBatches enter the consuming window engine's
-  columnar ingest (`WinSeqTPULogic._svc_batch_native` -> one C++ call
+  columnar ingest (`WinSeqTPULogic._svc_batch` -> one C++ call
   per chunk) with no per-tuple Python anywhere on the path.
 * :class:`ParallelColumnFeeder` -- the channel-free variant: feeder
   threads hand pooled columns **straight into a columnar sink** under

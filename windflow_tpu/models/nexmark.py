@@ -374,8 +374,8 @@ def build_q8_new_users(graph, persons, auctions, win_len, sink,
     auction in the same tumbling window (persons |><| auctions
     re-keyed by seller).  Sinked records: key = person id, ts =
     window start, value = (city, auction id).  ``source_of(keys, tss,
-    values)`` overrides the watermarked record source -- bench.py
-    injects stamped sources to measure watermark-to-result latency."""
+    values)`` overrides the watermarked record source (stamped sources
+    measure watermark-to-result latency)."""
     import windflow_tpu as wf
     from ..eventtime import LEFT, RIGHT, WindowJoin, tag_side
     from ..operators.basic_ops import Sink
@@ -414,9 +414,8 @@ def q8_oracle(persons, auctions, win_len):
     return sorted(out)
 
 
-# eager baseline twins for the bench gate (tools/bench_gate.py): the
-# oracles ARE the single-threaded reference implementations, exposed
-# under the twin names the bench rows cite
+# eager baseline twins: the oracles ARE the single-threaded reference
+# implementations, exposed under the names a comparison cites
 q3_baseline = q3_oracle
 q4_baseline = q4_oracle
 q6_baseline = q6_oracle

@@ -106,13 +106,11 @@ class StatsRecord:
     # launch RTT floor this splits a launch's fixed cost from its
     # compute: est. fixed = launches x floor, est. compute = the rest.
     device_time_ms: float = 0.0
-    # resident-lane gauge (docs/PLANNER.md "Resident state"): bytes of
-    # per-key window state living in device memory ACROSS launches
-    # (FFAT forest / pane-partial rings).  Separate from the shipped
-    # byte counters above, which on the resident lane count only NEW
-    # bytes per launch (events in + results out) -- the >=10x
-    # bytes/launch claim is the ratio between the two lanes' shipped
-    # counters, measurable because state never re-ships.
+    # resident gauge (operators/tpu/ffat_resident.py): bytes of per-key
+    # window state living in device memory ACROSS launches (the FFAT
+    # forest).  Separate from the shipped byte counters above, which on
+    # that lane count only NEW bytes per launch (events in + results
+    # out).
     device_state_bytes: int = 0
     # ingest-plane metrics (ingest/; zero outside ingest sources):
     # admission-shed tuples, live credit level, tuples parked in outlet

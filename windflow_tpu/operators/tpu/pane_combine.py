@@ -157,9 +157,9 @@ class PaneCombineLogic(NodeLogic):
         if isinstance(item, EOSMarker):
             return  # triggering is purely count-based here
         if isinstance(item, TupleBatch):
-            from .win_seq_tpu import _key_groups
+            from ...core.tuples import key_groups
             keys = item.key
-            order, keys_s, bounds = _key_groups(keys)
+            order, keys_s, bounds = key_groups(keys)
             ids, tss, vals = item.id, item.ts, item["value"]
             if order is not None:
                 ids, tss, vals = ids[order], tss[order], vals[order]

@@ -5,12 +5,15 @@ reference archives tuples per key, batches ``batch_len`` fired windows,
 copies them to pinned buffers and launches a CUDA kernel per batch on a
 private stream (svc :391-645), this engine:
 
-* keeps each key's series in growing host buffers (consolidated into
-  sorted numpy arrays at flush time -- the pinned-staging analogue);
-* accumulates descriptors of fired windows (key, gwid, extent) until
-  ``batch_len``;
-* assembles one flat ragged buffer + [start, end) extents and launches
-  a jitted XLA program via `WindowComputeEngine` (ops/window_compute);
+* keeps the keyed-window state in one store: the C++ engine
+  (`runtime/native.NativeWindowEngine`) where it can serve, else its
+  Python twin (`window_store.PyWindowStore`), both behind the same
+  calls (``ingest``, ``flush``, ``eos``, ``ready``, ...);
+* lets fired windows gather in the store until ``batch_len`` (or the
+  buffer or age bound);
+* flushes one flat buffer + [start, end) extents from the store and
+  launches a jitted XLA program via `WindowComputeEngine`
+  (ops/window_compute);
 * overlaps host batching with device execution through async dispatch,
   flushing the *previous* batch's results lazily -- the double-buffered
   ``waitAndFlush`` protocol (win_seq_gpu.hpp:267-297).
@@ -21,23 +24,23 @@ Win_Seq_GPU does in the reference (win_farm_gpu.hpp:82-86).
 """
 from __future__ import annotations
 
-import heapq as _heapq
 import threading as _threading
 import time as _time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, List, Optional
 
 import numpy as np
 
 from ...core.basic import (OrderingMode, Pattern, Role, RoutingMode,
                            WinOperatorConfig, WinType)
-from ...core.meta import default_hash
 from ...core.tuples import BasicRecord, SynthChunk, TupleBatch
-from ...core import win_assign as wa
 from ...ops.window_compute import WindowComputeEngine
 from ...runtime.emitters import StandardEmitter
 from ...runtime.node import EOSMarker, NodeLogic
 from ...telemetry import spans
 from ..base import Operator, StageSpec
+# `_TPUKeyState`: a Python-lane snapshot pickled before PR 30 names it
+# by this module
+from .window_store import PyWindowStore, _TPUKeyState  # noqa: F401
 
 DEFAULT_BATCH_LEN = 256
 # host staging-buffer capacity (elements) before a forced flush
@@ -114,20 +117,6 @@ class AdaptiveBatcher:
             self._grow = 0
             self._shrink = 0
         return self.batch_len
-
-
-def _key_groups(keys: np.ndarray):
-    """Stable-group a key column: (order, keys_sorted, bounds) with
-    ``order`` None when the column is already sorted (saves the
-    re-index on the columnar hot path)."""
-    if len(keys) > 1 and not np.all(keys[:-1] <= keys[1:]):
-        order = np.argsort(keys, kind="stable")
-        keys_s = keys[order]
-    else:
-        order, keys_s = None, keys
-    edges = np.nonzero(np.diff(keys_s))[0] + 1
-    bounds = np.concatenate([[0], edges, [len(keys_s)]])
-    return order, keys_s, bounds
 
 
 class _AsyncDispatcher:
@@ -318,42 +307,6 @@ def _collectable(pending, depth: int) -> Optional[str]:
     return None
 
 
-class _TPUKeyState:
-    __slots__ = ("sort_keys", "ts", "values", "pending_sort", "pending_ts",
-                 "pending_val", "pending_chunks", "next_fire", "opened_max",
-                 "max_id", "renumber_next", "emit_counter", "anchor",
-                 "pane_synced", "min_new_id", "queued", "indexed")
-
-    def __init__(self, emit_counter_start=0):
-        # resident-lane sync state (ops/window_compute.ResidentPaneCarry):
-        # pane indices below ``pane_synced`` are final in the device
-        # forest; ``min_new_id`` tracks the smallest id appended since
-        # the last launch, so a launch ships only panes the new data
-        # could have changed (None = everything dirty / nothing new)
-        self.pane_synced = None
-        self.min_new_id = None
-        # consolidated sorted arrays
-        self.sort_keys = np.empty(0, np.int64)
-        self.ts = np.empty(0, np.int64)
-        self.values = np.empty(0, np.float64)
-        # unsorted pending appends (sorted at consolidation): scalar
-        # lists for the record plane, array chunks for the batch plane
-        self.pending_sort: List[int] = []
-        self.pending_ts: List[int] = []
-        self.pending_val: List[float] = []
-        self.pending_chunks: List = []
-        self.next_fire = 0        # next lwid to fire
-        self.anchor = 0           # first window that can ever fire (set
-                                  # from the first tuple, like the
-                                  # native engine's anchor)
-        self.opened_max = -1      # highest lwid opened by any tuple
-        self.max_id = -1
-        self.queued = 0           # fired windows not yet staged
-        self.indexed = False      # listed in the logic's ``_due`` heap
-        self.renumber_next = 0
-        self.emit_counter = emit_counter_start
-
-
 class WinSeqTPULogic(NodeLogic):
     # the runtime hands SynthChunk descriptors through un-materialized
     accepts_synth_chunks = True
@@ -369,6 +322,14 @@ class WinSeqTPULogic(NodeLogic):
         (runtime/node.py); the Python staging lanes take one."""
         return self._native is not None
 
+    @property
+    def _store(self):
+        """The keyed-window store this logic stages from: the C++
+        engine where there is one, else its Python twin.  ``_native`` is
+        read at each call, so a test that sets it to None (or wraps it)
+        after construction gets what it asked for."""
+        return self._native if self._native is not None else self._py
+
     def __init__(self, win_kind: Any, win_len: int, slide_len: int,
                  win_type: WinType, *, batch_len: int = DEFAULT_BATCH_LEN,
                  triggering_delay: int = 0, result_factory=BasicRecord,
@@ -383,8 +344,7 @@ class WinSeqTPULogic(NodeLogic):
                  max_batch_delay_ms: float = DEFAULT_MAX_BATCH_DELAY_MS,
                  placement: str = "device",
                  adaptive_batch: bool = False,
-                 rtt_floor_ms: Optional[float] = None,
-                 resident: Optional[bool] = None):
+                 rtt_floor_ms: Optional[float] = None):
         if win_len == 0 or slide_len == 0:
             raise ValueError("win_len and slide_len must be > 0")
         if placement not in PLACEMENTS:
@@ -404,6 +364,9 @@ class WinSeqTPULogic(NodeLogic):
             self.engine = HostComputeEngine(win_kind)  # builtin kinds only
         else:
             self.engine = WindowComputeEngine(win_kind)
+        # the count->sum and mean->pair engines a store's flush may ask
+        # for, by kind, built on first use on the resolved lane
+        self._helpers: dict = {}
         # direct-feed plane (ingest/feed.py): parallel feeder threads
         # call feed_columns concurrently; staging is single-writer
         self._feed_lock = _threading.Lock()
@@ -420,26 +383,6 @@ class WinSeqTPULogic(NodeLogic):
         self.value_of = value_of or (lambda t: t.value)
         self.closing_func = closing_func
         self.emit_batches = emit_batches
-        self.keys: Dict[Any, _TPUKeyState] = {}
-        # THE FIRING RULE (docs/RUNTIME.md "When a window fires"; the
-        # native engine has the same, native/window_engine.cpp): TB
-        # windows on real stamps fire on this replica's stream time, the
-        # largest stamp it has ingested over all keys, so a key that goes
-        # quiet gets its rows when the stream passes them; CB windows and
-        # renumbered ids count a key's own arrivals and fire on the key's
-        # own largest id.  Under the stream rule a SEQ replica (output
-        # ids are window ids) emits a row only for a window that holds a
-        # tuple of the key and drops a key whose last window is staged;
-        # the other roles number a key's windows densely for the next
-        # stage, so they emit every one and keep every key.
-        self._stream_rule = win_type == WinType.TB and not renumbering
-        self._sparse = self._stream_rule and role == Role.SEQ
-        self._stream_time = -1
-        self._fired_time = -1     # the stream time the last trigger saw
-        self._due: List = []      # heap of (fire at, n, key): keys with an
-        self._due_n = 0           # opened window, by when it fires next
-        # batch under assembly: descriptors (key, gwid, start_key, end_key)
-        self.descriptors: List = []
         # in-flight batches, oldest first: (handle, descriptors, birth).
         # Depth > 1 keeps several device programs + async D2H copies in
         # flight so one high-latency transport roundtrip amortizes over
@@ -451,7 +394,6 @@ class WinSeqTPULogic(NodeLogic):
         self.async_dispatch = async_dispatch
         self.sync_emit = not async_dispatch
         self._dispatcher: Optional[_AsyncDispatcher] = None
-        self.ignored_tuples = 0
         self.launched_batches = 0
         self.last_launch_ms = 0.0  # newest picked-up->result host wall (ms)
         # launch also when this much unshipped data is buffered, even if
@@ -468,10 +410,13 @@ class WinSeqTPULogic(NodeLogic):
         # (full-batch fill time + RTT)
         self.max_batch_delay_ms = max_batch_delay_ms
         self._last_launch_t = 0.0
-        # window-result latency samples (descriptor creation -> emission),
+        # window-result latency samples (first ready window -> emission),
         # feeding the p99 metric of BASELINE.md
         self.latency_samples: List[float] = []
         self._batch_birth: Optional[float] = None
+        # the emit of the call in hand, for a launch from inside the
+        # Python store's ingest (``_on_full``)
+        self._emit = None
         # telemetry plane (telemetry/; docs/OBSERVABILITY.md): the
         # trace context of the most recent traced input crosses the
         # async dispatcher -- captured at svc, stamped with a device
@@ -480,13 +425,6 @@ class WinSeqTPULogic(NodeLogic):
         # gauge-grade for sampled traces, like the depth gauges
         self._trace_ctx = None
         self._trace_name = "win_seq_tpu"
-        # span layer (telemetry/spans.py): the names of this operator's
-        # spans and its launch ring, re-resolved in svc_init once the
-        # runtime has named the replica; ``_chunk_seq`` counts the
-        # chunks ingested, so a launch record can say which one fired it
-        self._chunk_seq = 0
-        self._track = self._track_of = None
-        self._name_spans("win_seq_tpu", None)
         # whole-partition device step (graph/device_step.py): while a
         # chunk is traversing the fused chain the step logic holds all
         # intra-chunk launch triggers and calls flush_chunk() once at
@@ -495,38 +433,35 @@ class WinSeqTPULogic(NodeLogic):
         # quiesce / idle_tick stay unguarded -- they run between
         # chunks, where the hold is always clear.
         self.chunk_hold = False
-        # the C++ columnar engine covers the hot standalone cases
+        # THE STORE.  The Python twin serves every shape and is always
+        # there (a test may set ``_native`` to None after construction;
+        # on the native lane it stays empty and answers the two gauges
+        # that have always read the Python side, ``Inputs_ignored`` and
+        # the audit's ``staging``); the C++ columnar engine covers the
+        # hot standalone cases
         # (native/window_engine.cpp): builtin kinds, identity window
         # assignment, default value column, role SEQ -- or role PLQ,
         # whose only difference under an identity config is that output
         # ids are per-key dense counters (plq_renumbered_id degenerates
         # to the emit counter), applied on the flushed batch
+        self._py = PyWindowStore(
+            win_len, slide_len, win_type, triggering_delay,
+            renumber=renumbering, kind=win_kind, role=role,
+            config=self.config, map_indexes=map_indexes,
+            on_kept=self._on_kept, on_full=self._on_full)
         self._native = None
-        # resident lane (docs/PLANNER.md "Resident state"): per-key
-        # pane partials stay device-resident across launches; a launch
-        # ships only new/changed partials.  True forces it on (and
-        # takes the Python staging path -- the native engine stages its
-        # own pane buffers), False opts out, None lets the planner
-        # promote eligible device-lane engines.
-        self.resident = resident
-        self._resident = None
-        self._plq_counters: Dict[Any, int] = {}
-        # non-integral record keys (the reference's templated key types)
-        # are interned into a reserved negative int64 range for the
-        # columnar/native stores and translated back on emission
-        self._key_intern: Dict[Any, int] = {}
-        self._key_extern: Dict[int, Any] = {}
-        self._saw_nonint_key = False
+        # a store's churn clock and counters as last read
+        self._churn = list(PyWindowStore._NO_STATS)
         cfg = self.config
         if (isinstance(win_kind, str)
                 and win_kind in ("sum", "count", "max", "min", "mean")
                 and role in (Role.SEQ, Role.PLQ)
                 and cfg.n_outer == 1 and cfg.n_inner == 1
                 and cfg.id_outer == 0 and cfg.id_inner == 0
-                and value_of is None and resident is not True):
+                and value_of is None):
             # no library (no toolchain, WINDFLOW_NATIVE=0): the Python
-            # staging lanes below are the supported fallback, and
-            # runtime/native.py has said why on stderr
+            # store is the supported fallback, and runtime/native.py
+            # has said why on stderr
             from ...runtime.native import (NativeWindowEngine,
                                            native_available)
             if native_available():
@@ -537,10 +472,13 @@ class WinSeqTPULogic(NodeLogic):
                     win_len, slide_len, win_type == WinType.TB,
                     triggering_delay, renumber=renumbering,
                     kind=win_kind, dense=role != Role.SEQ)
-                # the engine's churn clock and counters as last read
-                self._churn = [0] * len(NativeWindowEngine.STATS)
-        if resident is True:
-            self._enable_resident(required=True)
+        # span layer (telemetry/spans.py): the names of this operator's
+        # spans and its launch ring, re-resolved in svc_init once the
+        # runtime has named the replica; ``_chunk_seq`` counts the
+        # chunks ingested, so a launch record can say which one fired it
+        self._chunk_seq = 0
+        self._track = self._track_of = None
+        self._name_spans("win_seq_tpu", None)
 
     # -- placement plane (graph/planner.py; docs/PLANNER.md) ---------------
     def apply_placement(self, placement: str,
@@ -549,113 +487,57 @@ class WinSeqTPULogic(NodeLogic):
         graph start (before any thread runs) for 'auto' engines, and
         for pinned ones to record the resolution + RTT floor.  Host
         resolution swaps the XLA engine for the numpy host engine and
-        drops any cached helper engines so they rebuild on-lane."""
+        drops any cached helper engines so they rebuild on-lane;
+        online re-planning (graph/replanner.py) can flip a
+        host-resolved engine back."""
         from ...ops.host_compute import HostComputeEngine
         if placement not in ("device", "host"):
             raise ValueError(f"cannot resolve onto {placement!r}")
         self.resolved_placement = placement
         if rtt_floor_ms:
             self.rtt_floor_ms = rtt_floor_ms
-        if placement == "host":
-            # the host lane computes against the host staging store
-            # directly: drop any resident device state (recomputable
-            # from the retained series on a later flip back)
-            self._resident = None
-            for st in self.keys.values():
-                st.pane_synced = None
-                st.min_new_id = None
-            if not isinstance(self.engine, HostComputeEngine):
-                self.engine = HostComputeEngine(self.engine.kind)
-                for cached in ("_count_eng", "_mean_eng"):
-                    if hasattr(self, cached):
-                        delattr(self, cached)
-        elif isinstance(self.engine, HostComputeEngine):
-            # online re-planning (graph/replanner.py) can flip a
-            # host-resolved engine back: restore the XLA lane
-            self.engine = WindowComputeEngine(self.engine.kind)
-            for cached in ("_count_eng", "_mean_eng"):
-                if hasattr(self, cached):
-                    delattr(self, cached)
+        on_host = isinstance(self.engine, HostComputeEngine)
+        if (placement == "host") != on_host:
+            self.engine = self._make_engine(self.engine.kind)
+            self._helpers = {}
 
     def _make_engine(self, kind):
-        """Helper-engine factory honouring the resolved lane (the
-        count->sum and mean->pair engines must run where the main
-        engine runs)."""
+        """Engine factory honouring the resolved lane (the count->sum
+        and mean->pair engines must run where the main engine runs)."""
         if self.resolved_placement == "host":
             from ...ops.host_compute import HostComputeEngine
             return HostComputeEngine(kind)
         return WindowComputeEngine(kind)
 
-    # -- resident lane (ops/window_compute.ResidentPaneCarry;
-    # docs/PLANNER.md "Resident state & online re-planning") ---------------
-    def resident_eligible(self) -> bool:
-        """Shapes the resident pane carry serves: builtin monoid kind,
-        pane length (gcd(win, slide)) long enough to pre-reduce, role
-        SEQ on a device lane, Python staging (the native engine stages
-        its own pane buffers).  Everything else keeps the rebuild
-        path."""
-        kind = getattr(self.engine, "kind", None)
-        if not (isinstance(kind, str)
-                and kind in ("sum", "count", "max", "min")):
-            return False
-        pane = int(np.gcd(self.win_len, self.slide_len))
-        return (pane >= 16 and self.role == Role.SEQ
-                and self._native is None
-                and self.resolved_placement != "host")
-
-    def _enable_resident(self, required: bool = False) -> bool:
-        if self._resident is not None:
-            return True
-        if not self.resident_eligible():
-            if required:
-                raise ValueError(
-                    "resident=True needs an eligible engine: builtin "
-                    "sum/count/max/min kind, pane length (gcd(win, "
-                    "slide)) >= 16, role SEQ and a device lane -- the "
-                    "rebuild lane serves every other shape")
-            return False
-        from ...ops.window_compute import ResidentPaneCarry
-        pane = int(np.gcd(self.win_len, self.slide_len))
-        self._resident = ResidentPaneCarry(self.engine.kind,
-                                           self.win_len // pane)
-        for st in self.keys.values():
-            st.pane_synced = None
-        return True
-
-    def maybe_enable_resident(self) -> bool:
-        """Planner promotion hook (graph/planner.plan_graph): an
-        undecided (resident=None) engine joins the resident lane when
-        eligible; resident=False opts out, True forced it at
-        construction."""
-        if self.resident is False:
-            return False
-        return self._enable_resident()
-
-    def _reset_resident(self) -> None:
-        """Drop resident device state (restore / lane flip): the next
-        launch re-ships live partials from the host retained series."""
-        if self._resident is not None:
-            self._resident.reset()
-        for st in self.keys.values():
-            st.pane_synced = None
-            st.min_new_id = None
-
-    def device_resident_bytes(self) -> int:
-        """Gauge hook: bytes of window state resident in device memory
-        (the ``Device_state_bytes_resident`` stats field)."""
-        return (self._resident.state_bytes
-                if self._resident is not None else 0)
+    def _helper_engine(self, kind: Optional[str]):
+        """The engine a flushed batch needs: this logic's own (None) or
+        the helper of the kind the store named."""
+        if kind is None:
+            return self.engine
+        eng = self._helpers.get(kind)
+        if eng is None:
+            eng = self._helpers[kind] = self._make_engine(kind)
+        return eng
 
     def _name_spans(self, op: str, graph) -> None:
+        """Names this logic's spans.  The store decides two of them: the
+        native engine folds a chunk in one foreign call under this
+        logic's own ``fold`` span and stages under ``flush``, and its
+        churn counters go to the graph's registry; the Python store's
+        chunk is under the runtime's ``svc`` span and it stages under
+        ``stage``."""
         self._span_op = op
         for phase in ("fold", "flush", "stage", "submit_wait", "dispatch",
                       "ready_wait", "work_wait", "block", "emit",
                       "open", "trigger", "evict"):
             setattr(self, "_n_" + phase, f"wf/{op}/{phase}")
+        native = self._native is not None
+        self._n_chunk = self._n_fold if native else None
+        self._n_launch = self._n_flush if native else self._n_stage
         self._launches = (graph.ring(op) if graph is not None
                           else spans.LaunchRing(op))
         self._counters = (graph.counters_of(op)
-                          if graph is not None and self._native is not None
+                          if graph is not None and native
                           else spans.Counters(op))
 
     def _ingest_track(self):
@@ -686,10 +568,10 @@ class WinSeqTPULogic(NodeLogic):
     # -- direct columnar feed (ingest/feed.py) -----------------------------
     def feed_columns(self, keys, ids, ts, vals, emit) -> None:
         """Thread-safe columnar ingest for parallel feeder threads:
-        columns go straight into the staging store (the C++ engine when
-        built) under the feed lock -- no channel hop, no per-tuple
-        Python.  ``emit`` receives any results whose launch the ingest
-        triggers (the async dispatcher keeps emitting after return)."""
+        columns go straight into the store (the C++ engine when built)
+        under the feed lock -- no channel hop, no per-tuple Python.
+        ``emit`` receives any results whose launch the ingest triggers
+        (the async dispatcher keeps emitting after return)."""
         batch = TupleBatch({"key": np.asarray(keys, np.int64),
                             "id": np.asarray(ids, np.int64),
                             "ts": np.asarray(ts, np.int64),
@@ -704,56 +586,7 @@ class WinSeqTPULogic(NodeLogic):
         with self._feed_lock:
             self.eos_flush(emit)
 
-    # -- per-key helpers ---------------------------------------------------
-    def _key_state(self, key) -> _TPUKeyState:
-        st = self.keys.get(key)
-        if st is None:
-            start = self.map_indexes[0] if self.role == Role.MAP else 0
-            st = self.keys[key] = _TPUKeyState(start)
-        return st
-
-    def _consolidate(self, st: _TPUKeyState) -> None:
-        if not st.pending_sort and not st.pending_chunks:
-            return
-        chunks_sk = [c[0] for c in st.pending_chunks]
-        chunks_ts = [c[1] for c in st.pending_chunks]
-        chunks_v = [c[2] for c in st.pending_chunks]
-        if st.pending_sort:
-            chunks_sk.append(np.asarray(st.pending_sort, np.int64))
-            chunks_ts.append(np.asarray(st.pending_ts, np.int64))
-            chunks_v.append(np.asarray(st.pending_val, np.float64))
-        st.pending_chunks.clear()
-        sk = np.concatenate(chunks_sk)
-        ts = np.concatenate(chunks_ts)
-        vals = np.concatenate(chunks_v)
-        order = np.argsort(sk, kind="stable")
-        sk, ts, vals = sk[order], ts[order], vals[order]
-        if len(st.sort_keys) and len(sk) and sk[0] < st.sort_keys[-1]:
-            # out-of-order across consolidations (TB within delay): merge
-            merged = np.concatenate([st.sort_keys, sk])
-            order = np.argsort(merged, kind="stable")
-            st.sort_keys = merged[order]
-            st.ts = np.concatenate([st.ts, ts])[order]
-            st.values = np.concatenate([st.values, vals])[order]
-        else:
-            st.sort_keys = np.concatenate([st.sort_keys, sk])
-            st.ts = np.concatenate([st.ts, ts])
-            st.values = np.concatenate([st.values, vals])
-        st.pending_sort.clear()
-        st.pending_ts.clear()
-        st.pending_val.clear()
-
-    def _evict(self, st: _TPUKeyState, initial_id: int) -> None:
-        """Drop the prefix no window >= next_fire can reach (the archive
-        purge, win_seq_gpu.hpp:612-614)."""
-        keep_from = initial_id + st.next_fire * self.slide_len
-        cut = np.searchsorted(st.sort_keys, keep_from, side="left")
-        if cut:
-            st.sort_keys = st.sort_keys[cut:]
-            st.ts = st.ts[cut:]
-            st.values = st.values[cut:]
-
-    # -- batch plane -------------------------------------------------------
+    # -- the launch pipeline -------------------------------------------------
     def _finish(self, entry, emit, how: str) -> None:
         """Flush one in-flight batch: copy its result to the host
         (``block``), add the launch's host wall (picked up -> result on
@@ -852,7 +685,6 @@ class WinSeqTPULogic(NodeLogic):
         if self.stats is not None:  # single-writer: ingest thread
             self.stats.num_launches += 1
             self.stats.bytes_to_device += nbytes_in
-            self.stats.inputs_ignored = self.ignored_tuples
         rec = self._launches.open(self._chunk_seq, nbytes_in,
                                   _time.perf_counter())
         if self.async_dispatch:
@@ -887,38 +719,13 @@ class WinSeqTPULogic(NodeLogic):
             self._dispatcher = None
         self._flush_pending(emit, drain=True)
 
-    def _plq_renumber(self, d_keys: np.ndarray) -> np.ndarray:
-        """Dense per-key output ids for the native PLQ lane: windows of
-        a key arrive in firing order, so each gets the key's running
-        emit counter (win_seq.hpp:484 with an identity config)."""
-        out = np.empty(len(d_keys), np.int64)
-        order, keys_s, bounds = _key_groups(d_keys)
-        for j in range(len(bounds) - 1):
-            lo, hi = int(bounds[j]), int(bounds[j + 1])
-            key = int(keys_s[lo])
-            start = self._plq_counters.get(key, 0)
-            ids = np.arange(start, start + (hi - lo))
-            if order is None:
-                out[lo:hi] = ids
-            else:
-                out[order[lo:hi]] = ids
-            self._plq_counters[key] = start + (hi - lo)
-        return out
-
-    # interned ids live below _INTERN_CEIL, far outside any plausible
-    # user key, so a result batch can be tested for them vectorized
-    _INTERN_BASE = -(1 << 62)
-    _INTERN_CEIL = -(1 << 61)
-
-    def _intern_key(self, key) -> int:
-        iid = self._key_intern.get(key)
-        if iid is None:
-            iid = self._INTERN_BASE + len(self._key_intern)
-            self._key_intern[key] = iid
-            self._key_extern[iid] = key
-        return iid
-
     def _emit_results(self, results, descs, emit, trace=None) -> None:
+        """One finished batch to ``emit``: ``descs`` is the (keys,
+        gwids, rts) of the store's flush, row for row with
+        ``results``.  One result TupleBatch where the operator emits
+        batches and the store's rows have a key column; else a record a
+        row (int and string keys can mix, and a TupleBatch key column
+        cannot carry the strings)."""
         if trace is not None:
             # the captured trace context rides the first emission of
             # this finished batch to the sink (batch lanes attach to
@@ -932,422 +739,75 @@ class WinSeqTPULogic(NodeLogic):
                     except AttributeError:
                         pass
                 _e(item)
-        if isinstance(descs, tuple) and descs[0] == "native":
-            # native-engine batch: columnar descriptor arrays
-            _, d_keys, d_gwids, d_rts = descs
-            if self.role == Role.PLQ:
-                d_gwids = self._plq_renumber(d_keys)
-            has_interned = (bool(self._key_extern) and len(d_keys)
-                            and bool((d_keys < self._INTERN_CEIL).any()))
-            if self.emit_batches and not has_interned:
-                emit(TupleBatch({"key": d_keys, "id": d_gwids,
-                                 "ts": d_rts,
-                                 "value": np.asarray(results, np.float64)}))
-            else:
-                # per-record (also when interned keys must be restored:
-                # a TupleBatch key column cannot carry them)
-                ext = self._key_extern
-                for i in range(len(d_keys)):
-                    out = self.result_factory()
-                    out.value = float(results[i])
-                    k = int(d_keys[i])
-                    out.set_control_fields(ext.get(k, k), int(d_gwids[i]),
-                                           int(d_rts[i]))
-                    emit(out)
+        d_keys, d_gwids, d_rts = descs
+        d_keys, ids = self._store.output_ids(d_keys, d_gwids)
+        if self.emit_batches and isinstance(d_keys, np.ndarray):
+            emit(TupleBatch({"key": d_keys, "id": ids, "ts": d_rts,
+                             "value": np.asarray(results, np.float64)}))
             return
-        if (self.emit_batches and self.role == Role.SEQ
-                and (not self._saw_nonint_key    # O(1) common case
-                     or all(isinstance(d[0], (int, np.integer))
-                            for d in descs))):
-            # columnar emission: one result TupleBatch per device batch
-            # (any non-integral key in the batch falls through to
-            # record emission below -- int and string keys can mix)
-            out = TupleBatch({
-                "key": np.fromiter((d[0] for d in descs), np.int64,
-                                   len(descs)),
-                "id": np.fromiter((d[1] for d in descs), np.int64,
-                                  len(descs)),
-                "ts": np.fromiter((d[4] for d in descs), np.int64,
-                                  len(descs)),
-                "value": np.asarray(results, np.float64),
-            })
-            emit(out)
-            return
-        for (key, gwid, _s, _e, rts, kd_key), val in zip(descs, results):
+        if isinstance(d_keys, np.ndarray):
+            d_keys = d_keys.tolist()
+        if isinstance(ids, np.ndarray):
+            ids = ids.tolist()
+        for key, id_, rts, val in zip(d_keys, ids, d_rts.tolist(), results):
             out = self.result_factory()
             out.value = float(val)
-            out.set_control_fields(key, gwid, rts)
-            if self.role == Role.MAP:
-                st = self.keys[kd_key]
-                out.set_control_fields(key, st.emit_counter, rts)
-                st.emit_counter += self.map_indexes[1]
-            elif self.role == Role.PLQ:
-                st = self.keys[kd_key]
-                new_id = wa.plq_renumbered_id(default_hash(key),
-                                              st.emit_counter, self.config)
-                out.set_control_fields(key, new_id, rts)
-                st.emit_counter += 1
+            out.set_control_fields(key, id_, rts)
             emit(out)
 
-    # builtin associative kinds whose pane partials the host can
-    # pre-reduce before shipping (the Pane_Farm decomposition, applied
-    # as a transport optimization: ship partials, not tuples)
-    _PANE_KINDS = {"sum": "sum", "count": "sum", "max": "max", "min": "min"}
-
-    def _pane_partials(self, st: _TPUKeyState, base_key: int, n_panes: int,
-                       pane: int, kind: str):
-        """Per-pane host pre-reduction over one key's retained series."""
-        edges = base_key + np.arange(n_panes + 1, dtype=np.int64) * pane
-        pos = np.searchsorted(st.sort_keys, edges)
-        if kind == "count":
-            return np.diff(pos).astype(np.float64)
-        from ...runtime.native import pane_reduce
-        red = pane_reduce(st.values, pos, kind)  # exact [pos[i], pos[i+1])
-        if red is not None:
-            return red
-        if kind == "sum":
-            cs = np.concatenate([[0.0], np.cumsum(st.values)])
-            return cs[pos[1:]] - cs[pos[:-1]]
-        neutral = -np.inf if kind == "max" else np.inf
-        ufunc = np.maximum if kind == "max" else np.minimum
-        # reduceat over the non-empty panes' start edges only: empty
-        # panes collapse to equal edges so each segment ends exactly at
-        # the next non-empty pane's start, and clipping the buffer at
-        # pos[-1] keeps retained tuples beyond the batch's last window
-        # edge out of the final segment (reduceat runs it to the end)
-        vals = st.values[:int(pos[-1])]
-        out = np.full(n_panes, neutral)
-        nonempty = np.nonzero(np.diff(pos) > 0)[0]
-        if len(nonempty):
-            out[nonempty] = ufunc.reduceat(vals, pos[nonempty])
-        return out
-
-    def _launch(self, emit) -> None:
-        """Stage the fired windows from the Python store and launch:
-        one ``stage`` span, with ``submit_wait`` its child."""
-        if not self.descriptors:
-            return
-        tr = spans.track()
-        tr.begin(self._n_stage)
-        try:
-            self._stage_and_submit(emit)
-        finally:
-            tr.end()
-
-    def _stage_and_submit(self, emit) -> None:
-        descs = self.descriptors
-        self.descriptors = []
-        # group descriptors per key (preserving order)
-        keys_involved: List = []
-        per_key: Dict = {}
-        for i, d in enumerate(descs):
-            if d[5] not in per_key:
-                per_key[d[5]] = []
-                keys_involved.append(d[5])
-            per_key[d[5]].append(i)
-        pane = int(np.gcd(self.win_len, self.slide_len))
-        kind = self.engine.kind
-        use_panes = (isinstance(kind, str) and kind in self._PANE_KINDS
-                     and pane >= 16)
-        if use_panes and self._resident is not None:
-            self._launch_resident(descs, per_key, keys_involved, pane,
-                                  kind, emit)
-            return
-        starts = np.empty(len(descs), np.int64)
-        ends = np.empty(len(descs), np.int64)
-        gwids = np.fromiter((d[1] for d in descs), np.int64, len(descs))
-        bufs_v = []
-        off = 0
-        for k in keys_involved:
-            st = self.keys[k]
-            self._consolidate(st)
-            idxs = per_key[k]
-            if use_panes:
-                # window extents are pane-aligned (pane = gcd(win, slide)
-                # divides both the slide stride and the window length)
-                base_key = min(descs[i][2] for i in idxs)
-                max_end = max(descs[i][3] for i in idxs)
-                n_panes = (max_end - base_key) // pane
-                bufs_v.append(self._pane_partials(st, base_key, n_panes,
-                                                  pane, kind))
-                for i in idxs:
-                    starts[i] = off + (descs[i][2] - base_key) // pane
-                    ends[i] = off + (descs[i][3] - base_key) // pane
-                off += n_panes
-            else:
-                bufs_v.append(st.values)
-                for i in idxs:
-                    starts[i] = off + np.searchsorted(st.sort_keys,
-                                                      descs[i][2], "left")
-                    ends[i] = off + np.searchsorted(st.sort_keys,
-                                                    descs[i][3], "left")
-                off += len(st.values)
-            for i in idxs:  # CB: result ts = last tuple in extent
-                if descs[i][4] < 0:
-                    hi = int(np.searchsorted(st.sort_keys, descs[i][3],
-                                             "left"))
-                    lo = int(np.searchsorted(st.sort_keys, descs[i][2],
-                                             "left"))
-                    d = descs[i]
-                    descs[i] = (d[0], d[1], d[2], d[3],
-                                int(st.ts[hi - 1]) if hi > lo else 0, d[5])
-        flat_vals = (np.concatenate(bufs_v) if bufs_v
-                     else np.empty(0, np.float64))
-        eng = self.engine
-        if use_panes and kind == "count":
-            eng = self._count_engine()
-        birth = self._batch_birth or _time.perf_counter()
-        self._batch_birth = None
-        self._submit({"value": flat_vals}, starts, ends, gwids, descs,
-                     birth, emit, engine=eng)
-        # the staged flat buffer is dispatcher-owned now: evict consumed
-        # prefixes, and the keys whose last window this was
-        for k in keys_involved:
-            st = self.keys[k]
-            st.queued -= len(per_key[k])
-            if not self._drop_if_done(k, st):
-                self._evict(st, wa.initial_id_of_key(default_hash(k),
-                                                     self.config, self.role))
-
-    def _launch_resident(self, descs, per_key, keys_involved, pane,
-                         kind, emit) -> None:
-        """Resident-lane launch (docs/PLANNER.md "Resident state"):
-        ship only NEW/changed pane partials plus window extents and
-        answer the batch as pane-range queries against the
-        device-resident forest -- one fused scatter+query program per
-        launch, so the window carry never re-ships.  A pane is final
-        once below the fired frontier (the acceptance gate drops
-        tuples behind it), so ``pane_synced``/``min_new_id`` bound the
-        dirty range to O(new data) per launch."""
-        carry = self._resident
-        spans = {}
-        for k in keys_involved:
-            idxs = per_key[k]
-            initial_id = wa.initial_id_of_key(default_hash(k),
-                                              self.config, self.role)
-            lo_p = (min(descs[i][2] for i in idxs) - initial_id) // pane
-            hi_p = -(-(max(descs[i][3] for i in idxs) - initial_id)
-                     // pane)
-            spans[k] = (initial_id, lo_p, hi_p)
-            carry.row_of(k)
-            if carry.needs_grow(hi_p - lo_p):
-                # the batch's pane span (or key count) outgrew the
-                # forest: swap in a bigger EMPTY one and mark EVERY
-                # key dirty -- live partials recompute from the
-                # retained host series, which eviction keeps exactly
-                # down to the oldest unfired window.  (Never migrate
-                # by copying: launches still queued on the dispatcher
-                # scatter into the OLD forest object.)
-                carry.grow(hi_p - lo_p + 64)
-                for st2 in self.keys.values():
-                    st2.pane_synced = None
-        starts = np.empty(len(descs), np.int64)
-        ends = np.empty(len(descs), np.int64)
-        q_rows = np.empty(len(descs), np.int64)
-        gwids = np.fromiter((d[1] for d in descs), np.int64, len(descs))
-        run_rows, run_starts, run_lens, bufs = [], [], [], []
-        for k in keys_involved:
-            st = self.keys[k]
-            self._consolidate(st)
-            initial_id, lo_p, n_end = spans[k]
-            row = carry.rows[k]
-            if st.pane_synced is None:
-                dirty_lo = lo_p
-            else:
-                dirty_lo = st.pane_synced
-                if st.min_new_id is not None:
-                    dirty_lo = min(dirty_lo,
-                                   (st.min_new_id - initial_id) // pane)
-                # panes below this batch's oldest window start are
-                # dead (never read again): skip them even if unsynced
-                dirty_lo = max(dirty_lo, lo_p)
-            dirty_lo = min(dirty_lo, n_end)
-            if n_end > dirty_lo:
-                part = self._pane_partials(st, initial_id + dirty_lo
-                                           * pane, n_end - dirty_lo,
-                                           pane, kind)
-                bufs.append(np.asarray(part, np.float32))
-                # one CONSECUTIVE run of panes per key: ship a
-                # (row, start, len) descriptor, never positions
-                run_rows.append(row)
-                run_starts.append(dirty_lo)
-                run_lens.append(n_end - dirty_lo)
-            for i in per_key[k]:
-                starts[i] = (descs[i][2] - initial_id) // pane
-                ends[i] = -(-(descs[i][3] - initial_id) // pane)
-                q_rows[i] = row
-                if descs[i][4] < 0:  # CB: result ts = last in extent
-                    hi = int(np.searchsorted(st.sort_keys, descs[i][3],
-                                             "left"))
-                    lo = int(np.searchsorted(st.sort_keys, descs[i][2],
-                                             "left"))
-                    d = descs[i]
-                    descs[i] = (d[0], d[1], d[2], d[3],
-                                int(st.ts[hi - 1]) if hi > lo else 0,
-                                d[5])
-            st.pane_synced = n_end
-            st.min_new_id = None
-        cols = {
-            "value": (np.concatenate(bufs) if bufs
-                      else np.empty(0, np.float32)),
-            "run_rows": np.asarray(run_rows, np.int32),
-            "run_starts": np.asarray(run_starts, np.int64),
-            "run_lens": np.asarray(run_lens, np.int32),
-            "q_rows": q_rows,
-        }
-        birth = self._batch_birth or _time.perf_counter()
-        self._batch_birth = None
-        self._submit(cols, starts, ends, gwids, descs, birth, emit,
-                     engine=carry.launch_engine())
-        if self.stats is not None:  # single-writer: ingest thread
-            self.stats.device_state_bytes = carry.state_bytes
-        for k in keys_involved:
-            # a resident key keeps its row in the device forest, so its
-            # host state stays too
-            self.keys[k].queued -= len(per_key[k])
-            self._evict(self.keys[k], spans[k][0])
-
-    def _count_engine(self):
-        # count over panes = sum of per-pane counts
-        if not hasattr(self, "_count_eng"):
-            self._count_eng = self._make_engine("sum")
-        return self._count_eng
-
-    # -- descriptor generation (window assignment) -------------------------
-    def _fire_key(self, key, st: _TPUKeyState, front, emit) -> None:
-        """Queue every window of the key that ``front`` has passed: the
-        one place the rule is applied (the stream time, a CB key's own
-        largest id, or infinity at EOS)."""
-        cfg = self.config
-        hashcode = default_hash(key)
-        first_gwid = wa.first_gwid_of_key(hashcode, cfg)
-        initial_id = wa.initial_id_of_key(hashcode, cfg, self.role)
-        tb = self.win_type == WinType.TB
-        slack = self.triggering_delay if tb else 0
-        if self._sparse:
-            self._consolidate(st)
-        while st.next_fire <= st.opened_max:
-            lwid = st.next_fire
-            start = initial_id + lwid * self.slide_len
-            end = start + self.win_len
-            if front < end + slack:
-                break
-            st.next_fire += 1
-            if self._sparse:
-                lo, hi = np.searchsorted(st.sort_keys, (start, end))
-                if lo == hi:
-                    continue      # holds no tuple of the key: no row
-            gwid = wa.gwid_of_lwid(first_gwid, lwid, cfg)
-            rts = (gwid * self.slide_len + self.win_len - 1
-                   if tb else -1)  # CB: resolved at launch
-            if not self.descriptors:
-                self._batch_birth = _time.perf_counter()
-            self.descriptors.append((key, gwid, start, end, rts, key))
-            st.queued += 1
-            if (len(self.descriptors) >= self.batch_len
-                    and not self.chunk_hold):
-                self._launch(emit)
-
-    def _passed_lwid(self, initial_id: int) -> int:
-        """Local id of the last window the stream has passed for a key
-        whose windows start at ``initial_id``: fired for every key, so a
-        tuple below its end is late for every key, also for one whose
-        state is gone.  -1 where there is none (or no stream rule)."""
-        if not self._stream_rule:
-            return -1
-        t = (self._fired_time - self.triggering_delay - self.win_len
-             - initial_id)
-        return -1 if t < 0 else t // self.slide_len
-
-    def _admit(self, st: _TPUKeyState, first_rel: int, passed: int):
-        """Anchor a key on its first data, skip what lies empty before a
-        returning one's, and return the acceptance boundary (relative to
-        the key's initial id) with whether a tuple below it is late: it
-        is where a window has fired there, the key's own last or the
-        last the stream passed; below a new key's anchor lies a hopping
-        gap."""
-        first_w = ((first_rel - self.win_len) // self.slide_len + 1
-                   if first_rel >= self.win_len else 0)
-        if st.max_id < 0:
-            # first data: anchor the fire frontier at the first
-            # containing window (an epoch-scale first id must not fire
-            # ~id/slide empty windows), never at one the stream passed
-            st.anchor = st.next_fire = max(first_w, passed + 1)
-        elif (self._sparse and st.next_fire > st.opened_max
-              and first_w > st.next_fire):
-            st.next_fire = first_w
-        fired = st.next_fire > st.anchor
-        own = (self.win_len + (st.next_fire - 1) * self.slide_len
-               if fired else st.anchor * self.slide_len)
-        if passed >= 0:
-            return max(own, passed * self.slide_len + self.win_len), True
-        return own, fired
-
-    def _settle(self, key, st: _TPUKeyState, initial_id: int, emit) -> None:
-        """A key has new data: its part in the firing."""
-        if not self._stream_rule:
-            self._fire_key(key, st, st.max_id, emit)
-            return
-        if st.max_id > self._stream_time:
-            self._stream_time = st.max_id
-        self._index_key(key, st, initial_id)
-
-    def _index_key(self, key, st: _TPUKeyState, initial_id: int) -> None:
-        if st.indexed or st.next_fire > st.opened_max:
-            return
-        st.indexed = True
-        self._due_n += 1
-        _heapq.heappush(self._due, (
-            initial_id + st.next_fire * self.slide_len + self.win_len
-            + self.triggering_delay, self._due_n, key))
-
-    def _trigger(self, emit) -> None:
-        """The stream has moved: fire the windows it has passed, for the
-        keys that have them."""
-        now = self._fired_time = self._stream_time
-        due = self._due
-        while due and due[0][0] <= now:
-            key = _heapq.heappop(due)[2]
-            st = self.keys[key]
-            st.indexed = False
-            self._fire_key(key, st, now, emit)
-            self._index_key(key, st, wa.initial_id_of_key(
-                default_hash(key), self.config, self.role))
-            self._drop_if_done(key, st)
-
-    def _drop_if_done(self, key, st: _TPUKeyState) -> bool:
-        """Evict a key whose every opened window has fired and been
-        staged: a later tuple of it opens a new key."""
-        if (not self._sparse or st.queued or st.indexed
-                or st.next_fire <= st.opened_max
-                or self._resident is not None
-                or self.keys.get(key) is not st):
-            return False
-        del self.keys[key]
-        return True
-
-    # -- columnar ingest (the zero-copy fast path: a whole TupleBatch is
-    # partitioned by key and appended per key vectorized; the analogue of
-    # the reference feeding batches straight from pinned staging) --------
-    def _native_launch(self, emit, max_windows=None):
-        """Stage ready windows from the C++ engine and launch one XLA
-        program over the pane-partial buffer: one ``flush`` span, with
+    def _launch(self, emit, max_windows=None) -> None:
+        """Stage ready windows from the store and launch one program
+        over the flushed buffer: one ``flush`` / ``stage`` span, with
         ``submit_wait`` its child."""
+        store = self._store
         tr = spans.track()
-        tr.begin(self._n_flush)
+        tr.begin(self._n_launch)
         try:
-            self._flush_and_submit(emit, max_windows)
+            out = store.flush(max_windows or max(self.batch_len, 4096))
+            self._account_churn(store)
+            if out is None:
+                return
+            cols, starts, ends, d_keys, d_gwids, d_rts, kind = out
+            birth = self._batch_birth or _time.perf_counter()
+            # leftover ready windows (partial flush) restart the age clock
+            self._batch_birth = (_time.perf_counter() if store.ready()
+                                 else None)
+            if self.stats is not None:  # single-writer: ingest thread
+                # the Python store's late tuples; the native engine's
+                # are among its counters (``_account_churn``)
+                self.stats.inputs_ignored = self._py.ignored()
+            self._submit(cols, starts, ends, d_gwids,
+                         (d_keys, d_gwids, d_rts), birth, emit,
+                         engine=self._helper_engine(kind))
         finally:
             tr.end()
 
-    def _account_churn(self) -> None:
-        """What the native engine timed and counted since the last look:
-        ``open``, ``trigger`` and ``evict`` become children of the span
-        open round the call, the counters go to the registry.  A look
-        that finds no key opened and no clock moved costs one native
-        call: the fold's two counts, which move with every chunk, go
-        with the next look that does (every firing, and EOS)."""
-        s = self._native.stats()
+    def _on_kept(self, n: int) -> None:
+        """The Python store's hook: a chunk left ``n`` tuples with a
+        key.  That store's buffer bound counts the tuples it kept."""
+        self._buffered_since_launch += n
+
+    def _on_full(self, ready: int) -> None:
+        """The Python store's hook (window_store.PyWindowStore): a
+        window fired inside an ingest and ``ready`` are waiting.  That
+        store's batch leaves the moment it is full, and whole, where
+        the native engine's leaves after the ingest (``_folded``):
+        launch counts and sizes differ, and tests and the
+        AdaptiveBatcher read them (ROADMAP D2)."""
+        if self._batch_birth is None:
+            self._batch_birth = _time.perf_counter()
+        if ready >= self.batch_len and not self.chunk_hold:
+            self._launch(self._emit, max_windows=ready)
+
+    def _account_churn(self, store) -> None:
+        """What the store timed and counted since the last look (the
+        native engine does, ``NativeWindowEngine.STATS``): ``open``,
+        ``trigger`` and ``evict`` become children of the span open round
+        the call, the counters go to the registry.  A look that finds
+        no key opened and no clock moved costs one call: the fold's two
+        counts, which move with every chunk, go with the next look that
+        does (every firing, and EOS)."""
+        s = store.stats()
         last = self._churn
         if s[0] == last[0] and s[1] == last[1] and s[2] == last[2] \
                 and s[3] == last[3]:
@@ -1361,50 +821,24 @@ class WinSeqTPULogic(NodeLogic):
         self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
                             last[3:10])
 
-    def _flush_and_submit(self, emit, max_windows) -> None:
-        out = self._native.flush(max_windows or max(self.batch_len, 4096))
-        self._account_churn()
-        if out is None:
-            return
-        vals, starts, ends, d_keys, d_gwids, d_rts = out[:6]
-        birth = self._batch_birth or _time.perf_counter()
-        # leftover ready windows (partial flush) restart the age clock
-        self._batch_birth = (_time.perf_counter() if self._native.ready()
-                             else None)
-        cols = {"value": vals}
-        # count windows sum their per-pane counts; mean windows divide
-        # pane-sum totals by pane-count totals (pair program); max/min
-        # fold partials through the matching sparse-table engine
-        if self.engine.kind == "count":
-            eng = self._count_engine()
-        elif self.engine.kind == "mean":
-            cols["count"] = out[6]
-            eng = self._mean_engine()
-        else:
-            eng = None
-        self._submit(cols, starts, ends, d_gwids,
-                     ("native", d_keys, d_gwids, d_rts), birth, emit,
-                     engine=eng)
-
-    def _mean_engine(self):
-        if not hasattr(self, "_mean_eng"):
-            self._mean_eng = self._make_engine("mean_panes")
-        return self._mean_eng
-
     def _launch_due(self) -> bool:
         return ((_time.perf_counter() - self._last_launch_t) * 1e3
                 >= self.max_batch_delay_ms)
 
-    def _svc_batch_native(self, batch: TupleBatch, emit):
-        ids = batch.id if self.win_type == WinType.CB else batch.ts
-        ready = self._native.ingest(batch.key, ids, batch.ts,
-                                    batch["value"])
-        self._account_churn()
-        self._folded(ready, len(batch), emit)
-
-    def _folded(self, ready: int, n: int, emit) -> None:
-        """After a native ingest of ``n`` events left ``ready`` windows:
-        the age clock, and a launch where one is due."""
+    def _folded(self, ready: int, n: int, emit, chunk: bool = True) -> None:
+        """After an ingest of ``n`` events (a chunk, or one record) left
+        ``ready`` windows: the age clock, and a launch where one is due.
+        The Python store has counted what it kept of a chunk and sent
+        its full batches from inside the ingest (``_on_kept``,
+        ``_on_full``): what it leaves goes whole, on the age bound or,
+        after a chunk, the buffer bound (ROADMAP D2: the two stores'
+        rules are not yet one)."""
+        if self._native is None:
+            if (ready and not self.chunk_hold and (self._launch_due() or (
+                    chunk and self._buffered_since_launch
+                    >= self.max_buffer_elems))):
+                self._launch(emit, max_windows=ready)
+            return
         if ready and self._batch_birth is None:
             self._batch_birth = _time.perf_counter()
         self._buffered_since_launch += n
@@ -1412,201 +846,78 @@ class WinSeqTPULogic(NodeLogic):
                 and (ready >= self.batch_len
                      or self._buffered_since_launch >= self.max_buffer_elems
                      or self._launch_due())):
-            self._native_launch(emit)
-
-    def _svc_batch(self, batch: TupleBatch, emit):
-        if self._native is not None:
-            # one ``fold`` span a chunk round the native ingest and this
-            # operator's Python about it (a launch is its child ``flush``)
-            tr = self._ingest_track()
-            tr.begin(self._n_fold)
-            try:
-                self._svc_batch_native(batch, emit)
-            finally:
-                tr.end()
-            return
-        keys = batch.key
-        ids = batch.id if self.win_type == WinType.CB else batch.ts
-        vals = batch["value"]
-        tss = batch.ts
-        order, keys_s, bounds = _key_groups(keys)
-        if order is None:
-            ids_s, vals_s, tss_s = ids, vals, tss
-        else:
-            ids_s, vals_s, tss_s = ids[order], vals[order], tss[order]
-        uniq = keys_s[bounds[:-1]]
-        cfg = self.config
-        for j, key in enumerate(uniq):
-            key = key.item()
-            lo, hi = bounds[j], bounds[j + 1]
-            st = self._key_state(key)
-            hashcode = default_hash(key)
-            initial_id = wa.initial_id_of_key(hashcode, cfg, self.role)
-            k_ids = ids_s[lo:hi]
-            if self.renumbering:
-                k_ids = np.arange(st.renumber_next,
-                                  st.renumber_next + (hi - lo))
-                st.renumber_next += hi - lo
-            if not len(k_ids):
-                continue
-            # acceptance: drop tuples behind the already-fired frontier
-            passed = self._passed_lwid(initial_id)
-            min_boundary, late = self._admit(
-                st, int(k_ids.min()) - initial_id, passed)
-            keep = k_ids >= initial_id + min_boundary
-            if self.win_len < self.slide_len:  # hopping: drop gap tuples
-                n = (k_ids - initial_id) // self.slide_len
-                off = k_ids - initial_id
-                keep &= (off >= n * self.slide_len) & \
-                    (off < n * self.slide_len + self.win_len)
-            n_drop = int((~keep).sum())
-            if n_drop and late:
-                self.ignored_tuples += n_drop
-            if n_drop == len(k_ids):
-                if self._stream_rule:
-                    # late, yet the stream has come this far
-                    self._stream_time = max(self._stream_time,
-                                            int(k_ids.max()))
-                self._drop_if_done(key, st)
-                continue
-            k_ids = k_ids[keep]
-            st.pending_chunks.append(
-                (k_ids.astype(np.int64), tss_s[lo:hi][keep],
-                 vals_s[lo:hi][keep].astype(np.float64)))
-            if self._resident is not None:
-                mn = int(k_ids.min())
-                if st.min_new_id is None or mn < st.min_new_id:
-                    st.min_new_id = mn
-            self._buffered_since_launch += len(k_ids)
-            st.max_id = max(st.max_id, int(k_ids.max()))
-            last_w = wa.last_window_of(st.max_id, initial_id, self.win_len,
-                                       self.slide_len)
-            if last_w >= 0:
-                st.opened_max = max(st.opened_max, last_w)
-            self._settle(key, st, initial_id, emit)
-        if self._stream_rule:
-            self._trigger(emit)
-        if (self.descriptors and not self.chunk_hold
-                and (self._buffered_since_launch >= self.max_buffer_elems
-                     or self._launch_due())):
             self._launch(emit)
+
+    # -- ingest ----------------------------------------------------------------
+    def _svc_batch(self, item, emit) -> None:
+        """One chunk (columns, or a slice of the declared synthetic law
+        that the native engine generates and folds in one pass) into the
+        store, and a launch where one is due.  On the native lane under
+        one ``fold`` span round the foreign call and this operator's
+        Python about it (a launch is its child ``flush``)."""
+        store = self._store
+        self._emit = emit
+        name = self._n_chunk
+        if name is not None:
+            tr = self._ingest_track()
+            tr.begin(name)
+        try:
+            if type(item) is SynthChunk:
+                ready = store.synth_ingest(item.start, item.n, item.n_keys,
+                                           item.vmod, item.vscale, item.voff)
+            else:
+                ready = store.ingest(
+                    item.key,
+                    item.id if self.win_type == WinType.CB else item.ts,
+                    item.ts, item["value"])
+            self._account_churn(store)
+            self._folded(ready, len(item), emit)
+        finally:
+            if name is not None:
+                tr.end()
 
     def svc(self, item, channel_id, emit):
         if self.telemetry is not None:
             tr = getattr(item, "trace", None)
             if tr is not None:   # crosses the dispatcher (see _finish)
                 self._trace_ctx = tr
-        if isinstance(item, TupleBatch):
+        if isinstance(item, (TupleBatch, SynthChunk)):
             self._chunk_seq += 1
             self._svc_batch(item, emit)
             return
-        if isinstance(item, SynthChunk):
-            self._chunk_seq += 1
-            # declared synthetic stream: the native engine generates and
-            # folds the chunk in one pass (no host column materializes)
-            if self._native is not None:
-                tr = self._ingest_track()
-                tr.begin(self._n_fold)
-                try:
-                    ready = self._native.synth_ingest(
-                        item.start, item.n, item.n_keys, item.vmod,
-                        item.vscale, item.voff)
-                    self._account_churn()
-                    self._folded(ready, item.n, emit)
-                finally:
-                    tr.end()
-            else:
-                self._svc_batch(item.materialize(), emit)
-            return
-        if self._native is not None and not isinstance(item, EOSMarker):
-            # route records through the native engine as 1-row columns so
-            # mixed record/batch streams share one state store
-            key, tid, ts = item.get_control_fields()
-            if not isinstance(key, (int, np.integer)):
-                key = self._intern_key(key)
-            self._svc_batch_native(TupleBatch({
-                "key": np.array([key], np.int64),
-                "id": np.array([tid], np.int64),
-                "ts": np.array([ts], np.int64),
-                "value": np.array([self.value_of(item)], np.float64),
-            }), emit)
-            return
-        if self._native is not None:
-            return  # EOS markers: the native engine fires on eos_flush
+        # the record plane: one record into the same store, so mixed
+        # record/batch streams share it.  An EOS marker carries its
+        # key's last stamp and no tuple
         is_marker = isinstance(item, EOSMarker)
         t = item.record if is_marker else item
-        key, tid, ts = t.get_control_fields()
-        if not isinstance(key, (int, np.integer)):
-            self._saw_nonint_key = True
-        hashcode = default_hash(key)
-        st = self._key_state(key)
-        if self.renumbering and not is_marker:
-            tid = st.renumber_next
-            st.renumber_next += 1
-            t.set_control_fields(key, tid, ts)
-        id_ = tid if self.win_type == WinType.CB else ts
-        cfg = self.config
-        initial_id = wa.initial_id_of_key(hashcode, cfg, self.role)
-        if not is_marker:
-            passed = self._passed_lwid(initial_id)
-            min_boundary, late = self._admit(st, id_ - initial_id, passed)
-            if id_ < initial_id + min_boundary:
-                if late:
-                    self.ignored_tuples += 1
-                self._drop_if_done(key, st)
-                return
-            last_w = wa.last_window_of(id_, initial_id, self.win_len,
-                                       self.slide_len)
-            if last_w < 0:
-                self._drop_if_done(key, st)
-                return  # hopping gap
-            st.opened_max = max(st.opened_max, last_w)
-            st.pending_sort.append(id_)
-            st.pending_ts.append(ts)
-            st.pending_val.append(self.value_of(t))
-            if self._resident is not None and (
-                    st.min_new_id is None or id_ < st.min_new_id):
-                st.min_new_id = id_
-        st.max_id = max(st.max_id, id_)
-        self._settle(key, st, initial_id, emit)
-        if self._stream_rule:
-            self._trigger(emit)
-        if (self.descriptors and self._launch_due()
-                and not self.chunk_hold):
-            self._launch(emit)
+        store = self._store
+        self._emit = emit
+        ready = store.ingest_record(
+            t, None if is_marker else self.value_of(t))
+        self._account_churn(store)
+        self._folded(ready, 1, emit, chunk=False)
 
     def eos_flush(self, emit):
         """Fire every opened window, then drain both batches (the
         reference computes leftovers on CPU at EOS,
-        win_seq_gpu.hpp:648-710; we just launch a final batch)."""
-        if self._native is not None:
-            self._native.eos()
-            while self._native.ready():
-                self._native_launch(emit)
-            self._drain_all(emit)
-            return
-        for key, st in list(self.keys.items()):
-            st.indexed = False
-            self._fire_key(key, st, float("inf"), emit)
-        self._due.clear()
-        self._launch(emit)
+        win_seq_gpu.hpp:648-710; we just launch the final batches)."""
+        store = self._store
+        self._emit = emit
+        store.eos()
+        while store.ready():
+            self._launch(emit)
         self._drain_all(emit)
 
     def idle_tick(self, emit) -> None:
         """Stalled-stream launch trigger (RtNode timed gets): windows
-        that fired but sit staged/ready while no input arrives must
-        still launch once the rate-limit allows -- otherwise a paused
-        source withholds results until the next batch or EOS."""
+        that fired but sit ready in the store while no input arrives
+        must still launch once the rate-limit allows -- otherwise a
+        paused source withholds results until the next batch or EOS."""
         if self.pending:
             # inline-dispatch mode parks computed batches in `pending`
             # until the next launch; a stall must drain the ready ones
             self._flush_pending(emit)
-        if not self._launch_due():
-            return
-        if self._native is not None:
-            if self._native.ready():
-                self._native_launch(emit)
-        elif self.descriptors:
+        if self._launch_due() and self._store.ready():
             self._launch(emit)
 
     def flush_chunk(self, emit) -> int:
@@ -1615,14 +926,9 @@ class WinSeqTPULogic(NodeLogic):
         ``chunk_hold`` suppressed the intra-chunk triggers goes out as
         ONE launch.  Returns the number of launches issued (0 or 1) so
         the step logic can account launches-per-chunk."""
-        if self._native is not None:
-            ready = self._native.ready()
-            if ready:
-                self._native_launch(emit, max_windows=ready)
-                return 1
-            return 0
-        if self.descriptors:
-            self._launch(emit)
+        ready = self._store.ready()
+        if ready:
+            self._launch(emit, max_windows=ready)
             return 1
         return 0
 
@@ -1641,104 +947,43 @@ class WinSeqTPULogic(NodeLogic):
     # gauge reads from the auditor thread against the live engine -----
     def audit_in_flight(self) -> dict:
         """Windows absorbed but not yet emitted: submitted device
-        batches plus the batch under assembly -- the ``in_flight``
-        term of the conservation ledger's device leg."""
-        disp = self._dispatcher
-        pend = len(self.pending) + (disp.depth() if disp is not None
-                                    and hasattr(disp, "depth") else 0)
-        return {"device_batches": pend,
-                "staging": len(self.descriptors)}
+        batches (the inline pipeline's; the dispatcher's backlog is its
+        own) plus the Python store's batch under assembly -- the
+        ``in_flight`` term of the conservation ledger's device leg."""
+        return {"device_batches": len(self.pending),
+                "staging": self._py.ready()}
 
     def keyed_state_census(self):
         """(key count, byte estimate) of the per-key window state: the
-        keys that are live, not every key ever seen.  Python path:
-        sampled _TPUKeyState arrays; native path: the engine's count of
-        live keys (it owns the buffers: no byte estimate)."""
-        if self._native is not None:
-            n = self._native.snapshot()["keys_live"]
+        keys that are live, not every key ever seen.  The Python store
+        samples one key state's arrays; the native engine owns its
+        buffers and estimates no bytes."""
+        snap = self._store.snapshot()
+        n, est = snap["keys_live"], snap.get("bytes_est")
+        if est is None:
             return (n, 0) if n else None
-        keys = self.keys
-        n = len(keys)
-        if n == 0:
-            return (0, 0)
-        try:
-            st = next(iter(keys.values()))
-            per = (st.sort_keys.nbytes + st.ts.nbytes
-                   + st.values.nbytes + 96)
-        except (RuntimeError, StopIteration, AttributeError):
-            per = 96  # resized under us: count-only estimate
-        res = self.device_resident_bytes()
-        if res:
-            # ROADMAP item 4: resident-forest bytes surface as the
-            # census "device" tier (metrics render them under
-            # windflow_keyed_state_bytes{tier="device"})
-            return (n, n * per, {"tiers": {"device": [n, int(res)]}})
-        return (n, n * per)
+        return (n, est)
 
     # -- checkpoint / resume (utils/checkpoint.py policy layer) --------
     def state_dict(self):
         """Pickle-friendly snapshot (quiescent contract: no device
-        batches in flight).  Native-path state is the engine's versioned
-        binary blob; Python-path state is the per-key store."""
-        import copy
-        st = {
-            "descriptors": list(self.descriptors),
-            "ignored_tuples": self.ignored_tuples,
-            "launched_batches": self.launched_batches,
-            "buffered": self._buffered_since_launch,
-            "stream_time": self._stream_time,
-            "fired_time": self._fired_time,
-        }
-        if self._native is not None:
-            st["native"] = self._native.serialize()
-            st["plq_counters"] = dict(self._plq_counters)
-            if self._key_intern:
-                st["key_intern"] = dict(self._key_intern)
-        else:
-            # deep copy: a live checkpoint resumes the stream after the
-            # snapshot, and an aliased store would keep advancing
-            st["keys"] = copy.deepcopy(self.keys)
+        batches in flight).  The native engine's state is its versioned
+        binary blob under ``native``; the Python store's is its per-key
+        store under ``keys``, with what it had fired and its times."""
+        st = {"launched_batches": self.launched_batches,
+              "buffered": self._buffered_since_launch}
+        st.update(self._store.serialize())
         return st
 
     def load_state(self, state):
-        self.descriptors = list(state.get("descriptors", []))
-        self.ignored_tuples = state.get("ignored_tuples", 0)
         self.launched_batches = state.get("launched_batches", 0)
         self._buffered_since_launch = state.get("buffered", 0)
-        if "native" in state:
-            if self._native is None:
-                raise RuntimeError(
-                    "snapshot came from the native engine but this "
-                    "replica runs the Python path")
-            self._native.deserialize(state["native"])
-            self._plq_counters = dict(state.get("plq_counters", {}))
-            self._key_intern = dict(state.get("key_intern", {}))
-            self._key_extern = {v: k for k, v in self._key_intern.items()}
-        else:
-            if self._native is not None:
-                raise RuntimeError(
-                    "snapshot came from the Python path but this "
-                    "replica runs the native engine")
-            import copy
-            self.keys = copy.deepcopy(state["keys"])
-            self._stream_time = state.get("stream_time", -1)
-            self._fired_time = state.get("fired_time", -1)
-            self._due = []
-            for key, st in self.keys.items():
-                st.indexed = False
-                self._index_key(key, st, wa.initial_id_of_key(
-                    default_hash(key), self.config, self.role))
-            # re-derive the non-integral-key flag from the restored
-            # store (every descriptor's key is in it): the columnar
-            # emit shortcut keys off the flag, and a fresh replica
-            # restoring string-keyed state would otherwise crash in
-            # np.fromiter on the first launch
-            self._saw_nonint_key = any(
-                not isinstance(k, (int, np.integer)) for k in self.keys)
-        # resident carry is NOT part of the snapshot (it is derivable
-        # from the retained host series): drop it so the next launch
-        # re-ships live partials -- restores stay lane-portable
-        self._reset_resident()
+        lanes = ("the native engine", "the Python path")
+        if ("native" in state) != (self._native is not None):
+            came, runs = lanes if "native" in state else lanes[::-1]
+            raise RuntimeError(
+                f"snapshot came from {came} but this replica runs {runs}")
+        self._store.deserialize(state)
 
     def svc_end(self):
         # error-path teardown: eos_flush already drained (and cleared)
@@ -1765,7 +1010,7 @@ class WinSeqTPU(Operator):
                  async_dispatch=True,
                  max_batch_delay_ms=DEFAULT_MAX_BATCH_DELAY_MS,
                  placement="device", adaptive_batch=False,
-                 rtt_floor_ms=None, resident=None):
+                 rtt_floor_ms=None):
         super().__init__(name, 1, RoutingMode.FORWARD, Pattern.WIN_SEQ_TPU)
         self.win_type = win_type
         self.kwargs = dict(
@@ -1776,8 +1021,7 @@ class WinSeqTPU(Operator):
             emit_batches=emit_batches, max_buffer_elems=max_buffer_elems,
             inflight_depth=inflight_depth, async_dispatch=async_dispatch,
             max_batch_delay_ms=max_batch_delay_ms, placement=placement,
-            adaptive_batch=adaptive_batch, rtt_floor_ms=rtt_floor_ms,
-            resident=resident)
+            adaptive_batch=adaptive_batch, rtt_floor_ms=rtt_floor_ms)
         self._renumbering = False
 
     def enable_renumbering(self):

@@ -3,9 +3,9 @@
 Every lazy ``import jax`` in the package goes through
 :func:`jax_modules`, so the persistent compilation cache is placed
 before the first program compiles -- whichever entry point got there
-first (chip_smoke.py, bench.py, an example, a fleet worker, a direct
-use of ``ops/``).  The package itself still imports without JAX: host
-plane processes never call this.
+first (chip_smoke.py, benchmarks/run.py, an example, a fleet worker, a
+direct use of ``ops/``).  The package itself still imports without JAX:
+host plane processes never call this.
 
 Also holds the per-device peak table the stats JSON's roofline estimate
 divides by.
@@ -48,8 +48,8 @@ def jax_modules():
 
 
 def open_tpu(min_devices: int = 1, out=None) -> dict:
-    """For the entry points that report device numbers (chip_smoke.py,
-    bench.py): load JAX, print what it found on ``out`` (stdout when
+    """For the entry points that must run on the chip (chip_smoke.py):
+    load JAX, print what it found on ``out`` (stdout when
     None), and refuse anything but a TPU with ``min_devices`` chips --
     a chip that cannot be opened is an error, never a CPU run.  Returns
     the device as JAX reports it."""

@@ -299,151 +299,6 @@ class DeviceBatchHandle:
         return np.asarray(self._dev)[: self._n]
 
 
-class _ResidentPaneHandle:
-    """Async result of one fused resident-pane launch: the device
-    array holds 2B ring-wrap query pieces; ``block()`` combines them
-    host-side in time order (same protocol as DeviceBatchHandle)."""
-
-    __slots__ = ("_dev", "_wraps", "_B", "_comb")
-
-    def __init__(self, dev, wraps, B, np_comb):
-        self._dev = dev
-        self._wraps = wraps
-        self._B = B
-        self._comb = np_comb
-        dev.copy_to_host_async()
-
-    def ready(self) -> bool:
-        return bool(self._dev.is_ready())
-
-    def wait(self) -> None:
-        self._dev.block_until_ready()
-
-    def block(self) -> np.ndarray:
-        out = np.asarray(self._dev)
-        head, tail = out[: self._B], out[self._B: 2 * self._B]
-        if self._wraps.any():
-            head = np.where(self._wraps, self._comb(head, tail), head)
-        return head
-
-
-class _ResidentPaneLaunch:
-    """One launch's engine view: pins the forest the staging was
-    computed against, so a concurrent capacity grow (which swaps the
-    carry's forest and re-ships everything dirty) can never retarget
-    an already-staged launch."""
-
-    __slots__ = ("carry", "forest")
-
-    def __init__(self, carry: "ResidentPaneCarry", forest):
-        self.carry = carry
-        self.forest = forest
-
-    def compute(self, cols, starts, ends, gwids) -> _ResidentPaneHandle:
-        with self.carry._lock:
-            dev, wraps, B = self.forest.update_runs_query_launch(
-                cols["run_rows"], cols["run_starts"], cols["run_lens"],
-                np.asarray(cols["value"], np.float32),
-                cols["q_rows"], starts, ends)
-        return _ResidentPaneHandle(dev, wraps, B, self.carry.np_comb)
-
-
-class ResidentPaneCarry:
-    """Device-resident pane-partial state for the WinSeqTPULogic
-    resident lane (docs/PLANNER.md "Resident state").
-
-    Where the rebuild lane re-ships the whole staged pane buffer
-    (window carry included) on every launch, this keeps one per-key
-    ring of pane partials resident in device memory as a
-    :class:`~windflow_tpu.ops.flatfat_jax.BatchedFlatFAT` forest
-    (donated, double-buffered jit carry) and ships only NEW/changed
-    partials per launch; windows are answered as pane-range queries in
-    the same fused scatter+query program.  Keyed by pane index: ring
-    position = absolute pane id mod capacity, alias-safe because the
-    engine's fired frontier proves panes below the oldest unfired
-    window dead before their slots are reused."""
-
-    KINDS = ("sum", "count", "max", "min")
-
-    def __init__(self, kind: str, panes_per_window: int,
-                 initial_keys: int = 16, headroom: int = 1024):
-        _, jnp = _jax()
-        if kind not in self.KINDS:
-            raise ValueError(f"resident pane carry needs a builtin "
-                             f"monoid kind, not {kind!r}")
-        self.kind = kind
-        comb = {"sum": jnp.add, "count": jnp.add,
-                "max": jnp.maximum, "min": jnp.minimum}[kind]
-        self.np_comb = {"sum": np.add, "count": np.add,
-                        "max": np.maximum, "min": np.minimum}[kind]
-        self.neutral = (0.0 if kind in ("sum", "count")
-                        else (-np.inf if kind == "max" else np.inf))
-        self.combine = comb
-        self.panes_per_window = panes_per_window
-        self.capacity = next_pow2(panes_per_window + headroom)
-        self._initial_keys = max(2, initial_keys)
-        from .flatfat_jax import BatchedFlatFAT
-        self.forest = BatchedFlatFAT(comb, self.neutral,
-                                     self._initial_keys, self.capacity)
-        self.rows: Dict[Any, int] = {}
-        # serializes forest launches against snapshot reads (the tree
-        # swap in update_query_launch is not atomic with the query)
-        self._lock = threading.Lock()
-
-    @property
-    def state_bytes(self) -> int:
-        return self.forest.state_bytes
-
-    def row_of(self, key) -> int:
-        """Assign/look up the key's forest row.  Returns the row; when
-        it does not fit the current forest the caller must call
-        :meth:`grow` (which swaps in a bigger EMPTY forest) and mark
-        every key dirty -- the forest is never migrated by copying,
-        because launches already queued on the dispatcher still
-        scatter into the OLD forest object and a snapshot copy would
-        silently lose them."""
-        row = self.rows.get(key)
-        if row is None:
-            row = self.rows[key] = len(self.rows)
-        return row
-
-    def needs_grow(self, span: int) -> bool:
-        return span > self.capacity or len(self.rows) > self.forest.n_keys
-
-    def grow(self, min_capacity: int) -> None:
-        """Key-count or pane-span overflow: swap in a bigger EMPTY
-        forest -- the caller must mark every key fully dirty so the
-        next launch re-ships live partials (they are recomputable
-        from the host retained series, which the engine's eviction
-        keeps exactly down to the oldest unfired window).  Launches
-        already in flight keep their pinned (old, complete) forest,
-        so their queries stay correct."""
-        from .flatfat_jax import BatchedFlatFAT
-        n = self.capacity
-        while n < min_capacity:
-            n <<= 1
-        k = self._initial_keys
-        while k < max(1, len(self.rows)):
-            k <<= 1
-        with self._lock:
-            self.capacity = n
-            self.forest = BatchedFlatFAT(self.combine, self.neutral,
-                                         k, n)
-
-    def reset(self) -> None:
-        """Drop all resident state (lane flip / state restore): the
-        next launch recomputes live partials from the host store."""
-        from .flatfat_jax import BatchedFlatFAT
-        with self._lock:
-            self.rows.clear()
-            self.forest = BatchedFlatFAT(self.combine, self.neutral,
-                                         self._initial_keys,
-                                         self.capacity)
-
-    def launch_engine(self) -> _ResidentPaneLaunch:
-        return _ResidentPaneLaunch(self, self.forest)
-
-
 class WindowComputeEngine:
     """Executes batches of window extents against a flat value buffer.
 

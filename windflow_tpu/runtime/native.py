@@ -585,9 +585,14 @@ class NativeWindowEngine:
     is for callers whose output ids count a key's windows (role PLQ) or
     whose host twin emits the empty ones (graph/native_lowering.py):
     every window from the key's anchor on is emitted and no key is
-    evicted."""
+    evicted.
 
-    __slots__ = ("lib", "ptr", "_stats", "_stats_p")
+    The window operator (operators/tpu/win_seq_tpu.py) stages from this
+    engine or from its Python twin (operators/tpu/window_store.py)
+    through the same calls."""
+
+    __slots__ = ("lib", "ptr", "_stats", "_stats_p", "engine_kind", "tb",
+                 "dense", "plq_counters", "key_intern", "key_extern")
 
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4}
     # what ``stats()`` returns, in order: nanoseconds creating key
@@ -614,6 +619,18 @@ class NativeWindowEngine:
         self._stats = (ctypes.c_longlong * len(self.STATS))()
         self._stats_p = ctypes.cast(self._stats,
                                     ctypes.POINTER(ctypes.c_longlong))
+        # the helper engine a flushed buffer needs: count windows sum
+        # their per-pane counts; mean windows divide pane-sum totals by
+        # pane-count totals (pair program); sum, max and min fold
+        # partials through the engine of their own kind
+        self.engine_kind = {"count": "sum", "mean": "mean_panes"}.get(kind)
+        self.tb, self.dense = is_tb, dense
+        self.plq_counters: dict = {}
+        # non-integral record keys (the reference's templated key types)
+        # are interned into a reserved negative int64 range and
+        # translated back on emission
+        self.key_intern: dict = {}
+        self.key_extern: dict = {}
 
     def stats(self):
         """The engine's churn clock and counters (:data:`STATS`) as a
@@ -653,6 +670,63 @@ class NativeWindowEngine:
             ts.ctypes.data_as(ctypes.POINTER(LL)),
             vals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
             len(keys))
+
+    # interned ids live below _INTERN_CEIL, far outside any plausible
+    # user key, so a result batch can be tested for them vectorized
+    _INTERN_BASE = -(1 << 62)
+    _INTERN_CEIL = -(1 << 61)
+
+    def intern_key(self, key) -> int:
+        iid = self.key_intern.get(key)
+        if iid is None:
+            iid = self._INTERN_BASE + len(self.key_intern)
+            self.key_intern[key] = iid
+            self.key_extern[iid] = key
+        return iid
+
+    def ingest_record(self, t, val) -> int:
+        """One record ``t`` of value ``val`` as a 1-row chunk, so mixed
+        record/batch streams share one state store.  ``val`` None is an
+        EOS marker, which is nothing to this engine (it fires on
+        ``eos()``): it answers 0, so the caller launches nothing."""
+        if val is None:
+            return 0
+        import numpy as np
+        key, tid, ts = t.get_control_fields()
+        if not isinstance(key, (int, np.integer)):
+            key = self.intern_key(key)
+        return self.ingest(np.array([key], np.int64),
+                           np.array([ts if self.tb else tid], np.int64),
+                           np.array([ts], np.int64),
+                           np.array([val], np.float64))
+
+    def output_ids(self, keys, gwids):
+        """The keys and output ids of one flushed batch's rows, asked
+        as the batch is emitted.  A ``dense`` engine numbers a key's
+        windows 0, 1, 2, ... in firing order (win_seq.hpp:484 with an
+        identity config: role PLQ); interned keys come back as they
+        were given, in a list (a key column cannot carry them)."""
+        import numpy as np
+        ids = gwids
+        if self.dense:
+            from ..core.tuples import key_groups
+            ids = np.empty(len(keys), np.int64)
+            order, keys_s, bounds = key_groups(keys)
+            for j in range(len(bounds) - 1):
+                lo, hi = int(bounds[j]), int(bounds[j + 1])
+                key = int(keys_s[lo])
+                start = self.plq_counters.get(key, 0)
+                run = np.arange(start, start + (hi - lo))
+                if order is None:
+                    ids[lo:hi] = run
+                else:
+                    ids[order[lo:hi]] = run
+                self.plq_counters[key] = start + (hi - lo)
+        if self.key_extern and len(keys) \
+                and bool((keys < self._INTERN_CEIL).any()):
+            ext = self.key_extern
+            keys = [ext.get(k, k) for k in keys.tolist()]
+        return keys, ids
 
     def synth_ingest(self, start: int, n: int, n_keys: int,
                      vmod: int = 97, vscale: float = 1.0,
@@ -696,10 +770,11 @@ class NativeWindowEngine:
         self.lib.wfn_engine_eos(self.ptr)
 
     def flush(self, max_windows: int):
-        """Returns (vals[f64], starts, ends, keys, gwids, rts[, cnts])
-        numpy copies, or None when nothing is ready.  ``cnts`` (per-pane
-        tuple counts, same layout as vals) is appended only for the
-        'mean' kind."""
+        """Returns (cols, starts, ends, keys, gwids, rts, engine_kind)
+        as numpy copies, or None when nothing is ready: ``cols`` holds
+        the pane partials under ``value`` (f64) and, for the 'mean'
+        kind only, the per-pane tuple counts in the same layout under
+        ``count``; ``engine_kind`` is the helper engine they need."""
         import numpy as np
         LL = ctypes.c_longlong
         PD = ctypes.POINTER(ctypes.c_double)
@@ -719,27 +794,38 @@ class NativeWindowEngine:
         def arr(p, n, dt):
             return np.ctypeslib.as_array(p, shape=(n,)).astype(dt, copy=True)
 
-        out = (arr(vals_p, nv, np.float64), arr(sp, b, np.int64),
-               arr(ep, b, np.int64), arr(kp, b, np.int64),
-               arr(gp, b, np.int64), arr(rp, b, np.int64))
+        cols = {"value": arr(vals_p, nv, np.float64)}
         if n_cnts.value:
-            out = out + (arr(cnts_p, n_cnts.value, np.float64),)
-        return out
+            cols["count"] = arr(cnts_p, n_cnts.value, np.float64)
+        return (cols, arr(sp, b, np.int64), arr(ep, b, np.int64),
+                arr(kp, b, np.int64), arr(gp, b, np.int64),
+                arr(rp, b, np.int64), self.engine_kind)
 
-    def serialize(self) -> bytes:
-        """Versioned binary snapshot of all mutable engine state."""
+    def serialize(self) -> dict:
+        """All mutable state, as the checkpoint envelope has always
+        named it (``WinSeqTPULogic.state_dict``): the C++ engine's
+        versioned binary snapshot under ``native``, the dense output
+        counters and the interned keys."""
         n = self.lib.wfn_engine_serialize(self.ptr, None, 0)
         buf = ctypes.create_string_buffer(n)
         got = self.lib.wfn_engine_serialize(self.ptr, buf, n)
         if got != n:
             raise RuntimeError("engine snapshot size changed mid-call")
-        return buf.raw[:n]
+        state = {"native": buf.raw[:n],
+                 "plq_counters": dict(self.plq_counters)}
+        if self.key_intern:
+            state["key_intern"] = dict(self.key_intern)
+        return state
 
-    def deserialize(self, blob: bytes) -> None:
+    def deserialize(self, state: dict) -> None:
         """Restore a snapshot into an identically-configured engine."""
+        blob = state["native"]
         ok = self.lib.wfn_engine_deserialize(self.ptr, blob, len(blob))
         if not ok:
             raise ValueError("malformed or mismatched engine snapshot")
+        self.plq_counters = dict(state.get("plq_counters", {}))
+        self.key_intern = dict(state.get("key_intern", {}))
+        self.key_extern = {v: k for k, v in self.key_intern.items()}
 
     def __del__(self):
         lib, ptr = getattr(self, "lib", None), getattr(self, "ptr", None)

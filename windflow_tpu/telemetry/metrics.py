@@ -111,10 +111,9 @@ def render_openmetrics(apps: dict) -> str:
         for _op, reps, lab in per_op():
             out.append(f"{metric}_total{_labels(**lab)} "
                        f"{sum(int(r.get(field, 0) or 0) for r in reps)}")
-    # device-lane derivations (docs/PLANNER.md "Resident state"): NEW
-    # bytes shipped per launch (state never re-ships on the resident
-    # lane, so the >=10x claim is measurable here) + the resident
-    # state footprint gauge
+    # device-lane derivations: bytes shipped per launch (the resident
+    # FFAT forest never re-ships, operators/tpu/ffat_resident.py) + the
+    # resident state footprint gauge
     family("windflow_device_bytes_per_launch", "gauge",
            "bytes shipped per device launch (events in + results out)")
     for _op, reps, lab in per_op():
