@@ -222,4 +222,22 @@ void wfn_partition_mod(const long long* keys, long long n, long long ndest,
     }
 }
 
+// Mask to rows: out[0..k) = the indices i with mask[i] != 0, ascending,
+// and k returned (TupleBatch.take of a filter's mask, core/tuples.py:
+// what np.nonzero answers, bit for bit).  Branch-free: every index is
+// stored and the cursor moves by whether the byte was set, so a mask a
+// third full costs what an empty one does (np.nonzero takes a
+// mispredicted branch a row).  `out` holds n entries.  (Eight bytes at a
+// time through a table of places read no faster on the chip's host, which
+// has AVX2 and no AVX-512: 32.9 us against 30.5 for 65,536 rows, PR 31.)
+long long wfn_mask_to_rows(const unsigned char* mask, long long n,
+                           long long* out) {
+    long long k = 0;
+    for (long long i = 0; i < n; ++i) {
+        out[k] = i;
+        k += mask[i] != 0;
+    }
+    return k;
+}
+
 }  // extern "C"

@@ -525,27 +525,45 @@ struct Engine {
         }
     }
 
+    // A call's selection (a filtered batch that carries its rows instead
+    // of copies of its columns, core/tuples.py): row j of the call is
+    // row rows[j] of each column whose bit is set in `through` (1 keys,
+    // 2 ids, 4 stamps, 8 values); a column whose bit is clear is compact
+    // already (a map laid a new one over the selection).  The walks take
+    // SEL as a template parameter: without a selection they compile to
+    // the code they were before there was one.
+    struct Sel {
+        const i64* rows;
+        int through;
+    };
+    enum : int { SEL_KEYS = 1, SEL_IDS = 2, SEL_TSS = 4, SEL_VALS = 8 };
+
     // The one walk over a call's tuples: a probe each, and the key's
     // partial brought up to date.  IDS: the ids are read (renumbered
     // ids are implicit); EXT: 1 keeps the largest value, 2 the
     // smallest, 0 reads no value.
-    template <bool IDS, int EXT, typename TV>
-    void gather(const i64* bkeys, const i64* ids, const TV* vals, i64 n) {
+    template <bool IDS, int EXT, bool SEL, typename TV>
+    void gather(const i64* bkeys, const i64* ids, const TV* vals, i64 n,
+                Sel s) {
+        const bool ck = SEL && !(s.through & SEL_KEYS);   // compact columns
+        const bool ci = SEL && !(s.through & SEL_IDS);
+        const bool cv = SEL && !(s.through & SEL_VALS);
         for (i64 j = 0; j < n; ++j) {
-            const int32_t d = dense_of(bkeys[j]);
+            const i64 r = SEL ? s.rows[j] : j;
+            const int32_t d = dense_of(bkeys[ck ? j : r]);
             slot_of[j] = d;
             Part& pt = parts[d];
             ++pt.count;
             if (IDS) {
-                const i64 id = ids[j];
+                const i64 id = ids[ci ? j : r];
                 if (id < pt.lo) pt.lo = id;
                 if (id > pt.hi) pt.hi = id;
             }
             if (EXT == 1) {
-                const double v = (double)vals[j];
+                const double v = (double)vals[cv ? j : r];
                 if (v > pt.ext) pt.ext = v;
             } else if (EXT == 2) {
-                const double v = (double)vals[j];
+                const double v = (double)vals[cv ? j : r];
                 if (v < pt.ext) pt.ext = v;
             }
         }
@@ -553,9 +571,9 @@ struct Engine {
 
     // TV = double or float: f32 sources fold without a host-side
     // widening copy (values widen at the accumulate)
-    template <typename TV>
+    template <bool SEL, typename TV>
     void ingest_batch(const i64* bkeys, const i64* ids, const i64* tss,
-                      const TV* vals, i64 n) {
+                      const TV* vals, i64 n, Sel s = Sel{nullptr, 0}) {
         ++call_id;
         parts.clear();
         d_slot.clear();
@@ -563,13 +581,13 @@ struct Engine {
         opened_now.clear();
         if ((i64)slot_of.size() < n) slot_of.resize(n);
         if (renumber)
-            gather<false, 0>(bkeys, ids, vals, n);
+            gather<false, 0, SEL>(bkeys, ids, vals, n, s);
         else if (by_key_lane && kind == Kind::MAX)
-            gather<true, 1>(bkeys, ids, vals, n);
+            gather<true, 1, SEL>(bkeys, ids, vals, n, s);
         else if (by_key_lane && kind == Kind::MIN)
-            gather<true, 2>(bkeys, ids, vals, n);
+            gather<true, 2, SEL>(bkeys, ids, vals, n, s);
         else
-            gather<true, 0>(bkeys, ids, vals, n);
+            gather<true, 0, SEL>(bkeys, ids, vals, n, s);
         const std::size_t nd = parts.size();
         d_accept.resize(nd);
         d_single.resize(nd);
@@ -590,7 +608,7 @@ struct Engine {
                     prepare(d);
             }
         }
-        if (n_single) fold_singly(ids, tss, vals, n);
+        if (n_single) fold_singly<SEL>(ids, tss, vals, n, s);
         for (std::size_t d = 0; d < nd; ++d)
             settle(*d_state[d], d_slot[d], parts[d].hi);
         if (stream_rule) trigger();
@@ -598,20 +616,25 @@ struct Engine {
 
     // The second walk, for the keys fold_key() left: each of their
     // tuples against the acceptance boundary and into its own pane.
-    template <typename TV>
-    void fold_singly(const i64* ids, const i64* tss, const TV* vals, i64 n) {
+    template <bool SEL, typename TV>
+    void fold_singly(const i64* ids, const i64* tss, const TV* vals, i64 n,
+                     Sel s) {
         // hopping windows (win < slide): whether an id opens a window
         // depends on its position inside the slide period, so the
         // opened-window frontier must be tracked per accepted tuple --
         // the batch's final max_id alone misses windows opened by
         // mid-batch ids when the batch ends in a gap
         const bool hopping = win < slide;
+        const bool ci = SEL && !(s.through & SEL_IDS);
+        const bool ct = SEL && !(s.through & SEL_TSS);
+        const bool cv = SEL && !(s.through & SEL_VALS);
         i64 folded = 0;
         for (i64 j = 0; j < n; ++j) {
             const int32_t d = slot_of[j];
             if (!d_single[d]) continue;
+            const i64 r = SEL ? s.rows[j] : j;
             KeyState& st = *d_state[d];
-            const i64 id = renumber ? st.arrivals++ : ids[j];
+            const i64 id = renumber ? st.arrivals++ : ids[ci ? j : r];
             if (!renumber && id < d_accept[d]) {
                 ++ignored;
                 continue;
@@ -623,11 +646,11 @@ struct Engine {
                 if (id >= nn * slide + win) continue;  // gap tuple
                 if (nn > st.opened_max) st.opened_max = nn;
             }
-            fold(st, p, (double)vals[j]);
+            fold(st, p, (double)vals[cv ? j : r]);
             ++folded;
             if (!is_tb && id >= st.plid[p]) {
                 st.plid[p] = id;
-                st.plts[p] = tss[j];
+                st.plts[p] = tss[ct ? j : r];
             }
         }
         folded_singly += folded;
@@ -1074,7 +1097,7 @@ void wfn_engine_free(void* e) { delete static_cast<Engine*>(e); }
 i64 wfn_engine_ingest(void* ep, const i64* keys, const i64* ids,
                       const i64* tss, const double* vals, i64 n) {
     Engine& e = *static_cast<Engine*>(ep);
-    e.ingest_batch(keys, ids, tss, vals, n);
+    e.ingest_batch<false>(keys, ids, tss, vals, n);
     return e.n_ready();
 }
 
@@ -1082,7 +1105,38 @@ i64 wfn_engine_ingest(void* ep, const i64* keys, const i64* ids,
 i64 wfn_engine_ingest_f32(void* ep, const i64* keys, const i64* ids,
                           const i64* tss, const float* vals, i64 n) {
     Engine& e = *static_cast<Engine*>(ep);
-    e.ingest_batch(keys, ids, tss, vals, n);
+    e.ingest_batch<false>(keys, ids, tss, vals, n);
+    return e.n_ready();
+}
+
+// The same through a selection (Engine::Sel): the call's n rows are
+// rows sel[0..n) of the columns named in `through`, each n_base long,
+// and rows 0..n of the others.  The rows are checked before a tuple is
+// folded (one pass, no branch a row): -1 and nothing ingested where one
+// lies outside [0, n_base).
+static bool rows_in_range(const i64* sel, i64 n, i64 n_base) {
+    unsigned bad = 0;
+    for (i64 j = 0; j < n; ++j)
+        bad |= (std::uint64_t)sel[j] >= (std::uint64_t)n_base;
+    return !bad;
+}
+
+i64 wfn_engine_ingest_sel(void* ep, const i64* keys, const i64* ids,
+                          const i64* tss, const double* vals,
+                          const i64* sel, i64 n, int through, i64 n_base) {
+    if (!rows_in_range(sel, n, n_base)) return -1;
+    Engine& e = *static_cast<Engine*>(ep);
+    e.ingest_batch<true>(keys, ids, tss, vals, n, Engine::Sel{sel, through});
+    return e.n_ready();
+}
+
+i64 wfn_engine_ingest_sel_f32(void* ep, const i64* keys, const i64* ids,
+                              const i64* tss, const float* vals,
+                              const i64* sel, i64 n, int through,
+                              i64 n_base) {
+    if (!rows_in_range(sel, n, n_base)) return -1;
+    Engine& e = *static_cast<Engine*>(ep);
+    e.ingest_batch<true>(keys, ids, tss, vals, n, Engine::Sel{sel, through});
     return e.n_ready();
 }
 

@@ -79,11 +79,32 @@ def cuts_of(n, chunking):
 
 # -- driving an engine by hand -----------------------------------------------
 
-def drive(lane, kind, keys, ts, vals, chunking, look_at=None):
+def scattered(rng, cols, compact=()):
+    """A chunk's columns as a selected batch hands them over (PR 31):
+    each column's rows scattered, in order, over a base column some
+    three times as long whose other rows hold what no stream does, and
+    the rows' places.  Columns named in ``compact`` stay as they are: a
+    map laid them over the selection."""
+    n = len(cols["keys"])
+    sel = np.sort(rng.choice(3 * n + 2, n, replace=False)).astype(np.int64)
+    out = {}
+    for name, col in cols.items():
+        if name in compact:
+            out[name] = col
+            continue
+        base = np.full(3 * n + 2, -(1 << 40), col.dtype)
+        base[sel] = col
+        out[name] = base
+    return out, sel
+
+
+def drive(lane, kind, keys, ts, vals, chunking, look_at=None, through=None):
     """Feed the stream (to a lane of ``LANES``, or one given whole) in
     chunks, staging whatever is ready after each and at EOS.  Returns the engine, every staged window in firing order
     as (key, window, value, result ts), a digest of every byte staged,
-    and ``keys_live`` after the chunk that ends at ``look_at``."""
+    and ``keys_live`` after the chunk that ends at ``look_at``.
+    ``through`` feeds every chunk through a selection
+    (:func:`scattered`): the names of the columns that stay compact."""
     win, slide, is_tb, delay, renumber, dense = LANES.get(lane, lane)
     eng = NativeWindowEngine(win, slide, is_tb, delay, renumber=renumber,
                              kind=kind, dense=dense)
@@ -111,8 +132,17 @@ def drive(lane, kind, keys, ts, vals, chunking, look_at=None):
                 rows.append((int(d_keys[i]), int(gwids[i]), float(v),
                              int(rts[i])))
     lo = 0
+    rng = np.random.RandomState(len(keys))
     for hi in cuts_of(len(keys), chunking):
-        if eng.ingest(keys[lo:hi], ts[lo:hi], ts[lo:hi], vals[lo:hi]):
+        if through is None:
+            ready = eng.ingest(keys[lo:hi], ts[lo:hi], ts[lo:hi],
+                               vals[lo:hi])
+        else:
+            c, sel = scattered(rng, {"keys": keys[lo:hi], "ids": ts[lo:hi],
+                                     "ts": ts[lo:hi], "vals": vals[lo:hi]},
+                               through)
+            ready = eng.ingest(c["keys"], c["ids"], c["ts"], c["vals"], sel)
+        if ready:
             take()
         if hi == look_at:
             take()
@@ -495,6 +525,99 @@ def test_the_counters_reach_the_stats_json_and_the_metrics_page():
     text = render_openmetrics({"a": {"report": report}})
     for name in ("folded_by_key_total", "folded_singly_total"):
         assert f"windflow_engine_{name}{{" in text, name
+
+
+# -- the engine through a selection (PR 31) -----------------------------------
+
+# what a selected batch leaves compact: nothing (a filter alone), the
+# keys (a join laid them over it), the values, all but the ids
+COMPACT = {"none": (), "keys": ("keys",), "vals": ("vals",),
+           "all_but_ids": ("keys", "ts", "vals")}
+
+
+def selection_cases():
+    for lane in LANES:
+        for kind in KINDS:
+            yield lane, kind, "late"     # late rows are counted as ignored
+    for kind in KINDS:
+        yield "tb", kind, "disordered"
+        yield "cb", kind, "inorder"
+
+
+@pytest.mark.parametrize("lane,kind,shape", list(selection_cases()),
+                         ids=lambda v: str(v))
+def test_through_a_selection_the_engine_stages_the_same_bytes(lane, kind,
+                                                               shape):
+    """``ingest(..., sel)`` reads rows ``sel[j]`` of the base columns in
+    its two walks: what it stages, counts as ignored, opens and fires is
+    what ``ingest`` of the gathered columns does, byte for byte, under
+    every chunking (one event a call, chunks that straddle a pane edge,
+    the whole stream) on every lane: the digest is ``GOLDEN``'s."""
+    keys, ts, vals = stream(shape, GOLDEN_N)
+    which = list(COMPACT)[(len(lane) + len(kind)) % len(COMPACT)]
+    for compact in {"none", which}:
+        for f32 in (False, True):
+            if f32 and compact != "none":
+                continue
+            h = hashlib.sha256()
+            for name, chunking in GOLDEN_CHUNKINGS.items():
+                eng, rows, digest, _live = drive(
+                    lane, kind, keys, ts,
+                    vals.astype(np.float32) if f32 else vals,
+                    min(chunking, GOLDEN_N), through=COMPACT[compact])
+                s = eng.snapshot()
+                h.update(f"{name}:{digest}:{eng.ignored()}:"
+                         f"{s['keys_opened']}:{s['windows_fired']}:"
+                         f"{len(rows)};".encode())
+            # values are whole numbers under 1000: exact in f32 too
+            assert h.hexdigest()[:16] == GOLDEN[f"{lane}/{kind}/{shape}"], \
+                (compact, f32)
+
+
+def test_a_selection_folds_by_key_where_the_gathered_chunk_would():
+    """The by-key lane and the one-by-one walk both read through the
+    selection: the two counts and the late rows are the gathered
+    chunk's."""
+    keys, ts, vals = stream("late", N_SMALL)
+    for lane, kind in (("tb", "count"), ("tb", "max"), ("tb", "sum"),
+                       ("cb", "count"), ("hopping", "min")):
+        for chunking in (128, 100):
+            plain, rows, digest, _l = drive(lane, kind, keys, ts, vals,
+                                            chunking)
+            through, rows_t, digest_t, _l = drive(lane, kind, keys, ts, vals,
+                                                  chunking, through=())
+            assert rows_t == rows and digest_t == digest
+            assert through.ignored() == plain.ignored() > 0
+            counts = [{k: v for k, v in e.snapshot().items()
+                       if not k.endswith("_ns")} for e in (through, plain)]
+            assert counts[0] == counts[1], (lane, kind)
+            assert counts[0]["folded_by_key"] + counts[0]["folded_singly"] > 0
+
+
+def test_a_selection_of_a_column_that_needs_converting():
+    """A base value column that is not a float in a row (whole numbers, a
+    strided view) is gathered first and converted after: its rows alone."""
+    keys, ts, _vals = stream("inorder", 2000)
+    vals = (ts % 13 + 1)
+    want = drive("tb", "sum", keys, ts, vals.astype(np.float64), 500)[1]
+    eng = NativeWindowEngine(*LANES["tb"][:4], kind="sum")
+    rng = np.random.RandomState(1)
+    got = []
+    for lo in range(0, 2000, 500):
+        c, sel = scattered(rng, {"keys": keys[lo:lo + 500],
+                                 "ids": ts[lo:lo + 500],
+                                 "ts": ts[lo:lo + 500],
+                                 "vals": vals[lo:lo + 500]})
+        wide = np.zeros(2 * len(c["ids"]), np.int64)
+        wide[::2] = c["ids"]
+        eng.ingest(c["keys"].astype(np.int32), wide[::2], c["ts"],
+                   c["vals"], sel)
+    eng.eos()
+    out = eng.flush(1 << 30)
+    cols, starts, ends, d_keys, gwids, rts, _ = out
+    got = [(int(k), int(w), float(cols["value"][a:b].sum()), int(r))
+           for k, w, a, b, r in zip(d_keys, gwids, starts, ends, rts)]
+    assert sorted(got) == sorted(want)
 
 
 if __name__ == "__main__":

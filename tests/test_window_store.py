@@ -71,12 +71,35 @@ def take(store, kind, rows):
                          int(rts[j]), v if np.isfinite(v) else None))
 
 
-def drive(store, kind, win_type, lo=0, hi=N, rows=None):
+def drive(store, kind, win_type, lo=0, hi=N, rows=None, through=None,
+          dtype=np.float64, late=False):
+    """``through``: every chunk is handed over as a selected batch holds
+    it (PR 31): base columns three times as long with the chunk's rows
+    scattered over them in order, the rows' places as ``sel``, and the
+    columns named in ``through`` compact.  ``late``: one row in 40 is
+    stamped 200 back, behind its key's fired windows."""
     keys, ids, ts, vals = stream(win_type)
+    vals = vals.astype(dtype)
+    if late:
+        back = np.arange(N) % 40 == 39
+        ids = np.where(back, np.maximum(ids - 200, 0), ids)
     rows = [] if rows is None else rows
+    rng = np.random.RandomState(lo + 1)
     for a in range(lo, hi, CHUNK):
         b = min(a + CHUNK, hi)
-        ready = store.ingest(keys[a:b], ids[a:b], ts[a:b], vals[a:b])
+        cols = {"keys": keys[a:b], "ids": ids[a:b], "ts": ts[a:b],
+                "vals": vals[a:b]}
+        if through is None:
+            ready = store.ingest(*cols.values())
+        else:
+            n = b - a
+            sel = np.sort(rng.choice(3 * n, n, replace=False))
+            for name, col in cols.items():
+                if name not in through:
+                    base = np.full(3 * n, -9, col.dtype)
+                    base[sel] = col
+                    cols[name] = base
+            ready = store.ingest(*cols.values(), sel)
         assert ready == store.ready()
         take(store, kind, rows)
     return rows
@@ -122,6 +145,60 @@ def test_both_stores_stage_the_same_windows_after_the_same_chunk(
             assert ids == list(range(len(ids)))
     else:
         assert all(r[1] == r[2] for r in rows)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32],
+                         ids=["f64", "f32"])
+@pytest.mark.parametrize("through", [(), ("keys",), ("vals", "ts")],
+                         ids=["bare", "joined", "mapped"])
+@pytest.mark.parametrize("win_type", [WinType.CB, WinType.TB],
+                         ids=["cb", "tb"])
+@pytest.mark.parametrize("kind", ["sum", "count", "max"])
+@pytest.mark.parametrize("which", [
+    pytest.param("native", marks=needs_native), "python"])
+def test_through_a_selection_a_store_stages_what_the_gathered_columns_do(
+        which, kind, win_type, through, dtype):
+    """``ingest(..., sel)`` on either store: the same windows after the
+    same chunk (a chunk of 50 straddles the pane edge at 32: SUM and CB
+    fold one by one, TB COUNT and MAX by key where a chunk lies in one
+    pane), the same late rows counted as ignored, the same keys live."""
+    got = []
+    for thr in (None, through):
+        store = make_store(which, kind, win_type, Role.SEQ)
+        rows, marks = [], []
+        for a in range(0, N, CHUNK):
+            drive(store, kind, win_type, a, a + CHUNK, rows, through=thr,
+                  dtype=dtype, late=True)
+            marks.append(len(rows))
+        store.eos()
+        take(store, kind, rows)
+        got.append((rows, marks, store.ignored(),
+                    store.snapshot()["keys_live"]))
+    assert got[0] == got[1]
+    assert got[0][2] > 0 and len(got[0][0]) > 40      # late rows; windows
+
+
+@pytest.mark.parametrize("which", [
+    pytest.param("native", marks=needs_native), "python"])
+def test_a_selection_that_leaves_its_base_is_refused_before_anything_folds(
+        which):
+    """The rows come from a batch that checked them; handed in from
+    elsewhere they are outside input to native code."""
+    keys, ids, ts, vals = stream(WinType.TB)
+    store = make_store(which, "count", WinType.TB, Role.SEQ)
+    for bad in ([0, 5, 100], [-1, 3, 4], [2, 1 << 40, 3]):
+        with pytest.raises(IndexError):
+            store.ingest(keys[:100], ids[:100], ts[:100], vals[:100],
+                         np.array(bad, np.int64))
+    assert store.snapshot()["keys_live"] == 0 and store.ready() == 0
+    # a base column shorter than the others bounds the rows
+    with pytest.raises(IndexError):
+        store.ingest(keys[:100], ids[:50], ts[:100], vals[:100],
+                     np.array([1, 60], np.int64))
+    # every column compact: the rows are not followed at all
+    assert store.ingest(keys[:3], ids[:3], ts[:3], vals[:3],
+                        np.array([7, 8, 900], np.int64)) == 0
+    assert store.snapshot()["keys_live"] == 3
 
 
 @pytest.mark.parametrize("win,slide", [(64, 32), (12, 4)],

@@ -193,6 +193,71 @@ class BasicRecord:
         return f"BasicRecord(key={self.key}, id={self.id}, ts={self.ts}, value={self.value})"
 
 
+class _Selection:
+    """The rows a selected :class:`TupleBatch` stands for: ``rows[j]`` of
+    every column of ``base``.  Shared by a selected batch and what
+    ``with_cols`` derives from it, so a column gathered for one is there
+    for the other.  ``rows`` is shorter than the base columns (a take
+    that would not shorten gathers at once), which is how a reader that
+    is handed columns of both kinds tells them apart."""
+
+    __slots__ = ("base", "rows", "read")
+
+    def __init__(self, base: Dict[str, np.ndarray], rows: np.ndarray):
+        self.base = base
+        self.rows = rows
+        self.read: Dict[str, np.ndarray] = {}  # base columns gathered so far
+
+    def column(self, name: str, pool: Optional["ColumnPool"] = None):
+        col = self.read.get(name)
+        if col is None:
+            col = self.read[name] = _gather(self.base[name], self.rows,
+                                            pool)
+        return col
+
+
+# the index buffers of selections: a mask's rows are compacted into a
+# buffer as long as the mask, and a buffer is lent again once the
+# selection that reads it is gone (ColumnPool's refcount rule, under its
+# lock).  One for the process: ``take`` is called by user code and by
+# logics that hold no graph's pool, and a fresh 512 KB a chunk would be
+# mapped and faulted in anew each time
+_ROWS_POOL = ColumnPool(max_per_bucket=4)
+
+
+def _take_rows(n: int) -> np.ndarray:
+    return _ROWS_POOL.take(n, np.int64)
+
+
+def _gather(col: np.ndarray, rows: np.ndarray,
+            pool: Optional[ColumnPool]) -> np.ndarray:
+    if pool is None or col.ndim != 1 or (
+            col.base is not None and not col.flags.owndata
+            and not col.flags.c_contiguous):
+        return np.take(col, rows, axis=0)   # odd layout: let numpy
+    return np.take(col, rows, axis=0, out=pool.take(len(rows), col.dtype))
+
+
+def _same_length(cols: Dict[str, np.ndarray], n: int) -> None:
+    for name, col in cols.items():
+        if len(col) != n:
+            raise ValueError(f"column '{name}' length {len(col)} != {n}")
+
+
+_native = None      # runtime/native: that package imports this module
+
+
+def _rows_of_mask(mask: np.ndarray) -> np.ndarray:
+    """``np.nonzero(mask)[0]``: from the native library's branch-free
+    pass into a pooled buffer where this process has the library loaded
+    (runtime/native.mask_to_rows), else from numpy."""
+    global _native
+    if _native is None:
+        from ..runtime import native as _native
+    rows = _native.mask_to_rows(mask, _take_rows)
+    return np.nonzero(mask)[0] if rows is None else rows
+
+
 class TupleBatch:
     """Columnar micro-batch of tuples: dict of equal-length numpy columns.
 
@@ -201,12 +266,29 @@ class TupleBatch:
     flows over host queues on the batch plane and the host-side staging
     format for device transfers (the TPU analogue of the reference's
     pinned-buffer batch assembly, win_seq_gpu.hpp:552-596).
+
+    **A row subset carries its selection.**  ``take`` of a mask or of
+    scattered indices copies no column: it answers a batch that holds
+    the base columns and the rows, whose ``len`` is the rows'.  ``key``,
+    ``id``, ``ts`` and ``batch[name]`` gather their column on first read
+    and keep it; ``with_cols`` lays compact columns over the selection
+    and carries it on; a second ``take`` composes the two.  ``cols``
+    gathers whatever is left and answers the dict it always did, after
+    which the batch is an ordinary one.  A reader that can follow rows
+    itself (the window engine) asks for :attr:`selection` and
+    :meth:`held` and gathers nothing.  Like a :class:`SynthChunk`, a
+    selected batch is materialized (:meth:`compact`) at every plane
+    boundary -- ``Outlet`` does before a put -- so no queue pins a whole
+    base chunk for the third of its rows that survived a filter.
     """
 
-    # ``trace`` carries a sampled telemetry TraceContext end to end
-    # (telemetry/trace.py); it stays unset on untraced batches (getattr
-    # default read) so batch construction pays nothing for it
-    __slots__ = ("cols", "trace")
+    # ``_cols``: the columns that are ``len(self)`` long (all of them,
+    # or what was laid over a selection); ``_sel``: None, or the
+    # selection the other columns are read through.  ``trace`` carries a
+    # sampled telemetry TraceContext end to end (telemetry/trace.py); it
+    # stays unset on untraced batches (getattr default read) so batch
+    # construction pays nothing for it
+    __slots__ = ("_cols", "_sel", "trace")
 
     CONTROL = ("key", "id", "ts")
 
@@ -214,11 +296,17 @@ class TupleBatch:
         for c in self.CONTROL:
             if c not in cols:
                 raise ValueError(f"TupleBatch missing control column '{c}'")
-        n = len(cols["key"])
-        for name, col in cols.items():
-            if len(col) != n:
-                raise ValueError(f"column '{name}' length {len(col)} != {n}")
-        self.cols = cols
+        _same_length(cols, len(cols["key"]))
+        self._cols = cols
+        self._sel = None
+
+    @classmethod
+    def _selected(cls, laid: Dict[str, np.ndarray],
+                  sel: _Selection) -> "TupleBatch":
+        out = cls.__new__(cls)
+        out._cols = laid
+        out._sel = sel
+        return out
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -247,67 +335,165 @@ class TupleBatch:
 
     # -- accessors ---------------------------------------------------------
     def __len__(self) -> int:
-        return len(self.cols["key"])
+        sel = self._sel
+        return len(self._cols["key"]) if sel is None else len(sel.rows)
+
+    def _read(self, name: str) -> np.ndarray:
+        """A column that is not among the compact ones: gathered through
+        the selection (once), or no column of this batch."""
+        if self._sel is None:
+            raise KeyError(name)
+        return self._sel.column(name)
 
     @property
     def key(self) -> np.ndarray:
-        return self.cols["key"]
+        col = self._cols.get("key")
+        return col if col is not None else self._read("key")
 
     @property
     def id(self) -> np.ndarray:
-        return self.cols["id"]
+        col = self._cols.get("id")
+        return col if col is not None else self._read("id")
 
     @property
     def ts(self) -> np.ndarray:
-        return self.cols["ts"]
+        col = self._cols.get("ts")
+        return col if col is not None else self._read("ts")
 
     def __getitem__(self, name: str) -> np.ndarray:
-        return self.cols[name]
+        col = self._cols.get(name)
+        return col if col is not None else self._read(name)
+
+    @property
+    def cols(self) -> Dict[str, np.ndarray]:
+        """Every column, ``len(self)`` long, by name.  On a selected
+        batch this is where the columns nobody read are gathered."""
+        if self._sel is not None:
+            self.compact()
+        return self._cols
+
+    def names(self) -> list:
+        """The column names in ``cols``' order, gathering nothing."""
+        if self._sel is None:
+            return list(self._cols)
+        base = self._sel.base
+        return list(base) + [k for k in self._cols if k not in base]
 
     def payload_names(self):
-        return [c for c in self.cols if c not in self.CONTROL]
+        return [c for c in self.names() if c not in self.CONTROL]
+
+    # -- the selection, for a reader that follows rows itself --------------
+    @property
+    def selection(self) -> Optional[np.ndarray]:
+        """The rows (int64 indices into the base columns) of a selected
+        batch, None on an ordinary one."""
+        sel = self._sel
+        return None if sel is None else sel.rows
+
+    def held(self, name: str) -> np.ndarray:
+        """The column as this batch holds it, gathering nothing: compact
+        (``len(self)`` long) where it was laid over the selection or has
+        been read, else the base column, to be read at ``selection``'s
+        rows.  The two are told apart by their length: a selection is
+        shorter than its base."""
+        col = self._cols.get(name)
+        if col is None:
+            sel = self._sel
+            if sel is None:
+                raise KeyError(name)
+            col = sel.read.get(name)
+            if col is None:
+                col = sel.base[name]
+        return col
+
+    def selection_counts(self) -> Tuple[int, int]:
+        """(columns the selection carries, of those gathered so far);
+        (0, 0) on an ordinary batch."""
+        sel = self._sel
+        return (0, 0) if sel is None else (len(sel.base), len(sel.read))
+
+    def compact(self, pool: Optional[ColumnPool] = None) -> "TupleBatch":
+        """Make a selected batch an ordinary one, in place: gather every
+        base column that no compact one replaced and that was not read
+        yet, and let the base go.  The columns come out in the order an
+        eager gather followed by the same ``with_cols`` gave."""
+        sel = self._sel
+        if sel is not None:
+            laid = self._cols
+            cols = {k: laid[k] if k in laid else sel.column(k, pool)
+                    for k in sel.base}
+            for k, v in laid.items():
+                cols.setdefault(k, v)
+            self._cols, self._sel = cols, None
+        return self
 
     # -- transforms --------------------------------------------------------
     def take(self, idx, pool: Optional[ColumnPool] = None) -> "TupleBatch":
-        """Row subset.  Slices stay zero-copy views; boolean masks are
-        converted to indices once and gathered with np.take, which is
-        4-5x faster than boolean fancy indexing repeated per column
-        (the filter stages live on this path).  A contiguous index run
-        ships as a slice view (zero copies); with ``pool`` the gathered
-        columns reuse arena buffers instead of allocating.  A riding
+        """Row subset.  Slices and contiguous index runs stay zero-copy
+        views.  A boolean mask becomes its rows in one branch-free
+        native pass (``np.nonzero`` where the library is not loaded: a
+        mispredicted branch a row); the rows, or an index array, then
+        ride with the base columns as a selection (see the class) and
+        no column is copied until it is read.  With ``pool`` the caller
+        is a partitioner whose sub-batches cross a queue next: the
+        columns are gathered at once, into arena buffers.  A riding
         trace context propagates to every sub-batch (KEYBY partitions
         keep their sampled path traced)."""
+        sel = self._sel
         if isinstance(idx, slice):
-            return self._carry(
-                TupleBatch({k: v[idx] for k, v in self.cols.items()}))
+            laid = {k: v[idx] for k, v in self._cols.items()}
+            if sel is None:
+                return self._carry(TupleBatch(laid))
+            return self._carry(TupleBatch._selected(
+                laid, _Selection(sel.base, sel.rows[idx])))
         idx = np.asarray(idx)
+        n_self = len(self)
         if idx.dtype == np.bool_:
-            if len(idx) != len(self):
+            if len(idx) != n_self:
                 raise IndexError(
                     f"boolean mask length {len(idx)} != batch "
-                    f"length {len(self)}")
-            idx = np.nonzero(idx)[0]
-        elif idx.size == 0:
-            idx = idx.astype(np.intp)   # e.g. a bare [] (float64)
-        n = len(idx)
-        if n > 1 and int(idx[-1]) - int(idx[0]) == n - 1 \
-                and bool((np.diff(idx) == 1).all()):
-            # contiguous ascending run: zero-copy view instead of a
-            # gather (the cheap first/last guard gates the O(n) check)
+                    f"length {n_self}")
+            idx = _rows_of_mask(idx)
+            n = len(idx)
+            # a mask's rows ascend: one run iff first and last say so
+            run = n > 1 and int(idx[-1]) - int(idx[0]) == n - 1
+        else:
+            if idx.size == 0:
+                idx = idx.astype(np.intp)   # e.g. a bare [] (float64)
+            elif idx.dtype.kind not in "iu":
+                raise TypeError(f"rows cannot be taken by an index of "
+                                f"dtype {idx.dtype}")
+            n = len(idx)
+            if n:
+                # a selection's rows are read by native code: in range
+                # and counted from the front, as np.take would have it
+                lo, hi = int(idx.min()), int(idx.max())
+                if lo < -n_self or hi >= n_self:
+                    raise IndexError(
+                        f"index {lo if lo < -n_self else hi} is out of "
+                        f"bounds for a batch of {n_self} rows")
+                if lo < 0:
+                    idx = np.where(idx < 0, idx + n_self, idx)
+            # the cheap first/last guard gates the O(n) check
+            run = n > 1 and int(idx[-1]) - int(idx[0]) == n - 1 \
+                and bool((np.diff(idx) == 1).all())
+        if run:
+            # contiguous ascending run: zero-copy view, not a gather
             lo = int(idx[0])
-            return self._carry(TupleBatch({k: v[lo:lo + n]
-                                           for k, v in self.cols.items()}))
-        if pool is None:
-            return self._carry(TupleBatch({k: np.take(v, idx, axis=0)
-                                           for k, v in self.cols.items()}))
-        out = {}
-        for k, v in self.cols.items():
-            if v.base is not None and not v.flags.owndata \
-                    and not v.flags.c_contiguous:
-                out[k] = np.take(v, idx, axis=0)  # odd layout: let numpy
-                continue
-            out[k] = np.take(v, idx, axis=0, out=pool.take(n, v.dtype))
-        return self._carry(TupleBatch(out))
+            return self.take(slice(lo, lo + n))
+        if sel is None:
+            out = TupleBatch._selected({}, _Selection(self._cols, idx))
+            n_base = n_self
+        else:
+            # a take of a take: the two selections composed; what was
+            # laid over the first is compact and is subset now
+            out = TupleBatch._selected(
+                {k: np.take(v, idx, axis=0) for k, v in self._cols.items()},
+                _Selection(sel.base, np.take(sel.rows, idx)))
+            n_base = len(next(iter(sel.base.values())))
+        if pool is not None or n >= n_base:
+            out.compact(pool)   # a plane boundary next, or no subset
+        return self._carry(out)
 
     def _carry(self, out: "TupleBatch") -> "TupleBatch":
         """Propagate a riding trace context onto a derived batch."""
@@ -317,8 +503,9 @@ class TupleBatch:
         return out
 
     def concat(self, other: "TupleBatch") -> "TupleBatch":
+        theirs = other.cols
         out = TupleBatch(
-            {k: np.concatenate([v, other.cols[k]]) for k, v in self.cols.items()}
+            {k: np.concatenate([v, theirs[k]]) for k, v in self.cols.items()}
         )
         # either side's context rides on (self's stamp wins: it entered
         # the stream earlier, so the merged batch's latency is honest)
@@ -328,23 +515,38 @@ class TupleBatch:
         return out
 
     def with_cols(self, **cols) -> "TupleBatch":
-        out = dict(self.cols)
+        out = dict(self._cols)
         out.update(cols)
-        return self._carry(TupleBatch(out))
+        if self._sel is None:
+            return self._carry(TupleBatch(out))
+        _same_length(cols, len(self))
+        return self._carry(TupleBatch._selected(out, self._sel))
 
     def records(self, cls=BasicRecord) -> Iterator[Any]:
         """Materialize records at the API edge (slow path, tests only)."""
+        cols = self.cols
         names = self.payload_names()
         for i in range(len(self)):
-            r = cls(self.cols["key"][i].item(), self.cols["id"][i].item(),
-                    self.cols["ts"][i].item())
+            r = cls(cols["key"][i].item(), cols["id"][i].item(),
+                    cols["ts"][i].item())
             for p in names:
                 if hasattr(r, p):
-                    setattr(r, p, self.cols[p][i].item())
+                    setattr(r, p, cols[p][i].item())
             yield r
 
+    def __reduce__(self):
+        # a batch that leaves the process leaves compact
+        return (_rebuild, (self.cols, getattr(self, "trace", None)))
+
     def __repr__(self):
-        return f"TupleBatch(n={len(self)}, cols={list(self.cols)})"
+        return f"TupleBatch(n={len(self)}, cols={self.names()})"
+
+
+def _rebuild(cols, trace) -> TupleBatch:
+    out = TupleBatch(cols)
+    if trace is not None:
+        out.trace = trace
+    return out
 
 
 class EOS:

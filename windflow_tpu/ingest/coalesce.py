@@ -240,7 +240,7 @@ class ChunkCoalescer:
         idx = adm.sample_take(n, free)
         if idx is None:
             return batch, n
-        kept = batch.take(idx)
+        kept = batch.take(idx).compact()   # staged: a plane boundary
         shed_n = n - len(kept)
         if shed_n:
             self._shed(batch, shed_n, adm.policy)

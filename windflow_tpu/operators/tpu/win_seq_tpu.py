@@ -865,11 +865,20 @@ class WinSeqTPULogic(NodeLogic):
             if type(item) is SynthChunk:
                 ready = store.synth_ingest(item.start, item.n, item.n_keys,
                                            item.vmod, item.vscale, item.voff)
-            else:
+            elif item.selection is None:
                 ready = store.ingest(
                     item.key,
                     item.id if self.win_type == WinType.CB else item.ts,
                     item.ts, item["value"])
+            else:
+                # a filtered batch that carries its rows: the store reads
+                # the columns as the batch holds them, through the rows
+                ts = item.held("ts")
+                ready = store.ingest(
+                    item.held("key"),
+                    item.held("id") if self.win_type == WinType.CB else ts,
+                    ts, item.held("value"), item.selection)
+                self._counters.selected(*item.selection_counts(), len(item))
             self._account_churn(store)
             self._folded(ready, len(item), emit)
         finally:

@@ -487,7 +487,19 @@ class PyWindowStore:
 
     # -- columnar ingest: a whole chunk is partitioned by key and
     # appended per key vectorized -------------------------------------------
-    def ingest(self, keys, ids, tss, vals) -> int:
+    def ingest(self, keys, ids, tss, vals, sel=None) -> int:
+        """One chunk's columns.  With ``sel`` (a selected batch's rows)
+        a column longer than ``sel`` is a base column: this store
+        gathers its rows here, where the native engine reads through
+        them (runtime/native.NativeWindowEngine.ingest)."""
+        if sel is not None:
+            n = len(sel)
+            if n and int(np.min(sel)) < 0:     # np.take would wrap it
+                raise IndexError("a selection's row lies outside its "
+                                 "base columns")
+            keys, ids, tss, vals = (
+                c if len(c) == n else np.take(c, sel, axis=0)
+                for c in (keys, ids, tss, vals))
         order, keys_s, bounds = key_groups(keys)
         if order is None:
             ids_s, vals_s, tss_s = ids, vals, tss

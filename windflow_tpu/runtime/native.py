@@ -178,13 +178,21 @@ def get_lib():
         lib.wfn_engine_new.argtypes = [LL, LL, ctypes.c_int, LL, ctypes.c_int,
                                        ctypes.c_int, ctypes.c_int]
         lib.wfn_engine_free.argtypes = [ctypes.c_void_p]
+        # the calls made once a chunk take their arrays by address
+        # (``arr.ctypes.data``): a typed pointer costs a cast an array
+        VP = ctypes.c_void_p
         lib.wfn_engine_ingest.restype = LL
-        lib.wfn_engine_ingest.argtypes = [ctypes.c_void_p, PLL, PLL, PLL,
-                                          PD, LL]
+        lib.wfn_engine_ingest.argtypes = [VP, VP, VP, VP, VP, LL]
         lib.wfn_engine_ingest_f32.restype = LL
-        lib.wfn_engine_ingest_f32.argtypes = [
-            ctypes.c_void_p, PLL, PLL, PLL,
-            ctypes.POINTER(ctypes.c_float), LL]
+        lib.wfn_engine_ingest_f32.argtypes = [VP, VP, VP, VP, VP, LL]
+        lib.wfn_engine_ingest_sel.restype = LL
+        lib.wfn_engine_ingest_sel.argtypes = [VP, VP, VP, VP, VP, VP, LL,
+                                              ctypes.c_int, LL]
+        lib.wfn_engine_ingest_sel_f32.restype = LL
+        lib.wfn_engine_ingest_sel_f32.argtypes = [VP, VP, VP, VP, VP, VP,
+                                                  LL, ctypes.c_int, LL]
+        lib.wfn_mask_to_rows.restype = LL
+        lib.wfn_mask_to_rows.argtypes = [VP, LL, VP]
         lib.wfn_engine_synth_ingest.restype = LL
         lib.wfn_engine_synth_ingest.argtypes = [
             ctypes.c_void_p, LL, LL, LL, LL,
@@ -365,6 +373,21 @@ class NativeChannel:
                 lib.wfn_channel_free(ptr)
         except (TypeError, AttributeError):
             pass  # interpreter shutdown: ctypes globals already torn down
+
+
+def mask_to_rows(mask, take):
+    """``np.nonzero(mask)[0]`` of a boolean (or 0/1 byte) mask from one
+    branch-free pass with the GIL released, into ``take(len(mask))``'s
+    int64 buffer (a pool's: the answer is a view of it).  None
+    where this process has not loaded the library (nothing is built for
+    a filter's sake) or the mask is not one byte a row in a row: the
+    caller then asks numpy."""
+    lib = _lib
+    if not lib or mask.itemsize != 1 or not mask.flags.c_contiguous:
+        return None
+    out = take(len(mask))
+    return out[:lib.wfn_mask_to_rows(mask.ctypes.data, len(mask),
+                                     out.ctypes.data)]
 
 
 def pane_prereduce(keys, tss, values, pane: int):
@@ -646,30 +669,55 @@ class NativeWindowEngine:
         self.lib.wfn_engine_stats(self.ptr, buf)
         return dict(zip(self.STATS, buf))
 
-    def ingest(self, keys, ids, ts, vals) -> int:
+    def ingest(self, keys, ids, ts, vals, sel=None) -> int:
+        """One chunk's columns.  With ``sel`` (the rows of a selected
+        batch, ``TupleBatch.selection``) the chunk is ``len(sel)`` rows:
+        a column as long as ``sel`` is compact, a longer one is a base
+        column whose rows ``sel[j]`` the engine reads in its walks
+        (nothing is gathered here; a selection is shorter than its
+        base, core/tuples.py)."""
         import numpy as np
+        vals = np.asarray(vals)
+        through = 0
+        if sel is not None:
+            n = len(sel)
+            sel = np.ascontiguousarray(sel, np.int64)
+            if len(vals) != n and not (
+                    vals.dtype.kind == "f" and vals.flags.c_contiguous
+                    and vals.itemsize in (4, 8)):
+                vals = np.take(vals, sel, axis=0)  # to be converted: its
+                #                                    rows alone
+            # Engine::Sel's bits: 1 keys, 2 ids, 4 stamps, 8 values; the
+            # engine checks the rows against the shortest base column
+            n_base = None
+            for bit, col in ((1, keys), (2, ids), (4, ts), (8, vals)):
+                if len(col) != n:
+                    through |= bit
+                    if n_base is None or len(col) < n_base:
+                        n_base = len(col)
+            if not through:
+                sel = None      # every column compact already
         keys = np.ascontiguousarray(keys, np.int64)
         ids = np.ascontiguousarray(ids, np.int64)
         ts = np.ascontiguousarray(ts, np.int64)
-        LL = ctypes.c_longlong
-        vals = np.asarray(vals)
-        if vals.dtype == np.float32 and vals.flags.c_contiguous:
-            # f32 lane: no widening copy; the engine widens per element
-            return self.lib.wfn_engine_ingest_f32(
-                self.ptr,
-                keys.ctypes.data_as(ctypes.POINTER(LL)),
-                ids.ctypes.data_as(ctypes.POINTER(LL)),
-                ts.ctypes.data_as(ctypes.POINTER(LL)),
-                vals.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-                len(keys))
-        vals = np.ascontiguousarray(vals, np.float64)
-        return self.lib.wfn_engine_ingest(
-            self.ptr,
-            keys.ctypes.data_as(ctypes.POINTER(LL)),
-            ids.ctypes.data_as(ctypes.POINTER(LL)),
-            ts.ctypes.data_as(ctypes.POINTER(LL)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            len(keys))
+        # f32 lane: no widening copy; the engine widens per element
+        f32 = vals.dtype == np.float32 and vals.flags.c_contiguous
+        if not f32:
+            vals = np.ascontiguousarray(vals, np.float64)
+        lib = self.lib
+        if sel is None:
+            fn = lib.wfn_engine_ingest_f32 if f32 else lib.wfn_engine_ingest
+            return fn(self.ptr, keys.ctypes.data, ids.ctypes.data,
+                      ts.ctypes.data, vals.ctypes.data, len(keys))
+        fn = lib.wfn_engine_ingest_sel_f32 if f32 \
+            else lib.wfn_engine_ingest_sel
+        ready = fn(self.ptr, keys.ctypes.data, ids.ctypes.data,
+                   ts.ctypes.data, vals.ctypes.data, sel.ctypes.data, n,
+                   through, n_base)
+        if ready < 0:
+            raise IndexError(f"a selection's row lies outside its base "
+                             f"columns of {n_base} rows")
+        return ready
 
     # interned ids live below _INTERN_CEIL, far outside any plausible
     # user key, so a result batch can be tested for them vectorized

@@ -626,6 +626,10 @@ class Outlet:
         return len(self.dests)
 
     def send_to(self, dest_idx: int, item: Any) -> None:
+        if type(item) is TupleBatch:
+            # plane boundary: a filtered batch that carries its rows
+            # (core/tuples.py) crosses no queue with its base chunk
+            item.compact()
         ch, pid = self.dests[dest_idx]
         cells = self.audit_cells
         if cells is None:
@@ -659,6 +663,9 @@ class Outlet:
         (one channel lock round trip instead of one per item).  Put
         faults never reach this path: RtNode._flush_emits falls back to
         per-item sends whenever put-level faults are bound."""
+        for item in items:
+            if type(item) is TupleBatch:
+                item.compact()      # plane boundary, as in send_to
         ch, pid = self.dests[dest_idx]
         cells = self.audit_cells
         cell = None

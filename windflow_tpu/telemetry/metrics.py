@@ -166,7 +166,13 @@ def render_openmetrics(apps: dict) -> str:
             ("folded_by_key", "counter", "tuples the engine folded with "
              "their key's others of the call in one combine"),
             ("folded_singly", "counter", "tuples the engine folded one "
-             "by one")):
+             "by one"),
+            ("cols_selected", "counter", "columns the selected batches "
+             "the operator ingested carried"),
+            ("cols_gathered", "counter", "columns of selected batches "
+             "that were gathered on read"),
+            ("rows_by_selection", "counter", "rows the window store read "
+             "through a batch's selection")):
         metric = f"windflow_engine_{name}"
         family(metric, kind, text + " (span layer)")
         for lab, seen in engines:

@@ -460,6 +460,10 @@ ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "folded_singly")
 
 
+# what a window operator counts of the selected batches it ingests
+SELECTION_COUNTERS = ("cols_selected", "cols_gathered", "rows_by_selection")
+
+
 class Counters:
     """The latest value of each counter of one operator; of ``keys_live``
     the largest value noted in each 100 ms bucket and of the fold's two
@@ -467,13 +471,31 @@ class Counters:
     instants can be read afterwards.  Written by the operator's ingest
     thread alone."""
 
-    __slots__ = ("operator", "values", "live", "folded")
+    __slots__ = ("operator", "values", "live", "folded", "cols_selected",
+                 "cols_gathered", "rows_by_selection")
 
     def __init__(self, operator: str):
         self.operator = operator
         self.values: Dict[str, int] = dict.fromkeys(ENGINE_COUNTERS, 0)
         self.live: Dict[int, int] = {}      # bucket -> largest keys_live
         self.folded: Dict[int, tuple] = {}  # bucket -> (by key, singly)
+        # the selected batches (core/tuples.py) the operator ingested:
+        # the columns they carried, those of them that had been gathered
+        # by the time the store had read the batch, and the rows the
+        # store was handed through a selection
+        self.cols_selected = 0
+        self.cols_gathered = 0
+        self.rows_by_selection = 0
+
+    def selected(self, carried: int, gathered: int, rows: int) -> None:
+        """One selected batch ingested (the window operator, after its
+        store's ingest): integer adds, no clock."""
+        self.cols_selected += carried
+        self.cols_gathered += gathered
+        self.rows_by_selection += rows
+
+    def selected_totals(self) -> Dict[str, int]:
+        return {n: getattr(self, n) for n in SELECTION_COUNTERS}
 
     def note(self, at_ns: int, values) -> None:
         """The counters' values, in :data:`ENGINE_COUNTERS`' order, as
@@ -710,6 +732,8 @@ def report(g: Optional[SpanGraph]) -> Optional[dict]:
         kept = g.counters.get(row["operator"])
         if kept is not None:
             out["Counters"] = dict(kept.values)
+            if kept.cols_selected:
+                out["Counters"].update(kept.selected_totals())
         out.update({k.capitalize(): v for k, v in shares(row).items()})
         last = recent.get((row["operator"], row["track"]))
         if last is not None:
