@@ -75,7 +75,10 @@ struct KeyState {
     i64 staged_upto = -1;     // last window flush() staged for the key
     int32_t queued = 0;       // fired windows of the key waiting in `ready`
     bool live = false;        // the pool slot holds a key
-    bool indexed = false;     // listed in `due` under next_fire
+    bool indexed = false;     // listed in `due` under due_at
+    i64 due_at = -1;          // the window the key's listing that counts
+                              // is under (an earlier listing, left behind
+                              // when the anchor moved back, is passed over)
 
     // back to a fresh slot; the rings keep their capacity, so a key
     // opened in a reused slot allocates nothing
@@ -85,7 +88,7 @@ struct KeyState {
         plid.clear();
         plts.clear();
         pane_base = next_fire = anchor = arrivals = 0;
-        opened_max = max_id = staged_upto = -1;
+        opened_max = max_id = staged_upto = due_at = -1;
         queued = 0;
         live = indexed = false;
     }
@@ -136,7 +139,16 @@ struct Engine {
     i64 n_live = 0;
     std::vector<Desc> ready;  // fired, unstaged; consumed from ready_head
     std::size_t ready_head = 0;
-    i64 ignored = 0;          // tuples dropped behind the fired frontier
+    i64 ignored = 0;          // tuples that belonged to a window and were
+                              // not folded: behind a window that had fired
+                              // (the stream's last, or a CB key's own), or
+                              // below the anchor of a key that cannot move
+                              // it (CB windows, renumbered ids)
+    // disorder, counted: tuples whose stamp lay behind the stream time
+    // when they came (the largest stamp of every tuple before them) as
+    // gather() met them, those of them that were then dropped, and the
+    // times a live key's anchor moved back (move_back)
+    i64 late_seen = 0, late_dropped = 0, anchors_moved = 0;
     // stream-time trigger: keys with an opened, unfired window are
     // listed under the window they fire next, so a firing visits the
     // keys that fire
@@ -399,6 +411,7 @@ struct Engine {
     inline void index_key(KeyState& st, int32_t slot) {
         if (st.indexed || st.next_fire > st.opened_max) return;
         due[st.next_fire].push_back(slot);
+        st.due_at = st.next_fire;
         st.indexed = true;
     }
 
@@ -416,6 +429,9 @@ struct Engine {
             const auto node = due.extract(due.begin());
             for (int32_t slot : node.mapped()) {
                 KeyState& st = pool[slot];
+                // a listing its key left behind (move_back), or that a
+                // later one of the same window has served
+                if (!st.indexed || st.due_at != node.key()) continue;
                 st.indexed = false;
                 fire_key(st, slot, stream_time);
                 index_key(st, slot);
@@ -435,9 +451,12 @@ struct Engine {
     }
 
     // a key's first data (of this life): anchor the fire frontier at
-    // the first window containing the earliest tuple -- firing from 0
-    // on an epoch-scale first id/ts would emit ~id/slide empty windows
-    // (flood/OOM) -- and never at a window the stream has passed
+    // the first window containing the earliest tuple of the call --
+    // firing from 0 on an epoch-scale first id/ts would emit ~id/slide
+    // empty windows (flood/OOM) -- and never at a window the stream has
+    // passed.  Under the stream rule the anchor is where the key stands
+    // now, not a promise: the first tuple of a key to arrive need not be
+    // its earliest (move_back).
     inline void anchor_key(KeyState& st, i64 first) {
         i64 a = first_window_of(first);
         if (stream_rule && a <= fired_upto) a = fired_upto + 1;
@@ -445,14 +464,46 @@ struct Engine {
         st.pane_base = pane_of(a * slide);
     }
 
-    // the acceptance boundary of a key: tuples below it are late (they
-    // fall in a window that has fired, the key's own last or the last
-    // the stream passed) and are counted; tuples below a new key's
-    // anchor lie in a hopping gap, below the ring, and are not
+    // A tuple of a live key that belongs to window `w`, before the one
+    // the key fires next, and to no window the stream has passed
+    // (w > fired_upto): the key's first tuple to arrive was not its
+    // earliest, or the empty windows in front of a returning key's
+    // tuple were skipped too soon.  The key fires from `w`, its ring
+    // grows at the front to hold w's panes (what was cut there held
+    // nothing: every window from the key's last opened one on lay
+    // empty), and settle() lists it under `w`; its old listing stays
+    // in `due` and is passed over.  Timed with `open`.
+    void move_back(KeyState& st, i64 w) {
+        const i64 t0 = now_ns();
+        st.next_fire = w;
+        if (w < st.anchor) st.anchor = w;
+        const i64 k = st.pane_base - pane_of(w * slide);
+        if (k > 0) {
+            st.pacc.insert(st.pacc.begin(), k, neutral);
+            st.pcnt.insert(st.pcnt.begin(), k, 0);
+            st.pane_base -= k;
+        }
+        st.indexed = false;
+        ++anchors_moved;
+        open_ns += now_ns() - t0;
+    }
+
+    // the end of the window before the one the key fires next: a tuple
+    // below it belongs to a window the key has left behind
+    inline i64 left_behind(const KeyState& st) const {
+        return st.next_fire > 0 ? (st.next_fire - 1) * slide + win
+                                : INT64_MIN;
+    }
+
+    // the acceptance boundary of a key: tuples below it fall in a window
+    // that has fired and are counted as ignored.  Under the stream rule
+    // the stream alone decides (the end of the last window it passed: a
+    // key fires no window the stream has not passed); a CB or renumbered
+    // key fires on its own ids, and its boundary is its own last fired
+    // window's end
     inline i64 accept_of(const KeyState& st) const {
-        i64 own = st.next_fire > st.anchor
-            ? (st.next_fire - 1) * slide + win : INT64_MIN;
-        return std::max(own, stream_accept());
+        if (stream_rule) return stream_accept();
+        return st.next_fire > st.anchor ? left_behind(st) : INT64_MIN;
     }
 
     // per key and call, before the fold: this batch's id range, the
@@ -466,14 +517,28 @@ struct Engine {
             pt.lo = st.arrivals;
             pt.hi = st.arrivals + pt.count - 1;
         }
+        bool look = false;
         if (st.max_id < 0) {
             anchor_key(st, pt.lo);
-        } else if (sparse && st.next_fire > st.opened_max) {
-            // every window the key opened has fired: the windows that
-            // lie empty before this batch's first tuple are skipped
-            // here, not one by one at the trigger
-            i64 w0 = first_window_of(pt.lo);
-            if (w0 > st.next_fire) st.next_fire = w0;
+        } else if (stream_rule) {
+            if (pt.lo >= stream_accept()) {
+                if (pt.lo < left_behind(st)) {
+                    // the call's earliest tuple of the key lies before
+                    // the window the key fires next
+                    move_back(st, first_window_of(pt.lo));
+                } else if (sparse && st.next_fire > st.opened_max) {
+                    // every window the key opened has fired: the
+                    // windows that lie empty before this call's first
+                    // tuple are skipped here, not one by one at the
+                    // trigger
+                    const i64 w0 = first_window_of(pt.lo);
+                    if (w0 > st.next_fire) st.next_fire = w0;
+                }
+            } else {
+                // a late tuple hides the earliest one that counts:
+                // fold_singly() looks at each
+                look = true;
+            }
         }
         d_accept[d] = accept_of(st);
         // pre-grow the ring to this batch's frontier so the fold
@@ -481,6 +546,7 @@ struct Engine {
         i64 hi_rel = pane_of(pt.hi) - st.pane_base;
         if (hi_rel >= 0) ensure_pane(st, hi_rel);
         fold_key(d, st, pt, hi_rel);
+        if (look) d_single[d] = 2;
     }
 
     // The key's partial of this call into its pane with one combine,
@@ -569,6 +635,39 @@ struct Engine {
         }
     }
 
+    // Which of the call's tuples lie behind the stream time as they
+    // come (the largest stamp of every tuple before them): counted, and
+    // marked in slot_of's sign for fold_singly(), which knows whether
+    // they stay.  gather()'s walk is bound by what it does a tuple, so
+    // this is a walk of its own, over the ids it has just read: first
+    // whether the stamps run in order from the stream time on (block by
+    // block, no branch a tuple: an in-order stream pays that and no
+    // more), and only where they do not, each tuple against the running
+    // stream time.
+    static constexpr int32_t LATE_MARK = INT32_MIN;
+    template <bool THROUGH>
+    void note_late(const i64* ids, const i64* rows, i64 n) {
+        if (n == 0) return;
+        auto at = [&](i64 j) { return ids[THROUGH ? rows[j] : j]; };
+        bool sorted = at(0) >= stream_time;
+        for (i64 b = 1; sorted && b < n; b += 4096) {
+            const i64 e = std::min<i64>(b + 4096, n);
+            i64 back = 0;
+            for (i64 j = b; j < e; ++j) back += at(j) < at(j - 1);
+            sorted = back == 0;
+        }
+        if (sorted) return;
+        i64 front = stream_time, late = 0;
+        for (i64 j = 0; j < n; ++j) {
+            const i64 id = at(j);
+            const int32_t behind = id < front;
+            late += behind;
+            slot_of[j] |= -behind & LATE_MARK;
+            front = id > front ? id : front;
+        }
+        late_seen += late;
+    }
+
     // TV = double or float: f32 sources fold without a host-side
     // widening copy (values widen at the accumulate)
     template <bool SEL, typename TV>
@@ -588,6 +687,12 @@ struct Engine {
             gather<true, 2, SEL>(bkeys, ids, vals, n, s);
         else
             gather<true, 0, SEL>(bkeys, ids, vals, n, s);
+        if (stream_rule) {
+            if (SEL && (s.through & SEL_IDS))
+                note_late<true>(ids, s.rows, n);
+            else
+                note_late<false>(ids, nullptr, n);
+        }
         const std::size_t nd = parts.size();
         d_accept.resize(nd);
         d_single.resize(nd);
@@ -630,22 +735,33 @@ struct Engine {
         const bool cv = SEL && !(s.through & SEL_VALS);
         i64 folded = 0;
         for (i64 j = 0; j < n; ++j) {
-            const int32_t d = slot_of[j];
+            const int32_t d = slot_of[j] & ~LATE_MARK;
             if (!d_single[d]) continue;
+            const bool late = slot_of[j] < 0;
             const i64 r = SEL ? s.rows[j] : j;
             KeyState& st = *d_state[d];
             const i64 id = renumber ? st.arrivals++ : ids[ci ? j : r];
             if (!renumber && id < d_accept[d]) {
                 ++ignored;
+                late_dropped += late;
                 continue;
             }
-            const i64 p = pane_of(id) - st.pane_base;
-            if (p < 0) continue;  // hopping-gap tuple below the ring
-            if (hopping) {
-                const i64 nn = id / slide;
-                if (id >= nn * slide + win) continue;  // gap tuple
-                if (nn > st.opened_max) st.opened_max = nn;
+            const i64 nn = hopping ? id / slide : 0;
+            if (hopping && id >= nn * slide + win) {
+                // a gap tuple belongs to no window: nothing is owed it
+                late_dropped += late;
+                continue;
             }
+            if (d_single[d] == 2 && id < left_behind(st))
+                move_back(st, first_window_of(id));
+            const i64 p = pane_of(id) - st.pane_base;
+            if (p < 0) {
+                // below the anchor of a key that cannot move it (CB
+                // windows)
+                ++ignored;
+                continue;
+            }
+            if (hopping && nn > st.opened_max) st.opened_max = nn;
             fold(st, p, (double)vals[cv ? j : r]);
             ++folded;
             if (!is_tb && id >= st.plid[p]) {
@@ -728,13 +844,14 @@ struct Engine {
                     ++ignored;
                     continue;
                 }
+                const i64 nn = hopping ? id / slide : 0;
+                if (hopping && id >= nn * slide + win) continue;  // gap
                 const i64 p = pane_of(id) - st.pane_base;
-                if (p < 0) continue;
-                if (hopping) {
-                    const i64 nn = id / slide;
-                    if (id >= nn * slide + win) continue;  // gap
-                    if (nn > st.opened_max) st.opened_max = nn;
+                if (p < 0) {
+                    ++ignored;
+                    continue;
                 }
+                if (hopping && nn > st.opened_max) st.opened_max = nn;
                 fold(st, p, v);
                 ++folded;
                 if (!is_tb && id >= st.plid[p]) {
@@ -1171,13 +1288,15 @@ i64 wfn_engine_ignored(void* ep) {
     return static_cast<Engine*>(ep)->ignored;
 }
 
-// What key churn costs, how many keys there are and how the fold went,
-// into out[11]: nanoseconds spent creating key states (open), finding
-// and queueing fired windows (trigger) and evicting (evict), since the
-// engine was made; keys opened, keys evicted, keys live now and at their
-// peak, windows fired; tuples folded with their key's others of the call
-// in one combine, tuples folded one by one; the stream time (-1 before
-// the first stamp).
+// What key churn costs, how many keys there are, how the fold went and
+// what disorder it met, into out[14]: nanoseconds spent creating key
+// states and moving anchors back (open), finding and queueing fired
+// windows (trigger) and evicting (evict), since the engine was made; keys
+// opened, keys evicted, keys live now and at their peak, windows fired;
+// tuples folded with their key's others of the call in one combine,
+// tuples folded one by one; tuples accepted whose stamp lay behind the
+// stream time when they came, times a live key's anchor moved back,
+// tuples ignored; the stream time (-1 before the first stamp).
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -1190,7 +1309,10 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[7] = e.windows_fired;
     out[8] = e.folded_by_key;
     out[9] = e.folded_singly;
-    out[10] = e.stream_time;
+    out[10] = e.late_seen - e.late_dropped;
+    out[11] = e.anchors_moved;
+    out[12] = e.ignored;
+    out[13] = e.stream_time;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }

@@ -295,7 +295,15 @@ def test_a_late_tuple_in_an_otherwise_one_pane_chunk():
 # sha256 of every byte the engine of commit 359ec96 (the per-tuple fold)
 # staged, in firing order, then `ignored`, keys opened and windows
 # fired; regenerate with `python tests/test_fold_by_key.py` in a checkout
-# of the engine to be trusted
+# of the engine to be trusted.  Every in-order digest is that commit's.
+# Twenty others are PR 32's, where that engine lost tuples: under
+# `tb_delay/*/disordered` it anchored a key at its first tuple to arrive
+# and never emitted the window an earlier straggler opened (483 rows of
+# the 485 owed: tests/test_out_of_order.py holds the engine to the plain
+# recomputation there); under `cb/*/late` and `cb_hopping/*` it dropped
+# the tuples below a key's anchor without counting them (the same staged
+# bytes, `ignored` two to three higher: every tuple is now folded,
+# counted as ignored, or in a hopping gap).
 GOLDEN_CHUNKINGS = {"c1": 1, "c7": 7, "c128": 128, "c129": 129,
                     "c1000": 1000, "whole": 1 << 30}
 GOLDEN_N = 4000
@@ -338,19 +346,19 @@ GOLDEN = json.loads("""
 "tb/mean/disordered": "723dbec41b6eddf2",
 "tb/mean/late": "157248bc205a36bd",
 "tb_delay/count/inorder": "b776b9f4c1528e1d",
-"tb_delay/count/disordered": "7fe361124236907a",
+"tb_delay/count/disordered": "02f45928b0dadfb0",
 "tb_delay/count/late": "e4ce709b23b8bbe0",
 "tb_delay/sum/inorder": "3f996067650e73f7",
-"tb_delay/sum/disordered": "3196e14a86c9a44d",
+"tb_delay/sum/disordered": "84d86a48f0efeba2",
 "tb_delay/sum/late": "fa5c5921e688eeed",
 "tb_delay/max/inorder": "9c057798a76344dc",
-"tb_delay/max/disordered": "c7fa8b238aa2bd9c",
+"tb_delay/max/disordered": "c41fbca023e3db2f",
 "tb_delay/max/late": "fa9f718d8071fc67",
 "tb_delay/min/inorder": "6cdf1a0cd416dc7e",
-"tb_delay/min/disordered": "2090c012d1aebdb3",
+"tb_delay/min/disordered": "9faea69dbefe1579",
 "tb_delay/min/late": "eddb69632a5121dd",
 "tb_delay/mean/inorder": "e97622603f5a4e7d",
-"tb_delay/mean/disordered": "f3c1b04c62c2218c",
+"tb_delay/mean/disordered": "ae49aa0dfc774829",
 "tb_delay/mean/late": "2c51d4e90d7c6111",
 "tb_dense/count/inorder": "e3dbed7976a8ad09",
 "tb_dense/count/disordered": "e732d1412fc5517b",
@@ -399,19 +407,19 @@ GOLDEN = json.loads("""
 "tb_odd_pane/mean/late": "321e83c8041a40ec",
 "cb/count/inorder": "5c4c2d61c5306646",
 "cb/count/disordered": "aae7270e297f9753",
-"cb/count/late": "41d5b44f21e81021",
+"cb/count/late": "6d98436695348d1f",
 "cb/sum/inorder": "fcb33dfce0292884",
 "cb/sum/disordered": "9614c623682a87ff",
-"cb/sum/late": "32b7ff5c283ddd62",
+"cb/sum/late": "df85bb34375a66f3",
 "cb/max/inorder": "735a1746fc062297",
 "cb/max/disordered": "04e6bdd537d3e9e4",
-"cb/max/late": "cdd670da8f997c72",
+"cb/max/late": "1c70454c84db5067",
 "cb/min/inorder": "539c0895558e1a15",
 "cb/min/disordered": "b0bbdce691cd0a0f",
-"cb/min/late": "8be5e9cf9c492fd4",
+"cb/min/late": "38625dba3adf948d",
 "cb/mean/inorder": "7bf1e4616ec20bf7",
 "cb/mean/disordered": "df3e7caccc74fa44",
-"cb/mean/late": "4976bb0ad2171ea3",
+"cb/mean/late": "551c4eacc1df2803",
 "renumbered/count/inorder": "1393f02dbc11bb4d",
 "renumbered/count/disordered": "1393f02dbc11bb4d",
 "renumbered/count/late": "1393f02dbc11bb4d",
@@ -443,20 +451,20 @@ GOLDEN = json.loads("""
 "hopping/mean/disordered": "aa0fb9075eefc454",
 "hopping/mean/late": "554092ede20e6b64",
 "cb_hopping/count/inorder": "fb3c04e2629d402f",
-"cb_hopping/count/disordered": "05274b91b9351b5f",
-"cb_hopping/count/late": "969b31824d6a4875",
+"cb_hopping/count/disordered": "8d0131823f8fb3f2",
+"cb_hopping/count/late": "1e7862279eb2eef6",
 "cb_hopping/sum/inorder": "25b42fd15035ebcc",
-"cb_hopping/sum/disordered": "73297d178dbb9bb1",
-"cb_hopping/sum/late": "c9caec1b2288d79c",
+"cb_hopping/sum/disordered": "37c09da1aff5ca8e",
+"cb_hopping/sum/late": "8fe18a8176b80a93",
 "cb_hopping/max/inorder": "a025e941bb394550",
-"cb_hopping/max/disordered": "f9bf36d4a2de5ef3",
-"cb_hopping/max/late": "b2275add17b98c56",
+"cb_hopping/max/disordered": "dcc1f97da721cfb2",
+"cb_hopping/max/late": "dd795928af6f7597",
 "cb_hopping/min/inorder": "470ee2305dec46e7",
-"cb_hopping/min/disordered": "3cc0c089426ec90b",
-"cb_hopping/min/late": "a353f73edbf18266",
+"cb_hopping/min/disordered": "d1724c6438a3ef13",
+"cb_hopping/min/late": "3ac8e761f967b6eb",
 "cb_hopping/mean/inorder": "1beeae14f19b9a97",
-"cb_hopping/mean/disordered": "5b6a87f3d96ef91a",
-"cb_hopping/mean/late": "e4cf8b964c398265"
+"cb_hopping/mean/disordered": "63b2b8b2ffbf840b",
+"cb_hopping/mean/late": "77306df222a8797b"
 }
 """)
 
@@ -476,15 +484,14 @@ def test_the_counters_add_up_and_say_which_fold_ran():
             eng, _rows, _d, _l = drive(lane, kind, keys, ts, vals, 128)
             s = eng.snapshot()
             folded = s["folded_by_key"] + s["folded_singly"]
-            if lane == "tb":
-                assert folded == N_SMALL - eng.ignored(), kind
+            if lane != "hopping":
+                # nothing vanishes uncounted: below the ring of a CB key
+                # that keeps its anchor is ignored too
+                assert folded == N_SMALL - eng.ignored(), (lane, kind)
             else:
-                # neither folded nor counted late: a hopping gap's
-                # tuples, and those below the ring of a key that keeps
-                # its anchor (CB, renumbered: never evicted)
-                assert 0 < folded <= N_SMALL - eng.ignored(), (lane, kind)
-                assert (folded < N_SMALL - eng.ignored()) \
-                    == (lane != "renumbered"), (lane, kind)
+                # a hopping gap's tuples belong to no window: neither
+                # folded nor owed
+                assert 0 < folded < N_SMALL - eng.ignored(), (lane, kind)
             if by_key and kind != "sum":
                 assert s["folded_by_key"] > 0.5 * folded, (lane, kind)
             else:

@@ -63,10 +63,13 @@ from ..telemetry.histogram import LogHistogram
 # 15 = those Counters gain folded_by_key and folded_singly (tuples the
 # engine folded with their key's others of the call in one combine, and
 # one by one).
+# 16 = those Counters gain late_accepted, anchors_moved and
+# inputs_ignored (tuples accepted behind the engine's stream time, times
+# a live key's anchor moved back, tuples dropped behind a fired window).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 15
+SCHEMA_VERSION = 16
 
 
 @dataclass

@@ -819,7 +819,7 @@ class WinSeqTPULogic(NodeLogic):
                 tr.account(name, s[i] - last[i])
         last[:] = s
         self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
-                            last[3:10])
+                            last[3:13])
 
     def _launch_due(self) -> bool:
         return ((_time.perf_counter() - self._last_launch_t) * 1e3

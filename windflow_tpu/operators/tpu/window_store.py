@@ -37,7 +37,7 @@ class _TPUKeyState:
     __slots__ = ("sort_keys", "ts", "values", "pending_sort", "pending_ts",
                  "pending_val", "pending_chunks", "next_fire", "opened_max",
                  "max_id", "renumber_next", "emit_counter", "anchor",
-                 "queued", "indexed")
+                 "queued", "indexed", "due_at")
 
     def __init__(self, emit_counter_start=0):
         # consolidated sorted arrays
@@ -58,6 +58,7 @@ class _TPUKeyState:
         self.max_id = -1
         self.queued = 0           # fired windows not yet staged
         self.indexed = False      # listed in the store's ``_due`` heap
+        self.due_at = -1          # when the listing that counts fires
         self.renumber_next = 0
         self.emit_counter = emit_counter_start
 
@@ -125,7 +126,14 @@ class PyWindowStore:
         # fired windows not yet staged: (key, gwid, start_key, end_key,
         # rts, key)
         self.descriptors: List = []
+        # tuples that belonged to a window and were not kept (behind a
+        # window that had fired, or below the anchor of a key that cannot
+        # move it); and what disorder the stream rule met: tuples kept
+        # whose stamp lay behind the stream time when they came, times a
+        # live key's anchor moved back (``_reach``)
         self.ignored_tuples = 0
+        self.late_accepted = 0
+        self.anchors_moved = 0
         self._saw_nonint_key = False
 
     # -- the engine's small calls ------------------------------------------
@@ -149,7 +157,10 @@ class PyWindowStore:
                    + st.values.nbytes + 96)
         except (RuntimeError, StopIteration, AttributeError):
             per = 96  # empty, or resized under us: count-only estimate
-        return {"keys_live": n, "bytes_est": n * per}
+        return {"keys_live": n, "bytes_est": n * per,
+                "late_accepted": self.late_accepted,
+                "anchors_moved": self.anchors_moved,
+                "inputs_ignored": self.ignored_tuples}
 
     def serialize(self) -> dict:
         """The per-key store, the windows fired and not yet staged, and
@@ -161,6 +172,8 @@ class PyWindowStore:
             "keys": copy.deepcopy(self.keys),
             "descriptors": list(self.descriptors),
             "ignored_tuples": self.ignored_tuples,
+            "late_accepted": self.late_accepted,
+            "anchors_moved": self.anchors_moved,
             "stream_time": self._stream_time,
             "fired_time": self._fired_time,
         }
@@ -169,6 +182,8 @@ class PyWindowStore:
         self.keys = copy.deepcopy(state["keys"])
         self.descriptors = list(state.get("descriptors", []))
         self.ignored_tuples = state.get("ignored_tuples", 0)
+        self.late_accepted = state.get("late_accepted", 0)
+        self.anchors_moved = state.get("anchors_moved", 0)
         self._stream_time = state.get("stream_time", -1)
         self._fired_time = state.get("fired_time", -1)
         self._due = []
@@ -413,29 +428,52 @@ class PyWindowStore:
              - initial_id)
         return -1 if t < 0 else t // self.slide_len
 
+    def _first_window(self, rel: int) -> int:
+        """The first window that holds (or follows) an id ``rel`` past
+        the key's initial id."""
+        return ((rel - self.win_len) // self.slide_len + 1
+                if rel >= self.win_len else 0)
+
     def _admit(self, st: _TPUKeyState, first_rel: int, passed: int):
-        """Anchor a key on its first data, skip what lies empty before a
-        returning one's, and return the acceptance boundary (relative to
-        the key's initial id) with whether a tuple below it is late: it
-        is where a window has fired there, the key's own last or the
-        last the stream passed; below a new key's anchor lies a hopping
-        gap."""
-        first_w = ((first_rel - self.win_len) // self.slide_len + 1
-                   if first_rel >= self.win_len else 0)
+        """Anchor a key on its first data and return the acceptance
+        boundary, relative to the key's initial id: a tuple below it is
+        counted as ignored.  Under the stream rule the stream alone
+        decides: the end of the last window it passed (None where it has
+        passed none), since a key fires no window the stream has not
+        passed; a tuple at or above it that lies before the window the
+        key fires next moves the key back (``_reach``).  A CB or
+        renumbered key fires on its own ids: its boundary is the end of
+        its own last fired window, or its anchor."""
         if st.max_id < 0:
             # first data: anchor the fire frontier at the first
             # containing window (an epoch-scale first id must not fire
             # ~id/slide empty windows), never at one the stream passed
-            st.anchor = st.next_fire = max(first_w, passed + 1)
-        elif (self._sparse and st.next_fire > st.opened_max
-              and first_w > st.next_fire):
-            st.next_fire = first_w
-        fired = st.next_fire > st.anchor
-        own = (self.win_len + (st.next_fire - 1) * self.slide_len
-               if fired else st.anchor * self.slide_len)
-        if passed >= 0:
-            return max(own, passed * self.slide_len + self.win_len), True
-        return own, fired
+            st.anchor = st.next_fire = max(self._first_window(first_rel),
+                                           passed + 1)
+        if self._stream_rule:
+            return (passed * self.slide_len + self.win_len
+                    if passed >= 0 else None)
+        if st.next_fire > st.anchor:
+            return self.win_len + (st.next_fire - 1) * self.slide_len
+        return st.anchor * self.slide_len
+
+    def _reach(self, st: _TPUKeyState, first_rel: int) -> None:
+        """The earliest tuple kept of a key (under the stream rule; at
+        or above the acceptance boundary) lies in window ``w``.  Before
+        the one the key fires next: its first tuple to arrive was not
+        its earliest, or the empty windows before a returning key's
+        tuple were skipped too soon, and the key fires from ``w`` (its
+        listing in ``_due`` is left behind and passed over).  After it,
+        with every window the key opened fired: the empty ones between
+        are skipped here."""
+        w = self._first_window(first_rel)
+        if w < st.next_fire:
+            st.next_fire = w
+            st.anchor = min(st.anchor, w)
+            st.indexed = False
+            self.anchors_moved += 1
+        elif self._sparse and st.next_fire > st.opened_max:
+            st.next_fire = w
 
     def _settle(self, key, st: _TPUKeyState, initial_id: int) -> None:
         """A key has new data: its part in the firing."""
@@ -451,9 +489,9 @@ class PyWindowStore:
             return
         st.indexed = True
         self._due_n += 1
-        _heapq.heappush(self._due, (
-            initial_id + st.next_fire * self.slide_len + self.win_len
-            + self.triggering_delay, self._due_n, key))
+        st.due_at = (initial_id + st.next_fire * self.slide_len
+                     + self.win_len + self.triggering_delay)
+        _heapq.heappush(self._due, (st.due_at, self._due_n, key))
 
     def _trigger(self) -> None:
         """The stream has moved: fire the windows it has passed, for the
@@ -461,8 +499,10 @@ class PyWindowStore:
         now = self._fired_time = self._stream_time
         due = self._due
         while due and due[0][0] <= now:
-            key = _heapq.heappop(due)[2]
-            st = self.keys[key]
+            at, _, key = _heapq.heappop(due)
+            st = self.keys.get(key)
+            if st is None or not st.indexed or st.due_at != at:
+                continue      # a listing its key left behind (_reach)
             st.indexed = False
             self._fire_key(key, st, now)
             self._index_key(key, st, self._initial_id(key))
@@ -501,10 +541,19 @@ class PyWindowStore:
                 c if len(c) == n else np.take(c, sel, axis=0)
                 for c in (keys, ids, tss, vals))
         order, keys_s, bounds = key_groups(keys)
+        late_s = None
+        if self._stream_rule and len(ids):
+            # behind the stream time as each came: the largest stamp of
+            # every tuple before it, in arrival order
+            front = np.maximum.accumulate(
+                np.concatenate(([self._stream_time], ids[:-1])))
+            late_s = ids < front
         if order is None:
             ids_s, vals_s, tss_s = ids, vals, tss
         else:
             ids_s, vals_s, tss_s = ids[order], vals[order], tss[order]
+            if late_s is not None:
+                late_s = late_s[order]
         uniq = keys_s[bounds[:-1]]
         for j, key in enumerate(uniq):
             key = key.item()
@@ -518,19 +567,20 @@ class PyWindowStore:
                 st.renumber_next += hi - lo
             if not len(k_ids):
                 continue
-            # acceptance: drop tuples behind the already-fired frontier
+            # acceptance: tuples behind the already-fired frontier are
+            # dropped and counted; a hopping-gap tuple belongs to no
+            # window, and nothing is owed it
             passed = self._passed_lwid(initial_id)
-            min_boundary, late = self._admit(
-                st, int(k_ids.min()) - initial_id, passed)
-            keep = k_ids >= initial_id + min_boundary
+            boundary = self._admit(st, int(k_ids.min()) - initial_id, passed)
+            keep = (k_ids >= initial_id + boundary if boundary is not None
+                    else np.ones(len(k_ids), bool))
+            self.ignored_tuples += len(k_ids) - int(keep.sum())
             if self.win_len < self.slide_len:  # hopping: drop gap tuples
                 n = (k_ids - initial_id) // self.slide_len
                 off = k_ids - initial_id
                 keep &= (off >= n * self.slide_len) & \
                     (off < n * self.slide_len + self.win_len)
-            n_drop = int((~keep).sum())
-            if n_drop and late:
-                self.ignored_tuples += n_drop
+            n_drop = len(k_ids) - int(keep.sum())
             if n_drop == len(k_ids):
                 if self._stream_rule:
                     # late, yet the stream has come this far
@@ -539,6 +589,10 @@ class PyWindowStore:
                 self._drop_if_done(key, st)
                 continue
             k_ids = k_ids[keep]
+            if self._stream_rule:
+                self.late_accepted += int(late_s[lo:hi][keep].sum())
+                if st.max_id >= 0:
+                    self._reach(st, int(k_ids.min()) - initial_id)
             st.pending_chunks.append(
                 (k_ids.astype(np.int64), tss_s[lo:hi][keep],
                  vals_s[lo:hi][keep].astype(np.float64)))
@@ -576,10 +630,10 @@ class PyWindowStore:
         initial_id = self._initial_id(key)
         if val is not None:
             passed = self._passed_lwid(initial_id)
-            min_boundary, late = self._admit(st, id_ - initial_id, passed)
-            if id_ < initial_id + min_boundary:
-                if late:
-                    self.ignored_tuples += 1
+            fresh = st.max_id < 0
+            boundary = self._admit(st, id_ - initial_id, passed)
+            if boundary is not None and id_ < initial_id + boundary:
+                self.ignored_tuples += 1
                 self._drop_if_done(key, st)
                 return len(self.descriptors)
             last_w = wa.last_window_of(id_, initial_id, self.win_len,
@@ -587,6 +641,10 @@ class PyWindowStore:
             if last_w < 0:
                 self._drop_if_done(key, st)
                 return len(self.descriptors)  # hopping gap
+            if self._stream_rule:
+                self.late_accepted += id_ < self._stream_time
+                if not fresh:
+                    self._reach(st, id_ - initial_id)
             st.opened_max = max(st.opened_max, last_w)
             st.pending_sort.append(id_)
             st.pending_ts.append(ts)

@@ -622,10 +622,13 @@ class NativeWindowEngine:
     # states, finding and queueing fired windows, evicting; keys opened,
     # evicted, live now, live at their peak; windows fired; tuples
     # folded with their key's others of the call in one combine, tuples
-    # folded one by one; stream time
+    # folded one by one; tuples accepted whose stamp lay behind the
+    # stream time when they came, times a live key's anchor moved back
+    # (docs/RUNTIME.md 5a), tuples ignored; stream time
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
              "windows_fired", "folded_by_key", "folded_singly",
+             "late_accepted", "anchors_moved", "inputs_ignored",
              "stream_time")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,

@@ -167,6 +167,12 @@ def render_openmetrics(apps: dict) -> str:
              "their key's others of the call in one combine"),
             ("folded_singly", "counter", "tuples the engine folded one "
              "by one"),
+            ("late_accepted", "counter", "tuples the engine accepted "
+             "whose stamp lay behind its stream time when they came"),
+            ("anchors_moved", "counter", "times a live key's anchor moved "
+             "back for a tuple earlier than the key's first to arrive"),
+            ("inputs_ignored", "counter", "tuples the engine dropped "
+             "behind a window that had fired"),
             ("cols_selected", "counter", "columns the selected batches "
              "the operator ingested carried"),
             ("cols_gathered", "counter", "columns of selected batches "

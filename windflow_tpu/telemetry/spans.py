@@ -451,13 +451,20 @@ class LaunchRing:
 # -- counters --------------------------------------------------------------
 
 # what the native window engine counts (runtime/native.py
-# ``NativeWindowEngine.STATS[3:10]``): key states it created and evicted
+# ``NativeWindowEngine.STATS[3:13]``): key states it created and evicted
 # since it was made, those live now and at their peak, windows it fired,
 # tuples it folded with their key's others of the call in one combine
-# and tuples it folded one by one
+# and tuples it folded one by one, and what disorder it met: tuples it
+# accepted whose stamp lay behind its stream time when they came, times
+# a live key's anchor moved back, tuples it ignored
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "keys_live_peak", "windows_fired", "folded_by_key",
-                   "folded_singly")
+                   "folded_singly", "late_accepted", "anchors_moved",
+                   "inputs_ignored")
+# those of them kept as a series (the last values noted in each 100 ms
+# bucket), so that what moved between two instants can be read
+SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
+                   "anchors_moved", "inputs_ignored")
 
 
 # what a window operator counts of the selected batches it ingests
@@ -466,19 +473,19 @@ SELECTION_COUNTERS = ("cols_selected", "cols_gathered", "rows_by_selection")
 
 class Counters:
     """The latest value of each counter of one operator; of ``keys_live``
-    the largest value noted in each 100 ms bucket and of the fold's two
-    counts the last, so that the peak and the tuples folded between two
-    instants can be read afterwards.  Written by the operator's ingest
-    thread alone."""
+    the largest value noted in each 100 ms bucket and of the
+    :data:`SERIES_COUNTERS` the last, so that the peak, the tuples folded
+    between two instants and the disorder met between them can be read
+    afterwards.  Written by the operator's ingest thread alone."""
 
-    __slots__ = ("operator", "values", "live", "folded", "cols_selected",
+    __slots__ = ("operator", "values", "live", "series", "cols_selected",
                  "cols_gathered", "rows_by_selection")
 
     def __init__(self, operator: str):
         self.operator = operator
         self.values: Dict[str, int] = dict.fromkeys(ENGINE_COUNTERS, 0)
         self.live: Dict[int, int] = {}      # bucket -> largest keys_live
-        self.folded: Dict[int, tuple] = {}  # bucket -> (by key, singly)
+        self.series: Dict[int, tuple] = {}  # bucket -> SERIES_COUNTERS
         # the selected batches (core/tuples.py) the operator ingested:
         # the columns they carried, those of them that had been gathered
         # by the time the store had read the batch, and the rows the
@@ -506,9 +513,9 @@ class Counters:
             self.live[b] = live
             if len(self.live) > TIMELINE_BUCKETS:
                 _trim(self.live)
-        self.folded[b] = (v["folded_by_key"], v["folded_singly"])
-        if len(self.folded) > TIMELINE_BUCKETS:
-            _trim(self.folded)
+        self.series[b] = tuple(v.get(n, 0) for n in SERIES_COUNTERS)
+        if len(self.series) > TIMELINE_BUCKETS:
+            _trim(self.series)
 
     def live_peak(self, t0_s: float, t1_s: float) -> Optional[int]:
         """The largest ``keys_live`` noted in the buckets of [t0_s,
@@ -522,16 +529,24 @@ class Counters:
         before = [b for b in live if b < b0]
         return live[max(before)] if before else None
 
-    def folded_between(self, t0_s: float, t1_s: float) -> tuple:
-        """(by key, singly): the tuples folded between the last note in
-        the buckets before ``t0_s``'s and the last in those up to
-        ``t1_s``'s (good to a bucket and a note at each end)."""
+    def moved_between(self, t0_s: float, t1_s: float) -> Dict[str, int]:
+        """By how much each of the :data:`SERIES_COUNTERS` moved between
+        the last note in the buckets before ``t0_s``'s and the last in
+        those up to ``t1_s``'s (good to a bucket and a note at each
+        end)."""
         b0, b1 = int(t0_s * 1e9) // BUCKET_NS, int(t1_s * 1e9) // BUCKET_NS
-        folded = self.folded.copy()
-        at = [max((b for b in folded if b <= edge), default=None)
+        series = self.series.copy()
+        none = (0,) * len(SERIES_COUNTERS)
+        at = [max((b for b in series if b <= edge), default=None)
               for edge in (b0 - 1, b1)]
-        lo, hi = (folded[b] if b is not None else (0, 0) for b in at)
-        return hi[0] - lo[0], hi[1] - lo[1]
+        lo, hi = (series[b] if b is not None else none for b in at)
+        return {n: h - l for n, l, h in zip(SERIES_COUNTERS, lo, hi)}
+
+    def folded_between(self, t0_s: float, t1_s: float) -> tuple:
+        """(by key, singly): the tuples folded between two instants
+        (:meth:`moved_between`)."""
+        moved = self.moved_between(t0_s, t1_s)
+        return moved["folded_by_key"], moved["folded_singly"]
 
 
 # -- graphs and the registry -----------------------------------------------
