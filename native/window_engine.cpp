@@ -31,11 +31,12 @@
 
 #include <algorithm>
 #include <chrono>
+#include <cstddef>
 #include <cstdint>
 #include <cstring>
-#include <deque>
 #include <limits>
 #include <map>
+#include <new>
 #include <numeric>
 #include <vector>
 
@@ -50,48 +51,102 @@ inline i64 now_ns() {
         std::chrono::steady_clock::now().time_since_epoch()).count();
 }
 
-struct KeyState {
-    // pane-partial ring: pacc[j] is the combine partial of absolute
-    // pane (pane_base + j); pcnt[j] its tuple count.  plid/plts track
-    // the max tuple id seen per pane and its timestamp -- the CB
-    // result-timestamp lane (result ts = ts of the last tuple in the
-    // window extent, matching the host engine); empty for TB windows,
-    // whose result ts is pure window arithmetic.
-    std::vector<double> pacc;
-    std::vector<i64> pcnt;
-    std::vector<i64> plid, plts;
-    i64 key = 0;
-    i64 pane_base = 0;        // absolute pane index of pacc[0]
+// A KEY STATE IN ONE PLACE.  What a key's first tuple of a call walks:
+//
+//   tab[home_of(key)]  ->  KeyState (pool block base + slot * 192 B)
+//   24 B record             line 0  the hot line: what prepare(),
+//                                   fold_key(), settle(), fire_key(),
+//                                   flush() and evict() read
+//                           line 1  the ring's first RING_IN panes
+//                                   (acc, cnt)   |  or, once the ring has
+//                                   left the key state, {block, cap}  ->
+//                                   one heap block: acc[cap] cnt[cap]
+//                                   (CB lanes: lid[cap] lts[cap] after)
+//                           line 2  due_at, anchor, arrivals, a spare block
+//
+// The slot's address is arithmetic, the ring of a key whose windows are
+// a few panes (every TB lane whose call spans few panes of a key) lies
+// directly behind the line that addresses it, and nothing else is
+// allocated a key.  A ring leaves the key state ("spills") where it
+// outgrows RING_IN panes: long windows over a small pane, a call that
+// spans many panes of one key, move_back() growing it at the front, and
+// every ring of a CB lane (its two extra lanes, the last tuple's id and
+// stamp a pane, have no room inline).  RING_IN is what one line holds.
+constexpr int RING_IN = 4;    // 64 B / (an accumulator + a count)
+
+struct alignas(64) KeyState {
+    // -- line 0 --
+    i64 key = 0;              // here and not behind: evict() finds the
+                              // table record by it, straight after
+                              // flush() has had this line and no other
+                              // (46 us a chunk of 4,300 dying keys, PR 33)
+    i64 pane_base = 0;        // absolute pane index of the ring's pane 0
     i64 next_fire = 0;        // next window (lwid) to fire
+    i64 opened_max = -1;
+    i64 max_id = -1;
+    i64 staged_upto = -1;     // last window flush() staged for the key
+    int32_t queued = 0;       // fired windows of the key waiting in `ready`
+    // The pane-partial ring: pane j is absolute pane (pane_base + j), its
+    // combine partial and its tuple count; on CB lanes also the max
+    // tuple id seen in it and that tuple's stamp -- the CB
+    // result-timestamp lane (result ts = ts of the last tuple in the
+    // window extent, matching the host engine); TB windows' result ts is
+    // pure window arithmetic.  `len` panes long as a snapshot states it
+    // (ensure_pane()'s headroom included); the first `room` of them are
+    // stored, the others hold nothing yet.
+    int32_t len = 0;
+    int32_t room = 0;         // min(len, the storage's capacity)
+    bool live = false;        // the pool slot holds a key
+    bool indexed = false;     // listed in `due` under due_at
+    bool spilled = false;     // the ring lives in `out.block`
+    // -- line 1 --
+    union {
+        struct { double acc[RING_IN]; i64 cnt[RING_IN]; } in;
+        struct { char* block; i64 cap; } out;
+    };
+    // -- line 2 --
+    i64 due_at = -1;          // the window the key's listing that counts
+                              // is under (an earlier listing, left behind
+                              // when the anchor moved back, is passed over):
+                              // trigger()'s, which reads all three lines
     i64 anchor = 0;           // first window that can ever fire for this
                               // key (set from the first tuple; windows
                               // before it are never emitted, matching
                               // the on-demand window creation of the
                               // scalar path, win_seq.hpp:417-428)
-    i64 opened_max = -1;
-    i64 max_id = -1;
     i64 arrivals = 0;         // renumber lane: running arrival count
                               // (ids implicit; such keys are never evicted)
-    i64 staged_upto = -1;     // last window flush() staged for the key
-    int32_t queued = 0;       // fired windows of the key waiting in `ready`
-    bool live = false;        // the pool slot holds a key
-    bool indexed = false;     // listed in `due` under due_at
-    i64 due_at = -1;          // the window the key's listing that counts
-                              // is under (an earlier listing, left behind
-                              // when the anchor moved back, is passed over)
+    char* spare = nullptr;    // the block a former key of the slot left:
+    i64 spare_cap = 0;        // a reused slot allocates nothing
 
-    // back to a fresh slot; the rings keep their capacity, so a key
-    // opened in a reused slot allocates nothing
+    KeyState() : out{nullptr, 0} {}
+
+    // back to a fresh slot; a block stays with the slot
     void reset() {
-        pacc.clear();
-        pcnt.clear();
-        plid.clear();
-        plts.clear();
+        if (spilled) {
+            if (out.cap > spare_cap) {
+                ::operator delete(spare);
+                spare = out.block;
+                spare_cap = out.cap;
+            } else {
+                ::operator delete(out.block);
+            }
+        }
         pane_base = next_fire = anchor = arrivals = 0;
         opened_max = max_id = staged_upto = due_at = -1;
-        queued = 0;
-        live = indexed = false;
+        queued = len = room = 0;
+        live = indexed = spilled = false;
     }
+};
+static_assert(sizeof(KeyState) == 192 && offsetof(KeyState, in) == 64
+              && offsetof(KeyState, due_at) == 128, "KeyState's three lines");
+
+// a ring's lanes, wherever it lives (Engine::ring)
+struct Ring {
+    double* acc;
+    i64* cnt;
+    i64* lid;                 // CB lanes only
+    i64* lts;
 };
 
 struct Desc {
@@ -133,9 +188,22 @@ struct Engine {
     int pshift;               // log2(pane) when pane is a power of two
     double neutral;
     // key states live in a pool whose slots are reused; the table
-    // below maps key -> slot
-    std::deque<KeyState> pool;
+    // below maps key -> slot.  The pool is blocks of POOL_BLOCK states
+    // that never move (d_state holds addresses for the length of a
+    // call, `takes` and `due` hold slots): a slot's address is its
+    // block's base and a multiple of the state's size, with the few
+    // bases in one small array
+    static constexpr int POOL_SHIFT = 12;
+    static constexpr int32_t POOL_BLOCK = 1 << POOL_SHIFT;
+    std::vector<KeyState*> blocks;
+    int32_t n_slots = 0;
     std::vector<int32_t> free_slots;
+    inline KeyState& state(int32_t slot) {
+        return blocks[slot >> POOL_SHIFT][slot & (POOL_BLOCK - 1)];
+    }
+    inline const KeyState& state(int32_t slot) const {
+        return blocks[slot >> POOL_SHIFT][slot & (POOL_BLOCK - 1)];
+    }
     i64 n_live = 0;
     std::vector<Desc> ready;  // fired, unstaged; consumed from ready_head
     std::size_t ready_head = 0;
@@ -170,6 +238,34 @@ struct Engine {
     // was made: folded with their key's other tuples of the call in one
     // combine, or one by one (wfn_engine_stats)
     i64 folded_by_key = 0, folded_singly = 0;
+    // WHERE A CALL RUNS AHEAD OF ITSELF.  On a table that has outgrown
+    // AHEAD_TABLE_BYTES (of the order of a core's second-level cache:
+    // some thousand live keys) a key's first touch in a call is a trip
+    // to memory, and the walks fetch what they will need before they
+    // need it: gather() the table records of its next block of tuples,
+    // dense_of() the key state at the key's first tuple, the visits by
+    // slot (prepare / settle, trigger()'s due list) the state some keys
+    // ahead (flush()'s visits and the eviction's probes were tried and
+    // gave nothing: a firing's keys are the ones trigger() has just
+    // visited).  On a smaller table everything a call touches is in
+    // the caches and the walks run as they are written: THE TUPLE WALK
+    // IS BOUND BY WHAT IT DOES A TUPLE (two micro-ops more a tuple cost
+    // the 111-key cell 6-7 %, PR 32), so running ahead is a template
+    // parameter of gather() like SEL, and what decides is the table's
+    // size, which is in front of the code: no option.  Counted: the keys
+    // prepare() visited over all calls, those of them in a call that
+    // ran ahead, and the rings that left their key state (KeyState).
+    static constexpr std::size_t AHEAD_TABLE_BYTES = 256u << 10;
+    i64 key_touches = 0, walked_ahead = 0, rings_spilled = 0;
+    // gather()'s own fetches cost every tuple a few micro-ops, and pay
+    // only where first touches land on records the walk has not just
+    // been near: keys the call did not open (a new key's record lies
+    // beside its predecessor's, home_of(), which the hardware follows).
+    // So the probe runs ahead in a call that follows one with more than
+    // one such touch a block of tuples: stragglers to quiet keys, keys
+    // drawn at random from a large population; not a population that
+    // only advances
+    bool probes_ahead = false;
     // scatter-ingest machinery: an open-addressing table (linear
     // probing from home_of) of one record a key: its pool slot and,
     // under the stamp of the call that wrote it, its index into the
@@ -229,6 +325,43 @@ struct Engine {
         tab.assign(m, Entry{0, -1, -1, 0});
     }
 
+    inline bool runs_ahead() const {
+        return tab.size() * sizeof(Entry) > AHEAD_TABLE_BYTES;
+    }
+
+    static inline void fetch(const void* p) { __builtin_prefetch(p); }
+    // a key state's hot line and its ring's
+    static inline void fetch_state(const KeyState* st) {
+        fetch(st);
+        fetch(reinterpret_cast<const char*>(st) + 64);
+    }
+    // the panes round relative pane `p` of a ring that left its key
+    // state (the state's own lines are at hand)
+    inline void fetch_ring(const KeyState& st, i64 p) const {
+        if (!st.spilled || st.room == 0) return;
+        p = p < 0 ? 0 : p < st.room ? p : st.room - 1;
+        const Ring r = ring(st);
+        fetch(r.acc + p);
+        fetch(r.cnt + p);
+    }
+
+    ~Engine() { drop_pool(); }
+    Engine(const Engine&) = delete;
+    Engine& operator=(const Engine&) = delete;
+
+    void drop_pool() {
+        for (int32_t s = 0; s < n_slots; ++s) {
+            KeyState& st = state(s);
+            if (st.spilled) ::operator delete(st.out.block);
+            ::operator delete(st.spare);
+        }
+        for (KeyState* b : blocks) delete[] b;
+        blocks.clear();
+        free_slots.clear();
+        n_slots = 0;
+        n_live = 0;
+    }
+
     inline i64 pane_of(i64 id) const {
         return pshift >= 0 ? id >> pshift : id / pane;
     }
@@ -267,10 +400,11 @@ struct Engine {
             s = free_slots.back();
             free_slots.pop_back();
         } else {
-            s = (int32_t)pool.size();
-            pool.emplace_back();
+            s = n_slots++;
+            if ((std::size_t)(s >> POOL_SHIFT) == blocks.size())
+                blocks.push_back(new KeyState[POOL_BLOCK]);
         }
-        KeyState& st = pool[s];
+        KeyState& st = state(s);
         st.key = key;
         st.live = true;
         ++keys_opened;
@@ -305,7 +439,7 @@ struct Engine {
     // forget a key: its table entry goes by a backward shift (no
     // tombstone), its slot goes back to the pool
     void evict(int32_t slot) {
-        KeyState& st = pool[slot];
+        KeyState& st = state(slot);
         const std::size_t mask = tab.size() - 1;
         std::size_t i = home_of(st.key);
         while (tab[i].slot != slot) i = (i + 1) & mask;
@@ -342,39 +476,147 @@ struct Engine {
             if (opened) opened_now.push_back(e.dense);
             parts.push_back(Part{0, INT64_MAX, INT64_MIN, neutral});
             d_slot.push_back(e.slot);
-            d_state.push_back(&pool[e.slot]);
+            KeyState* st = &state(e.slot);
+            d_state.push_back(st);
+            fetch_state(st);  // prepare() is a walk away
         }
         return e.dense;
     }
 
+    // -- the ring ----------------------------------------------------------
+    // panes a key state of this engine holds itself
+    inline i64 ring_in() const { return is_tb ? RING_IN : 0; }
+
+    // the lanes of a block of `cap` panes, one behind the other
+    static inline Ring lanes_of(char* block, i64 cap) {
+        double* acc = reinterpret_cast<double*>(block);
+        i64* cnt = reinterpret_cast<i64*>(acc + cap);
+        return Ring{acc, cnt, cnt + cap, cnt + 2 * cap};
+    }
+    inline Ring ring(KeyState& st) const {
+        if (!st.spilled) return Ring{st.in.acc, st.in.cnt, nullptr, nullptr};
+        return lanes_of(st.out.block, st.out.cap);
+    }
+    inline Ring ring(const KeyState& st) const {
+        return ring(const_cast<KeyState&>(st));
+    }
+
+    // panes [from, to) of the ring hold nothing
+    inline void blank(const Ring& r, i64 from, i64 to) const {
+        if (from >= to) return;
+        std::fill(r.acc + from, r.acc + to, neutral);
+        std::fill(r.cnt + from, r.cnt + to, (i64)0);
+        if (!is_tb) {
+            std::fill(r.lid + from, r.lid + to, INT64_MIN);
+            std::fill(r.lts + from, r.lts + to, (i64)0);
+        }
+    }
+
+    // the ring's stored panes moved by `by` places inside its storage
+    // (the caller has made room)
+    inline void shift(const Ring& r, i64 from, i64 n, i64 by) const {
+        if (n <= 0) return;
+        std::memmove(r.acc + from + by, r.acc + from, n * sizeof(double));
+        std::memmove(r.cnt + from + by, r.cnt + from, n * sizeof(i64));
+        if (!is_tb) {
+            std::memmove(r.lid + from + by, r.lid + from, n * sizeof(i64));
+            std::memmove(r.lts + from + by, r.lts + from, n * sizeof(i64));
+        }
+    }
+
+    // The ring into a block of `cap` panes (its `room` stored panes
+    // with it): out of the key state, or out of a block it outgrew.
+    // The slot's spare block is taken where it is large enough.
+    void spill(KeyState& st, i64 cap) {
+        const Ring old = ring(st);
+        const i64 lanes = is_tb ? 2 : 4;
+        char* block;
+        if (st.spare_cap >= cap) {
+            block = st.spare;
+            cap = st.spare_cap;
+            st.spare = nullptr;
+            st.spare_cap = 0;
+        } else {
+            block = static_cast<char*>(
+                ::operator new((std::size_t)(cap * lanes) * sizeof(i64)));
+        }
+        const Ring now = lanes_of(block, cap);
+        if (st.room > 0) {
+            std::memcpy(now.acc, old.acc, st.room * sizeof(double));
+            std::memcpy(now.cnt, old.cnt, st.room * sizeof(i64));
+            if (!is_tb) {
+                std::memcpy(now.lid, old.lid, st.room * sizeof(i64));
+                std::memcpy(now.lts, old.lts, st.room * sizeof(i64));
+            }
+        }
+        if (st.spilled)
+            ::operator delete(st.out.block);
+        else
+            ++rings_spilled;
+        st.spilled = true;
+        st.out.block = block;
+        st.out.cap = cap;
+    }
+
+    inline i64 capacity(const KeyState& st) const {
+        return st.spilled ? st.out.cap : ring_in();
+    }
+
+    // a ring's new length, where a key state can state it
+    static inline i64 checked(i64 len) {
+        if (len > INT32_MAX) throw std::bad_alloc();
+        return len;
+    }
+
+    // the ring's length set to `len` panes, the stored ones among them
+    // blank from `used` on
+    inline void stretch(KeyState& st, i64 len, i64 used) {
+        st.len = (int32_t)len;
+        const i64 room = std::min(len, capacity(st));
+        blank(ring(st), used, room);
+        st.room = (int32_t)room;
+    }
+
     // grow the pane ring so relative pane p_rel is addressable
     inline void ensure_pane(KeyState& st, i64 p_rel) {
-        if (p_rel < (i64)st.pacc.size()) return;
+        if (p_rel < st.room) return;
+        grow_ring(st, p_rel);
+    }
+
+    void grow_ring(KeyState& st, i64 p_rel) {
         // geometric headroom: rings grow a few panes per batch; the
         // +8 keeps amortized growth O(1) without doubling a large ring
-        i64 n = p_rel + 1 + std::min<i64>(p_rel / 2 + 8, 4096);
-        st.pacc.resize(n, neutral);
-        st.pcnt.resize(n, 0);
-        if (!is_tb) {
-            st.plid.resize(n, INT64_MIN);
-            st.plts.resize(n, 0);
+        i64 len = st.len;
+        if (p_rel >= len)
+            len = checked(p_rel + 1 + std::min<i64>(p_rel / 2 + 8, 4096));
+        if (p_rel >= capacity(st)) spill(st, len);
+        stretch(st, len, st.room);
+    }
+
+    // CB lanes: the pane's last tuple (by id) and its stamp
+    inline void stamp_last(KeyState& st, i64 p_rel, i64 id, i64 ts) {
+        const Ring r = ring(st);
+        if (id >= r.lid[p_rel]) {
+            r.lid[p_rel] = id;
+            r.lts[p_rel] = ts;
         }
     }
 
     inline void fold(KeyState& st, i64 p_rel, double v) {
+        const Ring r = ring(st);
         switch (kind) {
-            case Kind::COUNT: st.pacc[p_rel] += 1.0; break;
+            case Kind::COUNT: r.acc[p_rel] += 1.0; break;
             case Kind::MAX:
-                if (v > st.pacc[p_rel]) st.pacc[p_rel] = v;
+                if (v > r.acc[p_rel]) r.acc[p_rel] = v;
                 break;
             case Kind::MIN:
-                if (v < st.pacc[p_rel]) st.pacc[p_rel] = v;
+                if (v < r.acc[p_rel]) r.acc[p_rel] = v;
                 break;
             case Kind::SUM:
             case Kind::MEAN:
-            default: st.pacc[p_rel] += v; break;
+            default: r.acc[p_rel] += v; break;
         }
-        ++st.pcnt[p_rel];
+        ++r.cnt[p_rel];
     }
 
     // -- firing -----------------------------------------------------------
@@ -384,9 +626,10 @@ struct Engine {
     // one pass over their panes.
     inline bool holds_tuple(const KeyState& st, i64 start, i64& q) const {
         i64 ps = pane_of(start) - st.pane_base;
-        i64 pe = std::min<i64>(ps + ppw, (i64)st.pcnt.size());
+        i64 pe = std::min<i64>(ps + ppw, st.room);
+        const i64* cnt = ring(st).cnt;
         if (q < ps) q = ps < 0 ? 0 : ps;
-        while (q < pe && st.pcnt[q] == 0) ++q;
+        while (q < pe && cnt[q] == 0) ++q;
         return q < pe;
     }
 
@@ -427,8 +670,25 @@ struct Engine {
             // taken out first: a key that still has a window opened is
             // listed anew, under a later one
             const auto node = due.extract(due.begin());
-            for (int32_t slot : node.mapped()) {
-                KeyState& st = pool[slot];
+            const std::vector<int32_t>& slots = node.mapped();
+            const std::size_t ns = slots.size();
+            const bool ahead = runs_ahead();
+            for (std::size_t i = 0; i < ns; ++i) {
+                const int32_t slot = slots[i];
+                if (ahead) {
+                    // all three lines: the listing is in the last
+                    if (i + 12 < ns) {
+                        const KeyState* nx = &state(slots[i + 12]);
+                        fetch_state(nx);
+                        fetch(&nx->due_at);
+                    }
+                    if (i + 4 < ns) {
+                        const KeyState& nx = state(slots[i + 4]);
+                        fetch_ring(nx, pane_of(nx.next_fire * slide)
+                                   - nx.pane_base);
+                    }
+                }
+                KeyState& st = state(slot);
                 // a listing its key left behind (move_back), or that a
                 // later one of the same window has served
                 if (!st.indexed || st.due_at != node.key()) continue;
@@ -479,8 +739,17 @@ struct Engine {
         if (w < st.anchor) st.anchor = w;
         const i64 k = st.pane_base - pane_of(w * slide);
         if (k > 0) {
-            st.pacc.insert(st.pacc.begin(), k, neutral);
-            st.pcnt.insert(st.pcnt.begin(), k, 0);
+            // the stored panes that hold a tuple move up by k; where
+            // they then outgrow the storage the ring leaves for a block
+            i64 used = st.room;
+            const i64* cnt = ring(st).cnt;
+            while (used > 0 && cnt[used - 1] == 0) --used;
+            const i64 len = checked((i64)st.len + k);
+            if (used + k > capacity(st)) spill(st, len);
+            const Ring r = ring(st);
+            shift(r, 0, used, k);
+            blank(r, 0, k);
+            stretch(st, len, used + k);
             st.pane_base -= k;
         }
         st.indexed = false;
@@ -565,13 +834,14 @@ struct Engine {
             ++n_single;
             return;
         }
-        double& acc = st.pacc[hi_rel];
+        const Ring r = ring(st);
+        double& acc = r.acc[hi_rel];
         switch (kind) {
             case Kind::COUNT: acc += (double)pt.count; break;
             case Kind::MAX: if (pt.ext > acc) acc = pt.ext; break;
             default: if (pt.ext < acc) acc = pt.ext; break;  // MIN
         }
-        st.pcnt[hi_rel] += pt.count;
+        r.cnt[hi_rel] += pt.count;
         folded_by_key += pt.count;
     }
 
@@ -608,32 +878,55 @@ struct Engine {
     // partial brought up to date.  IDS: the ids are read (renumbered
     // ids are implicit); EXT: 1 keeps the largest value, 2 the
     // smallest, 0 reads no value.
-    template <bool IDS, int EXT, bool SEL, typename TV>
+    // AHEAD (the table has outgrown the caches, "WHERE A CALL RUNS
+    // AHEAD OF ITSELF"): while one block of tuples is walked the table
+    // records of the next are fetched, block-wise and not a tuple; a
+    // call that does not run ahead is the plain loop.
+    template <bool IDS, int EXT, bool SEL, bool AHEAD, typename TV>
     void gather(const i64* bkeys, const i64* ids, const TV* vals, i64 n,
                 Sel s) {
         const bool ck = SEL && !(s.through & SEL_KEYS);   // compact columns
         const bool ci = SEL && !(s.through & SEL_IDS);
         const bool cv = SEL && !(s.through & SEL_VALS);
-        for (i64 j = 0; j < n; ++j) {
-            const i64 r = SEL ? s.rows[j] : j;
-            const int32_t d = dense_of(bkeys[ck ? j : r]);
-            slot_of[j] = d;
-            Part& pt = parts[d];
-            ++pt.count;
-            if (IDS) {
-                const i64 id = ids[ci ? j : r];
-                if (id < pt.lo) pt.lo = id;
-                if (id > pt.hi) pt.hi = id;
+        auto walk = [&](i64 from, i64 to) {
+            for (i64 j = from; j < to; ++j) {
+                const i64 r = SEL ? s.rows[j] : j;
+                const int32_t d = dense_of(bkeys[ck ? j : r]);
+                slot_of[j] = d;
+                Part& pt = parts[d];
+                ++pt.count;
+                if (IDS) {
+                    const i64 id = ids[ci ? j : r];
+                    if (id < pt.lo) pt.lo = id;
+                    if (id > pt.hi) pt.hi = id;
+                }
+                if (EXT == 1) {
+                    const double v = (double)vals[cv ? j : r];
+                    if (v > pt.ext) pt.ext = v;
+                } else if (EXT == 2) {
+                    const double v = (double)vals[cv ? j : r];
+                    if (v < pt.ext) pt.ext = v;
+                }
             }
-            if (EXT == 1) {
-                const double v = (double)vals[cv ? j : r];
-                if (v > pt.ext) pt.ext = v;
-            } else if (EXT == 2) {
-                const double v = (double)vals[cv ? j : r];
-                if (v < pt.ext) pt.ext = v;
-            }
+        };
+        if (!AHEAD || !probes_ahead) {
+            walk(0, n);
+            return;
+        }
+        // the table may grow under the walk: a record fetched from the
+        // old one is a fetch wasted, no more
+        auto fetch_records = [&](i64 from, i64 to) {
+            for (i64 j = from; j < to; ++j)
+                fetch(&tab[home_of(bkeys[ck || !SEL ? j : s.rows[j]])]);
+        };
+        fetch_records(0, std::min<i64>(AHEAD_BLOCK, n));
+        for (i64 b = 0; b < n; b += AHEAD_BLOCK) {
+            const i64 e = std::min<i64>(b + AHEAD_BLOCK, n);
+            fetch_records(e, std::min<i64>(e + AHEAD_BLOCK, n));
+            walk(b, e);
         }
     }
+    static constexpr i64 AHEAD_BLOCK = 64;
 
     // Which of the call's tuples lie behind the stream time as they
     // come (the largest stamp of every tuple before them): counted, and
@@ -673,6 +966,15 @@ struct Engine {
     template <bool SEL, typename TV>
     void ingest_batch(const i64* bkeys, const i64* ids, const i64* tss,
                       const TV* vals, i64 n, Sel s = Sel{nullptr, 0}) {
+        if (runs_ahead())
+            ingest_walks<SEL, true>(bkeys, ids, tss, vals, n, s);
+        else
+            ingest_walks<SEL, false>(bkeys, ids, tss, vals, n, s);
+    }
+
+    template <bool SEL, bool AHEAD, typename TV>
+    void ingest_walks(const i64* bkeys, const i64* ids, const i64* tss,
+                      const TV* vals, i64 n, Sel s) {
         ++call_id;
         parts.clear();
         d_slot.clear();
@@ -680,13 +982,13 @@ struct Engine {
         opened_now.clear();
         if ((i64)slot_of.size() < n) slot_of.resize(n);
         if (renumber)
-            gather<false, 0, SEL>(bkeys, ids, vals, n, s);
+            gather<false, 0, SEL, AHEAD>(bkeys, ids, vals, n, s);
         else if (by_key_lane && kind == Kind::MAX)
-            gather<true, 1, SEL>(bkeys, ids, vals, n, s);
+            gather<true, 1, SEL, AHEAD>(bkeys, ids, vals, n, s);
         else if (by_key_lane && kind == Kind::MIN)
-            gather<true, 2, SEL>(bkeys, ids, vals, n, s);
+            gather<true, 2, SEL, AHEAD>(bkeys, ids, vals, n, s);
         else
-            gather<true, 0, SEL>(bkeys, ids, vals, n, s);
+            gather<true, 0, SEL, AHEAD>(bkeys, ids, vals, n, s);
         if (stream_rule) {
             if (SEL && (s.through & SEL_IDS))
                 note_late<true>(ids, s.rows, n);
@@ -697,8 +999,24 @@ struct Engine {
         d_accept.resize(nd);
         d_single.resize(nd);
         n_single = 0;
+        key_touches += (i64)nd;
+        if (AHEAD) walked_ahead += (i64)nd;
+        // the visit by slot: a key's state some keys ahead (dense_of()
+        // fetched it a walk ago; it may have left the inner caches
+        // since), a ring outside its key state a few keys ahead
+        auto ahead_of = [&](std::size_t d) {
+            if (!AHEAD) return;
+            if (d + 12 < nd) fetch_state(d_state[d + 12]);
+            if (d + 4 < nd) {
+                const KeyState& nx = *d_state[d + 4];
+                fetch_ring(nx, pane_of(parts[d + 4].hi) - nx.pane_base);
+            }
+        };
         if (opened_now.empty()) {
-            for (std::size_t d = 0; d < nd; ++d) prepare(d);
+            for (std::size_t d = 0; d < nd; ++d) {
+                ahead_of(d);
+                prepare(d);
+            }
         } else {
             // `open`: the keys this call created, timed apart (their
             // anchor and their ring); the others after them
@@ -707,15 +1025,19 @@ struct Engine {
             open_ns += now_ns() - t0;
             std::size_t o = 0;  // opened_now is ascending
             for (std::size_t d = 0; d < nd; ++d) {
+                ahead_of(d);
                 if (o < opened_now.size() && (std::size_t)opened_now[o] == d)
                     ++o;
                 else
                     prepare(d);
             }
         }
+        probes_ahead = (i64)(nd - opened_now.size()) * AHEAD_BLOCK > n;
         if (n_single) fold_singly<SEL>(ids, tss, vals, n, s);
-        for (std::size_t d = 0; d < nd; ++d)
+        for (std::size_t d = 0; d < nd; ++d) {
+            if (AHEAD && d + 8 < nd) fetch(d_state[d + 8]);
             settle(*d_state[d], d_slot[d], parts[d].hi);
+        }
         if (stream_rule) trigger();
     }
 
@@ -764,10 +1086,7 @@ struct Engine {
             if (hopping && nn > st.opened_max) st.opened_max = nn;
             fold(st, p, (double)vals[cv ? j : r]);
             ++folded;
-            if (!is_tb && id >= st.plid[p]) {
-                st.plid[p] = id;
-                st.plts[p] = tss[ct ? j : r];
-            }
+            if (!is_tb) stamp_last(st, p, id, tss[ct ? j : r]);
         }
         folded_singly += folded;
     }
@@ -806,7 +1125,7 @@ struct Engine {
             if (e0 >= endE) continue;
             bool opened;
             const int32_t slot = locate(k, opened).slot;
-            KeyState& st = pool[slot];
+            KeyState& st = state(slot);
             const i64 id0 = e0 / K;
             const i64 cnt = (endE - e0 + K - 1) / K;
             if (st.max_id < 0 && !mask) {
@@ -854,10 +1173,7 @@ struct Engine {
                 if (hopping && nn > st.opened_max) st.opened_max = nn;
                 fold(st, p, v);
                 ++folded;
-                if (!is_tb && id >= st.plid[p]) {
-                    st.plid[p] = id;
-                    st.plts[p] = id;  // the law sets ts = id
-                }
+                if (!is_tb) stamp_last(st, p, id, id);  // the law: ts = id
             }
             folded_singly += folded;
             settle(st, slot, last_ok);
@@ -869,11 +1185,11 @@ struct Engine {
     // (panes outside it hold no tuples by construction)
     inline double pane_at(const KeyState& st, i64 p_abs) const {
         i64 r = p_abs - st.pane_base;
-        return (r >= 0 && r < (i64)st.pacc.size()) ? st.pacc[r] : neutral;
+        return (r >= 0 && r < st.room) ? ring(st).acc[r] : neutral;
     }
     inline i64 cnt_at(const KeyState& st, i64 p_abs) const {
         i64 r = p_abs - st.pane_base;
-        return (r >= 0 && r < (i64)st.pcnt.size()) ? st.pcnt[r] : 0;
+        return (r >= 0 && r < st.room) ? ring(st).cnt[r] : 0;
     }
 
     inline i64 n_ready() const { return (i64)(ready.size() - ready_head); }
@@ -903,15 +1219,11 @@ struct Engine {
         i64 keep_from = (st.queued > 0 ? st.staged_upto + 1
                                        : st.next_fire) * slide;
         i64 cut = pane_of(keep_from) - st.pane_base;
-        i64 sz = (i64)st.pacc.size();
         if (cut <= 0) return;
-        if (cut > sz) cut = sz;
-        st.pacc.erase(st.pacc.begin(), st.pacc.begin() + cut);
-        st.pcnt.erase(st.pcnt.begin(), st.pcnt.begin() + cut);
-        if (!is_tb) {
-            st.plid.erase(st.plid.begin(), st.plid.begin() + cut);
-            st.plts.erase(st.plts.begin(), st.plts.begin() + cut);
-        }
+        if (cut > st.len) cut = st.len;
+        const i64 kept = std::max<i64>(st.room - cut, 0);
+        shift(ring(st), cut, kept, -cut);
+        stretch(st, st.len - cut, kept);
         st.pane_base += cut;
     }
 
@@ -926,7 +1238,7 @@ struct Engine {
         const Desc* taken = ready.data() + ready_head;
         ++flush_id;
         f_touched.clear();
-        if (takes.size() < pool.size()) takes.resize(pool.size());
+        if ((i64)takes.size() < n_slots) takes.resize(n_slots);
         // the extent of each key's taken windows (a key's windows were
         // appended in order, but batches interleave keys)
         i64 n_vals = 0;
@@ -955,7 +1267,7 @@ struct Engine {
         f_dead.clear();
         i64 off = 0, pf = 0;
         for (int32_t slot : f_touched) {
-            KeyState& st = pool[slot];
+            KeyState& st = state(slot);
             Take& t = takes[slot];
             const i64 p0 = pane_of(t.lo);
             const i64 n_panes = (t.hi - t.lo) / pane;
@@ -1004,12 +1316,11 @@ struct Engine {
                 // CB: result ts = ts of the max-id tuple in the extent,
                 // which lives in the last non-empty pane of the range
                 // (binary search on the span's count prefix)
-                const KeyState& st = pool[ds.slot];
+                const KeyState& st = state(ds.slot);
                 i64 q = std::lower_bound(pfx + ps, pfx + pe + 1, pfx[pe])
                     - pfx;
                 i64 r = pane_of(t.lo) + (q - 1) - st.pane_base;
-                st_rts[d] = (r >= 0 && r < (i64)st.plts.size())
-                    ? st.plts[r] : 0;
+                st_rts[d] = (r >= 0 && r < st.room) ? ring(st).lts[r] : 0;
             }
         }
         ready_head += take;
@@ -1021,7 +1332,7 @@ struct Engine {
             ready_head = 0;
         }
         if (!is_tb)  // the CB lane read the rings' stamps just above
-            for (int32_t slot : f_touched) retire(pool[slot], slot);
+            for (int32_t slot : f_touched) retire(state(slot), slot);
         if (!f_dead.empty()) {
             const i64 t0 = now_ns();
             for (int32_t slot : f_dead) evict(slot);
@@ -1032,10 +1343,10 @@ struct Engine {
 
     void eos() {
         const i64 t0 = now_ns();
-        for (std::size_t s = 0; s < pool.size(); ++s) {
-            KeyState& st = pool[s];
+        for (int32_t s = 0; s < n_slots; ++s) {
+            KeyState& st = state(s);
             if (!st.live) continue;
-            fire_key(st, (int32_t)s, INT64_MAX);
+            fire_key(st, s, INT64_MAX);
             st.indexed = false;
         }
         due.clear();
@@ -1043,9 +1354,7 @@ struct Engine {
     }
 
     void clear() {
-        pool.clear();
-        free_slots.clear();
-        n_live = 0;
+        drop_pool();
         ready.clear();
         ready_head = 0;
         due.clear();
@@ -1075,6 +1384,16 @@ struct Engine {
         const unsigned char* p =
             reinterpret_cast<const unsigned char*>(v.data());
         b.insert(b.end(), p, p + v.size() * sizeof(T));
+    }
+    // a ring's lane as a vector of `len`: `room` stored, the rest `fill`
+    template <typename T>
+    static void put_lane(std::vector<unsigned char>& b, const T* lane,
+                         i64 room, i64 len, T fill) {
+        put<i64>(b, len);
+        room = std::min(room, len);
+        const unsigned char* p = reinterpret_cast<const unsigned char*>(lane);
+        b.insert(b.end(), p, p + room * sizeof(T));
+        for (i64 j = room; j < len; ++j) put(b, fill);
     }
     template <typename T>
     static bool get(const unsigned char*& p, const unsigned char* end,
@@ -1110,17 +1429,21 @@ struct Engine {
         put(b, keys_opened); put(b, keys_evicted);
         put(b, keys_live_peak); put(b, windows_fired);
         put(b, n_live);
-        for (const KeyState& st : pool) {
+        for (int32_t s = 0; s < n_slots; ++s) {
+            const KeyState& st = state(s);
             if (!st.live) continue;
             put(b, st.key);
             put(b, st.next_fire); put(b, st.anchor);
             put(b, st.opened_max); put(b, st.max_id);
             put(b, st.pane_base); put(b, st.arrivals);
             put(b, st.staged_upto);
-            put_vec(b, st.pacc);
-            put_vec(b, st.pcnt);
-            put_vec(b, st.plid);
-            put_vec(b, st.plts);
+            // the ring, a lane after the other, each `len` long: the
+            // stored panes and the blank ones behind them
+            const Ring r = ring(st);
+            put_lane(b, r.acc, st.room, st.len, neutral);
+            put_lane(b, r.cnt, st.room, st.len, (i64)0);
+            put_lane(b, r.lid, st.room, is_tb ? 0 : st.len, (i64)INT64_MIN);
+            put_lane(b, r.lts, st.room, is_tb ? 0 : st.len, (i64)0);
         }
         put(b, n_ready());
         for (std::size_t i = ready_head; i < ready.size(); ++i) {
@@ -1155,22 +1478,47 @@ struct Engine {
             bool fresh;
             const int32_t slot = locate(key, fresh).slot;
             if (!fresh) return false;  // a key twice
-            KeyState& st = pool[slot];
+            KeyState& st = state(slot);
+            std::vector<double> acc;
+            std::vector<i64> cnt, lid, lts;
             if (!get(p, end, st.next_fire) || !get(p, end, st.anchor)
                 || !get(p, end, st.opened_max) || !get(p, end, st.max_id)
                 || !get(p, end, st.pane_base) || !get(p, end, st.arrivals)
                 || !get(p, end, st.staged_upto)
-                || !get_vec(p, end, st.pacc) || !get_vec(p, end, st.pcnt)
-                || !get_vec(p, end, st.plid) || !get_vec(p, end, st.plts))
+                || !get_vec(p, end, acc) || !get_vec(p, end, cnt)
+                || !get_vec(p, end, lid) || !get_vec(p, end, lts))
                 return false;
-            if (st.pcnt.size() != st.pacc.size()
-                || st.plid.size() != st.plts.size())
+            if (cnt.size() != acc.size() || lid.size() != lts.size()
+                || acc.size() > (std::size_t)INT32_MAX)
                 return false;
-            // CB engines index plid/plts in lockstep with pacc on every
-            // ingest; a snapshot with short ts-lane vectors would pass
-            // the pairwise checks above and then write out of bounds
-            if (!is_tb && st.plid.size() != st.pacc.size())
+            // CB engines index the id and stamp lanes in lockstep with
+            // the accumulators on every ingest; a snapshot with short
+            // ts-lane vectors would pass the pairwise checks above and
+            // then write out of bounds (a TB engine keeps no such lanes
+            // and reads none)
+            if (!is_tb && lid.size() != acc.size())
                 return false;
+            // the ring stays in the key state where what it holds fits
+            // (the blank panes behind need no storage)
+            const i64 len = (i64)acc.size();
+            i64 used = len;
+            while (used > 0 && cnt[used - 1] == 0
+                   && std::memcmp(&acc[used - 1], &neutral, sizeof(double))
+                       == 0
+                   && (is_tb || (lid[used - 1] == INT64_MIN
+                                 && lts[used - 1] == 0)))
+                --used;
+            if (used > ring_in()) spill(st, len);
+            const i64 room = std::min(len, capacity(st));
+            const Ring r = ring(st);
+            std::copy(acc.begin(), acc.begin() + room, r.acc);
+            std::copy(cnt.begin(), cnt.begin() + room, r.cnt);
+            if (!is_tb) {
+                std::copy(lid.begin(), lid.begin() + room, r.lid);
+                std::copy(lts.begin(), lts.begin() + room, r.lts);
+            }
+            st.len = (int32_t)len;
+            st.room = (int32_t)room;
             if (stream_rule) index_key(st, slot);
         }
         // locate() counted the restored keys as opened: the snapshot's
@@ -1190,7 +1538,7 @@ struct Engine {
                 h = (h + 1) & mask;
             if (tab[h].slot < 0) return false;
             ds.slot = tab[h].slot;
-            ++pool[ds.slot].queued;
+            ++state(ds.slot).queued;
             ready.push_back(ds);
         }
         return p == end;
@@ -1289,14 +1637,17 @@ i64 wfn_engine_ignored(void* ep) {
 }
 
 // What key churn costs, how many keys there are, how the fold went and
-// what disorder it met, into out[14]: nanoseconds spent creating key
+// what disorder it met, into out[17]: nanoseconds spent creating key
 // states and moving anchors back (open), finding and queueing fired
 // windows (trigger) and evicting (evict), since the engine was made; keys
 // opened, keys evicted, keys live now and at their peak, windows fired;
 // tuples folded with their key's others of the call in one combine,
 // tuples folded one by one; tuples accepted whose stamp lay behind the
 // stream time when they came, times a live key's anchor moved back,
-// tuples ignored; the stream time (-1 before the first stamp).
+// tuples ignored; the stream time (-1 before the first stamp); the keys
+// the per-key visit of the calls met, those of them in a call that ran
+// ahead of itself (Engine, "WHERE A CALL RUNS AHEAD OF ITSELF"), and the
+// rings that left their key state (KeyState).
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -1313,6 +1664,9 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[11] = e.anchors_moved;
     out[12] = e.ignored;
     out[13] = e.stream_time;
+    out[14] = e.key_touches;
+    out[15] = e.walked_ahead;
+    out[16] = e.rings_spilled;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }

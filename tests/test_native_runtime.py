@@ -369,3 +369,481 @@ def test_engine_deserialize_corruption_fuzz():
             e2.deserialize({"native": bytes(blob[:cut])})
         except Exception:
             pass
+
+
+# -- a key's state in one place (PR 33) ---------------------------------------
+#
+# The engine's key states moved (native/window_engine.cpp "A KEY STATE IN ONE
+# PLACE": a flat pool, a hot line, a short ring inside the key state, walks
+# that run ahead of themselves on a big table).  None of that may change a
+# staged byte, a count or a snapshot: every case below was pinned at the
+# commit before the move (1d0498c, PR 32), as a digest of everything that
+# engine staged in firing order (``test_fold_by_key.drive``) or as the bytes
+# of its snapshot, in ``tests/golden/key_state_pr32.json``; regenerate with
+# ``python tests/test_native_runtime.py`` in a checkout of the engine to be
+# trusted.
+
+import base64  # noqa: E402
+import hashlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+
+from test_fold_by_key import drive as drive_lane  # noqa: E402
+from test_fold_by_key import stream as churn_stream  # noqa: E402
+
+from windflow_tpu.runtime.native import NativeWindowEngine  # noqa: E402
+
+KS_GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "golden", "key_state_pr32.json")
+KS_WIN, KS_SLIDE = 32768, 16384     # 3,000 keys live: the table outgrows
+KS_DELAY = KS_WIN * 3 // 10         # the bound under which no call runs ahead
+KS_LAWS = {"live": 0, "ooo": KS_DELAY}
+
+
+def q5_law(n, delay=0, seed=3):
+    """``nexmark_q5_live``'s law at the tests' size, and with ``delay``
+    ``nexmark_q5_ooo``'s: arrival ``i`` is the bid created at ``e = i -
+    d`` (``d`` 0 nine times in ten, else uniform over 1..delay) on the
+    hot auction of its hundred or one of the 111 round the newest; an
+    auction lives some 1,700 bids.  Returns keys, stamps, values."""
+    rng = np.random.RandomState(seed)
+    i = np.arange(n, dtype=np.int64)
+    d = np.where(rng.rand(n) < 0.1, rng.randint(1, delay + 1, n), 0) \
+        if delay else 0
+    e = np.maximum(i - d, 0)
+    last = e * 3 // 46
+    cold = np.maximum(last - 100, 0) + rng.randint(0, 111, n)
+    keys = np.where(rng.rand(n) < 0.5, last // 100 * 100, cold) + 1000
+    return keys.astype(np.int64), e, rng.randint(1, 1000, n).astype(float)
+
+
+def plain_counts(keys, ts, win, slide):
+    """{(key, window): count} for every window that holds a tuple of the
+    key (``win`` a multiple of ``slide``): the plain recomputation."""
+    want = {}
+    pane = ts // slide
+    for back in range(win // slide):
+        w = pane - back
+        ok = w >= 0
+        kw, n = np.unique(np.stack([keys[ok], w[ok]]), axis=1,
+                          return_counts=True)
+        for k, x, c in zip(kw[0].tolist(), kw[1].tolist(), n.tolist()):
+            want[(k, x)] = want.get((k, x), 0.0) + c
+    return want
+
+
+def ks_lane(law, dense=False):
+    return (KS_WIN, KS_SLIDE, True, KS_LAWS[law], False, dense)
+
+
+def ks_cuts(case, n):
+    """Where the calls end.  ``grows_mid_call``: calls of 4,096 until
+    the table has outgrown the bound, then one of 100,000 that opens
+    6,500 keys, so the table grows in the middle of a call that runs
+    ahead; ``crosses_between_calls``: calls of 1,024, so the table
+    crosses the bound between two calls with hundreds either side."""
+    if case == "grows_mid_call":
+        head = list(range(4096, 40961, 4096))
+        return head + [140960] + list(range(145056, n, 4096)) + [n]
+    assert case == "crosses_between_calls"
+    return list(range(1024, n, 1024)) + [n]
+
+
+def ks_digest(eng, rows, digest):
+    s = eng.snapshot()
+    return hashlib.sha256(
+        f"{digest}:{eng.ignored()}:{s['keys_opened']}:{s['keys_evicted']}:"
+        f"{s['windows_fired']}:{s['late_accepted']}:{s['anchors_moved']}:"
+        f"{s['folded_by_key']}:{s['folded_singly']}:{len(rows)}"
+        .encode()).hexdigest()[:16]
+
+
+def ks_q5(law, kind, chunking, through=None, dense=False, n=200_000):
+    keys, ts, vals = q5_law(n, KS_LAWS[law])
+    if isinstance(chunking, str):
+        chunking = ks_cuts(chunking, n)
+    eng, rows, digest, _ = drive_lane(ks_lane(law, dense), kind, keys, ts,
+                                      vals, chunking, through=through)
+    return eng, rows, ks_digest(eng, rows, digest)
+
+
+def ks_long_ring(ppw, kind, tb):
+    """A ring of 16 or 64 panes a window (``slide`` 16), far longer than
+    a key state holds: three stream shapes under three chunkings."""
+    h = hashlib.sha256()
+    for shape in ("inorder", "disordered", "late"):
+        keys, ts, vals = churn_stream(shape, 3000)
+        for chunking in (7, 129, 1 << 30):
+            eng, rows, digest, _ = drive_lane(
+                (ppw * 16, 16, tb, 40 if tb else 0, False, False), kind,
+                keys, ts, vals, min(chunking, 3000))
+            h.update(ks_digest(eng, rows, digest).encode())
+    return h.hexdigest()[:16]
+
+
+def ks_reuse(kind):
+    """Slots evicted and reused by keys whose rings are of the other
+    sort: calls of 400 stamps (a key's tuples of one call span 12 panes
+    of 32: its ring leaves the key state) and calls of 20 (it stays) by
+    turns, over keys that live 700 stamps."""
+    keys, ts, vals = churn_stream("inorder", 9000)
+    cuts, at, wide = [], 0, True
+    while at < 9000:
+        end = min(at + 1500, 9000)
+        cuts += list(range(at + (400 if wide else 20), end,
+                           400 if wide else 20)) + [end]
+        at, wide = end, not wide
+    eng, rows, digest, _ = drive_lane((64, 32, True, 0, False, False), kind,
+                                      keys, ts, vals, cuts)
+    return eng, rows, ks_digest(eng, rows, digest)
+
+
+def ks_moved_back(kind="count"):
+    """A ring that leaves the key state *because* ``move_back`` grew it
+    at the front: key 7's first bid to arrive is its latest (pane 12),
+    the stragglers of three later calls lie 2, 4 and 9 panes before it,
+    none behind a window the stream has passed (``delay`` 320)."""
+    clock = 1_000_000
+    calls = [([clock, 7], [400, 400]), ([7], [330]), ([7, 7], [200, 210]),
+             ([7], [100]), ([clock, 7], [460, 420]), ([clock], [2000])]
+    keys = np.concatenate([np.asarray(k, np.int64) for k, _ in calls])
+    ts = np.concatenate([np.asarray(t, np.int64) for _, t in calls])
+    cuts = np.cumsum([len(k) for k, _ in calls]).tolist()
+    eng, rows, digest, _ = drive_lane(
+        (64, 32, True, 320, False, False), kind, keys, ts,
+        np.arange(1.0, len(ts) + 1), cuts)
+    return eng, rows, ks_digest(eng, rows, digest), keys, ts
+
+
+def ks_synth(kind, dense):
+    """``synth_ingest`` over 5,000 keys (a table of 65,536 records)."""
+    eng = NativeWindowEngine(64, 32, True, 0, kind=kind, dense=dense)
+    digest, n_rows = hashlib.sha256(), 0
+    for lo in range(0, 1_000_000, 99_999):
+        eng.synth_ingest(lo, min(99_999, 1_000_000 - lo), 5000, 97, 0.5, 1.0)
+        if lo > 800_000:
+            eng.eos()
+        while True:
+            out = eng.flush(1 << 30)
+            if out is None:
+                break
+            n_rows += len(out[1])
+            for a in (out[0]["value"], *out[1:6]):
+                digest.update(np.ascontiguousarray(a).tobytes())
+    return ks_digest(eng, range(n_rows), digest.hexdigest())
+
+
+# name -> (lane, kind, stream, events before the snapshot, flush cap):
+# what a snapshot has to carry on each lane, fired windows still queued
+# (a partial take) and rings inside and outside the key state among it
+KS_SNAPSHOTS = {
+    "tb_count_ooo": ((256, 128, True, 40, False, False), "count",
+                     "disordered", 1900, 5),
+    "tb_mean_long_ring": ((1024, 16, True, 0, False, False), "mean",
+                          "inorder", 1500, 1 << 30),
+    "tb_dense_max": ((256, 128, True, 0, False, True), "max", "late",
+                     2100, 3),
+    "cb_sum": ((256, 128, False, 0, False, False), "sum", "inorder", 1700,
+               1 << 30),
+    "renumbered_min": ((96, 32, True, 0, True, False), "min", "inorder",
+                       1300, 2),
+}
+KS_SNAP_N, KS_SNAP_CHUNK = 3000, 130
+
+
+def ks_snap_engine(name):
+    (win, slide, tb, delay, renum, dense), kind = KS_SNAPSHOTS[name][:2]
+    return NativeWindowEngine(win, slide, tb, delay, renumber=renum,
+                              kind=kind, dense=dense)
+
+
+def ks_snap_feed(eng, name, lo, hi, rows):
+    """Events [lo, hi) of the snapshot's stream in calls of 130, at most
+    the case's cap of windows staged after each (everything, at EOS), each
+    window into ``rows`` with its staged panes (a restored engine lists
+    its keys in the snapshot's order, so windows of one firing may come
+    in another order than from the engine that never stopped)."""
+    _lane, _kind, shape, _cut, flush_cap = KS_SNAPSHOTS[name]
+    keys, ts, vals = churn_stream(shape, KS_SNAP_N)
+
+    def take(cap):
+        out = eng.flush(cap)
+        if out is None:
+            return False
+        cols, starts, ends, d_keys, gwids, rts = out[:6]
+        for j in range(len(starts)):
+            rows.append((int(d_keys[j]), int(gwids[j]), int(rts[j])) + tuple(
+                c[starts[j]:ends[j]].tobytes() for c in cols.values()))
+        return True
+    for a in range(lo, hi, KS_SNAP_CHUNK):
+        b = min(a + KS_SNAP_CHUNK, hi)
+        eng.ingest(keys[a:b], ts[a:b], ts[a:b], vals[a:b])
+        take(flush_cap)
+    if hi == KS_SNAP_N:
+        eng.eos()
+        while take(1 << 30):
+            pass
+
+
+def ks_rows_digest(rows):
+    assert len(set(r[:2] for r in rows)) == len(rows)
+    return hashlib.sha256(repr(sorted(rows)).encode()).hexdigest()[:16]
+
+
+def ks_snapshot(name):
+    """The snapshot's bytes mid-stream, and the digest of what the
+    engine staged from there to the end of the stream."""
+    cut = KS_SNAPSHOTS[name][3]
+    eng, after = ks_snap_engine(name), []
+    ks_snap_feed(eng, name, 0, cut, [])
+    blob = eng.serialize()["native"]
+    ks_snap_feed(eng, name, cut, KS_SNAP_N, after)
+    return blob, ks_rows_digest(after)
+
+
+def ks_cases():
+    """name -> a function that answers the case's digest."""
+    cases = {}
+    for law in KS_LAWS:
+        for kind in ("count", "max", "sum"):
+            for chunking in (4096, 65536):
+                cases[f"q5/{law}/{kind}/c{chunking}"] = (
+                    lambda law=law, kind=kind, c=chunking:
+                    ks_q5(law, kind, c)[2])
+        for case in ("grows_mid_call", "crosses_between_calls"):
+            cases[f"q5/{law}/count/{case}"] = (
+                lambda law=law, case=case: ks_q5(law, "count", case)[2])
+        cases[f"q5/{law}/count/dense"] = (
+            lambda law=law: ks_q5(law, "count", 4096, dense=True)[2])
+    for ppw in (16, 64):
+        for kind in ("count", "max", "sum", "mean"):
+            for tb in (True, False):
+                cases[f"ring/{ppw}/{kind}/{'tb' if tb else 'cb'}"] = (
+                    lambda ppw=ppw, kind=kind, tb=tb:
+                    ks_long_ring(ppw, kind, tb))
+    for kind in ("count", "sum"):
+        cases[f"reuse/{kind}"] = lambda kind=kind: ks_reuse(kind)[2]
+        for dense in (False, True):
+            cases[f"synth/{kind}/{'dense' if dense else 'sparse'}"] = (
+                lambda kind=kind, dense=dense: ks_synth(kind, dense))
+    cases["moved_back/count"] = lambda: ks_moved_back()[2]
+    return cases
+
+
+def ks_golden():
+    with open(KS_GOLDEN) as f:
+        return json.load(f)
+
+
+
+
+@pytest.mark.parametrize("case", list(ks_cases()))
+def test_the_engine_stages_what_the_parent_engine_staged(case):
+    """The live and the ooo law at the tests' size, by key and one by
+    one, the table growing inside a call that runs ahead and crossing
+    the bound between two; rings of 16 and 64 panes a window on every
+    lane that keeps one; slots reused by keys whose ring is of the
+    other sort; a ring ``move_back`` pushed out of its key state;
+    ``synth_ingest`` and ``dense`` engines: the parent's bytes."""
+    assert ks_cases()[case]() == ks_golden()["digests"][case]
+
+
+@pytest.mark.parametrize("through", [(), ("ids", "ts"), ("keys", "vals")],
+                         ids=["all_through", "ids_compact", "ids_through"])
+@pytest.mark.parametrize("law", list(KS_LAWS))
+def test_through_a_selection_a_big_table_stages_the_same(law, through):
+    """``ingest(..., sel)`` where the walk runs ahead: the ids read
+    through the rows or compact, the staged bytes are the plain call's."""
+    assert ks_q5(law, "count", 4096, through=through)[2] \
+        == ks_golden()["digests"][f"q5/{law}/count/c4096"]
+
+
+@pytest.mark.parametrize("law", list(KS_LAWS))
+def test_the_q5_laws_count_every_bid_in_every_window(law):
+    """Against the plain recomputation, stragglers included."""
+    keys, ts, _vals = q5_law(200_000, KS_LAWS[law])
+    eng, rows, _digest = ks_q5(law, "count", 4096)
+    got = {(k, w): v for k, w, v, _rts in rows}
+    assert len(got) == len(rows) and eng.ignored() == 0
+    assert got == plain_counts(keys, ts, KS_WIN, KS_SLIDE)
+    s = eng.snapshot()
+    assert s["keys_live_peak"] > 2000 and s["rings_spilled"] == 0
+    # every call but the first few ran ahead
+    assert 0.9 * s["key_touches"] < s["walked_ahead"] < s["key_touches"]
+
+
+@pytest.mark.parametrize("case", ["grows_mid_call", "crosses_between_calls"])
+@pytest.mark.parametrize("law", list(KS_LAWS))
+def test_a_call_runs_ahead_where_the_table_has_outgrown_the_bound(law, case):
+    """What decides is the table's size as the call begins (16,384
+    records: 1,023 live keys made it grow): before that no call runs
+    ahead, after it every call does, the one in which the table grows
+    again included; the rows either side are the plain recomputation's
+    (``test_the_engine_stages_what_the_parent_engine_staged`` holds the
+    same calls to the parent's bytes)."""
+    n = 200_000
+    keys, ts, vals = q5_law(n, KS_LAWS[law])
+    eng = NativeWindowEngine(*ks_lane(law)[:4], kind="count")
+    got, lo, was, crossed, grew = {}, 0, eng.snapshot(), None, 0
+
+    def drain():
+        while (out := eng.flush(1 << 30)) is not None:
+            for k, w, a, b in zip(out[3].tolist(), out[4].tolist(),
+                                  out[1].tolist(), out[2].tolist()):
+                got[(k, w)] = got.get((k, w), 0) + out[0]["value"][a:b].sum()
+    for i, hi in enumerate(ks_cuts(case, n)):
+        eng.ingest(keys[lo:hi], ts[lo:hi], ts[lo:hi], vals[lo:hi])
+        s = eng.snapshot()
+        touched = s["key_touches"] - was["key_touches"]
+        ahead = s["walked_ahead"] - was["walked_ahead"]
+        assert touched == len(np.unique(keys[lo:hi]))
+        lo = hi
+        if was["keys_live"] < 1023:
+            assert ahead == 0 and crossed is None
+        else:
+            assert ahead == touched > 0
+            crossed = i if crossed is None else crossed
+            grew += was["keys_live"] < 4095 <= s["keys_live"]
+        was = s
+        drain()
+    eng.eos()
+    drain()
+    assert crossed is not None and crossed > 0
+    assert grew == (case == "grows_mid_call")
+    assert got == plain_counts(keys, ts, KS_WIN, KS_SLIDE)
+    assert eng.ignored() == 0 and eng.snapshot()["rings_spilled"] == 0
+
+
+@pytest.mark.parametrize("case,spilled", [
+    ("ring16_tb", "all"), ("ring64_cb", "all"), ("cb", "all"),
+    ("reuse", "some"), ("tb", "none"), ("renumbered", "none")])
+def test_a_ring_leaves_its_key_state_where_it_outgrows_it(case, spilled):
+    """``rings_spilled``: every ring of a long window and of a CB lane
+    (its two extra lanes have no room inline), some where slots are
+    reused by keys of either sort, none on a TB lane of two or three
+    panes a window."""
+    if case == "reuse":
+        eng = ks_reuse("count")[0]
+    else:
+        lane = {"ring16_tb": (256, 16, True, 40, False, False),
+                "ring64_cb": (1024, 16, False, 0, False, False),
+                "cb": (256, 128, False, 0, False, False),
+                "tb": (256, 128, True, 0, False, False),
+                "renumbered": (256, 128, True, 0, True, False)}[case]
+        eng = drive_lane(lane, "sum", *churn_stream("inorder", 3000), 129)[0]
+    s = eng.snapshot()
+    assert s["keys_opened"] > 50 and s["walked_ahead"] == 0
+    assert {"all": s["rings_spilled"] == s["keys_opened"],
+            "some": 0 < s["rings_spilled"] < s["keys_opened"],
+            "none": s["rings_spilled"] == 0}[spilled]
+
+
+def test_a_ring_move_back_pushed_out_of_its_key_state_keeps_its_panes():
+    eng, rows, _digest, keys, ts = ks_moved_back()
+    got = {(k, w): v for k, w, v, _rts in rows if k == 7}
+    mine = keys == 7
+    assert got == plain_counts(keys[mine], ts[mine], 64, 32)
+    s = eng.snapshot()
+    assert eng.ignored() == 0 and s["anchors_moved"] == 3
+    # key 7's, and the clock key's: its stamps jump 48 panes
+    assert s["rings_spilled"] == 2
+
+
+@pytest.mark.parametrize("name", list(KS_SNAPSHOTS))
+def test_a_snapshot_is_the_parent_engines_byte_for_byte(name):
+    """The same stream leaves the same snapshot as at the parent commit
+    (so the parent's library restores this one's), and the parent's
+    bytes restore here: the engine stages from them what the engine
+    that never stopped did, and writes them out again unchanged."""
+    gold = ks_golden()["snapshots"][name]
+    theirs = base64.b64decode(gold["blob"])
+    mine, after = ks_snapshot(name)
+    assert mine == theirs and after == gold["after"]
+    eng, rows = ks_snap_engine(name), []
+    eng.deserialize({"native": theirs})
+    assert eng.serialize()["native"] == theirs
+    ks_snap_feed(eng, name, KS_SNAPSHOTS[name][3], KS_SNAP_N, rows)
+    assert ks_rows_digest(rows) == gold["after"] and len(rows) > 30
+
+
+@pytest.mark.parametrize("python_store", [False, True],
+                         ids=["native", "python"])
+def test_the_three_counts_reach_the_series_the_stats_and_the_metrics_page(
+        python_store):
+    """``key_touches``, ``walked_ahead``, ``rings_spilled`` where the
+    engine's other counts are: the span registry's series (cut at two
+    instants by ``touched_between``), ``Spans.Operators[].Counters`` and
+    ``/metrics``; the Python store visits no key state and reports none."""
+    import windflow_tpu as wf
+    from windflow_tpu.core import WinType
+    from windflow_tpu.core.tuples import TupleBatch
+    from windflow_tpu.graph.fuse import iter_logics
+    from windflow_tpu.operators.basic_ops import Sink
+    from windflow_tpu.operators.batch_ops import BatchSource
+    from windflow_tpu.operators.tpu.farms_tpu import KeyFarmTPU
+    from windflow_tpu.telemetry import spans
+    from windflow_tpu.telemetry.metrics import render_openmetrics
+    n, chunk = (30_000, 2048) if python_store else (200_000, 4096)
+    keys, ts, vals = q5_law(n, KS_DELAY)
+    sent, rows = {"i": 0}, {}
+
+    def body(ctx=None):
+        a = sent["i"]
+        if a >= n:
+            return None
+        sent["i"] = b = min(a + chunk, n)
+        return TupleBatch({"key": keys[a:b], "id": ts[a:b], "ts": ts[a:b],
+                           "value": vals[a:b]})
+
+    def sink(batch):
+        if batch is not None:
+            for k, w, v in zip(batch.key.tolist(), batch.id.tolist(),
+                               np.asarray(batch["value"]).tolist()):
+                rows[(k, w)] = v
+    g = wf.PipeGraph(f"key_state_{python_store}", wf.Mode.DEFAULT)
+    g.add_source(BatchSource(body)).add(
+        KeyFarmTPU("count", KS_WIN, KS_SLIDE, WinType.TB,
+                   triggering_delay=KS_DELAY, name="counts",
+                   emit_batches=True,
+                   value_of=(lambda t: t.value) if python_store else None)
+    ).add_sink(Sink(sink, name="sink"))
+    logic = next(lg for _, lg in iter_logics(g)
+                 if hasattr(lg, "launched_batches"))
+    assert (logic._native is None) == python_store
+    g.run()
+    assert rows == plain_counts(keys, ts, KS_WIN, KS_SLIDE)
+    report = json.loads(g.stats.to_json())
+    assert report["Schema_version"] >= 17
+    counted = [r for r in report["Spans"]["Operators"] if "Counters" in r]
+    names = ("key_touches", "walked_ahead", "rings_spilled")
+    if python_store:
+        assert not any(r["Counters"].get(n_) for r in counted
+                       for n_ in names)
+        assert all(c.touched_between(0.0, 1e12) == (0, 0)
+                   for c in spans.graph(g.name).counters.values())
+        return
+    c, snap = counted[0]["Counters"], logic._store.snapshot()
+    assert {n_: c[n_] for n_ in names} == {n_: snap[n_] for n_ in names}
+    assert c["rings_spilled"] == 0
+    assert 0.9 * c["key_touches"] < c["walked_ahead"] < c["key_touches"]
+    assert c["key_touches"] == sum(
+        len(np.unique(keys[a:a + chunk])) for a in range(0, n, chunk))
+    kept = spans.graph(g.name).counters[counted[0]["Operator"]]
+    assert kept.touched_between(0.0, 1e12) \
+        == (c["key_touches"], c["walked_ahead"])
+    text = render_openmetrics({"a": {"report": report}})
+    for n_ in names:
+        assert f"windflow_engine_{n_}_total{{" in text, n_
+
+
+if __name__ == "__main__":
+    gold = {"digests": {n: f() for n, f in ks_cases().items()},
+            "snapshots": {}}
+    for name in KS_SNAPSHOTS:
+        blob, after = ks_snapshot(name)
+        gold["snapshots"][name] = {
+            "blob": base64.b64encode(blob).decode(), "after": after}
+    with open(KS_GOLDEN, "w") as f:
+        json.dump(gold, f, indent=1)
+    print(f"{len(gold['digests'])} digests, {len(gold['snapshots'])} "
+          f"snapshots -> {KS_GOLDEN}")

@@ -569,3 +569,64 @@ def test_a_snapshot_written_by_the_parent_commit_loads(lane):
     if (other._native is not None) != (lane == "native"):
         with pytest.raises(RuntimeError, match="snapshot came from"):
             other.load_state(state)
+
+
+# -- a key's state in one place (PR 33): the two stores still agree -----------
+
+from test_native_runtime import (KS_LAWS, KS_SLIDE, KS_WIN,  # noqa: E402
+                                 churn_stream, q5_law)
+
+
+def store_of(which, kind, win, slide, win_type, delay=0):
+    if which == "native":
+        return NativeWindowEngine(win, slide, win_type == WinType.TB, delay,
+                                  kind=kind)
+    return PyWindowStore(win, slide, win_type, delay, kind=kind,
+                         role=Role.SEQ)
+
+
+def staged_by(store, kind, keys, ts, vals, chunk):
+    """Rows (as ``take`` makes them), how many there were after each
+    chunk, and the store's own counts after the last."""
+    rows, marks = [], []
+    for a in range(0, len(keys), chunk):
+        b = a + chunk
+        store.ingest(keys[a:b], ts[a:b], ts[a:b], vals[a:b])
+        take(store, kind, rows)
+        marks.append((len(rows), store.snapshot()["keys_live"]))
+    store.eos()
+    take(store, kind, rows)
+    s = store.snapshot()
+    return sorted(rows), marks, (s["late_accepted"], s["anchors_moved"],
+                                 s["inputs_ignored"], store.ignored())
+
+
+@needs_native
+@pytest.mark.parametrize("law", list(KS_LAWS))
+def test_both_stores_count_the_q5_laws_alike(law):
+    """3,000 keys live, born and evicted, one bid in ten a straggler
+    (``ooo``): the same rows after the same chunk, the same keys live,
+    the same disorder met."""
+    keys, ts, vals = q5_law(120_000, KS_LAWS[law])
+    got = [staged_by(store_of(which, "count", KS_WIN, KS_SLIDE, WinType.TB,
+                              KS_LAWS[law]), "count", keys, ts, vals, 4096)
+           for which in ("native", "python")]
+    assert got[0] == got[1]
+    assert len(got[0][0]) > 10_000 and max(m[1] for m in got[0][1]) > 2000
+    assert (got[0][2][0] > 10_000) == (law == "ooo")
+
+
+@needs_native
+@pytest.mark.parametrize("win_type", [WinType.CB, WinType.TB],
+                         ids=["cb", "tb"])
+@pytest.mark.parametrize("kind", ["sum", "count", "max", "min"])
+@pytest.mark.parametrize("ppw", [16, 64])
+def test_both_stores_stage_a_long_ring_alike(ppw, kind, win_type):
+    """Windows of 16 and 64 panes: the native engine's ring leaves the
+    key state; late rows (one in 97, 700 stamps back) and keys that die
+    among them."""
+    keys, ts, vals = churn_stream("late", 2000)
+    got = [staged_by(store_of(which, kind, ppw * 16, 16, win_type), kind,
+                     keys, ts, vals, 129) for which in ("native", "python")]
+    assert got[0] == got[1]
+    assert len(got[0][0]) > 200

@@ -66,10 +66,13 @@ from ..telemetry.histogram import LogHistogram
 # 16 = those Counters gain late_accepted, anchors_moved and
 # inputs_ignored (tuples accepted behind the engine's stream time, times
 # a live key's anchor moved back, tuples dropped behind a fired window).
+# 17 = those Counters gain key_touches, walked_ahead and rings_spilled
+# (keys the engine's calls visited, those of them in a call that ran
+# ahead of itself, pane rings that left their key state).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 16
+SCHEMA_VERSION = 17
 
 
 @dataclass

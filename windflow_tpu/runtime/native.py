@@ -624,12 +624,16 @@ class NativeWindowEngine:
     # folded with their key's others of the call in one combine, tuples
     # folded one by one; tuples accepted whose stamp lay behind the
     # stream time when they came, times a live key's anchor moved back
-    # (docs/RUNTIME.md 5a), tuples ignored; stream time
+    # (docs/RUNTIME.md 5a), tuples ignored; stream time; the keys the
+    # calls' per-key visit met, those of them in a call that ran ahead
+    # of itself (the table had outgrown the caches), the rings that
+    # left their key state (docs/RUNTIME.md 5a "A key state in one
+    # place")
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
              "windows_fired", "folded_by_key", "folded_singly",
              "late_accepted", "anchors_moved", "inputs_ignored",
-             "stream_time")
+             "stream_time", "key_touches", "walked_ahead", "rings_spilled")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
                  delay: int = 0, renumber: bool = False, kind: str = "sum",

@@ -173,6 +173,12 @@ def render_openmetrics(apps: dict) -> str:
              "back for a tuple earlier than the key's first to arrive"),
             ("inputs_ignored", "counter", "tuples the engine dropped "
              "behind a window that had fired"),
+            ("key_touches", "counter", "keys the engine's calls visited "
+             "(a key once a call)"),
+            ("walked_ahead", "counter", "of those, the visits of a call "
+             "that ran ahead of itself (a table beyond the caches)"),
+            ("rings_spilled", "counter", "pane rings that left their key "
+             "state for a block of their own"),
             ("cols_selected", "counter", "columns the selected batches "
              "the operator ingested carried"),
             ("cols_gathered", "counter", "columns of selected batches "
