@@ -401,7 +401,8 @@ def stage_kernels(shapes=None):
         se[1] = np.minimum(se[0] + rng.integers(0, 2 * WIN, b_pad), t_pad)
         t0 = time.perf_counter()
         got = np.asarray(window_sums_device(vals, se[0], se[1]))[:, 0]
-        want = np.asarray(wc._scan_program("sum")(vals, se))
+        want = np.asarray(wc._block_sum_program(
+            "sum", wc._block_levels(wc.next_pow2(2 * WIN)))(vals, se))
         if not (got == want).all():
             raise AssertionError(f"window_sum kernel != XLA at "
                                  f"T={t_pad} B={b_pad}")

@@ -230,6 +230,11 @@ struct Engine {
     i64 windows_fired = 0;
     // staging buffers (valid until the next flush)
     std::vector<double> st_vals, st_cnts;
+    i64 st_n = 0;                     // panes staged by the last flush
+    template <typename T>
+    static void grow(std::vector<T>& v, i64 n) {
+        if ((i64)v.size() < n) v.resize(n);
+    }
     std::vector<i64> st_starts, st_ends, st_keys, st_gwids, st_rts;
     std::vector<i64> f_prefix;        // flush(): count prefixes, flat
     std::vector<int32_t> f_touched, f_dead;
@@ -257,6 +262,13 @@ struct Engine {
     // ran ahead, and the rings that left their key state (KeyState).
     static constexpr std::size_t AHEAD_TABLE_BYTES = 256u << 10;
     i64 key_touches = 0, walked_ahead = 0, rings_spilled = 0;
+    // what flush() staged since the engine was made: the pane partials
+    // it copied into launch buffers (a key's span once a take, however
+    // many of the key's windows the take holds) and the windows they
+    // serve.  2 panes a window where a window is two panes and a key
+    // stages one a launch; 117 where a launch stages 31 windows of 3,600
+    // panes over one span of 3,630 (wfn_engine_stats)
+    i64 panes_staged = 0, windows_staged = 0;
     // gather()'s own fetches cost every tuple a few micro-ops, and pay
     // only where first touches land on records the walk has not just
     // been near: keys the call did not open (a new key's record lies
@@ -1181,17 +1193,6 @@ struct Engine {
         if (stream_rule) trigger();
     }
 
-    // pane accessors tolerant of extents beyond the retained ring
-    // (panes outside it hold no tuples by construction)
-    inline double pane_at(const KeyState& st, i64 p_abs) const {
-        i64 r = p_abs - st.pane_base;
-        return (r >= 0 && r < st.room) ? ring(st).acc[r] : neutral;
-    }
-    inline i64 cnt_at(const KeyState& st, i64 p_abs) const {
-        i64 r = p_abs - st.pane_base;
-        return (r >= 0 && r < st.room) ? ring(st).cnt[r] : 0;
-    }
-
     inline i64 n_ready() const { return (i64)(ready.size() - ready_head); }
 
     // flush()'s note of one key's part in a take: the extent of its
@@ -1231,8 +1232,7 @@ struct Engine {
     // Returns the number staged.  A partial take of a long `ready`
     // list costs the windows it takes.
     i64 flush(i64 max_windows) {
-        st_vals.clear();
-        st_cnts.clear();
+        st_n = 0;
         if (n_ready() == 0) return 0;
         const i64 take = std::min<i64>(max_windows, n_ready());
         const Desc* taken = ready.data() + ready_head;
@@ -1259,9 +1259,18 @@ struct Engine {
             }
             ++t.n;
         }
-        st_vals.resize(n_vals);
-        if (kind == Kind::MEAN) st_cnts.resize(n_vals);
-        f_prefix.resize(n_vals + (i64)f_touched.size());
+        // the buffers only grow: every pane staged is written below, and
+        // a resize from empty would fill 8 bytes a pane and lane first
+        // (a launch of SG2 stages 7.7 M panes)
+        st_n = n_vals;
+        grow(st_vals, n_vals);
+        if (kind == Kind::MEAN) grow(st_cnts, n_vals);
+        // the count prefix tells an empty window from one that holds a
+        // tuple and finds a CB window's last tuple: a sparse engine (TB
+        // windows on stream time, role SEQ) fires no empty window
+        // (fire_key: holds_tuple) and stamps by arithmetic, and keeps none
+        const bool prefixed = !sparse;
+        if (prefixed) grow(f_prefix, n_vals + (i64)f_touched.size());
         // each key once: its panes staged, its windows accounted, and
         // (TB) its consumed prefix dropped while its state is at hand
         f_dead.clear();
@@ -1273,15 +1282,37 @@ struct Engine {
             const i64 n_panes = (t.hi - t.lo) / pane;
             t.off = off;
             t.pf = pf;
-            i64 seen = 0;
-            f_prefix[pf++] = 0;
-            for (i64 p = 0; p < n_panes; ++p) {
-                const i64 c = cnt_at(st, p0 + p);
-                st_vals[off] = pane_at(st, p0 + p);
-                if (kind == Kind::MEAN) st_cnts[off] = (double)c;
-                ++off;
-                f_prefix[pf++] = (seen += c);
+            // the span in three runs: panes before the ring and behind
+            // what it stores hold nothing (no tuples, by construction),
+            // the run between is the ring's own and is copied as a block: a
+            // window of thousands of panes is staged at the speed of a
+            // copy, not of a branch a pane
+            const Ring rg = ring(st);
+            const i64 r0 = p0 - st.pane_base;
+            const i64 b0 = std::min(std::max<i64>(-r0, 0), n_panes);
+            const i64 b1 = std::max(b0, std::min(n_panes, st.room - r0));
+            double* v = st_vals.data() + off;
+            std::fill(v, v + b0, neutral);
+            if (b1 > b0)
+                std::memcpy(v + b0, rg.acc + r0 + b0,
+                            (std::size_t)(b1 - b0) * sizeof(double));
+            std::fill(v + b1, v + n_panes, neutral);
+            const i64* c = rg.cnt + r0;
+            if (kind == Kind::MEAN) {
+                double* n = st_cnts.data() + off;
+                std::fill(n, n + b0, 0.0);
+                for (i64 p = b0; p < b1; ++p) n[p] = (double)c[p];
+                std::fill(n + b1, n + n_panes, 0.0);
             }
+            if (prefixed) {
+                i64* px = f_prefix.data() + pf;
+                std::fill(px, px + b0 + 1, (i64)0);
+                i64 seen = 0;
+                for (i64 p = b0; p < b1; ++p) px[p + 1] = (seen += c[p]);
+                std::fill(px + b1 + 1, px + n_panes + 1, seen);
+                pf += n_panes + 1;
+            }
+            off += n_panes;
             st.staged_upto = (t.hi - win) / slide;
             st.queued -= t.n;
             if (is_tb) retire(st, slot);
@@ -1294,7 +1325,7 @@ struct Engine {
         for (i64 d = 0; d < take; ++d) {
             const Desc& ds = taken[d];
             const Take& t = takes[ds.slot];
-            const i64* pfx = f_prefix.data() + t.pf;
+            const i64* pfx = prefixed ? f_prefix.data() + t.pf : nullptr;
             st_keys[d] = ds.key;
             st_gwids[d] = ds.lwid;
             const i64 s = ds.lwid * slide;
@@ -1305,7 +1336,7 @@ struct Engine {
             // device combine emits the masked neutral 0, exactly like
             // the Python/XLA path (window_compute.py `jnp.where`) --
             // otherwise max/min kinds would emit the +-inf pane fill
-            bool empty = pfx[pe] == pfx[ps];
+            const bool empty = prefixed && pfx[pe] == pfx[ps];
             st_starts[d] = t.off + (empty ? 0 : ps);
             st_ends[d] = t.off + (empty ? 0 : pe);
             if (is_tb) {
@@ -1323,6 +1354,8 @@ struct Engine {
                 st_rts[d] = (r >= 0 && r < st.room) ? ring(st).lts[r] : 0;
             }
         }
+        panes_staged += n_vals;
+        windows_staged += take;
         ready_head += take;
         if (ready_head == ready.size()) {
             ready.clear();
@@ -1637,7 +1670,7 @@ i64 wfn_engine_ignored(void* ep) {
 }
 
 // What key churn costs, how many keys there are, how the fold went and
-// what disorder it met, into out[17]: nanoseconds spent creating key
+// what disorder it met, into out[19]: nanoseconds spent creating key
 // states and moving anchors back (open), finding and queueing fired
 // windows (trigger) and evicting (evict), since the engine was made; keys
 // opened, keys evicted, keys live now and at their peak, windows fired;
@@ -1646,8 +1679,9 @@ i64 wfn_engine_ignored(void* ep) {
 // stream time when they came, times a live key's anchor moved back,
 // tuples ignored; the stream time (-1 before the first stamp); the keys
 // the per-key visit of the calls met, those of them in a call that ran
-// ahead of itself (Engine, "WHERE A CALL RUNS AHEAD OF ITSELF"), and the
-// rings that left their key state (KeyState).
+// ahead of itself (Engine, "WHERE A CALL RUNS AHEAD OF ITSELF"), the
+// rings that left their key state (KeyState), and the pane partials and
+// windows flush() staged.
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -1667,6 +1701,8 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[14] = e.key_touches;
     out[15] = e.walked_ahead;
     out[16] = e.rings_spilled;
+    out[17] = e.panes_staged;
+    out[18] = e.windows_staged;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }
@@ -1681,9 +1717,9 @@ i64 wfn_engine_flush(void* ep, i64 max_windows, double** vals, i64* n_vals,
     Engine& e = *static_cast<Engine*>(ep);
     i64 b = e.flush(max_windows);
     *vals = e.st_vals.data();
-    *n_vals = (i64)e.st_vals.size();
+    *n_vals = e.st_n;
     *cnts = e.st_cnts.data();
-    *n_cnts = (i64)e.st_cnts.size();
+    *n_cnts = e.kind == Kind::MEAN ? e.st_n : 0;
     *starts = e.st_starts.data();
     *ends = e.st_ends.data();
     *keys = e.st_keys.data();

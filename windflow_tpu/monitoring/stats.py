@@ -69,10 +69,12 @@ from ..telemetry.histogram import LogHistogram
 # 17 = those Counters gain key_touches, walked_ahead and rings_spilled
 # (keys the engine's calls visited, those of them in a call that ran
 # ahead of itself, pane rings that left their key state).
+# 18 = those Counters gain panes_staged and windows_staged (pane partials
+# the engine's flush copied into launch buffers, windows they serve).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 17
+SCHEMA_VERSION = 18
 
 
 @dataclass

@@ -821,7 +821,7 @@ class WinSeqTPULogic(NodeLogic):
         # spans.ENGINE_COUNTERS: STATS without the clocks and the stream
         # time
         self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
-                            last[3:13] + last[14:17])
+                            last[3:13] + last[14:19])
 
     def _launch_due(self) -> bool:
         return ((_time.perf_counter() - self._last_launch_t) * 1e3

@@ -134,6 +134,12 @@ class PyWindowStore:
         self.ignored_tuples = 0
         self.late_accepted = 0
         self.anchors_moved = 0
+        # what ``flush`` staged: the elements of the flat buffers it made
+        # (pane partials where it pre-reduces, else the keys' own values)
+        # and the windows they serve; the native engine's counters of the
+        # same names (``NativeWindowEngine.STATS``)
+        self.panes_staged = 0
+        self.windows_staged = 0
         self._saw_nonint_key = False
 
     # -- the engine's small calls ------------------------------------------
@@ -160,7 +166,9 @@ class PyWindowStore:
         return {"keys_live": n, "bytes_est": n * per,
                 "late_accepted": self.late_accepted,
                 "anchors_moved": self.anchors_moved,
-                "inputs_ignored": self.ignored_tuples}
+                "inputs_ignored": self.ignored_tuples,
+                "panes_staged": self.panes_staged,
+                "windows_staged": self.windows_staged}
 
     def serialize(self) -> dict:
         """The per-key store, the windows fired and not yet staged, and
@@ -361,6 +369,8 @@ class PyWindowStore:
                     rts[i] = int(st.ts[hi - 1]) if hi > lo else 0
         flat_vals = (np.concatenate(bufs_v) if bufs_v
                      else np.empty(0, np.float64))
+        self.panes_staged += off
+        self.windows_staged += len(descs)
         # the flat buffer is a copy: evict the consumed prefixes, and
         # the keys whose last window this was.  A key with windows still
         # queued (a partial take) keeps what the first of them starts at

@@ -10,9 +10,20 @@ not a scalar-thread machine, so the design is different:
   concatenation of per-key series); window extents are [start, end)
   index pairs into it.  Windows never span keys, so segment math works
   on the flat buffer directly.
-* **invertible combines** (sum/count/mean) use one prefix scan over the
-  flat buffer + two gathers per window: O(T + B) work, no [B, W]
-  materialization, pure VPU-friendly code XLA fuses well.
+* **sums** (sum / mean, and count over pane partials) add each window's
+  OWN elements and nobody else's, so a window's result does not depend
+  on the buffer's length or the window's place in it: windows of up to
+  32 elements gather a masked [B, w_pad] tile; wider ones (3,600 panes a
+  window in SABER's SG2) go through the **blocked sum**: the buffer in
+  rows of 128, the rows' sums in rows of 128 again, and a window is the
+  tail of its first row, the head of its last and the whole rows
+  between, read a level up -- two row gathers a level, O(T + 256 B) work
+  a level, every partial a sum of elements of the window.  One float32
+  running sum over the whole buffer and ``c[end] - c[start]`` -- what
+  served wide windows before -- carries the rounding of the BUFFER's
+  magnitude into every window: past 2**24 (a few plugs' worth of
+  thousand-watt panes) every later sum is off in its low digits.
+  ``count`` over a store's own tuples is ``end - start``.
 * **semigroup combines** (max/min) use a sparse table (log-sweep of
   strided combines) + two gathers per window -- the classic O(1) range
   query, a TPU-shaped replacement for FlatFAT's per-window tree walk.
@@ -56,39 +67,29 @@ def next_pow2(n: int) -> int:
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _scan_program(kind: str):
-    """``se`` packs [starts; ends] as one int32 [2, B] array: a single
+def _count_program():
+    """Tuples a window over the store's own buffer: ``end - start``.
+    ``se`` packs [starts; ends] as one int32 [2, B] array: a single
     host->device transfer instead of three (padding rows are (0, 0), so
-    their range sums are 0 and the host slice drops them anyway)."""
-    jax, jnp = _jax()
+    they read 0 and the host slice drops them anyway)."""
+    jax, _ = _jax()
 
     @jax.jit
     def run(values, se):
-        starts, ends = se[0], se[1]
-        c = jnp.concatenate([jnp.zeros((1,), values.dtype),
-                             jnp.cumsum(values)])
-        s = c[ends] - c[starts]
-        n = (ends - starts).astype(values.dtype)
-        if kind == "sum":
-            out = s
-        elif kind == "count":
-            out = n
-        else:  # mean
-            out = s / jnp.maximum(n, 1)
-        return out
+        return (se[1] - se[0]).astype(values.dtype)
 
     return run
 
 
 @functools.lru_cache(maxsize=None)
 def _tile_sum_program(w_pad: int):
-    """Window sums via a masked [B, w_pad] gather-tile reduction.
-    Used instead of the prefix scan when every window spans few panes:
-    the scan's c[end]-c[start] differencing carries the f32 rounding of
-    the WHOLE buffer's magnitude into each window (catastrophic for
-    small windows late in the buffer), while the tile sums only the
-    window's own panes -- exact to within-window rounding, and for
-    w_pad this small the gather is cheaper than the scan anyway."""
+    """Window sums via a masked [B, w_pad] gather-tile reduction, for
+    windows of up to ``_TILE_MAX_W`` elements: the tile sums only the
+    window's own panes -- exact to within-window rounding -- and for
+    w_pad this small one gather is the cheapest way to read them.  Wider
+    windows take :func:`_block_sum_program`, which keeps the same
+    promise with two row gathers a level instead of ``w_pad`` element
+    gathers a window."""
     jax, jnp = _jax()
 
     @jax.jit
@@ -116,33 +117,102 @@ def _tile_mean_program(w_pad: int):
         idx = jnp.clip(idx, 0, T - 1)
         s = jnp.where(mask, values[idx], 0).sum(axis=1)
         n = jnp.where(mask, counts[idx], 0).sum(axis=1)
-        return s / jnp.maximum(n, 1)
+        return jnp.stack([s, n])     # the host divides (DeviceBatchHandle)
 
     return run
 
 
 # max pane extent (already padded to a power of two) served by the
-# gather-tile programs; wider windows take the prefix scan
+# gather-tile programs; wider windows take the blocked sum
 _TILE_MAX_W = 32
+# the blocked sum's row: one vector register's lanes
+_BLOCK = 128
+
+
+def _block_levels(w_pad: int) -> int:
+    """Levels the blocked sum needs for windows of up to ``w_pad``
+    elements: the smallest ``n`` with ``_BLOCK ** n >= w_pad`` (a window
+    of up to 128 elements touches two rows and none between; each level
+    up serves 128 times the extent)."""
+    n, reach = 1, _BLOCK
+    while reach < w_pad:
+        n, reach = n + 1, reach * _BLOCK
+    return n
 
 
 @functools.lru_cache(maxsize=None)
-def _scan_pair_program():
-    """Mean over pane partials: per-window sum of pane sums divided by
-    sum of pane counts (the native engine's MEAN staging ships both
-    buffers; a windowed mean is NOT the mean of pane means)."""
-    jax, jnp = _jax()
+def _block_sum_program(kind: str, n_levels: int):
+    """Sums over wide windows, each from the window's own elements.
 
-    @jax.jit
-    def run(values, counts, se):
-        starts, ends = se[0], se[1]
-        cv = jnp.concatenate([jnp.zeros((1,), values.dtype),
-                              jnp.cumsum(values)])
-        cc = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                              jnp.cumsum(counts)])
-        s = cv[ends] - cv[starts]
-        n = cc[ends] - cc[starts]
-        return s / jnp.maximum(n, 1)
+    Level 0 is the flat buffer in rows of ``_BLOCK``; level ``l + 1``
+    holds the sums of level ``l``'s rows, in rows of ``_BLOCK`` again.  A
+    window [lo, hi) at a level is: of the row ``lo`` lies in, the lanes
+    from ``lo`` on; of the row ``hi - 1`` lies in, the lanes up to it
+    (one masked row where the two are the same row); and the whole rows
+    strictly between, which are a window a level up.  ``n_levels``
+    (:func:`_block_levels`) is what the widest window of the launch
+    needs, after which nothing is left between.  Two row gathers a
+    level and column, [B, 128] each; no running sum anywhere, so every
+    partial is a sum of elements of the one window: where those are
+    integers whose sum float32 holds (pane counts; whole watts under
+    2**24), the result is exact, whatever the buffer's length and the
+    window's place in it, and for any floats the rounding is that of
+    adding the window's own elements pairwise.
+
+    ``kind``: ``sum`` (one column); ``mean`` (one column over the
+    store's own tuples: the sum over ``hi - lo``); ``mean_panes`` (pane
+    sums and pane counts, the native engine's MEAN staging: the sum of
+    pane sums over the sum of pane counts; a windowed mean is NOT the
+    mean of pane means).  A mean comes back as the pair [sums; counts]
+    and the host takes the quotient (:class:`DeviceBatchHandle`)."""
+    jax, jnp = _jax()
+    lane = np.arange(_BLOCK, dtype=np.int32)[None, :]
+    shift = _BLOCK.bit_length() - 1
+
+    def rows_of(flat):
+        pad = -flat.shape[0] % _BLOCK
+        if pad:
+            flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+        return flat.reshape(-1, _BLOCK)
+
+    def span_sums(cols, lo, hi):
+        """[len(cols), B]: each column's sum over every [lo, hi)."""
+        levels = [rows_of(c) for c in cols]
+        out = [jnp.zeros(lo.shape, c.dtype) for c in cols]
+        live = hi > lo
+        for level in range(n_levels):
+            last = hi - 1
+            r_lo, r_hi = lo >> shift, last >> shift
+            same = r_lo == r_hi
+            head = live[:, None] & (lane >= (lo & (_BLOCK - 1))[:, None]) \
+                & (~same[:, None] | (lane <= (last & (_BLOCK - 1))[:, None]))
+            tail = (live & ~same)[:, None] \
+                & (lane <= (last & (_BLOCK - 1))[:, None])
+            for j, rows in enumerate(levels):
+                top = rows.shape[0] - 1
+                out[j] = out[j] \
+                    + jnp.where(head, rows[jnp.clip(r_lo, 0, top)],
+                                0).sum(axis=1) \
+                    + jnp.where(tail, rows[jnp.clip(r_hi, 0, top)],
+                                0).sum(axis=1)
+            if level + 1 < n_levels:
+                levels = [rows_of(rows.sum(axis=1)) for rows in levels]
+                lo, hi = r_lo + 1, r_hi
+                live = live & (hi > lo)
+        return out
+
+    if kind == "mean_panes":
+        @jax.jit
+        def run(values, counts, se):
+            s, n = span_sums((values, counts), se[0], se[1])
+            return jnp.stack([s, n])
+    else:
+        @jax.jit
+        def run(values, se):
+            s, = span_sums((values,), se[0], se[1])
+            if kind == "sum":
+                return s
+            return jnp.stack([s, (se[1] - se[0]).astype(values.dtype)])
 
     return run
 
@@ -277,13 +347,21 @@ class DeviceBatchHandle:
     ``wait()`` until the device computation has finished (the GIL is
     released meanwhile); ``ready()`` asks the same without waiting.
     After either, ``block()`` is the end of the copy to the host and
-    near-free; entered before, it waits for the computation too."""
+    near-free; entered before, it waits for the computation too.
 
-    __slots__ = ("_dev", "_n")
+    A MEAN comes back as the pair [sums; counts] (``pair``) and the
+    quotient is taken here, on the host, in float32: a TPU v5e's float32
+    divide is not the correctly rounded one (a third of 4 M integer
+    pairs came out one or two units in the last place off; PR 34), and a
+    mean that is the IEEE quotient of an exact sum and an exact count can
+    be checked bit for bit.  65,536 quotients take some 20 us."""
 
-    def __init__(self, dev_array, n_valid: int):
+    __slots__ = ("_dev", "_n", "_pair")
+
+    def __init__(self, dev_array, n_valid: int, pair: bool = False):
         self._dev = dev_array
         self._n = n_valid
+        self._pair = pair
         dev_array.copy_to_host_async()
 
     def ready(self) -> bool:
@@ -296,7 +374,10 @@ class DeviceBatchHandle:
         self._dev.block_until_ready()
 
     def block(self) -> np.ndarray:
-        return np.asarray(self._dev)[: self._n]
+        out = np.asarray(self._dev)
+        if not self._pair:
+            return out[: self._n]
+        return out[0, : self._n] / np.maximum(out[1, : self._n], 1)
 
 
 class WindowComputeEngine:
@@ -323,6 +404,12 @@ class WindowComputeEngine:
         # one in-flight dispatch per ENGINE: farm replicas overlap
         # their launches, one engine's launches stay ordered
         self._lock = threading.Lock()
+        # the padded columns a launch ships, re-lent once the transfer
+        # has let go of them (by refcount: JAX holds the array until
+        # then).  A fresh 33 MB column costs its page faults every
+        # launch: 39 ms on the chip's host, a quarter of it the copy
+        from ..core.tuples import ColumnPool
+        self._padded = ColumnPool()
 
     def compute(self, cols: Dict[str, np.ndarray], starts: np.ndarray,
                 ends: np.ndarray, gwids: np.ndarray) -> DeviceBatchHandle:
@@ -348,10 +435,13 @@ class WindowComputeEngine:
         se = np.zeros((2, B_pad), dtype=np.int32)
         se[0, :B] = starts
         se[1, :B] = ends
+        # a mean comes back as [sums; counts] and is divided on the host
+        pair = self.kind in ("mean", "mean_panes")
 
         def pad_col(v, fill=0):
-            out = np.full(T_pad, fill, dtype=self.dtype)
-            out[:T] = v
+            out = self._padded.take(T_pad, self.dtype)
+            out[:T] = v               # each element written once: a wide
+            out[T:] = fill            # launch's column is 33 MB
             return out
 
         if self.is_ffat:
@@ -376,7 +466,7 @@ class WindowComputeEngine:
         elif self.kind == "mean_panes":
             wp = next_pow2(max(int((ends - starts).max()) if B else 1, 2))
             prog = (_tile_mean_program(wp) if wp <= _TILE_MAX_W
-                    else _scan_pair_program())
+                    else _block_sum_program("mean_panes", _block_levels(wp)))
             dev = prog(jnp.asarray(pad_col(cols[self.value_col])),
                        jnp.asarray(pad_col(cols["count"])),
                        jnp.asarray(se))
@@ -396,10 +486,14 @@ class WindowComputeEngine:
             dev = window_sums_device(
                 pad_col(cols[self.value_col]), se[0], se[1])[:, 0]
         else:
+            # sum, count or mean over the buffer as the store staged it
             wp = next_pow2(max(int((ends - starts).max()) if B else 1, 2))
-            prog = (_tile_sum_program(wp)
-                    if self.kind == "sum" and wp <= _TILE_MAX_W
-                    else _scan_program(self.kind))
+            if self.kind == "count":
+                prog = _count_program()
+            elif self.kind == "sum" and wp <= _TILE_MAX_W:
+                prog = _tile_sum_program(wp)
+            else:
+                prog = _block_sum_program(self.kind, _block_levels(wp))
             dev = prog(jnp.asarray(pad_col(cols[self.value_col])),
                        jnp.asarray(se))
-        return DeviceBatchHandle(dev, B)
+        return DeviceBatchHandle(dev, B, pair)

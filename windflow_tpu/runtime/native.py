@@ -615,7 +615,8 @@ class NativeWindowEngine:
     through the same calls."""
 
     __slots__ = ("lib", "ptr", "_stats", "_stats_p", "engine_kind", "tb",
-                 "dense", "plq_counters", "key_intern", "key_extern")
+                 "dense", "plq_counters", "key_intern", "key_extern",
+                 "_staged")
 
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4}
     # what ``stats()`` returns, in order: nanoseconds creating key
@@ -628,12 +629,14 @@ class NativeWindowEngine:
     # calls' per-key visit met, those of them in a call that ran ahead
     # of itself (the table had outgrown the caches), the rings that
     # left their key state (docs/RUNTIME.md 5a "A key state in one
-    # place")
+    # place"); the pane partials ``flush`` copied into launch buffers
+    # and the windows they serve (docs/RUNTIME.md 5c)
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
              "windows_fired", "folded_by_key", "folded_singly",
              "late_accepted", "anchors_moved", "inputs_ignored",
-             "stream_time", "key_touches", "walked_ahead", "rings_spilled")
+             "stream_time", "key_touches", "walked_ahead", "rings_spilled",
+             "panes_staged", "windows_staged")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
                  delay: int = 0, renumber: bool = False, kind: str = "sum",
@@ -655,6 +658,13 @@ class NativeWindowEngine:
         # partials through the engine of their own kind
         self.engine_kind = {"count": "sum", "mean": "mean_panes"}.get(kind)
         self.tb, self.dense = is_tb, dense
+        # where ``flush`` copies a launch's pane partials to: buffers
+        # that come back once the launch has read them (by refcount, no
+        # release call).  A fresh array as large as a wide launch's (62 MB
+        # a column in SABER's SG2) costs its page faults every time: 66 ms
+        # on the chip's host against 10 for the copy (PR 34)
+        from ..core.tuples import ColumnPool
+        self._staged = ColumnPool()
         self.plq_counters: dict = {}
         # non-integral record keys (the reference's templated key types)
         # are interned into a reserved negative int64 range and
@@ -849,9 +859,15 @@ class NativeWindowEngine:
         def arr(p, n, dt):
             return np.ctypeslib.as_array(p, shape=(n,)).astype(dt, copy=True)
 
-        cols = {"value": arr(vals_p, nv, np.float64)}
+        def partials(p, n):
+            out = self._staged.take(n, np.float64)
+            if n:
+                np.copyto(out, np.ctypeslib.as_array(p, shape=(n,)))
+            return out
+
+        cols = {"value": partials(vals_p, nv)}
         if n_cnts.value:
-            cols["count"] = arr(cnts_p, n_cnts.value, np.float64)
+            cols["count"] = partials(cnts_p, n_cnts.value)
         return (cols, arr(sp, b, np.int64), arr(ep, b, np.int64),
                 arr(kp, b, np.int64), arr(gp, b, np.int64),
                 arr(rp, b, np.int64), self.engine_kind)

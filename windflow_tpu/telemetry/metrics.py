@@ -179,6 +179,10 @@ def render_openmetrics(apps: dict) -> str:
              "that ran ahead of itself (a table beyond the caches)"),
             ("rings_spilled", "counter", "pane rings that left their key "
              "state for a block of their own"),
+            ("panes_staged", "counter", "pane partials the engine's flush "
+             "copied into launch buffers"),
+            ("windows_staged", "counter", "windows the engine's flush "
+             "staged for a launch"),
             ("cols_selected", "counter", "columns the selected batches "
              "the operator ingested carried"),
             ("cols_gathered", "counter", "columns of selected batches "

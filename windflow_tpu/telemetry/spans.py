@@ -451,7 +451,7 @@ class LaunchRing:
 # -- counters --------------------------------------------------------------
 
 # what the native window engine counts (runtime/native.py
-# ``NativeWindowEngine.STATS[3:13]`` and ``[14:17]``): key states it
+# ``NativeWindowEngine.STATS[3:13]`` and ``[14:19]``): key states it
 # created and evicted since it was made, those live now and at their
 # peak, windows it fired, tuples it folded with their key's others of
 # the call in one combine and tuples it folded one by one, what disorder
@@ -460,17 +460,19 @@ class LaunchRing:
 # and where its key states were: the keys its calls' per-key visit met,
 # those of them in a call that ran ahead of itself (the table had
 # outgrown the caches), the rings that left their key state
-# (docs/RUNTIME.md 5a "A key state in one place")
+# (docs/RUNTIME.md 5a "A key state in one place"); and what its flush
+# staged: the pane partials it copied into launch buffers and the windows
+# they serve (docs/RUNTIME.md 5c)
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "keys_live_peak", "windows_fired", "folded_by_key",
                    "folded_singly", "late_accepted", "anchors_moved",
                    "inputs_ignored", "key_touches", "walked_ahead",
-                   "rings_spilled")
+                   "rings_spilled", "panes_staged", "windows_staged")
 # those of them kept as a series (the last values noted in each 100 ms
 # bucket), so that what moved between two instants can be read
 SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
                    "anchors_moved", "inputs_ignored", "key_touches",
-                   "walked_ahead")
+                   "walked_ahead", "panes_staged", "windows_staged")
 
 
 # what a window operator counts of the selected batches it ingests
@@ -553,6 +555,12 @@ class Counters:
         (:meth:`moved_between`)."""
         moved = self.moved_between(t0_s, t1_s)
         return moved["folded_by_key"], moved["folded_singly"]
+
+    def staged_between(self, t0_s: float, t1_s: float) -> tuple:
+        """(pane partials, windows) the store's flush staged between two
+        instants (:meth:`moved_between`)."""
+        moved = self.moved_between(t0_s, t1_s)
+        return moved["panes_staged"], moved["windows_staged"]
 
     def touched_between(self, t0_s: float, t1_s: float) -> tuple:
         """(key touches, those in a call that ran ahead) between two
