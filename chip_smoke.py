@@ -399,17 +399,22 @@ def stage_kernels(shapes=None):
         se = np.zeros((2, b_pad), np.int32)
         se[0] = rng.integers(0, t_pad, b_pad)
         se[1] = np.minimum(se[0] + rng.integers(0, 2 * WIN, b_pad), t_pad)
+        # the XLA programs take the launch as the engine packs it
+        packed = wc.pack_launch(
+            np.empty(wc.packed_len(1, t_pad, b_pad), np.int32), (vals,), 0,
+            se[0], se[1], t_pad, b_pad)
         t0 = time.perf_counter()
         got = np.asarray(window_sums_device(vals, se[0], se[1]))[:, 0]
         want = np.asarray(wc._block_sum_program(
-            "sum", wc._block_levels(wc.next_pow2(2 * WIN)))(vals, se))
+            "sum", wc._block_levels(wc.next_pow2(2 * WIN)), t_pad,
+            b_pad)(packed))
         if not (got == want).all():
             raise AssertionError(f"window_sum kernel != XLA at "
                                  f"T={t_pad} B={b_pad}")
         got = np.asarray(wc._ffat_pallas_program(
             jnp.maximum, -np.inf, t_pad, b_pad)(vals, se))
         want = np.asarray(wc._ffat_program(
-            jnp.maximum, -np.inf, t_pad)(vals, se))
+            jnp.maximum, -np.inf, t_pad, b_pad)(packed))
         if not (got == want).all():
             raise AssertionError(f"flatfat_query kernel != XLA at "
                                  f"T={t_pad} B={b_pad}")

@@ -32,6 +32,8 @@ def until(cond, what):
 class Handle:
     """A launch's result: ready once ``event`` is set."""
 
+    buffers_in = 1      # what the launch record takes from a handle
+
     def __init__(self, n, log):
         self.n, self.log = n, log
         self.event = threading.Event()

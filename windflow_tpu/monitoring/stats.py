@@ -71,10 +71,13 @@ from ..telemetry.histogram import LogHistogram
 # ahead of itself, pane rings that left their key state).
 # 18 = those Counters gain panes_staged and windows_staged (pane partials
 # the engine's flush copied into launch buffers, windows they serve).
+# 19 = Spans.Launches rows gain Buffers_in (host arrays the row's
+# launches handed the device, summed: one a launch where the engine packs
+# it into one buffer, docs/RUNTIME.md 5c) and their Slowest row its own.
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 18
+SCHEMA_VERSION = 19
 
 
 @dataclass

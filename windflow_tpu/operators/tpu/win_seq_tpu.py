@@ -672,6 +672,7 @@ class WinSeqTPULogic(NodeLogic):
         finally:
             tr.end()
         rec.t_dispatched = _time.perf_counter()
+        rec.buffers_in = handle.buffers_in
         self.launched_batches += 1
         return handle, t_sub
 

@@ -37,6 +37,7 @@ class HostBatchHandle:
     result already materialized when ``compute`` returned."""
 
     __slots__ = ("_arr",)
+    buffers_in = 0      # nothing is handed to a device
 
     def __init__(self, arr: np.ndarray):
         self._arr = arr

@@ -31,6 +31,14 @@ not a scalar-thread machine, so the design is different:
   the user's JAX function over the batch (the analogue of the
   reference's arbitrary ``__host__ __device__`` functor path).
 
+A builtin launch hands the device ONE host buffer, inside the jitted
+call (:func:`pack_launch`, :func:`_packed`): the padded value columns and
+the extents in one pooled int32 array, the float32 sections written
+through a float32 view of the same memory and cast back bit for bit by
+the program.  Nothing is converted to a device array on the dispatcher's
+thread: JAX's call path takes a host array itself, and the Python in
+front of a ``jnp.asarray`` bought nothing (docs/RUNTIME.md 5c).
+
 All shapes are bucketed to powers of two so XLA compiles a small, cached
 set of programs (the reference instead reallocates pinned buffers
 adaptively, win_seq_gpu.hpp:574-592).  Dispatch is async: results come
@@ -56,10 +64,58 @@ BUILTIN_KINDS = ("sum", "count", "mean", "max", "min")
 PAIR_KINDS = ("mean_panes",)
 
 def next_pow2(n: int) -> int:
-    p = 1
-    while p < max(1, n):
-        p <<= 1
-    return p
+    return 1 << (max(1, int(n)) - 1).bit_length()
+
+
+# ---------------------------------------------------------------------------
+# what a launch hands the device: one packed buffer
+# ---------------------------------------------------------------------------
+
+def packed_len(n_cols: int, t_pad: int, b_pad: int) -> int:
+    """int32 words of one packed launch: ``n_cols`` value sections of
+    ``t_pad``, then starts and ends of ``b_pad`` each."""
+    return n_cols * t_pad + 2 * b_pad
+
+
+def pack_launch(buf: np.ndarray, cols, fill, starts, ends,
+                t_pad: int, b_pad: int) -> np.ndarray:
+    """Lay one launch out in ``buf`` (int32, :func:`packed_len` long):
+    ``[cols[0] | cols[1] ... | starts | ends]``.  The value sections are
+    written through a float32 view of the same memory, each element once:
+    ``f32[:T] = v`` is the one float64 -> float32 conversion, the tail
+    takes ``fill`` (the combine's neutral), padding extents are (0, 0).
+    :func:`_packed` is the device's side of the same layout."""
+    f32 = buf.view(np.float32)
+    o = 0
+    for v in cols:
+        T = len(v)
+        f32[o:o + T] = v
+        f32[o + T:o + t_pad] = fill
+        o += t_pad
+    B = len(starts)
+    for x in (starts, ends):
+        buf[o:o + B] = x
+        buf[o + B:o + b_pad] = 0
+        o += b_pad
+    return buf
+
+
+def _packed(body: Callable, n_cols: int, t_pad: int, b_pad: int):
+    """``body(*value columns, se)`` jitted over the one packed launch
+    buffer: static slices at :func:`pack_launch`'s offsets, the value
+    sections turned back into float32 bit for bit, the extents as one
+    int32 [2, b_pad] array (padding rows are (0, 0): they read 0 and the
+    host slice drops them anyway)."""
+    jax, jnp = _jax()
+
+    @jax.jit
+    def run(buf):
+        cols = [jax.lax.bitcast_convert_type(
+            buf[j * t_pad:(j + 1) * t_pad], jnp.float32)
+            for j in range(n_cols)]
+        return body(*cols, buf[n_cols * t_pad:].reshape(2, b_pad))
+
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -67,22 +123,19 @@ def next_pow2(n: int) -> int:
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _count_program():
-    """Tuples a window over the store's own buffer: ``end - start``.
-    ``se`` packs [starts; ends] as one int32 [2, B] array: a single
-    host->device transfer instead of three (padding rows are (0, 0), so
-    they read 0 and the host slice drops them anyway)."""
-    jax, _ = _jax()
+def _count_program(b_pad: int):
+    """Tuples a window over the store's own buffer: ``end - start``; the
+    launch carries no value section."""
+    _, jnp = _jax()
 
-    @jax.jit
-    def run(values, se):
-        return (se[1] - se[0]).astype(values.dtype)
+    def run(se):
+        return (se[1] - se[0]).astype(jnp.float32)
 
-    return run
+    return _packed(run, 0, 0, b_pad)
 
 
 @functools.lru_cache(maxsize=None)
-def _tile_sum_program(w_pad: int):
+def _tile_sum_program(w_pad: int, t_pad: int, b_pad: int):
     """Window sums via a masked [B, w_pad] gather-tile reduction, for
     windows of up to ``_TILE_MAX_W`` elements: the tile sums only the
     window's own panes -- exact to within-window rounding -- and for
@@ -90,9 +143,8 @@ def _tile_sum_program(w_pad: int):
     windows take :func:`_block_sum_program`, which keeps the same
     promise with two row gathers a level instead of ``w_pad`` element
     gathers a window."""
-    jax, jnp = _jax()
+    _, jnp = _jax()
 
-    @jax.jit
     def run(values, se):
         starts, ends = se[0], se[1]
         T = values.shape[0]
@@ -101,14 +153,13 @@ def _tile_sum_program(w_pad: int):
         idx = jnp.clip(idx, 0, T - 1)
         return jnp.where(mask, values[idx], 0).sum(axis=1)
 
-    return run
+    return _packed(run, 1, t_pad, b_pad)
 
 
 @functools.lru_cache(maxsize=None)
-def _tile_mean_program(w_pad: int):
-    jax, jnp = _jax()
+def _tile_mean_program(w_pad: int, t_pad: int, b_pad: int):
+    _, jnp = _jax()
 
-    @jax.jit
     def run(values, counts, se):
         starts, ends = se[0], se[1]
         T = values.shape[0]
@@ -119,7 +170,7 @@ def _tile_mean_program(w_pad: int):
         n = jnp.where(mask, counts[idx], 0).sum(axis=1)
         return jnp.stack([s, n])     # the host divides (DeviceBatchHandle)
 
-    return run
+    return _packed(run, 2, t_pad, b_pad)
 
 
 # max pane extent (already padded to a power of two) served by the
@@ -141,7 +192,7 @@ def _block_levels(w_pad: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _block_sum_program(kind: str, n_levels: int):
+def _block_sum_program(kind: str, n_levels: int, t_pad: int, b_pad: int):
     """Sums over wide windows, each from the window's own elements.
 
     Level 0 is the flat buffer in rows of ``_BLOCK``; level ``l + 1``
@@ -165,7 +216,7 @@ def _block_sum_program(kind: str, n_levels: int):
     pane sums over the sum of pane counts; a windowed mean is NOT the
     mean of pane means).  A mean comes back as the pair [sums; counts]
     and the host takes the quotient (:class:`DeviceBatchHandle`)."""
-    jax, jnp = _jax()
+    _, jnp = _jax()
     lane = np.arange(_BLOCK, dtype=np.int32)[None, :]
     shift = _BLOCK.bit_length() - 1
 
@@ -202,31 +253,30 @@ def _block_sum_program(kind: str, n_levels: int):
         return out
 
     if kind == "mean_panes":
-        @jax.jit
         def run(values, counts, se):
             s, n = span_sums((values, counts), se[0], se[1])
             return jnp.stack([s, n])
     else:
-        @jax.jit
         def run(values, se):
             s, = span_sums((values,), se[0], se[1])
             if kind == "sum":
                 return s
             return jnp.stack([s, (se[1] - se[0]).astype(values.dtype)])
 
-    return run
+    return _packed(run, 2 if kind == "mean_panes" else 1, t_pad, b_pad)
 
 
 @functools.lru_cache(maxsize=None)
-def _sparse_table_program(kind: str, n_levels: int):
+def _sparse_table_program(kind: str, t_pad: int, b_pad: int):
     """Range-min/max via log-sweep sparse table: level j holds the
-    combine over [i, i + 2^j).  Result = combine(table[j][start],
-    table[j][end - 2^j]) with j = floor(log2(len)) per window."""
-    jax, jnp = _jax()
+    combine over [i, i + 2^j), one level a bit of ``t_pad``.  Result =
+    combine(table[j][start], table[j][end - 2^j]) with j =
+    floor(log2(len)) per window."""
+    _, jnp = _jax()
     neutral = -np.inf if kind == "max" else np.inf
     comb = jnp.maximum if kind == "max" else jnp.minimum
+    n_levels = t_pad.bit_length()
 
-    @jax.jit
     def run(values, se):
         starts, ends = se[0], se[1]
         T = values.shape[0]
@@ -249,7 +299,7 @@ def _sparse_table_program(kind: str, n_levels: int):
         # host-side result buffer stays finite
         return jnp.where(se[1] > se[0], out, 0)
 
-    return run
+    return _packed(run, 1, t_pad, b_pad)
 
 
 @functools.lru_cache(maxsize=None)
@@ -270,16 +320,16 @@ def _custom_program(fn: Callable, w_pad: int, col_names: tuple):
 
 
 @functools.lru_cache(maxsize=None)
-def _ffat_program(combine: Callable, neutral: float, t_pad: int):
+def _ffat_program(combine: Callable, neutral: float, t_pad: int,
+                  b_pad: int):
     """FlatFAT path: build the device aggregator tree over the flat
     buffer, then answer every window with a vectorized range query --
     the Win_SeqFFAT_GPU pipeline (flatfat_gpu.hpp kernels) in one jitted
     chain."""
     from .flatfat_jax import _programs
-    jax, jnp = _jax()
+    _, jnp = _jax()
     build, _update, query = _programs(combine, neutral, t_pad)
 
-    @jax.jit
     def run(values, se):
         starts, ends = se[0], se[1]
         valid = ends > starts
@@ -287,7 +337,7 @@ def _ffat_program(combine: Callable, neutral: float, t_pad: int):
         out = query(tree, starts, ends, valid)
         return jnp.where(valid, out, 0)
 
-    return run
+    return _packed(run, 1, t_pad, b_pad)
 
 
 # Largest shapes the opt-in Pallas kernels take; anything larger keeps
@@ -354,14 +404,19 @@ class DeviceBatchHandle:
     divide is not the correctly rounded one (a third of 4 M integer
     pairs came out one or two units in the last place off; PR 34), and a
     mean that is the IEEE quotient of an exact sum and an exact count can
-    be checked bit for bit.  65,536 quotients take some 20 us."""
+    be checked bit for bit.  65,536 quotients take some 20 us.
 
-    __slots__ = ("_dev", "_n", "_pair")
+    ``buffers_in`` is how many host arrays the launch handed the device
+    (1 on the packed paths; ``spans.Launch.buffers_in``)."""
 
-    def __init__(self, dev_array, n_valid: int, pair: bool = False):
+    __slots__ = ("_dev", "_n", "_pair", "buffers_in")
+
+    def __init__(self, dev_array, n_valid: int, pair: bool = False,
+                 buffers_in: int = 1):
         self._dev = dev_array
         self._n = n_valid
         self._pair = pair
+        self.buffers_in = buffers_in
         dev_array.copy_to_host_async()
 
     def ready(self) -> bool:
@@ -388,8 +443,7 @@ class WindowComputeEngine:
     (the TPU twin of the GPU functor signature, API:104/118).
     """
 
-    def __init__(self, kind: Any = "sum", value_col: str = "value",
-                 dtype=np.float32):
+    def __init__(self, kind: Any = "sum", value_col: str = "value"):
         # kind may also be ("ffat", combine_fn, neutral): device FlatFAT
         # tree over the flat buffer (Win_SeqFFAT_GPU analogue)
         is_ffat = isinstance(kind, tuple) and len(kind) == 3 \
@@ -400,14 +454,15 @@ class WindowComputeEngine:
         self.kind = kind
         self.is_ffat = is_ffat
         self.value_col = value_col
-        self.dtype = dtype
         # one in-flight dispatch per ENGINE: farm replicas overlap
         # their launches, one engine's launches stay ordered
         self._lock = threading.Lock()
-        # the padded columns a launch ships, re-lent once the transfer
-        # has let go of them (by refcount: JAX holds the array until
-        # then).  A fresh 33 MB column costs its page faults every
-        # launch: 39 ms on the chip's host, a quarter of it the copy
+        # the buffers a launch hands the device, re-lent once the
+        # transfer has let go of them (by refcount: the runtime holds the
+        # host array it was called with until then, and on the CPU
+        # backend, which may alias it, until the program has run).  A
+        # fresh 67 MB buffer costs its page faults every launch: 39 ms a
+        # column of 33 MB on the chip's host, a quarter of it the copy
         from ..core.tuples import ColumnPool
         self._padded = ColumnPool()
 
@@ -419,7 +474,6 @@ class WindowComputeEngine:
 
     def _compute(self, cols: Dict[str, np.ndarray], starts: np.ndarray,
                  ends: np.ndarray, gwids: np.ndarray) -> DeviceBatchHandle:
-        _, jnp = _jax()
         B = len(starts)
         T = len(next(iter(cols.values())))
         # floor the shape buckets: padding a small launch to 2048 costs
@@ -429,55 +483,71 @@ class WindowComputeEngine:
         # compile
         T_pad = next_pow2(max(T, 2048))
         B_pad = next_pow2(max(B, 2048))
-        # starts/ends ride in ONE packed int32 array: every device_put
-        # has a fixed cost, so the builtin paths ship exactly two
-        # buffers (values + extents) per launch
+        if callable(self.kind) or (self.is_ffat and _use_pallas(
+                "WINDFLOW_PALLAS_FFAT", T_pad, B_pad)) or (
+                self.kind == "sum" and _use_pallas(
+                    "WINDFLOW_PALLAS_WINSUM", T_pad, B_pad)):
+            return self._compute_unpacked(cols, starts, ends, gwids,
+                                          T_pad, B_pad)
+        # every builtin combine: ONE packed buffer, handed to the jitted
+        # program as the numpy array it is (pack_launch has the layout)
+        values, fill = (cols[self.value_col],), 0
+        if self.is_ffat:
+            _, comb, fill = self.kind
+            prog = _ffat_program(comb, fill, T_pad, B_pad)
+        elif self.kind in ("max", "min"):
+            fill = -np.inf if self.kind == "max" else np.inf
+            prog = _sparse_table_program(self.kind, T_pad, B_pad)
+        elif self.kind == "count":
+            # over the buffer as the store staged it: the extents alone
+            values, T_pad = (), 0
+            prog = _count_program(B_pad)
+        else:
+            # the sums: the widest window of the launch picks the program
+            wp = next_pow2(max(int((ends - starts).max()) if B else 1, 2))
+            if self.kind == "mean_panes":
+                values += (cols["count"],)
+                prog = (_tile_mean_program(wp, T_pad, B_pad)
+                        if wp <= _TILE_MAX_W else _block_sum_program(
+                            "mean_panes", _block_levels(wp), T_pad, B_pad))
+            elif self.kind == "sum" and wp <= _TILE_MAX_W:
+                prog = _tile_sum_program(wp, T_pad, B_pad)
+            else:
+                # a wide sum, or a mean over the store's own tuples
+                prog = _block_sum_program(self.kind, _block_levels(wp),
+                                          T_pad, B_pad)
+        buf = pack_launch(
+            self._padded.take(packed_len(len(values), T_pad, B_pad),
+                              np.int32),
+            values, fill, starts, ends, T_pad, B_pad)
+        # a mean comes back as [sums; counts] and is divided on the host
+        return DeviceBatchHandle(prog(buf), B,
+                                 self.kind in ("mean", "mean_panes"))
+
+    def _compute_unpacked(self, cols, starts, ends, gwids, T_pad: int,
+                          B_pad: int) -> DeviceBatchHandle:
+        """The launches that keep their own columns: a user's window
+        function (the set of columns is the user's) and the two opt-in
+        Pallas kernels.  Host arrays go into the jitted call as they
+        are here too."""
+        B = len(starts)
+        T = len(next(iter(cols.values())))
         se = np.zeros((2, B_pad), dtype=np.int32)
         se[0, :B] = starts
         se[1, :B] = ends
-        # a mean comes back as [sums; counts] and is divided on the host
-        pair = self.kind in ("mean", "mean_panes")
 
         def pad_col(v, fill=0):
-            out = self._padded.take(T_pad, self.dtype)
-            out[:T] = v               # each element written once: a wide
-            out[T:] = fill            # launch's column is 33 MB
+            out = self._padded.take(T_pad, np.float32)
+            out[:T] = v
+            out[T:] = fill
             return out
 
         if self.is_ffat:
             _, comb, neutral = self.kind
-            vals_dev = jnp.asarray(pad_col(cols[self.value_col], neutral))
-            se_dev = jnp.asarray(se)
-            prog = (_ffat_pallas_program(comb, neutral, T_pad, B_pad)
-                    if _use_pallas("WINDFLOW_PALLAS_FFAT", T_pad, B_pad)
-                    else _ffat_program(comb, neutral, T_pad))
-            dev = prog(vals_dev, se_dev)
-        elif callable(self.kind):
-            valid = np.zeros(B_pad, dtype=bool)
-            valid[:B] = True
-            gwids_p = np.zeros(B_pad, dtype=np.int64)
-            gwids_p[:B] = gwids
-            w_pad = next_pow2(int((ends - starts).max()) if B else 1)
-            names = tuple(sorted(c for c in cols))
-            padded = [pad_col(cols[c]) for c in names]
-            prog = _custom_program(self.kind, w_pad, names)
-            dev = prog(jnp.asarray(gwids_p), jnp.asarray(se[0]),
-                       jnp.asarray(se[1]), jnp.asarray(valid), *padded)
-        elif self.kind == "mean_panes":
-            wp = next_pow2(max(int((ends - starts).max()) if B else 1, 2))
-            prog = (_tile_mean_program(wp) if wp <= _TILE_MAX_W
-                    else _block_sum_program("mean_panes", _block_levels(wp)))
-            dev = prog(jnp.asarray(pad_col(cols[self.value_col])),
-                       jnp.asarray(pad_col(cols["count"])),
-                       jnp.asarray(se))
-        elif self.kind in ("max", "min"):
-            fill = -np.inf if self.kind == "max" else np.inf
-            n_levels = max(1, int(np.log2(T_pad)) + 1)
-            prog = _sparse_table_program(self.kind, n_levels)
-            dev = prog(jnp.asarray(pad_col(cols[self.value_col], fill)),
-                       jnp.asarray(se))
-        elif (self.kind == "sum"
-              and _use_pallas("WINDFLOW_PALLAS_WINSUM", T_pad, B_pad)):
+            dev = _ffat_pallas_program(comb, neutral, T_pad, B_pad)(
+                pad_col(cols[self.value_col], neutral), se)
+            return DeviceBatchHandle(dev, B, buffers_in=2)
+        if self.kind == "sum":
             # hand-scheduled Pallas alternative to the XLA sum paths
             # (the ComputeBatch_Kernel twin).  T_pad/B_pad are powers
             # of two >= 2048, so the lane/row alignment holds by
@@ -485,15 +555,14 @@ class WindowComputeEngine:
             from .pallas.window_sum import window_sums_device
             dev = window_sums_device(
                 pad_col(cols[self.value_col]), se[0], se[1])[:, 0]
-        else:
-            # sum, count or mean over the buffer as the store staged it
-            wp = next_pow2(max(int((ends - starts).max()) if B else 1, 2))
-            if self.kind == "count":
-                prog = _count_program()
-            elif self.kind == "sum" and wp <= _TILE_MAX_W:
-                prog = _tile_sum_program(wp)
-            else:
-                prog = _block_sum_program(self.kind, _block_levels(wp))
-            dev = prog(jnp.asarray(pad_col(cols[self.value_col])),
-                       jnp.asarray(se))
-        return DeviceBatchHandle(dev, B, pair)
+            return DeviceBatchHandle(dev, B, buffers_in=3)
+        valid = np.zeros(B_pad, dtype=bool)
+        valid[:B] = True
+        gwids_p = np.zeros(B_pad, dtype=np.int64)
+        gwids_p[:B] = gwids
+        w_pad = next_pow2(int((ends - starts).max()) if B else 1)
+        names = tuple(sorted(cols))
+        prog = _custom_program(self.kind, w_pad, names)
+        dev = prog(gwids_p, se[0], se[1], valid,
+                   *[pad_col(cols[c]) for c in names])
+        return DeviceBatchHandle(dev, B, buffers_in=4 + len(names))

@@ -351,11 +351,13 @@ class Launch:
     ingest thread on the inline lane).  ``queue_wait``, ``dispatch``,
     ``ready_wait``, ``block`` and ``emit`` are their differences.
     ``collected`` (one of :data:`COLLECTED`) says how the dispatcher
-    came to take the result."""
+    came to take the result, ``buffers_in`` how many host arrays the
+    launch handed the device (1 where the engine packs the launch,
+    docs/RUNTIME.md 5c; 0 on the host lane)."""
 
-    __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "t_submitted",
-                 "t_picked", "t_dispatched", "t_ready_seen", "t_on_host",
-                 "t_emitted", "collected")
+    __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "buffers_in",
+                 "t_submitted", "t_picked", "t_dispatched", "t_ready_seen",
+                 "t_on_host", "t_emitted", "collected")
 
     def __init__(self, seq: int, chunk_seq: int, bytes_in: int,
                  t_submitted: float):
@@ -363,6 +365,7 @@ class Launch:
         self.chunk_seq = chunk_seq
         self.bytes_in = bytes_in
         self.bytes_out = 0
+        self.buffers_in = 0
         self.t_submitted = t_submitted
         self.t_picked = self.t_dispatched = self.t_ready_seen = None
         self.t_on_host = self.t_emitted = None
@@ -374,6 +377,7 @@ class Launch:
         return {"Seq": self.seq, "Chunk_seq": self.chunk_seq,
                 "Bytes_in": int(self.bytes_in),
                 "Bytes_out": int(self.bytes_out),
+                "Buffers_in": int(self.buffers_in),
                 "Picked_s": round(self.t_picked, 6),
                 "Collected": self.collected,
                 **{k: round(v, 4) for k, v in self.stages_ms().items()}}
@@ -429,13 +433,15 @@ class LaunchRing:
 
     def summary(self, t0: Optional[float] = None,
                 t1: Optional[float] = None) -> dict:
-        """Mean and longest of each stage over :meth:`finished`, how
-        many of them were collected in each way, and the launch whose
-        round trip (picked up to emitted) was the longest, whole: the
-        one to look for in a trace or a log."""
+        """Mean and longest of each stage over :meth:`finished`, the
+        host arrays those launches handed the device in all, how many of
+        them were collected in each way, and the launch whose round trip
+        (picked up to emitted) was the longest, whole: the one to look
+        for in a trace or a log."""
         done = self.finished(t0, t1)
         rows = [r.stages_ms() for r in done]
-        out = {"Operator": self.operator, "Launches": len(rows)}
+        out = {"Operator": self.operator, "Launches": len(rows),
+               "Buffers_in": sum(r.buffers_in for r in done)}
         for s in STAGES:
             vals = [r[s] for r in rows]
             out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
