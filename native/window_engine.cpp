@@ -987,6 +987,7 @@ struct Engine {
     template <bool SEL, bool AHEAD, typename TV>
     void ingest_walks(const i64* bkeys, const i64* ids, const i64* tss,
                       const TV* vals, i64 n, Sel s) {
+        call_begins();
         ++call_id;
         parts.clear();
         d_slot.clear();
@@ -1007,6 +1008,7 @@ struct Engine {
             else
                 note_late<false>(ids, nullptr, n);
         }
+        piece_ends(tuple_walk_ns);
         const std::size_t nd = parts.size();
         d_accept.resize(nd);
         d_single.resize(nd);
@@ -1045,12 +1047,19 @@ struct Engine {
             }
         }
         probes_ahead = (i64)(nd - opened_now.size()) * AHEAD_BLOCK > n;
+        // `open` inside the visit by key: the keys the call created and
+        // the anchors prepare() moved back; inside the second walk: the
+        // anchors fold_singly() moved back
+        piece_ends(key_walk_ns);
         if (n_single) fold_singly<SEL>(ids, tss, vals, n, s);
+        piece_ends(tuple_walk_ns);
         for (std::size_t d = 0; d < nd; ++d) {
             if (AHEAD && d + 8 < nd) fetch(d_state[d + 8]);
             settle(*d_state[d], d_slot[d], parts[d].hi);
         }
+        piece_ends(key_walk_ns);
         if (stream_rule) trigger();
+        call_ends();
     }
 
     // The second walk, for the keys fold_key() left: each of their
@@ -1223,6 +1232,7 @@ struct Engine {
         if (cut <= 0) return;
         if (cut > st.len) cut = st.len;
         const i64 kept = std::max<i64>(st.room - cut, 0);
+        panes_shifted += kept;
         shift(ring(st), cut, kept, -cut);
         stretch(st, st.len - cut, kept);
         st.pane_base += cut;
@@ -1234,6 +1244,7 @@ struct Engine {
     i64 flush(i64 max_windows) {
         st_n = 0;
         if (n_ready() == 0) return 0;
+        const i64 t_in = now_ns(), evict_in = evict_ns;
         const i64 take = std::min<i64>(max_windows, n_ready());
         const Desc* taken = ready.data() + ready_head;
         ++flush_id;
@@ -1371,6 +1382,7 @@ struct Engine {
             for (int32_t slot : f_dead) evict(slot);
             evict_ns += now_ns() - t0;
         }
+        stage_ns += (now_ns() - t_in) - (evict_ns - evict_in);
         return take;
     }
 
@@ -1576,6 +1588,50 @@ struct Engine {
         }
         return p == end;
     }
+
+    // THE INSIDE OF A BATCH CALL AND OF A FLUSH, on the clock of open_ns
+    // (a call at a time, never a key or a tuple at a time), read by
+    // wfn_engine_stats: ingest_walks() from entry to return less what
+    // `open` and `trigger` took inside it; of that, the walks that cost
+    // by the tuple (gather, note_late, fold_singly) and those that cost
+    // by the key (prepare of the keys the call did not open, settle);
+    // flush() from entry to return less `evict`.  retire() runs once a
+    // key inside the staging loop and has no clock: it counts the ring
+    // elements it moves down.
+    // Behind every other member and out of line, so that the walks are
+    // compiled as they were before there was a clock: members declared
+    // among the others move everything behind them (the table, the
+    // call's parts) to other offsets and cache lines, and a stamp that
+    // is inlined between two walks, or a value that lives across
+    // gather()'s loop, takes part in how that loop is laid out (THE
+    // TUPLE WALK IS BOUND BY WHAT IT DOES A TUPLE, above; PERF.md
+    // section 6, PR 37)
+    i64 ingest_ns = 0, tuple_walk_ns = 0, key_walk_ns = 0, stage_ns = 0;
+    i64 panes_shifted = 0;
+    // a batch call's clock: when it began and when its last piece ended,
+    // and `open_ns` / `trigger_ns` as they stood then
+    struct CallClock {
+        i64 began, mark, open_began, open_mark, trigger_began;
+    } call_clock;
+    __attribute__((noinline)) void call_begins() {
+        CallClock& c = call_clock;
+        c.began = c.mark = now_ns();
+        c.open_began = c.open_mark = open_ns;
+        c.trigger_began = trigger_ns;
+    }
+    // the piece of the call that ends here, less the `open` inside it
+    __attribute__((noinline)) void piece_ends(i64& into) {
+        CallClock& c = call_clock;
+        const i64 t = now_ns();
+        into += (t - c.mark) - (open_ns - c.open_mark);
+        c.mark = t;
+        c.open_mark = open_ns;
+    }
+    __attribute__((noinline)) void call_ends() {
+        const CallClock& c = call_clock;
+        ingest_ns += (now_ns() - c.began) - (open_ns - c.open_began)
+            - (trigger_ns - c.trigger_began);
+    }
 };
 
 }  // namespace
@@ -1670,7 +1726,8 @@ i64 wfn_engine_ignored(void* ep) {
 }
 
 // What key churn costs, how many keys there are, how the fold went and
-// what disorder it met, into out[19]: nanoseconds spent creating key
+// what disorder it met, into out[24].  The first nineteen: nanoseconds
+// spent creating key
 // states and moving anchors back (open), finding and queueing fired
 // windows (trigger) and evicting (evict), since the engine was made; keys
 // opened, keys evicted, keys live now and at their peak, windows fired;
@@ -1681,7 +1738,11 @@ i64 wfn_engine_ignored(void* ep) {
 // the per-key visit of the calls met, those of them in a call that ran
 // ahead of itself (Engine, "WHERE A CALL RUNS AHEAD OF ITSELF"), the
 // rings that left their key state (KeyState), and the pane partials and
-// windows flush() staged.
+// windows flush() staged.  Behind those nineteen, into out[19..24): what
+// a batch call took less its `open` and `trigger` (ingest), of that the
+// walks by the tuple and the walks by the key, what flush() took less
+// its `evict` (stage), nanoseconds on the same clock, and the ring
+// elements retire() moved down (Engine, beside open_ns).
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -1703,6 +1764,11 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[16] = e.rings_spilled;
     out[17] = e.panes_staged;
     out[18] = e.windows_staged;
+    out[19] = e.ingest_ns;
+    out[20] = e.tuple_walk_ns;
+    out[21] = e.key_walk_ns;
+    out[22] = e.stage_ns;
+    out[23] = e.panes_shifted;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }

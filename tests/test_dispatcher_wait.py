@@ -33,6 +33,7 @@ class Handle:
     """A launch's result: ready once ``event`` is set."""
 
     buffers_in = 1      # what the launch record takes from a handle
+    t_packed = t_called = None
 
     def __init__(self, n, log):
         self.n, self.log = n, log
@@ -91,7 +92,7 @@ class Rig:
         n = self.logic._launches.seq + 1
         one = np.asarray([n], np.int64)
         self.logic._submit({}, one, one, one, (one, one, one),
-                           time.perf_counter(), self._emit, engine=self)
+                           self._emit, engine=self)
 
     @property
     def dispatcher(self):

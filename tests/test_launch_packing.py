@@ -122,22 +122,29 @@ def test_a_users_window_function_keeps_its_columns():
 
 # -- the counter, through an ordinary graph ---------------------------------
 
-def test_a_graphs_launches_hand_the_device_one_buffer_each():
-    n_keys, n, chunk = 7, 40_000, 4096
+N_KEYS, N_EVENTS = 7, 40_000
+
+
+def seven_keys(chunk=4096):
+    """A ``BatchSource`` body: ``N_EVENTS`` events in chunks, id = ts =
+    index, key = index % 7, value = index % 5."""
     sent = {"i": 0}
 
     def body(ctx=None):
         a = sent["i"]
-        if a >= n:
+        if a >= N_EVENTS:
             return None
-        sent["i"] = b = min(a + chunk, n)
+        sent["i"] = b = min(a + chunk, N_EVENTS)
         i = np.arange(a, b, dtype=np.int64)
-        return TupleBatch({"key": i % n_keys, "id": i, "ts": i,
+        return TupleBatch({"key": i % N_KEYS, "id": i, "ts": i,
                            "value": (i % 5).astype(np.float64)})
+    return body
 
+
+def test_a_graphs_launches_hand_the_device_one_buffer_each():
     rows = []
     g = wf.PipeGraph("launch_packing", wf.Mode.DEFAULT)
-    pipe = g.add_source(BatchSource(body))
+    pipe = g.add_source(BatchSource(seven_keys()))
     pipe.add(KeyFarmTPU("sum", 2048, 1024, WinType.TB, name="packed",
                         emit_batches=True))
     pipe.add_sink(Sink(lambda b: rows.append(b) if b is not None else None))
@@ -152,6 +159,92 @@ def test_a_graphs_launches_hand_the_device_one_buffer_each():
     assert launches and all(
         row["Buffers_in"] == row["Launches"] > 0
         and row["Slowest"]["Buffers_in"] == 1 for row in launches)
+
+
+# -- the two stamps inside dispatch (PR 37) ----------------------------------
+
+def test_a_handle_brings_back_the_engines_two_stamps():
+    """``t_packed`` after the host's preparation and before the jitted
+    call, ``t_called`` as that call returns: on the packed path and on
+    the path that keeps its columns; the host lane takes none."""
+    import time
+    from windflow_tpu.ops.host_compute import HostComputeEngine
+    _, jnp = jax_modules()
+    cols, starts, ends = launch(33, 111)
+    one = {"value": cols["value"]}
+
+    def total(gwid, win, mask):
+        return jnp.where(mask, win["value"], 0).sum()
+
+    for eng in (engine("sum"), engine("mean"), WindowComputeEngine(total)):
+        t0 = time.perf_counter()
+        handle = eng.compute(one, starts, ends, np.arange(111))
+        t1 = time.perf_counter()
+        assert t0 <= handle.t_packed <= handle.t_called <= t1
+        assert len(handle.block()) == 111
+    host = HostComputeEngine("sum").compute(one, starts, ends,
+                                                  np.arange(111))
+    assert host.t_packed is None and host.t_called is None
+
+
+@pytest.mark.parametrize("lane", ("packed", "unpacked", "host"))
+def test_pack_call_and_handoff_tile_a_launchs_dispatch(lane):
+    """``t_picked <= t_packed <= t_called <= t_dispatched`` and the three
+    parts add up to the ``dispatch`` that stays, in the records and in
+    the stats JSON (schema 20); a lane without the stamps leaves the
+    parts None and every reader skips them."""
+    kind = "sum"
+    if lane == "unpacked":
+        _, jnp = jax_modules()
+
+        def kind(gwid, win, mask):
+            return jnp.where(mask, win["value"], 0).sum()
+    rows = {}
+
+    def sink(b):
+        if b is not None:
+            rows.update(zip(zip(b.key.tolist(), b.id.tolist()),
+                            np.asarray(b["value"]).tolist()))
+    name = f"launch_stamps_{lane}"
+    g = wf.PipeGraph(name, wf.Mode.DEFAULT)
+    pipe = g.add_source(BatchSource(seven_keys()))
+    pipe.add(KeyFarmTPU(kind, 2048, 1024, WinType.TB, name="stamped",
+                        emit_batches=True,
+                        placement="host" if lane == "host" else "device"))
+    pipe.add_sink(Sink(sink))
+    g.run()
+    # the rows are the plain recomputation's whatever the lane
+    i = np.arange(N_EVENTS)
+    want = {}
+    for back in (0, 1):
+        w = i // 1024 - back
+        for k, x, v in zip((i % N_KEYS)[w >= 0].tolist(), w[w >= 0].tolist(),
+                           (i % 5)[w >= 0].tolist()):
+            want[(k, x)] = want.get((k, x), 0.0) + v
+    assert rows == want
+    ring, = spans.graph(name).rings.values()
+    done = ring.finished()
+    assert len(done) >= 2
+    summary = ring.summary()
+    launches, = json.loads(g.stats.to_json())["Spans"]["Launches"]
+    if lane == "host":
+        assert all(r.t_packed is None and r.t_called is None for r in done)
+        assert all(r.stages_ms()[part] is None and summary[part] is None
+                   and launches[part] is None
+                   and part not in launches["Slowest"]
+                   for part in spans.DISPATCH_PARTS for r in done)
+        assert summary["dispatch"]["mean_ms"] >= 0
+        return
+    for r in done:
+        assert r.t_picked <= r.t_packed <= r.t_called <= r.t_dispatched
+        st = r.stages_ms()
+        assert st["pack"] + st["call"] + st["handoff"] \
+            == pytest.approx(st["dispatch"], abs=1e-9)
+    assert sum(summary[part]["mean_ms"] for part in spans.DISPATCH_PARTS) \
+        == pytest.approx(summary["dispatch"]["mean_ms"], abs=1e-3)
+    assert all(launches[part]["max_ms"] >= launches[part]["mean_ms"] >= 0
+               and launches["Slowest"][part] >= 0
+               for part in spans.DISPATCH_PARTS)
 
 
 # -- the pool's promise -----------------------------------------------------

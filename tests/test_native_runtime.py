@@ -836,6 +836,140 @@ def test_the_three_counts_reach_the_series_the_stats_and_the_metrics_page(
         assert f"windflow_engine_{n_}_total{{" in text, n_
 
 
+# -- the engine's clocks of the inside of fold and flush (PR 37) ------------
+
+PARENT_STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
+                "keys_evicted", "keys_live", "keys_live_peak",
+                "windows_fired", "folded_by_key", "folded_singly",
+                "late_accepted", "anchors_moved", "inputs_ignored",
+                "stream_time", "key_touches", "walked_ahead",
+                "rings_spilled", "panes_staged", "windows_staged")
+CLOCKS = ("ingest_ns", "tuple_walk_ns", "key_walk_ns", "stage_ns",
+          "panes_shifted", "copy_out_ns")
+
+
+@pytest.mark.parametrize("case,want", [
+    ("moved_back", [2, 2, 0, 2, 15, 9, 0, 5, 3, 0, 2000, 8, 0, 2, 22, 15]),
+    ("q5_ooo", [3908, 3908, 0, 2931, 6998, 58278, 1722, 5903, 2, 0, 59999,
+                6819, 5155, 0, 13208, 6998])])
+def test_the_first_nineteen_stats_keep_their_order_and_values(case, want):
+    """``_account_churn`` slices ``STATS`` by position: the new clocks
+    come behind the nineteen, whose counts on a fixed stream are the
+    ones the engine of the commit before read (PR 37's parent, by
+    position 3..18)."""
+    from windflow_tpu.telemetry import spans
+    assert NativeWindowEngine.STATS[:19] == PARENT_STATS
+    assert NativeWindowEngine.STATS[19:] == CLOCKS == tuple(
+        spans.ENGINE_CLOCKS)
+    eng = ks_moved_back()[0] if case == "moved_back" \
+        else ks_q5("ooo", "count", 4096, n=60_000)[0]
+    assert list(eng.stats())[3:19] == want
+    assert [eng.snapshot()[n] for n in PARENT_STATS[3:]] == want
+
+
+def test_each_clock_grows_only_in_the_call_it_times():
+    """0 before a call; ``ingest_ns`` and its two walks move in a batch
+    call and not in a flush, ``stage_ns`` and ``copy_out_ns`` in a flush
+    and not in a batch call; the walks lie inside ``ingest_ns`` and that,
+    with ``open`` and ``trigger``, inside the wall time round the calls;
+    the record-at-a-time lane (``synth_ingest``) has no clock."""
+    import time
+    keys, ts, vals = q5_law(120_000, KS_DELAY)
+    eng = NativeWindowEngine(KS_WIN, KS_SLIDE, True, KS_DELAY, kind="count")
+    assert not any(eng.snapshot()[n] for n in CLOCKS)
+    wall = 0
+    for lo in range(0, len(keys), 4096):
+        before = eng.snapshot()
+        t0 = time.perf_counter_ns()
+        ready = eng.ingest(keys[lo:lo + 4096], ts[lo:lo + 4096],
+                           ts[lo:lo + 4096], vals[lo:lo + 4096])
+        wall += time.perf_counter_ns() - t0
+        mid = eng.snapshot()
+        for n in ("ingest_ns", "tuple_walk_ns", "key_walk_ns"):
+            assert mid[n] > before[n], n
+        for n in ("stage_ns", "copy_out_ns", "panes_shifted", "evict_ns"):
+            assert mid[n] == before[n], n
+        if not ready:
+            continue
+        t0 = time.perf_counter_ns()
+        out = eng.flush(1 << 30)
+        flush_wall = time.perf_counter_ns() - t0
+        after = eng.snapshot()
+        assert out is not None
+        assert after["stage_ns"] > mid["stage_ns"]
+        assert after["copy_out_ns"] > mid["copy_out_ns"]
+        assert after["stage_ns"] - mid["stage_ns"] \
+            + after["copy_out_ns"] - mid["copy_out_ns"] \
+            + after["evict_ns"] - mid["evict_ns"] <= flush_wall
+        for n in ("ingest_ns", "tuple_walk_ns", "key_walk_ns", "open_ns",
+                  "trigger_ns"):
+            assert after[n] == mid[n], n
+    s = eng.snapshot()
+    assert s["windows_staged"] > 1000 and s["panes_shifted"] > 0
+    assert 0 < s["tuple_walk_ns"] + s["key_walk_ns"] <= s["ingest_ns"]
+    assert s["ingest_ns"] + s["open_ns"] + s["trigger_ns"] <= wall
+    # the wrapper's own clock: an attribute, mirrored into the slot of
+    # the buffer behind the twenty-four the engine refills
+    assert NativeWindowEngine.STATS.index("copy_out_ns") == 24
+    assert eng.stats()[24] == eng.stats()[24] == eng.copy_out_ns \
+        == s["copy_out_ns"] > 0
+    assert list(eng.stats())[19:24] == [s[n] for n in CLOCKS[:5]]
+    assert eng.flush(1 << 30) is None       # nothing ready: no clock read
+    assert eng.snapshot()["stage_ns"] == s["stage_ns"]
+    synth = NativeWindowEngine(64, 32, True, 0, kind="sum")
+    synth.synth_ingest(0, 10_000, 5)
+    assert synth.snapshot()["windows_fired"] > 0
+    assert not any(synth.snapshot()[n] for n in CLOCKS[:3])
+
+
+def test_panes_shifted_is_the_ring_elements_retire_moved_down():
+    """Two keys, windows of four panes of 10 sliding by one, no delay.
+    Key 1 has a tuple in every pane 0..7, key 2 in panes 2..7 (its
+    anchor is window 0 too: panes 2 and 3 lie in it); a ring grown to
+    hold pane 7 is ``7 + 1 + (7 // 2 + 8) = 19`` elements long with its
+    headroom (``grow_ring``).  The tuple at 79 makes the stream pass
+    windows 0..3; the flush stages them for both keys, each key fires
+    window 4 next, so its ring drops panes 0..3 and moves the 15
+    elements behind them down.  The second firing (a tuple at 119 passes
+    windows 4..7) finds key 1's ring at pane 4, 15 long, pane 11 inside
+    it: it drops 4..7 and moves 11 down; key 2 has nothing opened and is
+    evicted, which moves nothing."""
+    eng = NativeWindowEngine(40, 10, True, 0, kind="count")
+
+    def ingest(keys, ts):
+        ts = np.asarray(ts, np.int64)
+        return eng.ingest(np.asarray(keys, np.int64), ts, ts,
+                          np.ones(len(ts)))
+    ready = ingest([1] * 8 + [2] * 6,
+                   [5, 15, 25, 35, 45, 55, 65, 79, 25, 35, 45, 55, 65, 75])
+    assert ready == 8 and eng.snapshot()["panes_shifted"] == 0
+    assert len(eng.flush(1 << 30)[1]) == 8
+    assert eng.snapshot()["panes_shifted"] == 15 + 15
+    assert ingest([1] * 4, [85, 95, 105, 119]) == 8   # 4..7 of both keys
+    assert len(eng.flush(1 << 30)[1]) == 8
+    s = eng.snapshot()
+    assert s["panes_shifted"] == 15 + 15 + 11
+    assert (s["keys_evicted"], s["keys_live"]) == (1, 1)
+
+
+def test_the_clocks_are_no_part_of_the_state():
+    """A snapshot restores the engine's counts and none of its clocks:
+    the serialized bytes are the parent's (the golden snapshots above),
+    and an engine restored from them starts its clocks at 0."""
+    name = next(iter(KS_SNAPSHOTS))
+    eng, rows = ks_snap_engine(name), []
+    ks_snap_feed(eng, name, 0, KS_SNAPSHOTS[name][3], rows)
+    before = eng.snapshot()
+    assert before["ingest_ns"] > 0 and before["keys_opened"] > 0
+    twin = ks_snap_engine(name)
+    twin.deserialize(eng.serialize())
+    after = twin.snapshot()
+    assert after["keys_opened"] == before["keys_opened"]
+    assert after["keys_live"] == before["keys_live"]
+    assert not any(after[n] for n in CLOCKS)
+    assert twin.serialize()["native"] == eng.serialize()["native"]
+
+
 if __name__ == "__main__":
     gold = {"digests": {n: f() for n, f in ks_cases().items()},
             "snapshots": {}}

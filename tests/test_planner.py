@@ -391,7 +391,7 @@ def test_finish_normalizes_launch_wall_by_inflight_depth():
     logic._adaptive = AdaptiveBatcher(256, floor_ms=10.0, patience=1)
     t_sub = _t.perf_counter() - 0.080  # 80 ms wall, 8 deep => 10 ms each
     none = np.empty(0, np.int64)   # a batch of no rows
-    logic._finish((_H(), (none, none, none), t_sub, t_sub, 8, 0,
+    logic._finish((_H(), (none, none, none), t_sub, 8, 0,
                    logic._launches.open(0, 0, t_sub)), lambda *_: None,
                   spans.FORCED)
     # ~floor after normalization: a grow vote (raw 80 ms >= 8x floor

@@ -553,3 +553,101 @@ def test_the_stats_json_and_openmetrics_carry_the_spans():
     assert {"windflow_operator_busy_seconds",
             "windflow_operator_idle_seconds",
             "windflow_operator_blocked_seconds"} <= families
+
+
+# -- the inside of fold and flush (PR 37) ----------------------------------
+
+def test_between_reads_what_the_four_old_readers_read():
+    """``Counters.between(names, t0, t1)`` is the one cut of the series;
+    ``moved_between``, ``folded_between``, ``staged_between`` and
+    ``touched_between`` are callers of it, and the clocks of
+    ``ENGINE_CLOCKS`` are cut by it like any count; ``cut`` gives the
+    instants of the two notes it read between."""
+    c = spans.Counters("op")
+    names = spans.ENGINE_COUNTERS
+    rng = np.random.default_rng(5)
+    level = np.zeros(len(names), np.int64)
+    noted = []
+    for ms in (40, 130, 990, 1500, 1501, 2950, 3400):
+        level = level + rng.integers(0, 1000, len(names))
+        c.note(ms * MS, level.tolist())
+        noted.append((ms, dict(zip(names, level.tolist()))))
+
+    def moved(name, t0_ms, t1_ms):
+        # the last note in the buckets before t0's, the last up to t1's
+        lo = [v for ms, v in noted if ms // 100 < t0_ms // 100]
+        hi = [v for ms, v in noted if ms // 100 <= t1_ms // 100]
+        return (hi[-1][name] if hi else 0) - (lo[-1][name] if lo else 0)
+
+    def notes_at(t0_ms, t1_ms):
+        # the instants of those two notes: how wide the cut really is
+        lo = [ms for ms, _ in noted if ms // 100 < t0_ms // 100]
+        hi = [ms for ms, _ in noted if ms // 100 <= t1_ms // 100]
+        if not lo or not hi or hi[-1] <= lo[-1]:
+            return None
+        return lo[-1] / 1e3, hi[-1] / 1e3
+    assert notes_at(1000, 3000) == (0.99, 2.95)
+    assert notes_at(0, 3000) is None and notes_at(5000, 6000) is None
+    for t0, t1 in ((1000, 3000), (0, 3000), (100, 1550), (5000, 6000)):
+        cut = t0 / 1e3, t1 / 1e3
+        assert c.cut(*cut) == notes_at(t0, t1)
+        by_name = c.moved_between(*cut)
+        assert list(by_name) == list(spans.SERIES_COUNTERS)
+        assert by_name == {n: moved(n, t0, t1)
+                           for n in spans.SERIES_COUNTERS}
+        for reader, pair in ((c.folded_between,
+                              ("folded_by_key", "folded_singly")),
+                             (c.staged_between,
+                              ("panes_staged", "windows_staged")),
+                             (c.touched_between,
+                              ("key_touches", "walked_ahead"))):
+            assert reader(*cut) == c.between(pair, *cut) \
+                == tuple(by_name[n] for n in pair)
+        clocks = tuple(spans.ENGINE_CLOCKS)
+        assert c.between(clocks[::-1], *cut) \
+            == tuple(by_name[n] for n in clocks[::-1])
+    assert c.between((), 1.0, 3.0) == ()
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "python"])
+def test_the_engines_clocks_are_counters_and_no_spans(native):
+    """The inside of ``fold`` and ``flush`` reaches the registry's
+    series, ``Spans.Operators[].Counters`` (schema 20) and ``/metrics``
+    as counters; no span carries a clock's name and the two spans' self
+    time still holds what the clocks read; the Python store counts none."""
+    if native and not native_available():
+        pytest.skip("no native library")
+    rows = []
+    name = f"spans_clocks_{native}"
+    g = windowed(name, True, lambda b: rows.append(b) if b is not None
+                 else None, native=native)
+    g.run()
+    assert rows
+    clocks = tuple(spans.ENGINE_CLOCKS)
+    cells = cells_of(name)
+    assert not {c.phase for c in cells.values()} & (
+        {"ingest", "tuple_walk", "key_walk", "copy_out"} | set(clocks))
+    rep = json.loads(g.stats.to_json())
+    assert rep["Schema_version"] >= 20
+    if not native:
+        assert not any(c.values[n] for n in clocks
+                       for c in spans.graph(name).counters.values())
+        assert not any(r["Counters"].get(n) for n in clocks
+                       for r in rep["Spans"]["Operators"] if "Counters" in r)
+        return
+    kept, = spans.graph(name).counters.values()
+    row = next(r for r in rep["Spans"]["Operators"] if "Counters" in r)
+    assert {n: row["Counters"][n] for n in clocks} \
+        == {n: kept.values[n] for n in clocks}
+    assert kept.between(clocks, 0.0, 1e12) \
+        == tuple(kept.values[n] for n in clocks)
+    text = render_openmetrics({1: {"active": True, "report": rep}})
+    for n in clocks:
+        assert f"windflow_engine_{n}_total{{" in text, n
+    v = kept.values
+    fold = sum(c.self_ns for c in cells.values() if c.phase == "fold")
+    flush = sum(c.self_ns for c in cells.values() if c.phase == "flush")
+    assert 0 < v["tuple_walk_ns"] + v["key_walk_ns"] <= v["ingest_ns"] < fold
+    assert 0 < v["stage_ns"] and 0 < v["copy_out_ns"]
+    assert v["stage_ns"] + v["copy_out_ns"] < flush
+    assert v["panes_shifted"] > 0

@@ -428,7 +428,7 @@ def test_adaptive_resize_records_flight_event():
     for _ in range(2):  # launches near the floor -> x2 after patience
         t_sub = time.perf_counter()
         none = np.empty(0, np.int64)   # a batch of no rows
-        lg._finish((_Handle(), (none, none, none), t_sub, t_sub, 1, 0,
+        lg._finish((_Handle(), (none, none, none), t_sub, 1, 0,
                     lg._launches.open(0, 0, t_sub)), lambda x: None,
                    spans.READY)
     assert lg.batch_len == 512

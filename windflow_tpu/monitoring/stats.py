@@ -74,10 +74,15 @@ from ..telemetry.histogram import LogHistogram
 # 19 = Spans.Launches rows gain Buffers_in (host arrays the row's
 # launches handed the device, summed: one a launch where the engine packs
 # it into one buffer, docs/RUNTIME.md 5c) and their Slowest row its own.
+# 20 = those Counters gain the inside of fold and flush
+# (spans.ENGINE_CLOCKS: ingest_ns, tuple_walk_ns, key_walk_ns, stage_ns,
+# panes_shifted, copy_out_ns) and Spans.Launches rows the three parts of
+# dispatch (pack, call, handoff; None where the lane takes no stamps for
+# them), their Slowest row its own.
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 19
+SCHEMA_VERSION = 20
 
 
 @dataclass

@@ -239,13 +239,13 @@ class _AsyncDispatcher:
                 break
             if self.aborting or self.error is not None:
                 continue  # failed/aborted: drain the queue, launch nothing
-            (engine, cols, starts, ends, gwids, descs, birth, emit,
+            (engine, cols, starts, ends, gwids, descs, emit,
              nbytes_in, rec) = item
             last_emit = emit
             try:
                 handle, t_sub = logic._dispatch(
                     tr, engine, cols, starts, ends, gwids, rec)
-                pending.append((handle, descs, birth, t_sub,
+                pending.append((handle, descs, t_sub,
                                 len(pending) + 1, nbytes_in, rec))
                 # collect at depth (backpressure) AND any batch whose
                 # result is ready already -- otherwise results wait
@@ -383,7 +383,7 @@ class WinSeqTPULogic(NodeLogic):
         self.value_of = value_of or (lambda t: t.value)
         self.closing_func = closing_func
         self.emit_batches = emit_batches
-        # in-flight batches, oldest first: (handle, descriptors, birth).
+        # in-flight batches, oldest first: (handle, descriptors, ...).
         # Depth > 1 keeps several device programs + async D2H copies in
         # flight so one high-latency transport roundtrip amortizes over
         # many launches (deepens the reference's 2-deep waitAndFlush
@@ -395,7 +395,6 @@ class WinSeqTPULogic(NodeLogic):
         self.sync_emit = not async_dispatch
         self._dispatcher: Optional[_AsyncDispatcher] = None
         self.launched_batches = 0
-        self.last_launch_ms = 0.0  # newest picked-up->result host wall (ms)
         # launch also when this much unshipped data is buffered, even if
         # the window batch is not full -- bounds host memory and keeps
         # device transfers pipelined (the adaptive resize analogue,
@@ -410,10 +409,6 @@ class WinSeqTPULogic(NodeLogic):
         # (full-batch fill time + RTT)
         self.max_batch_delay_ms = max_batch_delay_ms
         self._last_launch_t = 0.0
-        # window-result latency samples (first ready window -> emission),
-        # feeding the p99 metric of BASELINE.md
-        self.latency_samples: List[float] = []
-        self._batch_birth: Optional[float] = None
         # the emit of the call in hand, for a launch from inside the
         # Python store's ingest (``_on_full``)
         self._emit = None
@@ -591,10 +586,10 @@ class WinSeqTPULogic(NodeLogic):
         """Flush one in-flight batch: copy its result to the host
         (``block``), add the launch's host wall (picked up -> result on
         host: dispatch, ready wait and block; no device clock is read)
-        to ``Device_time_ms``, sample the window-result latency, feed
-        the adaptive batch resize, emit.  Stamps the launch record;
-        ``how`` (``spans.COLLECTED``) says what brought the caller here."""
-        handle, descs, birth, t_sub, depth, nbytes_in, rec = entry
+        to ``Device_time_ms``, feed the adaptive batch resize, emit.
+        Stamps the launch record; ``how`` (``spans.COLLECTED``) says what
+        brought the caller here."""
+        handle, descs, t_sub, depth, nbytes_in, rec = entry
         rec.collected = how
         if rec.t_ready_seen is None:   # inline lane: block() waits too
             rec.t_ready_seen = _time.perf_counter()
@@ -607,9 +602,6 @@ class WinSeqTPULogic(NodeLogic):
         now = rec.t_on_host = _time.perf_counter()
         rec.bytes_out = results.nbytes
         launch_ms = (now - t_sub) * 1e3
-        self.last_launch_ms = launch_ms
-        if len(self.latency_samples) < 100_000:
-            self.latency_samples.append(now - birth)
         if self.stats is not None:  # single-writer: dispatcher thread
             self.stats.bytes_from_device += results.nbytes
             self.stats.device_time_ms += launch_ms
@@ -664,7 +656,10 @@ class WinSeqTPULogic(NodeLogic):
         """``engine.compute`` under the ``dispatch`` span, whose
         annotation carries the launch sequence number so that a program
         on the device line of a profiler trace can be joined to the
-        launch that caused it.  Returns (handle, t_picked)."""
+        launch that caused it.  The handle brings back what the engine
+        alone knows of the launch: the host arrays it handed the device
+        and its two stamps inside ``dispatch`` (None from a lane that
+        takes none).  Returns (handle, t_picked)."""
         t_sub = rec.t_picked = _time.perf_counter()
         tr.begin(self._n_dispatch, {"launch": rec.seq})
         try:
@@ -673,10 +668,11 @@ class WinSeqTPULogic(NodeLogic):
             tr.end()
         rec.t_dispatched = _time.perf_counter()
         rec.buffers_in = handle.buffers_in
+        rec.t_packed, rec.t_called = handle.t_packed, handle.t_called
         self.launched_batches += 1
         return handle, t_sub
 
-    def _submit(self, cols, starts, ends, gwids, descs, birth, emit,
+    def _submit(self, cols, starts, ends, gwids, descs, emit,
                 engine=None) -> None:
         """Hand one staged batch to the device: via the dispatcher
         thread (default) or inline with the waitAndFlush protocol."""
@@ -692,13 +688,13 @@ class WinSeqTPULogic(NodeLogic):
             if self._dispatcher is None:
                 self._dispatcher = _AsyncDispatcher(self)
             self._dispatcher.submit(
-                (eng, cols, starts, ends, gwids, descs, birth, emit,
+                (eng, cols, starts, ends, gwids, descs, emit,
                  nbytes_in, rec))
         else:
             self._flush_pending(emit)  # waitAndFlush of the previous
             handle, t_sub = self._dispatch(spans.track(), eng, cols,
                                            starts, ends, gwids, rec)
-            self.pending.append((handle, descs, birth, t_sub,
+            self.pending.append((handle, descs, t_sub,
                                  len(self.pending) + 1, nbytes_in, rec))
         self._buffered_since_launch = 0
         self._last_launch_t = _time.perf_counter()
@@ -769,16 +765,12 @@ class WinSeqTPULogic(NodeLogic):
             if out is None:
                 return
             cols, starts, ends, d_keys, d_gwids, d_rts, kind = out
-            birth = self._batch_birth or _time.perf_counter()
-            # leftover ready windows (partial flush) restart the age clock
-            self._batch_birth = (_time.perf_counter() if store.ready()
-                                 else None)
             if self.stats is not None:  # single-writer: ingest thread
                 # the Python store's late tuples; the native engine's
                 # are among its counters (``_account_churn``)
                 self.stats.inputs_ignored = self._py.ignored()
             self._submit(cols, starts, ends, d_gwids,
-                         (d_keys, d_gwids, d_rts), birth, emit,
+                         (d_keys, d_gwids, d_rts), emit,
                          engine=self._helper_engine(kind))
         finally:
             tr.end()
@@ -795,8 +787,6 @@ class WinSeqTPULogic(NodeLogic):
         the native engine's leaves after the ingest (``_folded``):
         launch counts and sizes differ, and tests and the
         AdaptiveBatcher read them (ROADMAP D2)."""
-        if self._batch_birth is None:
-            self._batch_birth = _time.perf_counter()
         if ready >= self.batch_len and not self.chunk_hold:
             self._launch(self._emit, max_windows=ready)
 
@@ -804,10 +794,12 @@ class WinSeqTPULogic(NodeLogic):
         """What the store timed and counted since the last look (the
         native engine does, ``NativeWindowEngine.STATS``): ``open``,
         ``trigger`` and ``evict`` become children of the span open round
-        the call, the counters go to the registry.  A look that finds
-        no key opened and no clock moved costs one call: the fold's two
-        counts, which move with every chunk, go with the next look that
-        does (every firing, and EOS)."""
+        the call, the counters go to the registry, and with them the
+        engine's clocks of the inside of ``fold`` and ``flush``
+        (``spans.ENGINE_CLOCKS``: counters, not children).  A look that
+        finds no key opened and no churn clock moved costs one call: the
+        fold's counts and clocks, which move with every chunk, go with
+        the next look that does (every firing, and EOS)."""
         s = store.stats()
         last = self._churn
         if s[0] == last[0] and s[1] == last[1] and s[2] == last[2] \
@@ -819,10 +811,10 @@ class WinSeqTPULogic(NodeLogic):
             if s[i] != last[i]:
                 tr.account(name, s[i] - last[i])
         last[:] = s
-        # spans.ENGINE_COUNTERS: STATS without the clocks and the stream
-        # time
+        # spans.ENGINE_COUNTERS: STATS without the churn clocks and the
+        # stream time
         self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
-                            last[3:13] + last[14:19])
+                            last[3:13] + last[14:])
 
     def _launch_due(self) -> bool:
         return ((_time.perf_counter() - self._last_launch_t) * 1e3
@@ -830,7 +822,7 @@ class WinSeqTPULogic(NodeLogic):
 
     def _folded(self, ready: int, n: int, emit, chunk: bool = True) -> None:
         """After an ingest of ``n`` events (a chunk, or one record) left
-        ``ready`` windows: the age clock, and a launch where one is due.
+        ``ready`` windows: a launch where one is due.
         The Python store has counted what it kept of a chunk and sent
         its full batches from inside the ingest (``_on_kept``,
         ``_on_full``): what it leaves goes whole, on the age bound or,
@@ -842,8 +834,6 @@ class WinSeqTPULogic(NodeLogic):
                     >= self.max_buffer_elems))):
                 self._launch(emit, max_windows=ready)
             return
-        if ready and self._batch_birth is None:
-            self._batch_birth = _time.perf_counter()
         self._buffered_since_launch += n
         if (ready and not self.chunk_hold
                 and (ready >= self.batch_len

@@ -37,7 +37,8 @@ class HostBatchHandle:
     result already materialized when ``compute`` returned."""
 
     __slots__ = ("_arr",)
-    buffers_in = 0      # nothing is handed to a device
+    buffers_in = 0      # nothing is handed to a device,
+    t_packed = t_called = None   # and ``dispatch`` has no parts
 
     def __init__(self, arr: np.ndarray):
         self._arr = arr

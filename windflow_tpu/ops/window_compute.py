@@ -51,6 +51,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import time
 from typing import Any, Callable, Dict
 
 import numpy as np
@@ -407,12 +408,19 @@ class DeviceBatchHandle:
     be checked bit for bit.  65,536 quotients take some 20 us.
 
     ``buffers_in`` is how many host arrays the launch handed the device
-    (1 on the packed paths; ``spans.Launch.buffers_in``)."""
+    (1 on the packed paths; ``spans.Launch.buffers_in``).  ``t_packed``
+    and ``t_called`` are the engine's two ``perf_counter`` stamps of the
+    launch (``spans.Launch``): the host had finished preparing what it
+    hands the runtime; the jitted call had returned, before the copy
+    back is started here."""
 
-    __slots__ = ("_dev", "_n", "_pair", "buffers_in")
+    __slots__ = ("_dev", "_n", "_pair", "buffers_in", "t_packed",
+                 "t_called")
 
-    def __init__(self, dev_array, n_valid: int, pair: bool = False,
-                 buffers_in: int = 1):
+    def __init__(self, dev_array, n_valid: int, t_packed: float,
+                 pair: bool = False, buffers_in: int = 1):
+        self.t_called = time.perf_counter()
+        self.t_packed = t_packed
         self._dev = dev_array
         self._n = n_valid
         self._pair = pair
@@ -520,8 +528,9 @@ class WindowComputeEngine:
             self._padded.take(packed_len(len(values), T_pad, B_pad),
                               np.int32),
             values, fill, starts, ends, T_pad, B_pad)
+        t_packed = time.perf_counter()
         # a mean comes back as [sums; counts] and is divided on the host
-        return DeviceBatchHandle(prog(buf), B,
+        return DeviceBatchHandle(prog(buf), B, t_packed,
                                  self.kind in ("mean", "mean_panes"))
 
     def _compute_unpacked(self, cols, starts, ends, gwids, T_pad: int,
@@ -544,25 +553,28 @@ class WindowComputeEngine:
 
         if self.is_ffat:
             _, comb, neutral = self.kind
-            dev = _ffat_pallas_program(comb, neutral, T_pad, B_pad)(
-                pad_col(cols[self.value_col], neutral), se)
-            return DeviceBatchHandle(dev, B, buffers_in=2)
-        if self.kind == "sum":
+            prog = _ffat_pallas_program(comb, neutral, T_pad, B_pad)
+            operands = (pad_col(cols[self.value_col], neutral), se)
+        elif self.kind == "sum":
             # hand-scheduled Pallas alternative to the XLA sum paths
             # (the ComputeBatch_Kernel twin).  T_pad/B_pad are powers
             # of two >= 2048, so the lane/row alignment holds by
             # construction.
             from .pallas.window_sum import window_sums_device
-            dev = window_sums_device(
-                pad_col(cols[self.value_col]), se[0], se[1])[:, 0]
-            return DeviceBatchHandle(dev, B, buffers_in=3)
-        valid = np.zeros(B_pad, dtype=bool)
-        valid[:B] = True
-        gwids_p = np.zeros(B_pad, dtype=np.int64)
-        gwids_p[:B] = gwids
-        w_pad = next_pow2(int((ends - starts).max()) if B else 1)
-        names = tuple(sorted(cols))
-        prog = _custom_program(self.kind, w_pad, names)
-        dev = prog(gwids_p, se[0], se[1], valid,
-                   *[pad_col(cols[c]) for c in names])
-        return DeviceBatchHandle(dev, B, buffers_in=4 + len(names))
+
+            def prog(*operands):
+                return window_sums_device(*operands)[:, 0]
+            operands = (pad_col(cols[self.value_col]), se[0], se[1])
+        else:
+            valid = np.zeros(B_pad, dtype=bool)
+            valid[:B] = True
+            gwids_p = np.zeros(B_pad, dtype=np.int64)
+            gwids_p[:B] = gwids
+            w_pad = next_pow2(int((ends - starts).max()) if B else 1)
+            names = tuple(sorted(cols))
+            prog = _custom_program(self.kind, w_pad, names)
+            operands = (gwids_p, se[0], se[1], valid,
+                        *[pad_col(cols[c]) for c in names])
+        t_packed = time.perf_counter()
+        return DeviceBatchHandle(prog(*operands), B, t_packed,
+                                 buffers_in=len(operands))

@@ -18,8 +18,10 @@ import os
 import subprocess
 import sys
 import threading
+import time
 from typing import Any, Optional
 
+from ..telemetry.spans import ENGINE_CLOCKS
 from .queues import CHANNEL_TIMEOUT
 
 _lib = None
@@ -616,7 +618,7 @@ class NativeWindowEngine:
 
     __slots__ = ("lib", "ptr", "_stats", "_stats_p", "engine_kind", "tb",
                  "dense", "plq_counters", "key_intern", "key_extern",
-                 "_staged")
+                 "_staged", "copy_out_ns")
 
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4}
     # what ``stats()`` returns, in order: nanoseconds creating key
@@ -630,13 +632,21 @@ class NativeWindowEngine:
     # of itself (the table had outgrown the caches), the rings that
     # left their key state (docs/RUNTIME.md 5a "A key state in one
     # place"); the pane partials ``flush`` copied into launch buffers
-    # and the windows they serve (docs/RUNTIME.md 5c)
+    # and the windows they serve (docs/RUNTIME.md 5c).  Behind these
+    # nineteen the inside of ``fold`` and ``flush``
+    # (``spans.ENGINE_CLOCKS``, in the order of ``wfn_engine_stats``'
+    # ``out[19..24)``; tests/test_native_runtime.py names each index):
+    # the engine fills all but the last, ``copy_out_ns``, which is this
+    # wrapper's own (``flush`` keeps it in an attribute and mirrors it
+    # into the slot behind what ``wfn_engine_stats`` writes, so that
+    # ``stats()`` hands it over with the rest and costs what it cost)
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
              "windows_fired", "folded_by_key", "folded_singly",
              "late_accepted", "anchors_moved", "inputs_ignored",
              "stream_time", "key_touches", "walked_ahead", "rings_spilled",
-             "panes_staged", "windows_staged")
+             "panes_staged", "windows_staged", *ENGINE_CLOCKS)
+    _COPY_OUT = STATS.index("copy_out_ns")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
                  delay: int = 0, renumber: bool = False, kind: str = "sum",
@@ -652,6 +662,7 @@ class NativeWindowEngine:
         self._stats = (ctypes.c_longlong * len(self.STATS))()
         self._stats_p = ctypes.cast(self._stats,
                                     ctypes.POINTER(ctypes.c_longlong))
+        self.copy_out_ns = 0
         # the helper engine a flushed buffer needs: count windows sum
         # their per-pane counts; mean windows divide pane-sum totals by
         # pane-count totals (pair program); sum, max and min fold
@@ -684,6 +695,7 @@ class NativeWindowEngine:
         thread that is not the one that feeds the engine."""
         buf = (ctypes.c_longlong * len(self.STATS))()
         self.lib.wfn_engine_stats(self.ptr, buf)
+        buf[self._COPY_OUT] = self.copy_out_ns
         return dict(zip(self.STATS, buf))
 
     def ingest(self, keys, ids, ts, vals, sel=None) -> int:
@@ -854,6 +866,7 @@ class NativeWindowEngine:
             ctypes.byref(kp), ctypes.byref(gp), ctypes.byref(rp))
         if b == 0:
             return None
+        t_staged = time.perf_counter_ns()
         nv = n_vals.value
 
         def arr(p, n, dt):
@@ -868,9 +881,13 @@ class NativeWindowEngine:
         cols = {"value": partials(vals_p, nv)}
         if n_cnts.value:
             cols["count"] = partials(cnts_p, n_cnts.value)
-        return (cols, arr(sp, b, np.int64), arr(ep, b, np.int64),
-                arr(kp, b, np.int64), arr(gp, b, np.int64),
-                arr(rp, b, np.int64), self.engine_kind)
+        out = (cols, arr(sp, b, np.int64), arr(ep, b, np.int64),
+               arr(kp, b, np.int64), arr(gp, b, np.int64),
+               arr(rp, b, np.int64), self.engine_kind)
+        # the copies out of the engine, handed over with its clocks
+        self.copy_out_ns += time.perf_counter_ns() - t_staged
+        self._stats[self._COPY_OUT] = self.copy_out_ns
+        return out
 
     def serialize(self) -> dict:
         """All mutable state, as the checkpoint envelope has always

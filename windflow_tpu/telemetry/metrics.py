@@ -14,6 +14,8 @@ from __future__ import annotations
 
 from typing import List
 
+from . import spans
+
 CONTENT_TYPE = "application/openmetrics-text; version=1.0.0; " \
     "charset=utf-8"
 
@@ -188,7 +190,9 @@ def render_openmetrics(apps: dict) -> str:
             ("cols_gathered", "counter", "columns of selected batches "
              "that were gathered on read"),
             ("rows_by_selection", "counter", "rows the window store read "
-             "through a batch's selection")):
+             "through a batch's selection"),
+            *((name, "counter", text)
+              for name, text in spans.ENGINE_CLOCKS.items())):
         metric = f"windflow_engine_{name}"
         family(metric, kind, text + " (span layer)")
         for lab, seen in engines:

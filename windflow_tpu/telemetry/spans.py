@@ -28,13 +28,16 @@ never per event or per tuple.  They are always on; there is no switch.
   ``slow_span``, with what the graph's other threads had open when it
   began.
 * :class:`LaunchRing` keeps one :class:`Launch` record per device
-  launch: six host stamps whose differences are the stages of a
-  launch's round trip.
+  launch: eight host stamps whose differences are the stages of a
+  launch's round trip and the parts of its ``dispatch``.
 * Work that native code timed on its own steady clock (the window
   engine's ``open``, ``trigger`` and ``evict``) is entered as a child of
   the span open round the call (:meth:`Track.account`), with no clock
   read here; what the engine counts (:data:`ENGINE_COUNTERS`) is kept
-  per operator in :class:`Counters`.
+  per operator in :class:`Counters`, and with it what the engine's
+  other clocks read of the inside of ``fold`` and ``flush``
+  (:data:`ENGINE_CLOCKS`: kept as counters, never entered as spans, so
+  the two spans' self time means what it meant).
 """
 from __future__ import annotations
 
@@ -353,11 +356,18 @@ class Launch:
     ``collected`` (one of :data:`COLLECTED`) says how the dispatcher
     came to take the result, ``buffers_in`` how many host arrays the
     launch handed the device (1 where the engine packs the launch,
-    docs/RUNTIME.md 5c; 0 on the host lane)."""
+    docs/RUNTIME.md 5c; 0 on the host lane).  ``t_packed`` and
+    ``t_called`` are the compute engine's, taken inside ``dispatch`` and
+    brought back by the handle: the host has finished preparing what it
+    hands the runtime; the jitted call has returned.  They cut
+    ``dispatch`` into ``pack``, ``call`` and ``handoff`` (what is left:
+    the handle starting the copy back, the way out of the engine); a
+    lane that does not take them (mesh, host) leaves them None."""
 
     __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "buffers_in",
-                 "t_submitted", "t_picked", "t_dispatched", "t_ready_seen",
-                 "t_on_host", "t_emitted", "collected")
+                 "t_submitted", "t_picked", "t_packed", "t_called",
+                 "t_dispatched", "t_ready_seen", "t_on_host", "t_emitted",
+                 "collected")
 
     def __init__(self, seq: int, chunk_seq: int, bytes_in: int,
                  t_submitted: float):
@@ -368,6 +378,7 @@ class Launch:
         self.buffers_in = 0
         self.t_submitted = t_submitted
         self.t_picked = self.t_dispatched = self.t_ready_seen = None
+        self.t_packed = self.t_called = None
         self.t_on_host = self.t_emitted = None
         self.collected = None
 
@@ -380,22 +391,33 @@ class Launch:
                 "Buffers_in": int(self.buffers_in),
                 "Picked_s": round(self.t_picked, 6),
                 "Collected": self.collected,
-                **{k: round(v, 4) for k, v in self.stages_ms().items()}}
+                **{k: round(v, 4) for k, v in self.stages_ms().items()
+                   if v is not None}}
 
     def stages_ms(self) -> Optional[dict]:
-        """The five stages in milliseconds; None until emitted."""
+        """The five stages in milliseconds and the three parts of
+        ``dispatch`` (None where the lane took no stamps for them); None
+        until emitted."""
         if self.t_emitted is None:
             return None
-        return {
+        out = {
             "queue_wait": 1e3 * (self.t_picked - self.t_submitted),
             "dispatch": 1e3 * (self.t_dispatched - self.t_picked),
             "ready_wait": 1e3 * (self.t_ready_seen - self.t_dispatched),
             "block": 1e3 * (self.t_on_host - self.t_ready_seen),
             "emit": 1e3 * (self.t_emitted - self.t_on_host),
+            "pack": None, "call": None, "handoff": None,
         }
+        if self.t_packed is not None:
+            out["pack"] = 1e3 * (self.t_packed - self.t_picked)
+            out["call"] = 1e3 * (self.t_called - self.t_packed)
+            out["handoff"] = 1e3 * (self.t_dispatched - self.t_called)
+        return out
 
 
+# the stages tile a launch's round trip; the parts tile its ``dispatch``
 STAGES = ("queue_wait", "dispatch", "ready_wait", "block", "emit")
+DISPATCH_PARTS = ("pack", "call", "handoff")
 
 # how a launch's result was collected: found ready straight after a
 # dispatch; waited for, with nothing staged to dispatch meanwhile;
@@ -433,17 +455,18 @@ class LaunchRing:
 
     def summary(self, t0: Optional[float] = None,
                 t1: Optional[float] = None) -> dict:
-        """Mean and longest of each stage over :meth:`finished`, the
-        host arrays those launches handed the device in all, how many of
-        them were collected in each way, and the launch whose round trip
-        (picked up to emitted) was the longest, whole: the one to look
-        for in a trace or a log."""
+        """Mean and longest of each stage and each part of ``dispatch``
+        over :meth:`finished` (a part over the launches that took its
+        stamps), the host arrays those launches handed the device in all,
+        how many of them were collected in each way, and the launch whose
+        round trip (picked up to emitted) was the longest, whole: the one
+        to look for in a trace or a log."""
         done = self.finished(t0, t1)
         rows = [r.stages_ms() for r in done]
         out = {"Operator": self.operator, "Launches": len(rows),
                "Buffers_in": sum(r.buffers_in for r in done)}
-        for s in STAGES:
-            vals = [r[s] for r in rows]
+        for s in STAGES + DISPATCH_PARTS:
+            vals = [r[s] for r in rows if r[s] is not None]
             out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
                       "max_ms": round(max(vals), 4)} if vals else None
         out["Collected"] = {how: sum(r.collected == how for r in done)
@@ -456,8 +479,35 @@ class LaunchRing:
 
 # -- counters --------------------------------------------------------------
 
+# The inside of ``fold`` and ``flush``, declared here once: the tail of
+# ``NativeWindowEngine.STATS``, of :data:`ENGINE_COUNTERS` and of the
+# series, the stats JSON's row and ``/metrics`` (by name and help text)
+# all read this.  Nanoseconds on the engine's steady clock, a call at a
+# time, unless said otherwise; kept as counters and never entered as
+# child spans, so ``fold`` and ``flush`` keep the self time they had.
+# THE ORDER IS THE ENGINE'S: ``wfn_engine_stats`` fills ``out[19..24)``
+# with the first five in this order (native/window_engine.cpp) and the
+# wrapper keeps the sixth behind them; tests/test_native_runtime.py
+# names each index, so a reordering here fails there and mislabels
+# nothing.  Each has a reader under benchmarks/metrics/ (PERF.md
+# section 3) and a family on ``/metrics``.
+ENGINE_CLOCKS = {
+    "ingest_ns": "nanoseconds inside the engine's batch calls, less "
+                 "their open and trigger",
+    "tuple_walk_ns": "of ingest_ns, the walks that cost by the tuple "
+                     "(gather, note_late, fold_singly)",
+    "key_walk_ns": "of ingest_ns, the walks that cost by the key "
+                   "(prepare of the keys a call did not open, settle)",
+    "stage_ns": "nanoseconds inside the engine's flush, less its evict "
+                "(staging the spans, retiring the rings, the rows)",
+    "panes_shifted": "ring elements the engine's retire() moved down "
+                     "after a staging",
+    "copy_out_ns": "nanoseconds copying a flush's columns out of the "
+                   "engine (perf_counter_ns round the copies, "
+                   "NativeWindowEngine.flush)",
+}
 # what the native window engine counts (runtime/native.py
-# ``NativeWindowEngine.STATS[3:13]`` and ``[14:19]``): key states it
+# ``NativeWindowEngine.STATS[3:13]`` and ``[14:]``): key states it
 # created and evicted since it was made, those live now and at their
 # peak, windows it fired, tuples it folded with their key's others of
 # the call in one combine and tuples it folded one by one, what disorder
@@ -468,17 +518,19 @@ class LaunchRing:
 # outgrown the caches), the rings that left their key state
 # (docs/RUNTIME.md 5a "A key state in one place"); and what its flush
 # staged: the pane partials it copied into launch buffers and the windows
-# they serve (docs/RUNTIME.md 5c)
+# they serve (docs/RUNTIME.md 5c); and the :data:`ENGINE_CLOCKS`
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "keys_live_peak", "windows_fired", "folded_by_key",
                    "folded_singly", "late_accepted", "anchors_moved",
                    "inputs_ignored", "key_touches", "walked_ahead",
-                   "rings_spilled", "panes_staged", "windows_staged")
+                   "rings_spilled", "panes_staged", "windows_staged",
+                   *ENGINE_CLOCKS)
 # those of them kept as a series (the last values noted in each 100 ms
 # bucket), so that what moved between two instants can be read
 SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
                    "anchors_moved", "inputs_ignored", "key_touches",
-                   "walked_ahead", "panes_staged", "windows_staged")
+                   "walked_ahead", "panes_staged", "windows_staged",
+                   *ENGINE_CLOCKS)
 
 
 # what a window operator counts of the selected batches it ingests
@@ -527,7 +579,9 @@ class Counters:
             self.live[b] = live
             if len(self.live) > TIMELINE_BUCKETS:
                 _trim(self.live)
-        self.series[b] = tuple(v.get(n, 0) for n in SERIES_COUNTERS)
+        # behind the values the instant of the note, so that a reader can
+        # tell how wide a cut of the series really was (:meth:`cut`)
+        self.series[b] = (*(v.get(n, 0) for n in SERIES_COUNTERS), at_ns)
         if len(self.series) > TIMELINE_BUCKETS:
             _trim(self.series)
 
@@ -543,36 +597,55 @@ class Counters:
         before = [b for b in live if b < b0]
         return live[max(before)] if before else None
 
-    def moved_between(self, t0_s: float, t1_s: float) -> Dict[str, int]:
-        """By how much each of the :data:`SERIES_COUNTERS` moved between
-        the last note in the buckets before ``t0_s``'s and the last in
-        those up to ``t1_s``'s (good to a bucket and a note at each
-        end)."""
+    def _notes(self, t0_s: float, t1_s: float) -> tuple:
+        """The two notes a cut of the series at [t0_s, t1_s] reads
+        between: the last in the buckets before ``t0_s``'s and the last
+        in those up to ``t1_s``'s (None where there is none)."""
         b0, b1 = int(t0_s * 1e9) // BUCKET_NS, int(t1_s * 1e9) // BUCKET_NS
         series = self.series.copy()
+        at = (max((b for b in series if b <= edge), default=None)
+              for edge in (b0 - 1, b1))
+        return tuple(None if b is None else series[b] for b in at)
+
+    def between(self, names, t0_s: float, t1_s: float) -> tuple:
+        """By how much each of ``names`` (of the :data:`SERIES_COUNTERS`)
+        moved between the two notes of :meth:`_notes` (good to a bucket
+        and a note at each end, and wider than [t0_s, t1_s] by as much:
+        :meth:`cut` says by how much), in ``names``' order."""
         none = (0,) * len(SERIES_COUNTERS)
-        at = [max((b for b in series if b <= edge), default=None)
-              for edge in (b0 - 1, b1)]
-        lo, hi = (series[b] if b is not None else none for b in at)
-        return {n: h - l for n, l, h in zip(SERIES_COUNTERS, lo, hi)}
+        lo, hi = (n or none for n in self._notes(t0_s, t1_s))
+        return tuple(hi[i] - lo[i] for i in map(SERIES_COUNTERS.index, names))
+
+    def cut(self, t0_s: float, t1_s: float) -> Optional[tuple]:
+        """The instants (seconds on the spans' clock) of the two notes
+        :meth:`between` reads between for [t0_s, t1_s]: what moved
+        between them is to be set against a whole, or a count, of the
+        same two instants and not of the window's.  None where there is
+        no note at one end, or nothing was noted between them."""
+        lo, hi = self._notes(t0_s, t1_s)
+        if lo is None or hi is None or hi[-1] <= lo[-1]:
+            return None
+        return lo[-1] / 1e9, hi[-1] / 1e9
+
+    def moved_between(self, t0_s: float, t1_s: float) -> Dict[str, int]:
+        """Every one of the :data:`SERIES_COUNTERS` by name
+        (:meth:`between`)."""
+        return dict(zip(SERIES_COUNTERS,
+                        self.between(SERIES_COUNTERS, t0_s, t1_s)))
 
     def folded_between(self, t0_s: float, t1_s: float) -> tuple:
-        """(by key, singly): the tuples folded between two instants
-        (:meth:`moved_between`)."""
-        moved = self.moved_between(t0_s, t1_s)
-        return moved["folded_by_key"], moved["folded_singly"]
+        """(by key, singly): the tuples folded between two instants."""
+        return self.between(("folded_by_key", "folded_singly"), t0_s, t1_s)
 
     def staged_between(self, t0_s: float, t1_s: float) -> tuple:
         """(pane partials, windows) the store's flush staged between two
-        instants (:meth:`moved_between`)."""
-        moved = self.moved_between(t0_s, t1_s)
-        return moved["panes_staged"], moved["windows_staged"]
+        instants."""
+        return self.between(("panes_staged", "windows_staged"), t0_s, t1_s)
 
     def touched_between(self, t0_s: float, t1_s: float) -> tuple:
         """(key touches, those in a call that ran ahead) between two
-        instants (:meth:`moved_between`)."""
-        moved = self.moved_between(t0_s, t1_s)
-        return moved["key_touches"], moved["walked_ahead"]
+        instants."""
+        return self.between(("key_touches", "walked_ahead"), t0_s, t1_s)
 
 
 # -- graphs and the registry -----------------------------------------------
