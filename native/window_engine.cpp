@@ -29,15 +29,25 @@
 // GIL-free: every entry point only touches caller-provided arrays and
 // internal state; Python calls via ctypes release the GIL.
 
+#include <pthread.h>
+#include <sched.h>
+#include <unistd.h>
+
 #include <algorithm>
+#include <atomic>
 #include <chrono>
+#include <condition_variable>
 #include <cstddef>
 #include <cstdint>
 #include <cstring>
+#include <ctime>
 #include <limits>
 #include <map>
+#include <mutex>
 #include <new>
 #include <numeric>
+#include <system_error>
+#include <thread>
 #include <vector>
 
 namespace {
@@ -50,6 +60,225 @@ inline i64 now_ns() {
     return std::chrono::duration_cast<std::chrono::nanoseconds>(
         std::chrono::steady_clock::now().time_since_epoch()).count();
 }
+
+// A FEW HOST LANES, ASLEEP BETWEEN CALLS.  What the engine hands to
+// more than one core: the walk over a call's tuples, in slices (Engine,
+// "THE TUPLE WALK IN SLICES").  One pool for the process, made by the
+// first call that engages it -- not when the library loads, not when an
+// engine is made -- so until then no thread exists.  A job is a few
+// pieces, each taken by whichever lane comes first, the calling thread
+// among them: a lane slow to wake costs the caller that lane's piece,
+// never a wait for it.  A lane with nothing to take spins for SPIN_NS,
+// then sleeps on a condition variable: a process whose calls stop (a
+// paced feed between chunks, a burst's silence) pays no core for its
+// lanes.  One call at a time (a try-lock: another engine's call
+// meanwhile walks alone); a forked child never touches its parent's
+// pool (the pid tells them apart) and makes its own; at exit the lanes
+// are stopped and joined.
+namespace lanes {
+
+// The caller and up to MAX - 1 helpers.  At four the walk is a quarter
+// of what it was and the rest of the calling thread (the generator, the
+// flush, the Python about the calls: a quarter of it in the 111-key
+// cell) paces it; a fifth to eighth lane would buy at most another
+// tenth of the thread for a wake and a merge each (docs/RUNTIME.md 5b)
+constexpr int MAX = 4;
+// a lane's spin before it sleeps: what a merge between two pieces of a
+// call takes, with room; a sleeping lane's wake is a futex call
+constexpr i64 SPIN_NS = 20000;
+
+inline void relax() {
+#if defined(__x86_64__) || defined(__i386__)
+    __builtin_ia32_pause();
+#endif
+}
+
+struct Pool {
+    using Fn = void (*)(void*, int);
+    const pid_t pid;
+    int lanes = 1;                    // the caller and the helpers
+    std::vector<std::thread> helpers;
+    std::vector<clockid_t> clocks;    // each helper's CPU clock
+    // the job: written by the caller before it publishes `claim`, read
+    // by a lane once it has taken a piece (it cannot change before that
+    // piece is done)
+    Fn fn = nullptr;
+    void* ctx = nullptr;
+    // the job's generation (bits 16 on), its pieces (8..15) and the
+    // next piece to take (0..7): one word, so a lane can only take a
+    // piece of the job it has read
+    std::atomic<std::uint64_t> claim{0};
+    std::atomic<int> done{0};
+    std::atomic<int> sleepers{0};
+    std::atomic<bool> stop{false};
+    std::mutex m;
+    std::condition_variable cv;
+
+    Pool(pid_t p, int n) : pid(p) {
+        try {
+            for (int i = 1; i < n; ++i) {
+                helpers.emplace_back([this] { serve(); });
+                clockid_t c;
+                if (pthread_getcpuclockid(helpers.back().native_handle(),
+                                          &c) == 0)
+                    clocks.push_back(c);
+            }
+        } catch (const std::system_error&) {
+            // fewer threads than asked for: the caller takes their pieces
+        }
+        lanes = 1 + (int)helpers.size();
+    }
+
+    static bool open(std::uint64_t c) {
+        return (c & 0xff) < ((c >> 8) & 0xff);
+    }
+
+    // a piece of the job, or -1 where none is left to take
+    int take() {
+        std::uint64_t c = claim.load(std::memory_order_acquire);
+        while (open(c))
+            if (claim.compare_exchange_weak(c, c + 1,
+                                            std::memory_order_acq_rel,
+                                            std::memory_order_acquire))
+                return (int)(c & 0xff);
+        return -1;
+    }
+
+    void work() {
+        for (int k; (k = take()) >= 0;) {
+            fn(ctx, k);
+            done.fetch_add(1, std::memory_order_release);
+        }
+    }
+
+    // a helper: pieces while there are any, a spin, then sleep
+    void serve() {
+        while (true) {
+            work();
+            const i64 t0 = now_ns();
+            for (int spin = 1; !open(claim.load(std::memory_order_acquire));
+                 ++spin) {
+                if (stop.load(std::memory_order_relaxed)) return;
+                relax();
+                if ((spin & 63) == 0 && now_ns() - t0 > SPIN_NS) {
+                    // counted before the job is read again, and the
+                    // caller reads the count after publishing: one of
+                    // the two sees the other (sequentially consistent)
+                    std::unique_lock<std::mutex> lk(m);
+                    sleepers.fetch_add(1);
+                    cv.wait(lk, [this] { return open(claim.load())
+                                                || stop.load(); });
+                    sleepers.fetch_sub(1);
+                    break;
+                }
+            }
+            if (stop.load()) return;
+        }
+    }
+
+    // fn(ctx, k) for k in [0, n), the caller taking pieces too
+    void run(Fn f, void* c, int n) {
+        fn = f;
+        ctx = c;
+        done.store(0, std::memory_order_relaxed);
+        const std::uint64_t gen =
+            (claim.load(std::memory_order_relaxed) >> 16) + 1;
+        claim.store(gen << 16 | (std::uint64_t)n << 8);
+        if (sleepers.load() > 0) {
+            { std::lock_guard<std::mutex> lk(m); }
+            cv.notify_all();
+        }
+        work();
+        for (int spin = 1; done.load(std::memory_order_acquire) < n; ++spin) {
+            relax();
+            if ((spin & 1023) == 0) sched_yield();
+        }
+    }
+
+    void halt() {
+        stop.store(true);
+        { std::lock_guard<std::mutex> lk(m); }
+        cv.notify_all();
+        for (std::thread& t : helpers)
+            if (t.joinable()) t.join();
+    }
+
+    // the helpers' CPU time, nanoseconds
+    i64 cpu_ns() const {
+        i64 sum = 0;
+        for (clockid_t c : clocks) {
+            timespec ts;
+            if (clock_gettime(c, &ts) == 0)
+                sum += (i64)ts.tv_sec * 1000000000 + ts.tv_nsec;
+        }
+        return sum;
+    }
+};
+
+// never freed: a helper of a call still running as the process exits
+// finds it where it was
+std::atomic<Pool*> the_pool{nullptr};
+std::atomic_flag busy = ATOMIC_FLAG_INIT;
+std::atomic<long long> here_of{0};    // pid << 8 | lanes
+
+// The lanes this process may have: where its CPU affinity leaves two
+// cores or more beyond the caller, the cores but one (the process's
+// other threads keep it: the dispatcher, the device runtime's), at most
+// MAX; else 1, none.  Read once a process (a forked child reads again).
+inline int here() {
+    const pid_t me = getpid();
+    const long long c = here_of.load(std::memory_order_relaxed);
+    if ((c >> 8) == me) return (int)(c & 0xff);
+    cpu_set_t set;
+    int cores = 1;
+    if (sched_getaffinity(0, sizeof set, &set) == 0) cores = CPU_COUNT(&set);
+    const int l = cores >= 3 ? std::min(MAX, cores - 1) : 1;
+    here_of.store((long long)me << 8 | l, std::memory_order_relaxed);
+    return l;
+}
+
+// A call's hold on the lanes: `held` where no other call has them, and
+// then the pool of this process (made here the first time; null where
+// the process may have no helper)
+struct Hold {
+    Pool* pool = nullptr;
+    const bool held;
+    Hold() : held(!busy.test_and_set(std::memory_order_acquire)) {
+        if (!held) return;
+        Pool* p = the_pool.load(std::memory_order_acquire);
+        const pid_t me = getpid();
+        if (p == nullptr || p->pid != me) {
+            // none yet, or a parent's: a forked child leaves it as it is
+            const int l = here();
+            p = l > 1 ? new Pool(me, l) : nullptr;
+            if (p != nullptr) the_pool.store(p, std::memory_order_release);
+        }
+        pool = p;
+    }
+    ~Hold() {
+        if (held) busy.clear(std::memory_order_release);
+    }
+    Hold(const Hold&) = delete;
+    Hold& operator=(const Hold&) = delete;
+
+    // fn(ctx, k) for k in [0, n): on the lanes, else on the caller
+    void run(Pool::Fn fn, void* ctx, int n) const {
+        if (pool != nullptr) {
+            pool->run(fn, ctx, n);
+            return;
+        }
+        for (int k = 0; k < n; ++k) fn(ctx, k);
+    }
+};
+
+struct Reaper {
+    ~Reaper() {
+        Pool* p = the_pool.load();
+        if (p != nullptr && p->pid == getpid()) p->halt();
+    }
+} reaper;
+
+}  // namespace lanes
 
 // A KEY STATE IN ONE PLACE.  What a key's first tuple of a call walks:
 //
@@ -894,32 +1123,57 @@ struct Engine {
     // AHEAD OF ITSELF"): while one block of tuples is walked the table
     // records of the next are fetched, block-wise and not a tuple; a
     // call that does not run ahead is the plain loop.
-    template <bool IDS, int EXT, bool SEL, bool AHEAD, typename TV>
+    // The walk's body over tuples [from, to), the one both walks run:
+    // `at.index(key)` is the key's index into `at`'s partials (the
+    // engine's: dense_of(); a slice's: its own table), kept in
+    // `at.slot(j)`.  ck / ci / cv: the column is compact (SEL).
+    template <bool IDS, int EXT, bool SEL, typename TV, typename At>
+    static inline void walk_tuples(At& at, const i64* bkeys, const i64* ids,
+                                   const TV* vals, Sel s, bool ck, bool ci,
+                                   bool cv, i64 from, i64 to) {
+        for (i64 j = from; j < to; ++j) {
+            const i64 r = SEL ? s.rows[j] : j;
+            const int32_t d = at.index(bkeys[ck ? j : r]);
+            at.slot(j) = d;
+            Part& pt = at.part(d);
+            ++pt.count;
+            if (IDS) {
+                const i64 id = ids[ci ? j : r];
+                if (id < pt.lo) pt.lo = id;
+                if (id > pt.hi) pt.hi = id;
+            }
+            if (EXT == 1) {
+                const double v = (double)vals[cv ? j : r];
+                if (v > pt.ext) pt.ext = v;
+            } else if (EXT == 2) {
+                const double v = (double)vals[cv ? j : r];
+                if (v < pt.ext) pt.ext = v;
+            }
+        }
+    }
+    // gather()'s `at`: the engine's table and per-call arrays
+    struct Own {
+        Engine& e;
+        inline int32_t index(i64 key) { return e.dense_of(key); }
+        inline int32_t& slot(i64 j) { return e.slot_of[j]; }
+        inline Part& part(int32_t d) { return e.parts[d]; }
+    };
+
+    template <bool IDS, int EXT, bool SEL, bool AHEAD, bool LANES = false,
+              typename TV>
     void gather(const i64* bkeys, const i64* ids, const TV* vals, i64 n,
                 Sel s) {
+        if (LANES) {
+            gather_in_slices<IDS, EXT, SEL>(bkeys, ids, vals, n, s);
+            return;
+        }
         const bool ck = SEL && !(s.through & SEL_KEYS);   // compact columns
         const bool ci = SEL && !(s.through & SEL_IDS);
         const bool cv = SEL && !(s.through & SEL_VALS);
+        Own own{*this};
         auto walk = [&](i64 from, i64 to) {
-            for (i64 j = from; j < to; ++j) {
-                const i64 r = SEL ? s.rows[j] : j;
-                const int32_t d = dense_of(bkeys[ck ? j : r]);
-                slot_of[j] = d;
-                Part& pt = parts[d];
-                ++pt.count;
-                if (IDS) {
-                    const i64 id = ids[ci ? j : r];
-                    if (id < pt.lo) pt.lo = id;
-                    if (id > pt.hi) pt.hi = id;
-                }
-                if (EXT == 1) {
-                    const double v = (double)vals[cv ? j : r];
-                    if (v > pt.ext) pt.ext = v;
-                } else if (EXT == 2) {
-                    const double v = (double)vals[cv ? j : r];
-                    if (v < pt.ext) pt.ext = v;
-                }
-            }
+            walk_tuples<IDS, EXT, SEL>(own, bkeys, ids, vals, s, ck, ci, cv,
+                                       from, to);
         };
         if (!AHEAD || !probes_ahead) {
             walk(0, n);
@@ -939,6 +1193,204 @@ struct Engine {
         }
     }
     static constexpr i64 AHEAD_BLOCK = 64;
+
+    // THE TUPLE WALK IN SLICES.  Where a call's table fits the caches
+    // (the walk does not run ahead), the call is long enough (LANES_FROM)
+    // and comes soon after the last (LANES_GAP_NS), gather() is split
+    // over the process's host lanes
+    // (namespace lanes): each takes a contiguous slice of the call's
+    // tuples and walks it with the same body, into partials of its own
+    // under a table of its own, never the engine's, in the order of
+    // first touch inside the slice.  The caller then merges, slice by
+    // slice in order: each key of a slice through dense_of(), which
+    // gives it its index in the order of its first tuple over the whole
+    // call and opens it where the table has no record (so the table,
+    // parts, d_slot, d_state and opened_now come out as the one walk
+    // leaves them), and its partial into the call's (counts add, ids
+    // and extremes by min and max, the first of equal extremes kept);
+    // slot_of is left in each slice's own indices, and made the call's
+    // on the lanes where the second walk reads it (fold_singly(); a call
+    // whose keys all fold by key never does).  Counts are integers and
+    // the rest is order-free: what the walks after gather() read is the
+    // one walk's, bit for bit.
+    // No knob: what decides is the table's size, the call's length, how
+    // soon it came after the last and the cores the process may run on
+    // (ingest_batch, in_slices()).  Counted:
+    // walked_in_lanes, the tuples of calls walked so.
+    struct Slice {
+        struct Rec {
+            i64 key;
+            i64 stamp;                // the slice's call that wrote it
+            int32_t at;
+        };
+        std::vector<Rec> tab;         // open addressing from the key, doubled
+        i64 stamp = 0;
+        std::vector<Part> parts;      // index = order of first touch
+        std::vector<i64> keys;
+        std::vector<int32_t> call_of; // index -> the call's (the merge's)
+        int32_t* slot_of = nullptr;
+        double neutral = 0.0;
+
+        void begin(std::size_t size, int32_t* so, double nt) {
+            if (tab.size() < size) tab.assign(size, Rec{0, 0, 0});
+            ++stamp;
+            parts.clear();
+            keys.clear();
+            slot_of = so;
+            neutral = nt;
+        }
+        void grow() {
+            tab.assign(tab.size() * 4, Rec{0, 0, 0});
+            const std::size_t mask = tab.size() - 1;
+            for (std::size_t i = 0; i < keys.size(); ++i) {
+                std::size_t h = ((std::size_t)keys[i] << 1) & mask;
+                while (tab[h].stamp == stamp) h = (h + 1) & mask;
+                tab[h] = Rec{keys[i], stamp, (int32_t)i};
+            }
+        }
+        inline int32_t index(i64 key) {
+            const std::size_t mask = tab.size() - 1;
+            std::size_t h = ((std::size_t)key << 1) & mask;
+            while (true) {
+                Rec& r = tab[h];
+                if (r.stamp != stamp) {
+                    if (((i64)keys.size() + 1) * 4 >= (i64)tab.size()) {
+                        grow();
+                        return index(key);
+                    }
+                    r = Rec{key, stamp, (int32_t)keys.size()};
+                    keys.push_back(key);
+                    parts.push_back(Part{0, INT64_MAX, INT64_MIN, neutral});
+                    return r.at;
+                }
+                if (r.key == key) return r.at;
+                h = (h + 1) & mask;
+            }
+        }
+        inline int32_t& slot(i64 j) { return slot_of[j]; }
+        inline Part& part(int32_t d) { return parts[d]; }
+    };
+    // A split call pays, whatever its length, the lanes' wake, the last
+    // slice's tail and the merge (60-90 us on the chip's host) and the
+    // caller's own work about the call slowing by some 40-100 us more,
+    // against some 4 ns a tuple saved: it gains from some 30,000-45,000
+    // tuples on (PERF.md section 6, PR 39: at 65,536 the 111-key cell
+    // gained 21 %, at 21,800 selected rows the YSB cell lost 12.6 %)
+    static constexpr i64 LANES_FROM = 49152;
+    // ... and where the caller feeds the engine as fast as it can: its
+    // last call ended less than LANES_GAP_NS before this one (0.1-0.3 ms
+    // between calls in the saturated cells).  A caller that waits for
+    // its input between calls (5 ms in the paced cell, 2.5 ms inside a
+    // burst) walks alone: it has the time, and on the chip's host a
+    // lane woken from sleep costs more CPU than the walk it takes over
+    // (PERF.md section 6, PR 39)
+    static constexpr i64 LANES_GAP_NS = 1000000;
+    // One slice a lane, for the process's one call at a time (the lanes'
+    // hold), and which call left slot_of in its slices' own indices:
+    // the merge leaves them there, and they are made the call's where a
+    // walk reads them (fold_singly(), ingest_walks) -- a call whose keys
+    // all fold by key never does.  Never freed, as the pool is not.
+    struct Slices {
+        std::vector<Slice> of = std::vector<Slice>(lanes::MAX);
+        const Engine* owner = nullptr;
+        i64 call = -1;                // the owner's call_id
+        i64 n = 0;
+        int pieces = 0;
+    };
+    static Slices& slices() {
+        static Slices* s = new Slices;
+        return *s;
+    }
+
+    // a lane-split call's walk: slice k
+    template <bool IDS, int EXT, bool SEL, typename TV>
+    struct SliceWalk {
+        Engine* e;
+        const i64* bkeys;
+        const i64* ids;
+        const TV* vals;
+        i64 n;
+        Sel s;
+        int pieces;
+        bool ck, ci, cv;
+        static void walk(void* p, int k) {
+            const SliceWalk& w = *static_cast<const SliceWalk*>(p);
+            Slice& sl = slices().of[k];
+            sl.begin(w.e->tab.size(), w.e->slot_of.data(), w.e->neutral);
+            walk_tuples<IDS, EXT, SEL>(sl, w.bkeys, w.ids, w.vals, w.s, w.ck,
+                                       w.ci, w.cv, w.n * k / w.pieces,
+                                       w.n * (k + 1) / w.pieces);
+        }
+    };
+    // ... and slice k + 1's indices in slot_of made the call's (a mark
+    // note_late() left in the sign stays)
+    static void to_the_call(void* p, int k) {
+        const Slices& ss = *static_cast<const Slices*>(p);
+        const Slice& sl = ss.of[k + 1];
+        const int32_t* to = sl.call_of.data();
+        int32_t* so = sl.slot_of;
+        for (i64 j = ss.n * (k + 1) / ss.pieces,
+                 e = ss.n * (k + 2) / ss.pieces; j < e; ++j)
+            so[j] = to[so[j] & ~LATE_MARK] | (so[j] & LATE_MARK);
+    }
+
+    template <bool IDS, int EXT, bool SEL, typename TV>
+    void gather_in_slices(const i64* bkeys, const i64* ids, const TV* vals,
+                          i64 n, Sel s) {
+        const lanes::Hold& hold = *lanes_hold;
+        Slices& ss = slices();
+        int pieces = hold.pool != nullptr ? hold.pool->lanes : lanes::MAX;
+        if (n < pieces) pieces = (int)n;
+        if (pieces < 2) {
+            gather<IDS, EXT, SEL, false>(bkeys, ids, vals, n, s);
+            return;
+        }
+        SliceWalk<IDS, EXT, SEL, TV> job{
+            this, bkeys, ids, vals, n, s, pieces,
+            SEL && !(s.through & SEL_KEYS), SEL && !(s.through & SEL_IDS),
+            SEL && !(s.through & SEL_VALS)};
+        hold.run(&decltype(job)::walk, &job, pieces);
+        for (int k = 0; k < pieces; ++k) {
+            Slice& sl = ss.of[k];
+            const std::size_t nk = sl.keys.size();
+            sl.call_of.resize(nk);
+            for (std::size_t i = 0; i < nk; ++i) {
+                const int32_t d = dense_of(sl.keys[i]);
+                sl.call_of[i] = d;
+                Part& pt = parts[d];
+                const Part& q = sl.parts[i];
+                pt.count += q.count;
+                if (q.lo < pt.lo) pt.lo = q.lo;
+                if (q.hi > pt.hi) pt.hi = q.hi;
+                if (EXT == 1 && q.ext > pt.ext) pt.ext = q.ext;
+                if (EXT == 2 && q.ext < pt.ext) pt.ext = q.ext;
+            }
+        }
+        // slice 0's indices are the call's already (its keys came first);
+        // the others' wait for a reader
+        ss.owner = this;
+        ss.call = split_call = call_id;
+        ss.n = n;
+        ss.pieces = pieces;
+        walked_in_lanes += n;
+    }
+
+    // slot_of as the one walk leaves it, where this engine's last call
+    // left it in its slices' indices: on the lanes during the call, on
+    // the caller after it (the test-only view, wfn_engine_last_call);
+    // false where another call has had the slices since
+    bool slots_of_the_call() {
+        if (split_call != call_id) return true;
+        Slices& ss = slices();
+        if (ss.owner != this || ss.call != call_id) return false;
+        if (lanes_hold != nullptr)
+            lanes_hold->run(&to_the_call, &ss, ss.pieces - 1);
+        else
+            for (int k = 0; k + 1 < ss.pieces; ++k) to_the_call(&ss, k);
+        ss.owner = nullptr;
+        split_call = -1;
+        return true;
+    }
 
     // Which of the call's tuples lie behind the stream time as they
     // come (the largest stamp of every tuple before them): counted, and
@@ -980,11 +1432,32 @@ struct Engine {
                       const TV* vals, i64 n, Sel s = Sel{nullptr, 0}) {
         if (runs_ahead())
             ingest_walks<SEL, true>(bkeys, ids, tss, vals, n, s);
+        else if (in_slices(n))
+            ingest_in_slices<SEL>(bkeys, ids, tss, vals, n, s);
         else
             ingest_walks<SEL, false>(bkeys, ids, tss, vals, n, s);
     }
 
-    template <bool SEL, bool AHEAD, typename TV>
+    // out of line: the entry points keep the code they had (PR 37's
+    // lesson, "THE INSIDE OF A BATCH CALL" below)
+    // The lanes are held for the whole call (the slices keep what the
+    // merge left until the call is done with it); a call that finds
+    // them held by another engine's walks as one.
+    template <bool SEL, typename TV>
+    __attribute__((noinline)) void ingest_in_slices(
+            const i64* bkeys, const i64* ids, const i64* tss, const TV* vals,
+            i64 n, Sel s) {
+        const lanes::Hold hold;
+        if (!hold.held) {
+            ingest_walks<SEL, false>(bkeys, ids, tss, vals, n, s);
+            return;
+        }
+        lanes_hold = &hold;
+        ingest_walks<SEL, false, true>(bkeys, ids, tss, vals, n, s);
+        lanes_hold = nullptr;
+    }
+
+    template <bool SEL, bool AHEAD, bool LANES = false, typename TV>
     void ingest_walks(const i64* bkeys, const i64* ids, const i64* tss,
                       const TV* vals, i64 n, Sel s) {
         call_begins();
@@ -995,13 +1468,13 @@ struct Engine {
         opened_now.clear();
         if ((i64)slot_of.size() < n) slot_of.resize(n);
         if (renumber)
-            gather<false, 0, SEL, AHEAD>(bkeys, ids, vals, n, s);
+            gather<false, 0, SEL, AHEAD, LANES>(bkeys, ids, vals, n, s);
         else if (by_key_lane && kind == Kind::MAX)
-            gather<true, 1, SEL, AHEAD>(bkeys, ids, vals, n, s);
+            gather<true, 1, SEL, AHEAD, LANES>(bkeys, ids, vals, n, s);
         else if (by_key_lane && kind == Kind::MIN)
-            gather<true, 2, SEL, AHEAD>(bkeys, ids, vals, n, s);
+            gather<true, 2, SEL, AHEAD, LANES>(bkeys, ids, vals, n, s);
         else
-            gather<true, 0, SEL, AHEAD>(bkeys, ids, vals, n, s);
+            gather<true, 0, SEL, AHEAD, LANES>(bkeys, ids, vals, n, s);
         if (stream_rule) {
             if (SEL && (s.through & SEL_IDS))
                 note_late<true>(ids, s.rows, n);
@@ -1051,6 +1524,7 @@ struct Engine {
         // the anchors prepare() moved back; inside the second walk: the
         // anchors fold_singly() moved back
         piece_ends(key_walk_ns);
+        if (LANES && n_single) slots_of_the_call();
         if (n_single) fold_singly<SEL>(ids, tss, vals, n, s);
         piece_ends(tuple_walk_ns);
         for (std::size_t d = 0; d < nd; ++d) {
@@ -1609,9 +2083,11 @@ struct Engine {
     i64 ingest_ns = 0, tuple_walk_ns = 0, key_walk_ns = 0, stage_ns = 0;
     i64 panes_shifted = 0;
     // a batch call's clock: when it began and when its last piece ended,
-    // and `open_ns` / `trigger_ns` as they stood then
+    // and `open_ns` / `trigger_ns` as they stood then; when the last
+    // call ended (THE TUPLE WALK IN SLICES, in_slices())
     struct CallClock {
         i64 began, mark, open_began, open_mark, trigger_began;
+        i64 ended = INT64_MIN / 2;
     } call_clock;
     __attribute__((noinline)) void call_begins() {
         CallClock& c = call_clock;
@@ -1628,9 +2104,25 @@ struct Engine {
         c.open_mark = open_ns;
     }
     __attribute__((noinline)) void call_ends() {
-        const CallClock& c = call_clock;
-        ingest_ns += (now_ns() - c.began) - (open_ns - c.open_began)
+        CallClock& c = call_clock;
+        c.ended = now_ns();
+        ingest_ns += (c.ended - c.began) - (open_ns - c.open_began)
             - (trigger_ns - c.trigger_began);
+    }
+
+    // THE TUPLE WALK IN SLICES (above), behind the clocks for the same
+    // reason: the tuples walked by calls split over the lanes, read by
+    // wfn_engine_stats; and the rule's test-only override
+    // (wfn_engine_lanes_forced): -1 the rule, 0 never, 1 every call on a
+    // table that fits the caches, whatever its length and the cores
+    i64 walked_in_lanes = 0;
+    int lanes_forced = -1;
+    const lanes::Hold* lanes_hold = nullptr;  // during a lane-split call
+    i64 split_call = -1;      // the call whose slot_of waits in slices
+    inline bool in_slices(i64 n) const {
+        if (lanes_forced >= 0) return lanes_forced > 0;
+        return n >= LANES_FROM && now_ns() - call_clock.ended < LANES_GAP_NS
+            && lanes::here() > 1;
     }
 };
 
@@ -1726,7 +2218,7 @@ i64 wfn_engine_ignored(void* ep) {
 }
 
 // What key churn costs, how many keys there are, how the fold went and
-// what disorder it met, into out[24].  The first nineteen: nanoseconds
+// what disorder it met, into out[25].  The first nineteen: nanoseconds
 // spent creating key
 // states and moving anchors back (open), finding and queueing fired
 // windows (trigger) and evicting (evict), since the engine was made; keys
@@ -1738,7 +2230,9 @@ i64 wfn_engine_ignored(void* ep) {
 // the per-key visit of the calls met, those of them in a call that ran
 // ahead of itself (Engine, "WHERE A CALL RUNS AHEAD OF ITSELF"), the
 // rings that left their key state (KeyState), and the pane partials and
-// windows flush() staged.  Behind those nineteen, into out[19..24): what
+// windows flush() staged.  out[19]: the tuples of calls whose walk was
+// split over the host lanes (Engine, "THE TUPLE WALK IN SLICES").
+// Behind those twenty, into out[20..25): what
 // a batch call took less its `open` and `trigger` (ingest), of that the
 // walks by the tuple and the walks by the key, what flush() took less
 // its `evict` (stage), nanoseconds on the same clock, and the ring
@@ -1764,11 +2258,63 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[16] = e.rings_spilled;
     out[17] = e.panes_staged;
     out[18] = e.windows_staged;
-    out[19] = e.ingest_ns;
-    out[20] = e.tuple_walk_ns;
-    out[21] = e.key_walk_ns;
-    out[22] = e.stage_ns;
-    out[23] = e.panes_shifted;
+    out[19] = e.walked_in_lanes;
+    out[20] = e.ingest_ns;
+    out[21] = e.tuple_walk_ns;
+    out[22] = e.key_walk_ns;
+    out[23] = e.stage_ns;
+    out[24] = e.panes_shifted;
+}
+
+// TEST ONLY (tests/test_native_runtime.py), no part of the engine's
+// configuration: the rule that splits a call's tuple walk over the
+// host lanes (Engine, "THE TUPLE WALK IN SLICES") held to never (0) or
+// to every call whose table fits the caches (1), or left to decide (-1)
+void wfn_engine_lanes_forced(void* ep, int mode) {
+    static_cast<Engine*>(ep)->lanes_forced = mode < 0 ? -1 : mode > 0;
+}
+
+// TEST ONLY: what the last batch call, of `n` tuples, left in the
+// per-call arrays, into out: the keys it met; per key in the order of
+// first touch its key, count, smallest and largest id, the extreme's
+// bits and its pool slot; slot_of[0..n) (made the call's here where a
+// lane-split call left it in its slices' indices); the keys it opened
+// and their indices.  Returns the length, minus the length where `cap`
+// is less, 0 where another engine's call has had the slices since.
+i64 wfn_engine_last_call(void* ep, i64 n, i64* out, i64 cap) {
+    Engine& e = *static_cast<Engine*>(ep);
+    const i64 nd = (i64)e.parts.size(), no = (i64)e.opened_now.size();
+    const i64 len = 1 + 6 * nd + n + 1 + no;
+    if (cap < len || n > (i64)e.slot_of.size()) return -len;
+    if (!e.slots_of_the_call()) return 0;
+    i64* o = out;
+    *o++ = nd;
+    for (i64 d = 0; d < nd; ++d) {
+        const auto& pt = e.parts[d];
+        *o++ = e.d_state[d]->key;
+        *o++ = pt.count;
+        *o++ = pt.lo;
+        *o++ = pt.hi;
+        std::memcpy(o++, &pt.ext, sizeof(i64));
+        *o++ = e.d_slot[d];
+    }
+    for (i64 j = 0; j < n; ++j) *o++ = e.slot_of[j];
+    *o++ = no;
+    for (i64 i = 0; i < no; ++i) *o++ = e.opened_now[i];
+    return len;
+}
+
+// TEST ONLY: this process's lanes, into out[4]: the helper threads its
+// pool has (0 before the first call that engages them, and in a forked
+// child until its own first), their CPU time in nanoseconds, the lanes
+// the rule gives this process, and the shortest call it splits
+void wfn_lanes_info(i64* out) {
+    const lanes::Pool* p = lanes::the_pool.load();
+    const bool mine = p != nullptr && p->pid == getpid();
+    out[0] = mine ? (i64)p->helpers.size() : 0;
+    out[1] = mine ? p->cpu_ns() : 0;
+    out[2] = lanes::here();
+    out[3] = Engine::LANES_FROM;
 }
 
 void wfn_engine_eos(void* ep) { static_cast<Engine*>(ep)->eos(); }

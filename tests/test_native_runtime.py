@@ -859,7 +859,8 @@ def test_the_first_nineteen_stats_keep_their_order_and_values(case, want):
     position 3..18)."""
     from windflow_tpu.telemetry import spans
     assert NativeWindowEngine.STATS[:19] == PARENT_STATS
-    assert NativeWindowEngine.STATS[19:] == CLOCKS == tuple(
+    assert NativeWindowEngine.STATS[19] == "walked_in_lanes"
+    assert NativeWindowEngine.STATS[20:] == CLOCKS == tuple(
         spans.ENGINE_CLOCKS)
     eng = ks_moved_back()[0] if case == "moved_back" \
         else ks_q5("ooo", "count", 4096, n=60_000)[0]
@@ -909,11 +910,11 @@ def test_each_clock_grows_only_in_the_call_it_times():
     assert 0 < s["tuple_walk_ns"] + s["key_walk_ns"] <= s["ingest_ns"]
     assert s["ingest_ns"] + s["open_ns"] + s["trigger_ns"] <= wall
     # the wrapper's own clock: an attribute, mirrored into the slot of
-    # the buffer behind the twenty-four the engine refills
-    assert NativeWindowEngine.STATS.index("copy_out_ns") == 24
-    assert eng.stats()[24] == eng.stats()[24] == eng.copy_out_ns \
+    # the buffer behind the twenty-five the engine refills
+    assert NativeWindowEngine.STATS.index("copy_out_ns") == 25
+    assert eng.stats()[25] == eng.stats()[25] == eng.copy_out_ns \
         == s["copy_out_ns"] > 0
-    assert list(eng.stats())[19:24] == [s[n] for n in CLOCKS[:5]]
+    assert list(eng.stats())[20:25] == [s[n] for n in CLOCKS[:5]]
     assert eng.flush(1 << 30) is None       # nothing ready: no clock read
     assert eng.snapshot()["stage_ns"] == s["stage_ns"]
     synth = NativeWindowEngine(64, 32, True, 0, kind="sum")
@@ -968,6 +969,241 @@ def test_the_clocks_are_no_part_of_the_state():
     assert after["keys_live"] == before["keys_live"]
     assert not any(after[n] for n in CLOCKS)
     assert twin.serialize()["native"] == eng.serialize()["native"]
+
+
+# -- the tuple walk in slices, on the host lanes (PR 39) ---------------------
+
+import ctypes  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+import time  # noqa: E402
+
+from test_fold_by_key import scattered  # noqa: E402
+
+from windflow_tpu.runtime.native import get_lib  # noqa: E402
+
+LANES_FROM = 49152                   # Engine::LANES_FROM
+SPLIT_CHUNKS = (LANES_FROM - 1, LANES_FROM, LANES_FROM + 1, 50_000)
+
+
+def lanes_info():
+    """(helper threads of this process's pool, their CPU ns, the lanes
+    the rule gives this process, the shortest call it splits)."""
+    out = (ctypes.c_longlong * 4)()
+    get_lib().wfn_lanes_info(out)
+    return list(out)
+
+
+def last_call(eng, n):
+    """What the engine's last call of ``n`` tuples left in its per-call
+    arrays (``wfn_engine_last_call``): per key in the order of first
+    touch (key, count, lo, hi, the extreme's bits, pool slot), slot_of,
+    the keys opened."""
+    lib = get_lib()
+    need = -lib.wfn_engine_last_call(eng.ptr, n, None, 0)
+    buf = (ctypes.c_longlong * need)()
+    assert lib.wfn_engine_last_call(eng.ptr, n, buf, need) == need
+    got = np.frombuffer(buf, np.int64)
+    nd = int(got[0])
+    keys = got[1:1 + 6 * nd].reshape(nd, 6)
+    return keys, got[1 + 6 * nd:1 + 6 * nd + n], got[2 + 6 * nd + n:]
+
+
+def split_stream(name):
+    """(lane, kind, keys, ids, values, chunks through a selection): a
+    Q5-shaped stream (111 keys, one hot); a YSB-shaped one, a view in
+    three of 100 campaigns' events through a selection; late events
+    (``note_late`` marks them, fold_singly drops or folds them); a MEAN,
+    which folds one by one; keys opened inside every call (a generation
+    of 13 keys every 700 events) under a MAX."""
+    rng = np.random.RandomState(11)
+    n = 4 * 50_000 + 17
+    if name in ("q5", "mean_singly", "ysb_selected"):
+        keys = np.where(rng.rand(n) < 0.5, 7, rng.randint(0, 111, n))
+        if name == "ysb_selected":
+            keys = rng.randint(0, 1000, n) // 10
+        ts = np.arange(n, dtype=np.int64)
+        vals = rng.randint(1, 1000, n).astype(np.float64)
+        kind = "mean" if name == "mean_singly" else "count"
+        return ((1 << 15, 1 << 14, True, 0, False, False), kind,
+                keys.astype(np.int64), ts, vals, name == "ysb_selected")
+    shape, kind = {"late": ("late", "count"),
+                   "opens_mid_call": ("inorder", "max")}[name]
+    keys, ts, vals = churn_stream(shape, n)
+    return (256, 128, True, 40, False, False), kind, keys, ts, vals, False
+
+
+def split_drive(name, chunk, mode):
+    """The stream in calls of ``chunk`` under the lanes' rule held to
+    never (0), always (1) or left to decide (-1): per call what
+    :func:`last_call` reads and the snapshot without its clocks, and
+    every staged byte."""
+    lane, kind, keys, ids, vals, selected = split_stream(name)
+    eng = NativeWindowEngine(*lane[:4], renumber=lane[4], kind=kind,
+                             dense=lane[5])
+    get_lib().wfn_engine_lanes_forced(eng.ptr, mode)
+    rng = np.random.RandomState(5)
+    calls, staged, sizes = [], hashlib.sha256(), []
+
+    def take():
+        while (out := eng.flush(1 << 30)) is not None:
+            for a in (*out[0].values(), *out[1:6]):
+                staged.update(np.ascontiguousarray(a).tobytes())
+    for lo in range(0, len(keys), chunk):
+        hi = min(lo + chunk, len(keys))
+        if selected:
+            c, sel = scattered(rng, {"keys": keys[lo:hi], "ids": ids[lo:hi],
+                                     "ts": ids[lo:hi], "vals": vals[lo:hi]})
+            eng.ingest(c["keys"], c["ids"], c["ts"], c["vals"], sel)
+        else:
+            eng.ingest(keys[lo:hi], ids[lo:hi], ids[lo:hi], vals[lo:hi])
+        parts, slots, opened = last_call(eng, hi - lo)
+        snap = {k: v for k, v in eng.snapshot().items()
+                if k not in CLOCKS and not k.endswith("_ns")
+                and k != "walked_in_lanes"}
+        calls.append((parts.tobytes(), slots.tobytes(), opened.tobytes(),
+                      snap))
+        sizes.append(hi - lo)
+        take()
+    eng.eos()
+    take()
+    assert eng.snapshot()["walked_ahead"] == 0   # the table fits, always
+    return calls, staged.hexdigest(), eng.snapshot()["walked_in_lanes"], \
+        sizes
+
+
+@pytest.mark.parametrize("chunk", SPLIT_CHUNKS)
+@pytest.mark.parametrize("name", ["q5", "ysb_selected", "late",
+                                  "mean_singly", "opens_mid_call"])
+def test_a_walk_in_slices_leaves_what_the_one_walk_leaves(name, chunk):
+    """Lanes forced off, forced on, and left to the rule, on calls just
+    under, at and over the shortest the rule splits: every call's
+    partials, their first-touch order and pool slots, slot_of (late
+    marks included), the keys opened, the snapshot, and every byte
+    staged are the one walk's; ``walked_in_lanes`` counts the tuples of
+    the calls split, which under the rule are some of those at or over
+    ``LANES_FROM`` (the ones that came within ``LANES_GAP_NS`` of the
+    last: this test's Python between two calls may take longer) where
+    the process may have helpers."""
+    assert lanes_info()[3] == LANES_FROM
+    one, on, rule = (split_drive(name, chunk, mode) for mode in (0, 1, -1))
+    for got in (on, rule):
+        assert got[1] == one[1]
+        for c, (a, b) in enumerate(zip(got[0], one[0])):
+            assert a == b, (name, chunk, c)
+    sizes = one[3]
+    assert one[2] == 0
+    assert on[2] == sum(sizes)
+    assert rule[2] <= (sum(n for n in sizes[1:] if n >= LANES_FROM)
+                       if lanes_info()[2] > 1 else 0)
+
+
+LANES_SCRIPT = r"""
+import ctypes, hashlib, json, os, sys, time
+import numpy as np
+from windflow_tpu.runtime.native import NativeWindowEngine, get_lib
+lib = get_lib()
+
+def info():
+    out = (ctypes.c_longlong * 4)()
+    lib.wfn_lanes_info(out)
+    return list(out)
+
+def tasks():
+    return len(os.listdir("/proc/self/task"))
+
+def run(chunk, mode, n=200_000):
+    rng = np.random.RandomState(1)
+    keys = np.where(rng.rand(n) < .5, 7, rng.randint(0, 111, n))
+    keys = keys.astype(np.int64)
+    ts = np.arange(n, dtype=np.int64)
+    eng = NativeWindowEngine(1 << 15, 1 << 14, True, 0, kind="count")
+    lib.wfn_engine_lanes_forced(eng.ptr, mode)
+    h = hashlib.sha256()
+    for a in range(0, n, chunk):
+        eng.ingest(keys[a:a + chunk], ts[a:a + chunk], ts[a:a + chunk],
+                   np.ones(len(keys[a:a + chunk])))
+        while (out := eng.flush(1 << 30)) is not None:
+            for c in (out[0]["value"], *out[1:6]):
+                h.update(np.ascontiguousarray(c).tobytes())
+    return h.hexdigest(), eng.snapshot()["walked_in_lanes"]
+
+got = {"lanes": info()[2], "tasks0": tasks(), "info0": info()}
+got["short"] = run(4096, -1)     # shorter than any call the rule splits
+got["tasks1"], got["info1"] = tasks(), info()
+got["long"] = run(65536, 1)      # every call split (under the rule, a
+#                                  call that follows the last by over a
+#                                  millisecond would walk alone)
+got["tasks2"], got["info2"] = tasks(), info()
+if sys.argv[1] == "asleep":
+    time.sleep(0.05)
+    c0 = info()[1]
+    time.sleep(0.2)
+    got["asleep_ns"] = info()[1] - c0
+elif sys.argv[1] == "fork":
+    pid = os.fork()
+    if pid == 0:
+        os._exit(0 if run(65536, 1) == got["long"] else 3)
+    for _ in range(600):
+        done, status = os.waitpid(pid, os.WNOHANG)
+        if done:
+            got["child"] = os.waitstatus_to_exitcode(status)
+            break
+        time.sleep(0.1)
+    else:
+        os.kill(pid, 9)
+        got["child"] = "hung"
+got["at"] = time.monotonic()
+print(json.dumps(got), flush=True)
+"""
+
+
+def lanes_script(what):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", LANES_SCRIPT, what], env=env,
+                       capture_output=True, text=True, timeout=120)
+    ended = time.monotonic()
+    assert p.returncode == 0, p.stderr[-2000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    if got["lanes"] < 2:
+        pytest.skip("the process may run on fewer than three cores")
+    return got, ended
+
+
+def test_no_lane_thread_exists_before_a_call_that_engages_them():
+    """Calls shorter than ``LANES_FROM`` start no thread; the first call
+    that engages the lanes starts the pool's helpers, and only them."""
+    got, _ = lanes_script("threads")
+    assert got["info0"][0] == got["info1"][0] == 0
+    assert got["tasks1"] == got["tasks0"]
+    assert got["short"][1] == 0 and got["long"][1] == 200_000
+    helpers = got["info2"][0]
+    assert helpers == got["lanes"] - 1
+    assert got["tasks2"] == got["tasks0"] + helpers
+
+
+def test_lanes_without_a_call_sleep():
+    """After a call, the helpers spin some microseconds and sleep: 200 ms
+    of no call cost them (nearly) no CPU."""
+    got, _ = lanes_script("asleep")
+    assert got["info2"][1] > 0
+    assert got["asleep_ns"] < 2_000_000
+
+
+def test_a_forked_child_walks_with_lanes_of_its_own():
+    """A child forked with the parent's lanes alive never waits on them:
+    its calls complete with the parent's answer."""
+    got, _ = lanes_script("fork")
+    assert got["child"] == 0
+
+
+def test_the_interpreter_exits_promptly_with_lanes_alive():
+    """The helpers are stopped and joined at exit: the process ends as
+    soon after its last line as a process without them."""
+    got, ended = lanes_script("exit")
+    assert got["info2"][0] > 0
+    assert ended - got["at"] < 5.0
 
 
 if __name__ == "__main__":

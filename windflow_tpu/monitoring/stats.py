@@ -79,10 +79,12 @@ from ..telemetry.histogram import LogHistogram
 # panes_shifted, copy_out_ns) and Spans.Launches rows the three parts of
 # dispatch (pack, call, handoff; None where the lane takes no stamps for
 # them), their Slowest row its own.
+# 21 = those Counters gain walked_in_lanes (tuples of the engine's calls
+# whose walk was split over the host lanes, docs/RUNTIME.md 5b).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 20
+SCHEMA_VERSION = 21
 
 
 @dataclass

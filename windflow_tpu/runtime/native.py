@@ -209,6 +209,13 @@ def get_lib():
         lib.wfn_engine_ignored.restype = LL
         lib.wfn_engine_ignored.argtypes = [ctypes.c_void_p]
         lib.wfn_engine_stats.argtypes = [ctypes.c_void_p, PLL]
+        # test-only (tests/test_native_runtime.py): the lanes' rule held
+        # to never / always, what the last call left, the process's lanes
+        lib.wfn_engine_lanes_forced.argtypes = [ctypes.c_void_p,
+                                                ctypes.c_int]
+        lib.wfn_engine_last_call.restype = LL
+        lib.wfn_engine_last_call.argtypes = [ctypes.c_void_p, LL, PLL, LL]
+        lib.wfn_lanes_info.argtypes = [PLL]
         lib.wfn_engine_eos.argtypes = [ctypes.c_void_p]
         lib.wfn_engine_flush.restype = LL
         lib.wfn_engine_flush.argtypes = [
@@ -632,10 +639,11 @@ class NativeWindowEngine:
     # of itself (the table had outgrown the caches), the rings that
     # left their key state (docs/RUNTIME.md 5a "A key state in one
     # place"); the pane partials ``flush`` copied into launch buffers
-    # and the windows they serve (docs/RUNTIME.md 5c).  Behind these
-    # nineteen the inside of ``fold`` and ``flush``
+    # and the windows they serve (docs/RUNTIME.md 5c); the tuples of the
+    # calls whose walk was split over the host lanes (docs/RUNTIME.md
+    # 5b).  Behind these twenty the inside of ``fold`` and ``flush``
     # (``spans.ENGINE_CLOCKS``, in the order of ``wfn_engine_stats``'
-    # ``out[19..24)``; tests/test_native_runtime.py names each index):
+    # ``out[20..25)``; tests/test_native_runtime.py names each index):
     # the engine fills all but the last, ``copy_out_ns``, which is this
     # wrapper's own (``flush`` keeps it in an attribute and mirrors it
     # into the slot behind what ``wfn_engine_stats`` writes, so that
@@ -645,7 +653,8 @@ class NativeWindowEngine:
              "windows_fired", "folded_by_key", "folded_singly",
              "late_accepted", "anchors_moved", "inputs_ignored",
              "stream_time", "key_touches", "walked_ahead", "rings_spilled",
-             "panes_staged", "windows_staged", *ENGINE_CLOCKS)
+             "panes_staged", "windows_staged", "walked_in_lanes",
+             *ENGINE_CLOCKS)
     _COPY_OUT = STATS.index("copy_out_ns")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,

@@ -185,6 +185,8 @@ def render_openmetrics(apps: dict) -> str:
              "copied into launch buffers"),
             ("windows_staged", "counter", "windows the engine's flush "
              "staged for a launch"),
+            ("walked_in_lanes", "counter", "tuples of the engine's calls "
+             "whose walk was split over the host lanes"),
             ("cols_selected", "counter", "columns the selected batches "
              "the operator ingested carried"),
             ("cols_gathered", "counter", "columns of selected batches "

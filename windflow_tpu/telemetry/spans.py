@@ -485,7 +485,7 @@ class LaunchRing:
 # all read this.  Nanoseconds on the engine's steady clock, a call at a
 # time, unless said otherwise; kept as counters and never entered as
 # child spans, so ``fold`` and ``flush`` keep the self time they had.
-# THE ORDER IS THE ENGINE'S: ``wfn_engine_stats`` fills ``out[19..24)``
+# THE ORDER IS THE ENGINE'S: ``wfn_engine_stats`` fills ``out[20..25)``
 # with the first five in this order (native/window_engine.cpp) and the
 # wrapper keeps the sixth behind them; tests/test_native_runtime.py
 # names each index, so a reordering here fails there and mislabels
@@ -518,19 +518,21 @@ ENGINE_CLOCKS = {
 # outgrown the caches), the rings that left their key state
 # (docs/RUNTIME.md 5a "A key state in one place"); and what its flush
 # staged: the pane partials it copied into launch buffers and the windows
-# they serve (docs/RUNTIME.md 5c); and the :data:`ENGINE_CLOCKS`
+# they serve (docs/RUNTIME.md 5c); the tuples of its calls whose walk was
+# split over the host lanes (docs/RUNTIME.md 5b); and the
+# :data:`ENGINE_CLOCKS`
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "keys_live_peak", "windows_fired", "folded_by_key",
                    "folded_singly", "late_accepted", "anchors_moved",
                    "inputs_ignored", "key_touches", "walked_ahead",
                    "rings_spilled", "panes_staged", "windows_staged",
-                   *ENGINE_CLOCKS)
+                   "walked_in_lanes", *ENGINE_CLOCKS)
 # those of them kept as a series (the last values noted in each 100 ms
 # bucket), so that what moved between two instants can be read
 SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
                    "anchors_moved", "inputs_ignored", "key_touches",
-                   "walked_ahead", "panes_staged", "windows_staged",
-                   *ENGINE_CLOCKS)
+                   "walked_ahead", "walked_in_lanes", "panes_staged",
+                   "windows_staged", *ENGINE_CLOCKS)
 
 
 # what a window operator counts of the selected batches it ingests
