@@ -1467,6 +1467,8 @@ struct Engine {
         }
         if (runs_ahead())
             ingest_walks<SEL, true>(bkeys, ids, tss, vals, n, s);
+        else if (ingest_one_key<SEL>(bkeys, ids, tss, vals, n, s))
+            return;
         else if (in_slices(n))
             ingest_in_slices<SEL>(bkeys, ids, tss, vals, n, s);
         else
@@ -2331,6 +2333,7 @@ struct Engine {
             ingest_walks<SEL, true, false, true>(bkeys, ids, tss, vals, n, s);
             return;
         }
+        if (ingest_one_key<SEL>(bkeys, ids, tss, vals, n, s)) return;
         if (in_slices(n)) {
             const lanes::Hold hold;
             if (hold.held) {
@@ -2387,8 +2390,8 @@ struct Engine {
     }
 
     // one tuple into ring pane `p` (its count is the caller's)
-    inline void fold_arg(KeyState& st, int32_t slot, i64 p, double v,
-                         i64 id) {
+    __attribute__((noinline)) void fold_arg(KeyState& st, int32_t slot,
+                                            i64 p, double v, i64 id) {
         const Ring r = ring(st);
         ++r.cnt[p];
         if (!(v >= r.acc[p])) return;
@@ -2464,6 +2467,218 @@ struct Engine {
             if (!ids.empty()) ties_of[{slot, base + rel}] = std::move(ids);
         }
         return true;
+    }
+
+    // THE ONE-KEY WALK (behind every other member for the reason the
+    // clocks are).  A call whose tuples all carry one key (a window with
+    // no key: WindFlow's non-keyed Win_Seq, Beam's Combine.globally)
+    // needs none of what gather() does a tuple for many keys: no probe,
+    // no slot_of, no partial in memory that every tuple of the call
+    // updates in turn (one chain of dependent updates: one key walked
+    // slower than 111, PERF.md section 6).  One pass keeps the
+    // key's partial in registers -- how many, the smallest and largest
+    // id, for MAX / MIN the extreme, for ARGMAX the largest value and the
+    // ids at it in arrival order (argmax_of_the_call's walk, in the same
+    // pass) -- and note_late()'s test whether the ids run in order from
+    // the stream time on.  It then leaves what the general walks leave:
+    // the key through dense_of() (opened where it has no record), parts[0]
+    // and arg_at[0]; slot_of only where a later step reads it (a call
+    // with late tuples, which note_late() marks; a key that folds one by
+    // one), so prepare(), keep_arg(), settle(), trigger() and flush() run
+    // as after the general walks, and a call that straddles a pane edge
+    // folds one by one as it would.
+    // No knob: what decides is what the engine observes.  A lane that
+    // folds by key (by_key_lane), a table that does not run ahead, and
+    // the engine's own last call touched one key (parts, as that call
+    // left them).  The pass compares each tuple's key with the first's;
+    // at a block of tuples that holds another key it gives up, having
+    // written nothing the general walk reads, and the call takes the
+    // general walk from the start: one block where the guess fails.
+    // On the caller alone, whatever the call's length: the pass is bound
+    // by the columns it reads, not by what it does a tuple, and on the
+    // chip's host it read a tuple in 1.0-1.1 ns on one core against
+    // 1.7 ns split over four lanes with their wake and merge (THE TUPLE
+    // WALK IN SLICES; PERF.md section 6).
+    // Counted (wfn_engine_stats): walked_one_key, the tuples of the calls
+    // it took.  Its time is the call's tuple walk (tuple_walk_ns);
+    // argmax_ns keeps what is still apart, the one-by-one fold.
+    i64 walked_one_key = 0;
+    i64 slots_owed = -1;      // the call whose slot_of it left unwritten:
+                              // 0 a tuple (wfn_engine_last_call's)
+
+    // The pass, over the kinds as walk_tuples() is over EXT (0 reads no
+    // value, 1 the largest, 2 the smallest; ARG the largest and the ids
+    // at it, into arg_at[0]).  False where a block of tuples holds one of
+    // another key.  A block of ONE_KEY_BLOCK is walked with no branch a
+    // tuple (the compilers make it a vector loop): the keys against the
+    // first, the smallest and largest id, whether the ids run in order,
+    // and whether a value of the block moves the extreme held; only a
+    // block that holds such a value is walked again, tuple by tuple, in
+    // arrival order (a few blocks of a call: the extreme of a stream's
+    // values is seldom passed).
+    static constexpr i64 ONE_KEY_BLOCK = 256;
+    template <int EXT, bool ARG, bool SEL, typename TV>
+    __attribute__((always_inline)) inline bool one_key_pass(
+            const i64* bkeys, const i64* ids, const i64* tss, const TV* vals,
+            i64 n, Sel s, i64 key, Part& pt, bool& in_order) {
+        const bool ck = SEL && !(s.through & SEL_KEYS);
+        const bool ci = SEL && !(s.through & SEL_IDS);
+        const bool ct = SEL && !(s.through & SEL_TSS);
+        const bool cv = SEL && !(s.through & SEL_VALS);
+        auto id_at = [&](i64 j) { return ids[ci || !SEL ? j : s.rows[j]]; };
+        auto v_at = [&](i64 j) {
+            return (double)vals[cv || !SEL ? j : s.rows[j]];
+        };
+        i64 lo = INT64_MAX, hi = INT64_MIN, back = id_at(0) < stream_time;
+        double ext = neutral, top = -INF;
+        std::vector<i64>* at_top = ARG ? &arg_at[0].ids : nullptr;
+        for (i64 b = 0; b < n; b += ONE_KEY_BLOCK) {
+            const i64 e = std::min<i64>(b + ONE_KEY_BLOCK, n);
+            i64 other = 0;
+            for (i64 j = b; j < e; ++j) {
+                other |= bkeys[ck || !SEL ? j : s.rows[j]] ^ key;
+                const i64 id = id_at(j);
+                lo = id < lo ? id : lo;
+                hi = id > hi ? id : hi;
+            }
+            if (other != 0) return false;
+            for (i64 j = b > 0 ? b : 1; j < e; ++j)
+                back += id_at(j) < id_at(j - 1);
+            if (EXT == 0 && !ARG) continue;
+            const double held = ARG ? top : ext;
+            i64 moves = 0;
+            for (i64 j = b; j < e; ++j) {
+                const double v = v_at(j);
+                moves += EXT == 1 ? v > held : EXT == 2 ? v < held
+                                                        : v >= held;
+            }
+            if (moves == 0) continue;
+            for (i64 j = b; j < e; ++j) {
+                const double v = v_at(j);
+                if (EXT == 1 && v > ext) ext = v;
+                if (EXT == 2 && v < ext) ext = v;
+                if (ARG && v >= top) {
+                    if (v > top) {
+                        top = v;
+                        at_top->clear();
+                    }
+                    at_top->push_back(tss[ct || !SEL ? j : s.rows[j]]);
+                }
+            }
+        }
+        pt = Part{n, lo, hi, ext};
+        if (ARG) arg_at[0].ext = top;
+        in_order = back == 0;
+        return true;
+    }
+
+    // slot_of as gather() leaves it on one key: 0 a tuple
+    inline void one_key_slots(i64 n) {
+        std::fill(slot_of.begin(), slot_of.begin() + n, 0);
+    }
+
+    // The call after the pass, ingest_walks()'s for one key, in pieces
+    // out of line: each function the general walks share gains one caller
+    // and no more, so that the compiler keeps the out-of-line functions
+    // it made before there was this walk (docs/RUNTIME.md 5b)
+    template <int EXT, bool ARG, bool SEL, typename TV>
+    bool one_key_call(const i64* bkeys, const i64* ids, const i64* tss,
+                      const TV* vals, i64 n, Sel s) {
+        call_begins();
+        if (ARG) reset_args(arg_at, parts.size());  // 1: the rule's
+        const i64 key = bkeys[SEL && (s.through & SEL_KEYS) ? s.rows[0] : 0];
+        Part pt;
+        bool in_order;
+        if (!one_key_pass<EXT, ARG, SEL>(bkeys, ids, tss, vals, n, s, key, pt,
+                                         in_order))
+            return false;
+        one_key_open(key, pt, n);
+        if (!in_order)
+            one_key_late(ids, SEL && (s.through & SEL_IDS) ? s.rows : nullptr,
+                         n);
+        piece_ends(tuple_walk_ns);
+        const bool singly = one_key_prepare(ARG, n);
+        if (singly) {
+            if (in_order) one_key_slots(n);
+            fold_singly<SEL, ARG>(ids, tss, vals, n, s);
+        }
+        one_key_settle(ARG ? argmax_ns : tuple_walk_ns, !singly && in_order);
+        return true;
+    }
+
+    // the key of the call and its partial where gather() leaves them
+    __attribute__((noinline)) void one_key_open(i64 key, const Part& pt,
+                                                i64 n) {
+        ++call_id;
+        parts.clear();
+        d_slot.clear();
+        d_state.clear();
+        opened_now.clear();
+        if ((i64)slot_of.size() < n) slot_of.resize(n);
+        dense_of(key);
+        parts[0] = pt;
+        walked_one_key += n;
+    }
+
+    // a call whose ids do not run in order: its late tuples marked
+    __attribute__((noinline)) void one_key_late(const i64* ids,
+                                                const i64* rows, i64 n) {
+        one_key_slots(n);
+        if (rows != nullptr)
+            note_late<true>(ids, rows, n);
+        else
+            note_late<false>(ids, nullptr, n);
+    }
+
+    // the visit by key; true where the key folds one by one
+    __attribute__((noinline)) bool one_key_prepare(bool arg, i64 n) {
+        d_accept.resize(parts.size());
+        d_single.resize(parts.size());
+        n_single = 0;
+        ++key_touches;
+        const i64 t0 = opened_now.empty() ? 0 : now_ns();
+        if (arg)
+            prepare<true>(0);
+        else
+            prepare<false>(0);
+        if (!opened_now.empty()) open_ns += now_ns() - t0;
+        probes_ahead = (i64)(1 - opened_now.size()) * AHEAD_BLOCK > n;
+        piece_ends(key_walk_ns);
+        return n_single != 0;
+    }
+
+    // settle() and the firing; `piece`: the clock of what ran since the
+    // visit by key
+    __attribute__((noinline)) void one_key_settle(i64& piece, bool owed) {
+        piece_ends(piece);
+        settle(*d_state[0], d_slot[0], parts[0].hi);
+        piece_ends(key_walk_ns);
+        trigger();            // a lane that folds by key has the stream rule
+        call_ends();
+        slots_owed = owed ? call_id : -1;
+    }
+
+    // The one way in, from ingest_batch() and ingest_argmax() where the
+    // table does not run ahead: true where the call was taken whole
+    template <bool SEL, typename TV>
+    __attribute__((noinline)) bool ingest_one_key(
+            const i64* bkeys, const i64* ids, const i64* tss, const TV* vals,
+            i64 n, Sel s) {
+        if (!by_key_lane || parts.size() != 1 || n <= 0) return false;
+        switch (kind) {
+            case Kind::COUNT:
+                return one_key_call<0, false, SEL>(bkeys, ids, tss, vals, n,
+                                                   s);
+            case Kind::MAX:
+                return one_key_call<1, false, SEL>(bkeys, ids, tss, vals, n,
+                                                   s);
+            case Kind::MIN:
+                return one_key_call<2, false, SEL>(bkeys, ids, tss, vals, n,
+                                                   s);
+            default:          // ARGMAX
+                return one_key_call<0, true, SEL>(bkeys, ids, tss, vals, n,
+                                                  s);
+        }
     }
 };
 
@@ -2582,6 +2797,8 @@ i64 wfn_engine_ignored(void* ep) {
 // out[26..29): an ARGMAX engine's clock of its own walks, the times a
 // pane's held maximum was set or raised, and the ids beyond the first at
 // the staged windows' maxima (Engine, "THE IDS AT A PANE'S MAXIMUM").
+// out[29]: the tuples of the calls the one-key walk took (Engine, "THE
+// ONE-KEY WALK").
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -2612,6 +2829,7 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[26] = e.argmax_ns;
     out[27] = e.max_raised;
     out[28] = e.tie_ids;
+    out[29] = e.walked_one_key;
 }
 
 // An ARGMAX engine's last flush, beside its pane maxima: each staged
@@ -2640,15 +2858,20 @@ void wfn_engine_lanes_forced(void* ep, int mode) {
 // per-call arrays, into out: the keys it met; per key in the order of
 // first touch its key, count, smallest and largest id, the extreme's
 // bits and its pool slot; slot_of[0..n) (made the call's here where a
-// lane-split call left it in its slices' indices); the keys it opened
-// and their indices.  Returns the length, minus the length where `cap`
-// is less, 0 where another engine's call has had the slices since.
+// lane-split call left it in its slices' indices, or where the one-key
+// walk left it unwritten); the keys it opened and their indices.
+// Returns the length, minus the length where `cap` is less, 0 where
+// another engine's call has had the slices since.
 i64 wfn_engine_last_call(void* ep, i64 n, i64* out, i64 cap) {
     Engine& e = *static_cast<Engine*>(ep);
     const i64 nd = (i64)e.parts.size(), no = (i64)e.opened_now.size();
     const i64 len = 1 + 6 * nd + n + 1 + no;
     if (cap < len || n > (i64)e.slot_of.size()) return -len;
     if (!e.slots_of_the_call()) return 0;
+    if (e.slots_owed == e.call_id) {
+        e.one_key_slots(n);
+        e.slots_owed = -1;
+    }
     i64* o = out;
     *o++ = nd;
     for (i64 d = 0; d < nd; ++d) {

@@ -198,15 +198,19 @@ def test_one_global_key_with_a_long_tail(which):
 def test_the_walk_in_slices_gives_the_one_walks_rows(n_keys):
     """Calls long enough to split, the lanes forced off and on: the same
     rows, ties in the same order, and every tuple of the forced calls
-    walked in slices."""
+    walked in slices -- but on one key, where every call after the first
+    is the one-key walk's, which the lanes' rule does not split."""
     keys, ids, vals = stream(3, 200_003, n_keys, WinType.TB, n_values=3)
     got = {}
     for mode in (0, 1):
         eng = NativeWindowEngine(1 << 14, 1 << 13, True, 0, kind="argmax")
         get_lib().wfn_engine_lanes_forced(eng.ptr, mode)
         got[mode] = drive(eng, keys, ids, vals, 50_000)
-        assert eng.snapshot()["walked_in_lanes"] == (0 if mode == 0
-                                                      else len(keys))
+        s = eng.snapshot()
+        one_key = len(keys) - 50_000 if n_keys == 1 else 0
+        assert s["walked_one_key"] == one_key
+        assert s["walked_in_lanes"] == (0 if mode == 0
+                                        else len(keys) - one_key)
     assert got[0] == got[1]
     assert sorted(got[0]) == reference(keys, ids, vals, 1 << 14, 1 << 13)
 
@@ -386,3 +390,82 @@ def test_the_composites_that_route_by_key_refuse_the_kind():
         WinFarmTPU("argmax", 64, 64, WinType.TB, parallelism=2).stages()
     ok = KeyFarmTPU("argmax", 64, 64, WinType.TB, emit_batches=True)
     assert ok.stages()
+
+
+# -- the one-key walk -------------------------------------------------------
+
+@needs_native
+@pytest.mark.parametrize("chunk", [50, 129, 1000],
+                         ids=["in_a_pane", "straddles", "long"])
+@pytest.mark.parametrize("shape", ["inorder", "late", "stray_first",
+                                   "stray_mid", "stray_last"])
+@pytest.mark.parametrize("win_type", [WinType.TB, WinType.CB],
+                         ids=["tb", "cb"])
+def test_the_one_key_walk_keeps_the_ids_at_the_maximum(win_type, shape,
+                                                       chunk):
+    """One key, values from five (ties at the maximum inside a call and
+    across calls), a TB tuple's own id apart from its stamp: per call the
+    general walks' partials, slot_of, counts and staged ids, snapshots
+    byte for byte, from the first call on (which the rule never admits);
+    a stray key at the first, a middle or the last tuple of a call gives
+    the call back to them.  The rows are the recomputation's."""
+    from test_native_runtime import one_key_owed_moves, one_key_pair, \
+        one_key_stream
+    n = 3000
+    cuts = list(range(chunk, n, chunk)) + [n]
+    keys, stamps, vals = one_key_stream(shape, cuts)
+    ids = stamps if win_type == WinType.CB else \
+        stamps * 3 + np.random.default_rng(1).integers(0, 3, n)
+    lane = (128, 64, win_type == WinType.TB, 40, False, False)
+    eng, moved = one_key_pair(lane, "argmax", keys, stamps, vals, cuts,
+                              tss=ids)
+    assert moved == one_key_owed_moves(keys, cuts, "argmax",
+                                       win_type == WinType.TB)
+    assert moved[0] == 0
+    if not shape.startswith("stray"):
+        assert (sum(moved) > 0) == (win_type == WinType.TB)
+    if shape == "late" or win_type == WinType.CB:
+        return     # late tuples drop; CB windows count a key's own ids
+    got = drive(make("native", 128, 64, win_type), keys, ids, vals, chunk,
+                stamps=stamps)
+    want = reference(keys, ids, vals, 128, 64, stamps)
+    assert len(want) > len({(w, k) for _, w, _, k in want})   # ties
+    assert sorted(got) == want
+
+
+@needs_native
+@pytest.mark.parametrize("through", [(), ("ids", "ts"), ("keys", "vals")],
+                         ids=["all_through", "ids_compact", "keys_compact"])
+def test_the_one_key_walk_reads_an_argmax_call_through_its_selection(
+        through):
+    """A selected batch on one key, late tuples among it: the general
+    walks' state and bytes, and the calls the rule admits walked once."""
+    from test_native_runtime import one_key_owed_moves, one_key_pair, \
+        one_key_stream
+    cuts = list(range(129, 3000, 129)) + [3000]
+    keys, stamps, vals = one_key_stream("late", cuts)
+    ids = stamps * 3 + np.random.default_rng(2).integers(0, 3, 3000)
+    _eng, moved = one_key_pair((128, 64, True, 40, False, False), "argmax",
+                               keys, stamps, vals, cuts, tss=ids,
+                               through=through)
+    assert moved == one_key_owed_moves(keys, cuts, "argmax", True)
+
+
+@needs_native
+def test_one_global_key_is_walked_once_a_call():
+    """Q7's shape: after the first call every call is the one-key walk's,
+    ``argmax_ns`` reads the one-by-one fold alone (a call that straddles
+    a window's edge), and the rows are the recomputation's."""
+    rng = np.random.default_rng(7)
+    n, win, chunk = 60_000, 4096, 1000
+    vals = np.round(10 ** (6 * rng.random(n)) * 100)
+    vals[rng.integers(0, n, 40)] = 99_999_999.0
+    keys, ids = np.zeros(n, np.int64), np.arange(n, dtype=np.int64)
+    eng = make("native", win, win, WinType.TB)
+    rows = drive(eng, keys, ids, vals, chunk)
+    assert sorted(rows) == reference(keys, ids, vals, win, win)
+    s = eng.snapshot()
+    assert s["walked_one_key"] == n - chunk
+    assert s["walked_in_lanes"] == 0
+    assert s["argmax_ns"] + s["tuple_walk_ns"] + s["key_walk_ns"] \
+        <= s["ingest_ns"]

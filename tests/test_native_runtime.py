@@ -864,8 +864,12 @@ def test_the_first_nineteen_stats_keep_their_order_and_values(case, want):
     assert NativeWindowEngine.STATS[20:26] == CLOCKS == tuple(
         spans.ENGINE_CLOCKS)
     # behind them an argmax engine's (PR 40): the engine's out[26..29)
-    assert NativeWindowEngine.STATS[26:] == ARGMAX_STATS == tuple(
+    assert NativeWindowEngine.STATS[26:29] == ARGMAX_STATS == tuple(
         spans.ENGINE_ARGMAX)
+    # and the one-key walk's tuples, out[29], the last
+    assert NativeWindowEngine.STATS[29:] == ("walked_one_key",)
+    assert spans.ENGINE_COUNTERS[-1] == spans.SERIES_COUNTERS[-1] \
+        == "walked_one_key"
     eng = ks_moved_back()[0] if case == "moved_back" \
         else ks_q5("ooo", "count", 4096, n=60_000)[0]
     assert list(eng.stats())[3:19] == want
@@ -1208,6 +1212,241 @@ def test_the_interpreter_exits_promptly_with_lanes_alive():
     got, ended = lanes_script("exit")
     assert got["info2"][0] > 0
     assert ended - got["at"] < 5.0
+
+
+
+# -- the one-key walk -------------------------------------------------------
+#
+# A call whose tuples all carry one key, after a call that touched one key,
+# on a lane that folds by key, is walked once with no probe (docs/RUNTIME.md
+# 5b).  Its twin here is the same engine fed the same calls with an empty
+# call in front of each: an empty call changes no row, no count and no byte
+# of a snapshot, and leaves the engine's last call touching no key, so every
+# call of the twin takes the general walks.
+
+import dataclasses  # noqa: E402
+
+from test_fold_by_key import LANES as FOLD_LANES  # noqa: E402
+from test_fold_by_key import owed  # noqa: E402
+
+ONE_KEY_LANES = ("tb", "tb_tumbling", "tb_delay", "tb_odd_pane", "tb_dense",
+                 "cb")
+ONE_KEY_SHAPES = ("inorder", "late", "stray_first", "stray_mid",
+                  "stray_last")
+ONE_KEY_N = 3000
+
+
+def one_key_stream(shape, cuts, seed=13):
+    """One key (5) on one clock, values from five whole numbers (ties at
+    every extreme); ``late`` sends one tuple in 23 some 300 stamps back
+    (behind the stream time: accepted where its window has not fired,
+    dropped where it has); ``stray_*`` puts a tuple of key 6 at the
+    first, middle or last tuple of every third call."""
+    n = cuts[-1]
+    rng = np.random.RandomState(seed)
+    ts = np.arange(n, dtype=np.int64)
+    keys = np.full(n, 5, np.int64)
+    vals = rng.randint(1, 6, n).astype(np.float64)
+    if shape == "late":
+        back = rng.randint(0, 23, n) == 0
+        ts = np.where(back, np.maximum(ts - 300, 0), ts)
+    elif shape.startswith("stray_"):
+        lo = 0
+        for i, hi in enumerate(cuts):
+            if i % 3 == 1:
+                at = {"first": lo, "mid": (lo + hi) // 2,
+                      "last": hi - 1}[shape[len("stray_"):]]
+                keys[at] = 6
+            lo = hi
+    else:
+        assert shape == "inorder"
+    return keys, ts, vals
+
+
+def one_key_digest(h, item):
+    """Every array of what a flush handed over, into ``h``."""
+    if isinstance(item, dict):
+        for v in item.values():
+            one_key_digest(h, v)
+    elif isinstance(item, (tuple, list)):
+        for v in item:
+            one_key_digest(h, v)
+    elif isinstance(item, np.ndarray):
+        h.update(np.ascontiguousarray(item).tobytes())
+    elif dataclasses.is_dataclass(item):
+        one_key_digest(h, [getattr(item, f.name)
+                           for f in dataclasses.fields(item)])
+    else:
+        h.update(repr(item).encode())
+
+
+def one_key_pair(lane, kind, keys, ids, vals, cuts, tss=None, through=None,
+                 snap_every=4):
+    """The calls ``cuts`` make of the stream into an engine and into its
+    twin (an empty call in front of each: the general walks), every ready
+    window staged after each call and at EOS.  Per call: what
+    :func:`last_call` reads, the counts (clocks and ``walked_one_key``
+    aside) and every staged byte are the twin's; every ``snap_every``
+    calls and at the end, the snapshot's bytes.  Returns the engine and,
+    per call, how far its ``walked_one_key`` moved.  ``tss``: the column
+    handed over as stamps (an argmax engine's tuple ids on TB)."""
+    win, slide, is_tb, delay, renumber, dense = FOLD_LANES.get(lane, lane)
+    tss = ids if tss is None else tss
+    engs = [NativeWindowEngine(win, slide, is_tb, delay, renumber=renumber,
+                               kind=kind, dense=dense) for _ in range(2)]
+    empty = np.empty(0, np.int64)
+    rng = np.random.RandomState(len(keys))
+    hashes = [hashlib.sha256(), hashlib.sha256()]
+    moved, lo = [], 0
+    aside = set(CLOCKS) | {"walked_one_key"}
+
+    def take(i):
+        while (out := engs[i].flush(1 << 30)) is not None:
+            one_key_digest(hashes[i], out)
+    for c, hi in enumerate(cuts):
+        cols = {"keys": keys[lo:hi], "ids": ids[lo:hi], "ts": tss[lo:hi],
+                "vals": vals[lo:hi]}
+        sel = None
+        if through is not None:
+            cols, sel = scattered(rng, cols, through)
+        before = engs[0].snapshot()["walked_one_key"]
+        engs[1].ingest(empty, empty, empty, np.empty(0))
+        for eng in engs:
+            eng.ingest(cols["keys"], cols["ids"], cols["ts"], cols["vals"],
+                       sel)
+        moved.append(engs[0].snapshot()["walked_one_key"] - before)
+        got = [last_call(eng, hi - lo) for eng in engs]
+        for a, b in zip(*got):
+            assert a.tobytes() == b.tobytes(), (lane, kind, c)
+        snaps = [{k: v for k, v in eng.snapshot().items()
+                  if k not in aside and not k.endswith("_ns")}
+                 for eng in engs]
+        assert snaps[0] == snaps[1], (lane, kind, c)
+        for i in (0, 1):
+            take(i)
+        assert hashes[0].hexdigest() == hashes[1].hexdigest(), (lane, kind, c)
+        if c % snap_every == snap_every - 1:
+            assert engs[0].serialize() == engs[1].serialize(), (lane, kind, c)
+        lo = hi
+    for i in (0, 1):
+        engs[i].eos()
+        take(i)
+    assert hashes[0].hexdigest() == hashes[1].hexdigest()
+    assert engs[0].serialize() == engs[1].serialize()
+    assert engs[1].snapshot()["walked_one_key"] == 0
+    return engs[0], moved
+
+
+def one_key_owed_moves(keys, cuts, kind, is_tb):
+    """What the rule admits: a call of one key after a call of one key, on
+    a lane that folds by key (TB; COUNT, MAX, MIN, ARGMAX), all its
+    tuples; else nothing."""
+    by_key = is_tb and kind in ("count", "max", "min", "argmax")
+    want, lo, before = [], 0, 0
+    for hi in cuts:
+        now = len(np.unique(keys[lo:hi]))
+        want.append(hi - lo if by_key and before == 1 and now == 1 else 0)
+        before, lo = now, hi
+    return want
+
+
+@pytest.mark.parametrize("chunk", [64, 129], ids=["in_a_pane", "straddles"])
+@pytest.mark.parametrize("shape", ONE_KEY_SHAPES)
+@pytest.mark.parametrize("lane", ONE_KEY_LANES)
+@pytest.mark.parametrize("kind", ["count", "max", "min"])
+def test_the_one_key_walk_leaves_what_the_general_walk_leaves(kind, lane,
+                                                              shape, chunk):
+    """COUNT, MAX and MIN on time- and count-based lanes: calls inside a
+    pane (folded by key) and calls that straddle a pane edge (one by
+    one), in order and with late tuples, and a stray key at the first, a
+    middle and the last tuple of a call (the walk gives up): per call the
+    partials, slot_of, the counts and every staged byte the general
+    walk's, snapshots byte for byte; ``walked_one_key`` moves by a call's
+    tuples where the rule admits the call and not otherwise (never the
+    first call, never a count-based lane); the rows are the plain
+    recomputation's."""
+    cuts = list(range(chunk, ONE_KEY_N, chunk)) + [ONE_KEY_N]
+    keys, ts, vals = one_key_stream(shape, cuts)
+    eng, moved = one_key_pair(lane, kind, keys, ts, vals, cuts)
+    is_tb = FOLD_LANES[lane][2]
+    assert moved == one_key_owed_moves(keys, cuts, kind, is_tb)
+    assert moved[0] == 0
+    assert (sum(moved) > ONE_KEY_N // 4) == is_tb
+    if not is_tb or FOLD_LANES[lane][5] or shape == "late":
+        return     # CB windows count a key's own ids, a dense lane emits
+        #            empty windows too, late tuples drop
+    win, slide = FOLD_LANES[lane][:2]
+    _eng, rows, _digest, _ = drive_lane(lane, kind, keys, ts, vals, cuts)
+    want = owed(keys, ts, vals, win, slide, kind)
+    got = {(k, w): v for k, w, v, _rts in rows}
+    assert len(got) == len(rows) and got == want
+
+
+@pytest.mark.parametrize("through", [(), ("ids", "ts"), ("keys", "vals")],
+                         ids=["all_through", "ids_compact", "keys_compact"])
+@pytest.mark.parametrize("kind", ["count", "max", "min"])
+def test_through_a_selection_the_one_key_walk_leaves_the_same(kind, through):
+    """A selected batch (its rows read through the selection, or compact
+    columns laid over it), late tuples among them: the general walk's
+    state and bytes, and the one-key walk takes the calls it takes on the
+    plain columns."""
+    cuts = list(range(129, ONE_KEY_N, 129)) + [ONE_KEY_N]
+    keys, ts, vals = one_key_stream("late", cuts)
+    _eng, moved = one_key_pair("tb_delay", kind, keys, ts, vals, cuts,
+                               through=through)
+    assert moved == one_key_owed_moves(keys, cuts, kind, True)
+
+
+def test_one_key_calls_of_one_tuple_and_the_counter_in_a_graph():
+    """A record at a time (calls of one tuple: every one after the first
+    on the one-key walk), then ``walked_one_key`` where the engine's other
+    counts are: the span registry's series, ``Spans.Operators[].Counters``
+    and ``/metrics``."""
+    import windflow_tpu as wf
+    from windflow_tpu.core import WinType
+    from windflow_tpu.core.tuples import TupleBatch
+    from windflow_tpu.operators.basic_ops import Sink
+    from windflow_tpu.operators.batch_ops import BatchSource
+    from windflow_tpu.operators.tpu.win_seq_tpu import WinSeqTPU
+    from windflow_tpu.telemetry import spans
+    from windflow_tpu.telemetry.metrics import render_openmetrics
+    keys, ts, vals = one_key_stream("inorder", [400])
+    _eng, moved = one_key_pair("tb", "count", keys, ts, vals,
+                               list(range(1, 401)))
+    assert moved == [0] + [1] * 399
+    n, chunk = 40_000, 1000
+    keys, ts, vals = one_key_stream("inorder", [n])
+    sent, rows = {"i": 0}, {}
+
+    def body(ctx=None):
+        a = sent["i"]
+        if a >= n:
+            return None
+        sent["i"] = b = min(a + chunk, n)
+        return TupleBatch({"key": keys[a:b], "id": ts[a:b], "ts": ts[a:b],
+                           "value": vals[a:b]})
+
+    def sink(batch):
+        if batch is not None:
+            for k, w, v in zip(batch.key.tolist(), batch.id.tolist(),
+                               np.asarray(batch["value"]).tolist()):
+                rows[(k, w)] = v
+    g = wf.PipeGraph("one_key_counter", wf.Mode.DEFAULT)
+    g.add_source(BatchSource(body)).add(
+        WinSeqTPU("max", 4096, 4096, WinType.TB, name="top",
+                  emit_batches=True)).add_sink(Sink(sink, name="sink"))
+    g.run()
+    assert rows == owed(keys, ts, vals, 4096, 4096, "max")
+    report = json.loads(g.stats.to_json())
+    assert report["Schema_version"] >= 23
+    counted = [r for r in report["Spans"]["Operators"] if "Counters" in r]
+    c = counted[0]["Counters"]
+    # every call but the first, whose engine had no last call
+    assert c["walked_one_key"] == n - chunk
+    kept = spans.graph(g.name).counters[counted[0]["Operator"]]
+    assert kept.between(("walked_one_key",), 0.0, 1e12) == (n - chunk,)
+    text = render_openmetrics({"a": {"report": report}})
+    assert "windflow_engine_walked_one_key_total{" in text
 
 
 if __name__ == "__main__":

@@ -83,10 +83,13 @@ from ..telemetry.histogram import LogHistogram
 # whose walk was split over the host lanes, docs/RUNTIME.md 5b).
 # 22 = those Counters gain spans.ENGINE_ARGMAX (argmax_ns, max_raised,
 # tie_ids: an argmax engine's own walks and what they kept, 0 elsewhere).
+# 23 = those Counters gain walked_one_key (tuples of the engine's calls
+# whose tuples all carried one key, walked once with no probe,
+# docs/RUNTIME.md 5b).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 22
+SCHEMA_VERSION = 23
 
 
 @dataclass

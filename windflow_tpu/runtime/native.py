@@ -655,14 +655,16 @@ class NativeWindowEngine:
     # into its slot, 25, which ``wfn_engine_stats`` leaves alone, so that
     # ``stats()`` hands it over with the rest and costs what it cost).
     # Behind it what an ``argmax`` engine clocks and counts
-    # (``spans.ENGINE_ARGMAX``, the engine's ``out[26..29)``)
+    # (``spans.ENGINE_ARGMAX``, the engine's ``out[26..29)``), and the
+    # tuples of the calls the one-key walk took (``out[29]``,
+    # docs/RUNTIME.md 5b)
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
              "windows_fired", "folded_by_key", "folded_singly",
              "late_accepted", "anchors_moved", "inputs_ignored",
              "stream_time", "key_touches", "walked_ahead", "rings_spilled",
              "panes_staged", "windows_staged", "walked_in_lanes",
-             *ENGINE_CLOCKS, *ENGINE_ARGMAX)
+             *ENGINE_CLOCKS, *ENGINE_ARGMAX, "walked_one_key")
     _COPY_OUT = STATS.index("copy_out_ns")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,

@@ -187,6 +187,8 @@ def render_openmetrics(apps: dict) -> str:
              "staged for a launch"),
             ("walked_in_lanes", "counter", "tuples of the engine's calls "
              "whose walk was split over the host lanes"),
+            ("walked_one_key", "counter", "tuples of the engine's calls "
+             "that carried one key, walked once with no probe"),
             ("cols_selected", "counter", "columns the selected batches "
              "the operator ingested carried"),
             ("cols_gathered", "counter", "columns of selected batches "

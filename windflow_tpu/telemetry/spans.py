@@ -514,7 +514,8 @@ ENGINE_CLOCKS = {
 ENGINE_ARGMAX = {
     "argmax_ns": "of ingest_ns, an argmax engine's own walks (each key's "
                  "largest value of a call and the ids at it; the one-by-one "
-                 "fold), out of what would be tuple_walk_ns",
+                 "fold), out of what would be tuple_walk_ns; where the "
+                 "one-key walk took the call, the one-by-one fold alone",
     "max_raised": "times an argmax engine set or raised a pane's held "
                   "maximum (and with it its ids)",
     "tie_ids": "ids beyond the first at the maxima of the windows an "
@@ -533,20 +534,23 @@ ENGINE_ARGMAX = {
 # (docs/RUNTIME.md 5a "A key state in one place"); and what its flush
 # staged: the pane partials it copied into launch buffers and the windows
 # they serve (docs/RUNTIME.md 5c); the tuples of its calls whose walk was
-# split over the host lanes (docs/RUNTIME.md 5b); and the
-# :data:`ENGINE_CLOCKS`
+# split over the host lanes (docs/RUNTIME.md 5b); the
+# :data:`ENGINE_CLOCKS` and :data:`ENGINE_ARGMAX`; and the tuples of its
+# calls that the one-key walk took (docs/RUNTIME.md 5b)
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "keys_live_peak", "windows_fired", "folded_by_key",
                    "folded_singly", "late_accepted", "anchors_moved",
                    "inputs_ignored", "key_touches", "walked_ahead",
                    "rings_spilled", "panes_staged", "windows_staged",
-                   "walked_in_lanes", *ENGINE_CLOCKS, *ENGINE_ARGMAX)
+                   "walked_in_lanes", *ENGINE_CLOCKS, *ENGINE_ARGMAX,
+                   "walked_one_key")
 # those of them kept as a series (the last values noted in each 100 ms
 # bucket), so that what moved between two instants can be read
 SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
                    "anchors_moved", "inputs_ignored", "key_touches",
                    "walked_ahead", "walked_in_lanes", "panes_staged",
-                   "windows_staged", *ENGINE_CLOCKS, *ENGINE_ARGMAX)
+                   "windows_staged", *ENGINE_CLOCKS, *ENGINE_ARGMAX,
+                   "walked_one_key")
 
 
 # what a window operator counts of the selected batches it ingests
