@@ -13,9 +13,10 @@ Stages (one chip, the default):
   A  the fed headline: numpy columns into ``WinSeqTPU("sum")``, TB
      4096/2048, 64 keys, 64 M events after an 8 M warm-up of the same
      graph (native C++ staging).
-  B  the Python-staged XLA lanes the native engine bypasses: a JAX
-     window function ``fn(gwid, cols, mask)`` (``_custom_program``) and
-     a non-builtin FFAT lift+combine (``_ffat_program``), 8 M events.
+  B  the XLA lanes of a user's function: a JAX window function
+     ``fn(gwid, cols, mask)`` (``_custom_program``, staged by the native
+     engine's raw-tuple lane) and a non-builtin FFAT lift+combine
+     (``_ffat_program``, staged by the Python store), 8 M events.
   C  the Python staging lane (a ``value_of``) under the whole-partition
      device step: CB 1024/16, 8 keys, 8 M events, equal to
      ``device_step=False`` and to numpy, <= 2 launches per chunk.
@@ -265,7 +266,9 @@ def _sum_of_squares(gwid, cols, mask):
 
 def stage_b(n_events=8_000_000, n_keys=N_KEYS, win=WIN, slide=SLIDE,
             chunk=SOURCE_BATCH):
-    """The Python-staged XLA lanes: custom window function, custom FFAT."""
+    """The XLA lanes of a user's function: a custom window function (the
+    native engine's raw-tuple lane stages it), custom FFAT (the Python
+    store stages it)."""
     import windflow_tpu as wf
     from windflow_tpu.operators.tpu.farms_tpu import WinSeqFFATTPU
     from windflow_tpu.operators.tpu.win_seq_tpu import WinSeqTPU
@@ -279,7 +282,7 @@ def stage_b(n_events=8_000_000, n_keys=N_KEYS, win=WIN, slide=SLIDE,
                               batch_len=DEVICE_BATCH, emit_batches=True,
                               max_buffer_elems=MAX_BUFFER,
                               inflight_depth=INFLIGHT),
-            lambda s: (s * s).sum(axis=0)),
+            lambda s: (s * s).sum(axis=0), True),
         # device FlatFAT build + range query (_ffat_program); hypot is
         # an associative combine no builtin covers
         "ffat hypot": (
@@ -288,9 +291,9 @@ def stage_b(n_events=8_000_000, n_keys=N_KEYS, win=WIN, slide=SLIDE,
                                   batch_len=DEVICE_BATCH,
                                   max_buffer_elems=MAX_BUFFER,
                                   inflight_depth=INFLIGHT),
-            lambda s: np.sqrt((s * s).sum(axis=0))),
+            lambda s: np.sqrt((s * s).sum(axis=0)), False),
     }
-    for name, (make_op, fold) in lanes.items():
+    for name, (make_op, fold, native) in lanes.items():
         want = reference_windows(values, n_keys, win, slide, fold)
         walls = []
         for _ in range(2):
@@ -299,7 +302,7 @@ def stage_b(n_events=8_000_000, n_keys=N_KEYS, win=WIN, slide=SLIDE,
             check(f"B {name}", sink.table(want.shape), want, SUM_RTOL)
             walls.append(wall)
         report(f"B/{name}", g, n_events, want.size, walls[0], walls[1],
-               want_native=False)
+               want_native=native)
 
 
 def stage_c(n_events=8_000_000, n_keys=8, win=1024, slide=16, chunk=8192,

@@ -387,8 +387,11 @@ struct Desc {
 // ARGMAX: a pane keeps its largest value and the ids of the tuples at
 // it (Engine, "THE IDS AT A PANE'S MAXIMUM"); a window's rows are those
 // ids, each with the value
+// TUPLES: a pane keeps its tuples, not a partial (Engine, "A PANE THAT
+// KEEPS ITS TUPLES"); a window's rows are what a user's function makes
+// of its raw values on the device
 enum class Kind : int { SUM = 0, COUNT = 1, MAX = 2, MIN = 3, MEAN = 4,
-                        ARGMAX = 5 };
+                        ARGMAX = 5, TUPLES = 6 };
 
 struct Engine {
     i64 win, slide, delay;
@@ -685,7 +688,7 @@ struct Engine {
 
     // forget a key: its table entry goes by a backward shift (no
     // tombstone), its slot goes back to the pool
-    void evict(int32_t slot) {
+    __attribute__((always_inline)) inline void evict(int32_t slot) {
         KeyState& st = state(slot);
         const std::size_t mask = tab.size() - 1;
         std::size_t i = home_of(st.key);
@@ -1189,9 +1192,9 @@ struct Engine {
 
     // (ARG: an argmax engine's own instantiation of the same walk, so
     // that each of the other kinds' keeps its one caller, and with it
-    // the compiler's inlining into that caller)
+    // the compiler's inlining into that caller; TUP a tuples engine's)
     template <bool IDS, int EXT, bool SEL, bool AHEAD, bool LANES = false,
-              bool ARG = false, typename TV>
+              bool ARG = false, bool TUP = false, typename TV>
     void gather(const i64* bkeys, const i64* ids, const TV* vals, i64 n,
                 Sel s) {
         if (LANES) {
@@ -1465,6 +1468,10 @@ struct Engine {
             ingest_argmax<SEL>(bkeys, ids, tss, vals, n, s);
             return;
         }
+        if (kind == Kind::TUPLES) {
+            ingest_tuples<SEL>(bkeys, ids, tss, vals, n, s);
+            return;
+        }
         if (runs_ahead())
             ingest_walks<SEL, true>(bkeys, ids, tss, vals, n, s);
         else if (ingest_one_key<SEL>(bkeys, ids, tss, vals, n, s))
@@ -1495,7 +1502,7 @@ struct Engine {
     }
 
     template <bool SEL, bool AHEAD, bool LANES = false, bool ARG = false,
-              typename TV>
+              bool TUP = false, typename TV>
     void ingest_walks(const i64* bkeys, const i64* ids, const i64* tss,
                       const TV* vals, i64 n, Sel s) {
         call_begins();
@@ -1505,7 +1512,14 @@ struct Engine {
         d_state.clear();
         opened_now.clear();
         if ((i64)slot_of.size() < n) slot_of.resize(n);
-        if (ARG) {  // COUNT's walk, in an instantiation of its own
+        if constexpr (TUP) {  // COUNT's walk, in an instantiation of its own
+            if (renumber)
+                gather<false, 0, SEL, AHEAD, false, false, true>(bkeys, ids,
+                                                                 vals, n, s);
+            else
+                gather<true, 0, SEL, AHEAD, false, false, true>(bkeys, ids,
+                                                                vals, n, s);
+        } else if (ARG) {  // COUNT's walk, in an instantiation of its own
             if (renumber)
                 gather<false, 0, SEL, AHEAD, LANES, true>(bkeys, ids, vals, n,
                                                           s);
@@ -1574,7 +1588,8 @@ struct Engine {
         // anchors fold_singly() moved back
         piece_ends(key_walk_ns);
         if (LANES && n_single) slots_of_the_call();
-        if (n_single) fold_singly<SEL, ARG>(ids, tss, vals, n, s);
+        if constexpr (TUP) fresh_series();
+        if (n_single) fold_singly<SEL, ARG, TUP>(ids, tss, vals, n, s);
         if (ARG)
             piece_ends(argmax_ns);
         else
@@ -1590,7 +1605,7 @@ struct Engine {
 
     // The second walk, for the keys fold_key() left: each of their
     // tuples against the acceptance boundary and into its own pane.
-    template <bool SEL, bool ARG = false, typename TV>
+    template <bool SEL, bool ARG = false, bool TUP = false, typename TV>
     void fold_singly(const i64* ids, const i64* tss, const TV* vals, i64 n,
                      Sel s) {
         // hopping windows (win < slide): whether an id opens a window
@@ -1631,7 +1646,9 @@ struct Engine {
                 continue;
             }
             if (hopping && nn > st.opened_max) st.opened_max = nn;
-            if (ARG)  // a TB tuple's own id rides in the stamps' place
+            if constexpr (TUP)
+                keep_tuple(st, d_slot[d], p, (double)vals[cv ? j : r], id);
+            else if (ARG)  // a TB tuple's own id rides in the stamps' place
                 fold_arg(st, d_slot[d], p, (double)vals[cv ? j : r],
                          is_tb ? tss[ct ? j : r] : id);
             else
@@ -1751,7 +1768,8 @@ struct Engine {
     // windows, all later than the last one staged, whose extents must
     // stay resident) -- or, where it has nothing opened and nothing
     // queued, note the key as done.
-    inline void retire(KeyState& st, int32_t slot) {
+    __attribute__((always_inline)) inline void retire(KeyState& st,
+                                                      int32_t slot) {
         if (sparse && st.queued == 0 && st.next_fire > st.opened_max) {
             f_dead.push_back(slot);
             return;
@@ -1773,6 +1791,7 @@ struct Engine {
     // Returns the number staged.  A partial take of a long `ready`
     // list costs the windows it takes.
     i64 flush(i64 max_windows) {
+        if (kind == Kind::TUPLES) return flush_tuples(max_windows);
         st_n = 0;
         if (n_ready() == 0) return 0;
         const i64 t_in = now_ns(), evict_in = evict_ns;
@@ -1934,6 +1953,7 @@ struct Engine {
     void clear() {
         drop_pool();
         ties_of.clear();
+        series_of.clear();
         ready.clear();
         ready_head = 0;
         due.clear();
@@ -2035,6 +2055,7 @@ struct Engine {
                     put_vec(b, it->second);
                 }
             }
+            if (kind == Kind::TUPLES) put_tuples(b, s);
         }
         put(b, n_ready());
         for (std::size_t i = ready_head; i < ready.size(); ++i) {
@@ -2085,6 +2106,8 @@ struct Engine {
                 return false;
             if (cnt.size() != acc.size() || lid.size() != lts.size()
                 || acc.size() > (std::size_t)INT32_MAX)
+                return false;
+            if (kind == Kind::TUPLES && !get_tuples(p, end, slot, cnt))
                 return false;
             // CB engines index the id and stamp lanes in lockstep with
             // the accumulators on every ingest; a snapshot with short
@@ -2680,6 +2703,265 @@ struct Engine {
                                                   s);
         }
     }
+
+    // A PANE THAT KEEPS ITS TUPLES (Kind::TUPLES; behind every other
+    // member for the reason the clocks are, and on walks of its own: no
+    // other kind's instantiation has a line of it).  What a user's window
+    // function reads is every tuple of the window, and no partial gives
+    // that (a median, a percentile, an order-sensitive sum).  So the
+    // ring keeps what it keeps for every kind -- a count a pane, which
+    // fires, anchors, accepts, evicts and retires as it does for COUNT
+    // -- and beside it each key's SERIES: the values of its tuples from
+    // the ring's first pane on, in id order, those of equal id in the
+    // order they came (an insert where a straggler comes within the
+    // delay; an append for a tuple in order).  The counts are the index:
+    // pane p's tuples are the series' from the sum of the counts before
+    // p on, so a fired window's extent is a difference of two count
+    // prefixes.  A call walks its tuples as COUNT's does (gather, in an
+    // instantiation of its own) and keeps each one by one (fold_singly,
+    // keep_tuple).  flush_tuples() stages a key's span once, as a block
+    // copy of its series, with every taken window's extent into it: the
+    // order the Python store gives (operators/tpu/window_store.py).
+    // The panes retire() cuts take their tuples with them; a slot's next
+    // key starts with an empty series.
+    // Counted (wfn_engine_stats): tuples_staged, the tuples the flushes
+    // copied into launch buffers (the panes of the spans are
+    // panes_staged, as for every kind).
+    struct Series {
+        std::vector<i64> ids;
+        std::vector<double> vals;
+        std::size_t head = 0;     // the tuples before it are retired
+    };
+    std::vector<Series> series_of;    // by pool slot
+    i64 tuples_staged = 0;
+
+    // a series for every pool slot; the keys the last call opened start
+    // theirs empty (a slot's former key left its tuples there: evict()
+    // is every kind's and keeps no lane of this one)
+    __attribute__((noinline)) void fresh_series() {
+        if (series_of.size() < (std::size_t)n_slots)
+            series_of.resize(n_slots);
+        for (int32_t d : opened_now) drop_tuples(d_slot[d], INT64_MAX);
+    }
+
+    // one tuple into ring pane `p` of the key in `slot`
+    __attribute__((noinline)) void keep_tuple(KeyState& st, int32_t slot,
+                                              i64 p, double v, i64 id) {
+        ++ring(st).cnt[p];
+        Series& s = series_of[slot];
+        if (s.ids.size() == s.head || id >= s.ids.back()) {
+            s.ids.push_back(id);
+            s.vals.push_back(v);
+            return;
+        }
+        const auto at = std::upper_bound(s.ids.begin() + s.head,
+                                         s.ids.end(), id);
+        s.vals.insert(s.vals.begin() + (at - s.ids.begin()), v);
+        s.ids.insert(at, id);
+    }
+
+    // the tuples of the ring's first `panes` panes
+    inline i64 tuples_before(const KeyState& st, i64 panes) const {
+        const i64* c = ring(st).cnt;
+        i64 n = 0;
+        for (i64 p = 0, e = std::min<i64>(panes, st.room); p < e; ++p)
+            n += c[p];
+        return n;
+    }
+
+    // the first `n` tuples of the key's series retired (INT64_MAX: all)
+    __attribute__((noinline)) void drop_tuples(int32_t slot, i64 n) {
+        if ((std::size_t)slot >= series_of.size()) return;
+        Series& s = series_of[slot];
+        if (n >= (i64)(s.ids.size() - s.head)) {  // all: the storage stays
+            s.ids.clear();
+            s.vals.clear();
+            s.head = 0;
+            return;
+        }
+        s.head += n;
+        // the retired prefix goes once it is half the series
+        if (s.head < 64 || s.head * 2 < s.ids.size()) return;
+        s.ids.erase(s.ids.begin(), s.ids.begin() + s.head);
+        s.vals.erase(s.vals.begin(), s.vals.begin() + s.head);
+        s.head = 0;
+    }
+
+    // A TUPLES engine's call, on the walks of its own (ahead of itself,
+    // or plainly: no lanes, the keep is one by one)
+    template <bool SEL, typename TV>
+    __attribute__((noinline)) void ingest_tuples(
+            const i64* bkeys, const i64* ids, const i64* tss, const TV* vals,
+            i64 n, Sel s) {
+        if (runs_ahead())
+            ingest_walks<SEL, true, false, false, true>(bkeys, ids, tss, vals,
+                                                        n, s);
+        else
+            ingest_walks<SEL, false, false, false, true>(bkeys, ids, tss,
+                                                         vals, n, s);
+    }
+
+    // flush() for TUPLES: the spans as flush() takes them, each key's
+    // count prefix over its span, its tuples as one block copy of its
+    // series, and every window's extent the difference of two prefixes
+    __attribute__((noinline)) i64 flush_tuples(i64 max_windows) {
+        st_n = 0;
+        if (n_ready() == 0) return 0;
+        const i64 t_in = now_ns(), evict_in = evict_ns;
+        const i64 take = std::min<i64>(max_windows, n_ready());
+        const Desc* taken = ready.data() + ready_head;
+        ++flush_id;
+        f_touched.clear();
+        if ((i64)takes.size() < n_slots) takes.resize(n_slots);
+        // each key's span over its taken windows, as flush() takes it
+        i64 n_panes = 0;
+        for (i64 d = 0; d < take; ++d) {
+            const Desc& ds = taken[d];
+            Take& t = takes[ds.slot];
+            const i64 s = ds.lwid * slide, e = s + win;
+            if (t.stamp != flush_id) {
+                t.stamp = flush_id;
+                t.lo = s;
+                t.hi = e;
+                t.n = 0;
+                f_touched.push_back(ds.slot);
+                n_panes += ppw;
+            } else {
+                if (s < t.lo) { n_panes += (t.lo - s) / pane; t.lo = s; }
+                if (e > t.hi) { n_panes += (e - t.hi) / pane; t.hi = e; }
+            }
+            ++t.n;
+        }
+        grow(f_prefix, n_panes + (i64)f_touched.size());
+        // each key's count prefix over its span, and where its tuples go
+        i64 n_vals = 0, pf = 0;
+        for (int32_t slot : f_touched) {
+            const KeyState& st = state(slot);
+            Take& t = takes[slot];
+            const i64 np = (t.hi - t.lo) / pane;
+            const i64 r0 = pane_of(t.lo) - st.pane_base;
+            const i64 b0 = std::min(std::max<i64>(-r0, 0), np);
+            const i64 b1 = std::max(b0, std::min(np, st.room - r0));
+            const i64* c = ring(st).cnt + r0;
+            i64* px = f_prefix.data() + pf;
+            std::fill(px, px + b0 + 1, (i64)0);
+            i64 seen = 0;
+            for (i64 p = b0; p < b1; ++p) px[p + 1] = (seen += c[p]);
+            std::fill(px + b1 + 1, px + np + 1, seen);
+            t.off = n_vals;
+            t.pf = pf;
+            n_vals += seen;
+            pf += np + 1;
+        }
+        st_n = n_vals;
+        grow(st_vals, n_vals);
+        // the tuples, a key's span as one block of its series
+        for (int32_t slot : f_touched) {
+            KeyState& st = state(slot);
+            const Take& t = takes[slot];
+            const i64 n = f_prefix[t.pf + (t.hi - t.lo) / pane];
+            if (n > 0) {
+                const i64 r0 = pane_of(t.lo) - st.pane_base;
+                const Series& s = series_of[slot];
+                const i64 from = (i64)s.head
+                    + tuples_before(st, std::max<i64>(r0, 0));
+                std::memcpy(st_vals.data() + t.off, s.vals.data() + from,
+                            (std::size_t)n * sizeof(double));
+            }
+            st.staged_upto = (t.hi - win) / slide;
+            st.queued -= t.n;
+        }
+        st_starts.resize(take);
+        st_ends.resize(take);
+        st_keys.resize(take);
+        st_gwids.resize(take);
+        st_rts.resize(take);
+        for (i64 d = 0; d < take; ++d) {
+            const Desc& ds = taken[d];
+            const Take& t = takes[ds.slot];
+            const i64* pfx = f_prefix.data() + t.pf;
+            st_keys[d] = ds.key;
+            st_gwids[d] = ds.lwid;
+            const i64 s = ds.lwid * slide;
+            const i64 ps = (s - t.lo) / pane, pe = ps + ppw;
+            st_starts[d] = t.off + pfx[ps];
+            st_ends[d] = t.off + pfx[pe];
+            if (is_tb) {
+                st_rts[d] = s + win - 1;
+            } else if (pfx[pe] == pfx[ps]) {
+                st_rts[d] = 0;
+            } else {
+                // CB: the stamp of the window's last tuple (flush())
+                const KeyState& st = state(ds.slot);
+                const i64 q = std::lower_bound(pfx + ps, pfx + pe + 1,
+                                               pfx[pe]) - pfx;
+                const i64 r = pane_of(t.lo) + (q - 1) - st.pane_base;
+                st_rts[d] = (r >= 0 && r < st.room) ? ring(st).lts[r] : 0;
+            }
+        }
+        panes_staged += n_panes;
+        windows_staged += take;
+        tuples_staged += n_vals;
+        ready_head += take;
+        if (ready_head == ready.size()) {
+            ready.clear();
+            ready_head = 0;
+        } else if (ready_head * 2 > ready.size()) {
+            ready.erase(ready.begin(), ready.begin() + ready_head);
+            ready_head = 0;
+        }
+        // retire() cuts the panes no window still to be staged reads
+        // (its rule, here first for the tuples of those panes); a key it
+        // finds done is evicted, and its series is emptied when its
+        // slot next holds a key (fresh_series)
+        f_dead.clear();
+        for (int32_t slot : f_touched) {
+            KeyState& st = state(slot);
+            const i64 cut = pane_of((st.queued > 0 ? st.staged_upto + 1
+                                                   : st.next_fire) * slide)
+                - st.pane_base;
+            if (cut > 0) drop_tuples(slot, tuples_before(st, cut));
+            retire(st, slot);
+        }
+        if (!f_dead.empty()) {
+            const i64 t0 = now_ns();
+            for (int32_t slot : f_dead) evict(slot);
+            evict_ns += now_ns() - t0;
+        }
+        stage_ns += (now_ns() - t_in) - (evict_ns - evict_in);
+        return take;
+    }
+
+    // serialize()'s: the key's tuples, ids and values, in series order
+    void put_tuples(std::vector<unsigned char>& b, int32_t slot) const {
+        static const Series none;
+        const Series& s = (std::size_t)slot < series_of.size()
+            ? series_of[slot] : none;
+        const i64 n = (i64)(s.ids.size() - s.head);
+        put<i64>(b, n);
+        const auto* pi = reinterpret_cast<const unsigned char*>(
+            s.ids.data() + s.head);
+        b.insert(b.end(), pi, pi + n * sizeof(i64));
+        put<i64>(b, n);
+        const auto* pv = reinterpret_cast<const unsigned char*>(
+            s.vals.data() + s.head);
+        b.insert(b.end(), pv, pv + n * sizeof(double));
+    }
+
+    // deserialize()'s: as many tuples as the ring's counts, in id order
+    bool get_tuples(const unsigned char*& p, const unsigned char* end,
+                    int32_t slot, const std::vector<i64>& cnt) {
+        if (series_of.size() < (std::size_t)n_slots)
+            series_of.resize(n_slots);
+        Series& s = series_of[slot];
+        if (!get_vec(p, end, s.ids) || !get_vec(p, end, s.vals))
+            return false;
+        s.head = 0;
+        i64 held = 0;
+        for (i64 c : cnt) held += c;
+        return s.ids.size() == s.vals.size() && (i64)s.ids.size() == held
+            && std::is_sorted(s.ids.begin(), s.ids.end());
+    }
 };
 
 }  // namespace
@@ -2798,7 +3080,8 @@ i64 wfn_engine_ignored(void* ep) {
 // pane's held maximum was set or raised, and the ids beyond the first at
 // the staged windows' maxima (Engine, "THE IDS AT A PANE'S MAXIMUM").
 // out[29]: the tuples of the calls the one-key walk took (Engine, "THE
-// ONE-KEY WALK").
+// ONE-KEY WALK").  out[30]: the tuples a TUPLES engine's flushes copied
+// into launch buffers (Engine, "A PANE THAT KEEPS ITS TUPLES").
 void wfn_engine_stats(void* ep, i64* out) {
     const Engine& e = *static_cast<Engine*>(ep);
     out[0] = e.open_ns;
@@ -2830,6 +3113,7 @@ void wfn_engine_stats(void* ep, i64* out) {
     out[27] = e.max_raised;
     out[28] = e.tie_ids;
     out[29] = e.walked_one_key;
+    out[30] = e.tuples_staged;
 }
 
 // An ARGMAX engine's last flush, beside its pane maxima: each staged

@@ -113,8 +113,8 @@ def test_a_users_window_function_keeps_its_columns():
 
     handle = WindowComputeEngine(spread).compute(cols, starts, ends,
                                                  np.arange(111))
-    # gwids, starts, ends, valid and the user's two columns
-    assert handle.buffers_in == 6
+    # the user's two columns, the extents and the window ids: one buffer
+    assert handle.buffers_in == 1
     assert (handle.block() == recomputed("sum", cols, starts, ends)
             - recomputed("sum", {"value": cols["count"]}, starts,
                          ends)).all()

@@ -866,10 +866,12 @@ def test_the_first_nineteen_stats_keep_their_order_and_values(case, want):
     # behind them an argmax engine's (PR 40): the engine's out[26..29)
     assert NativeWindowEngine.STATS[26:29] == ARGMAX_STATS == tuple(
         spans.ENGINE_ARGMAX)
-    # and the one-key walk's tuples, out[29], the last
-    assert NativeWindowEngine.STATS[29:] == ("walked_one_key",)
-    assert spans.ENGINE_COUNTERS[-1] == spans.SERIES_COUNTERS[-1] \
-        == "walked_one_key"
+    # the one-key walk's tuples, out[29]; a tuples engine's staged
+    # tuples, out[30], the last
+    assert NativeWindowEngine.STATS[29:] == ("walked_one_key",
+                                             "tuples_staged")
+    assert spans.ENGINE_COUNTERS[-2:] == spans.SERIES_COUNTERS[-2:] \
+        == ("walked_one_key", "tuples_staged")
     eng = ks_moved_back()[0] if case == "moved_back" \
         else ks_q5("ooo", "count", 4096, n=60_000)[0]
     assert list(eng.stats())[3:19] == want

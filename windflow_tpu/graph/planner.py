@@ -44,6 +44,7 @@ import json
 import math
 import os
 import socket
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -320,6 +321,13 @@ def launch_profile(logic) -> tuple:
 # graph pass
 # ---------------------------------------------------------------------------
 
+def _what(kind) -> str:
+    """A combine as a placement's reason names it."""
+    if isinstance(kind, str):
+        return kind
+    return "user window function" if callable(kind) else "custom combine"
+
+
 def plan_graph(graph) -> List[dict]:
     """Resolve every window engine replica's placement.  Pinned lanes
     ('device'/'host') pass through; 'auto' consults the cost model.
@@ -384,14 +392,18 @@ def plan_graph(graph) -> List[dict]:
                 continue
             seen.add(id(logic))
             pinned = getattr(logic, "placement", "device")
+            kind = logic.engine.kind
+            device_only = not isinstance(kind, str) or kind not in HOST_KINDS
+            if pinned == "host" and device_only:
+                # custom / FFAT combines and argmax have no host program:
+                # never run somewhere other than where they were asked to
+                raise ValueError(
+                    f"{name}: {_what(kind)} has no host program and cannot "
+                    "be placed on the host lane")
             if pinned == "auto":
-                kind = logic.engine.kind
-                if not isinstance(kind, str) or kind not in HOST_KINDS:
-                    # custom / FFAT combines and argmax have no host
-                    # program
-                    what = kind if isinstance(kind, str) else "custom combine"
+                if device_only:
                     entry = {"placement": "device",
-                             "reason": f"{what}: device only"}
+                             "reason": f"{_what(kind)}: device only"}
                 else:
                     tuples, bytes_ = launch_profile(logic)
                     entry = decide_placement(PlacementInputs(
@@ -411,6 +423,15 @@ def plan_graph(graph) -> List[dict]:
             if holder.stats is None:
                 holder.stats = graph.stats.register(name, str(rid))
             entry["operator"] = name
+            if callable(kind):
+                # a user's window function: where it runs and which store
+                # stages its windows, said where the run is watched
+                entry["store"] = ("native" if logic._native is not None
+                                  else "python")
+                print(f"[windflow] {name}: the user's window function "
+                      f"runs on the device ({entry['reason']}), its windows "
+                      f"staged by the {entry['store']} store",
+                      file=sys.stderr)
             # the lease's Resident bit marks NON-demotable lanes: a
             # custom/FFAT combine has no host program, so the arbiter
             # must never pick it for a device->host demotion.

@@ -86,10 +86,15 @@ from ..telemetry.histogram import LogHistogram
 # 23 = those Counters gain walked_one_key (tuples of the engine's calls
 # whose tuples all carried one key, walked once with no probe,
 # docs/RUNTIME.md 5b).
+# 24 = those Counters gain tuples_staged (tuples a tuples engine's flush
+# copied into launch buffers, docs/RUNTIME.md 5d); Spans.Launches rows gain
+# Rows (the windows a launch computed), rows and summaries Fn_elems and
+# Tile_elems (what a user's window function read, and the tile it ran
+# over).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 23
+SCHEMA_VERSION = 24
 
 
 @dataclass

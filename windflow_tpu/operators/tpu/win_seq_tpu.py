@@ -448,11 +448,13 @@ class WinSeqTPULogic(NodeLogic):
         # that have always read the Python side, ``Inputs_ignored`` and
         # the audit's ``staging``); the C++ columnar engine covers the
         # hot standalone cases
-        # (native/window_engine.cpp): builtin kinds, identity window
-        # assignment, default value column, role SEQ -- or role PLQ,
-        # whose only difference under an identity config is that output
-        # ids are per-key dense counters (plq_renumbered_id degenerates
-        # to the emit counter), applied on the flushed batch
+        # (native/window_engine.cpp): builtin kinds and a user's window
+        # function (its ``tuples`` lane: a pane keeps its tuples,
+        # docs/RUNTIME.md 5d), identity window assignment, default value
+        # column, role SEQ -- or role PLQ, whose only difference under an
+        # identity config is that output ids are per-key dense counters
+        # (plq_renumbered_id degenerates to the emit counter), applied on
+        # the flushed batch
         self._py = PyWindowStore(
             win_len, slide_len, win_type, triggering_delay,
             renumber=renumbering, kind=win_kind, role=role,
@@ -462,9 +464,10 @@ class WinSeqTPULogic(NodeLogic):
         # a store's churn clock and counters as last read
         self._churn = list(PyWindowStore._NO_STATS)
         cfg = self.config
-        if (isinstance(win_kind, str)
-                and win_kind in ("sum", "count", "max", "min", "mean",
-                                 "argmax")
+        native_kind = "tuples" if callable(win_kind) else win_kind
+        if (isinstance(native_kind, str)
+                and native_kind in ("sum", "count", "max", "min", "mean",
+                                    "argmax", "tuples")
                 and role in (Role.SEQ, Role.PLQ)
                 and cfg.n_outer == 1 and cfg.n_inner == 1
                 and cfg.id_outer == 0 and cfg.id_inner == 0
@@ -481,7 +484,7 @@ class WinSeqTPULogic(NodeLogic):
                 self._native = NativeWindowEngine(
                     win_len, slide_len, win_type == WinType.TB,
                     triggering_delay, renumber=renumbering,
-                    kind=win_kind, dense=role != Role.SEQ)
+                    kind=native_kind, dense=role != Role.SEQ)
         # span layer (telemetry/spans.py): the names of this operator's
         # spans and its launch ring, re-resolved in svc_init once the
         # runtime has named the replica; ``_chunk_seq`` counts the
@@ -684,6 +687,9 @@ class WinSeqTPULogic(NodeLogic):
         rec.t_dispatched = _time.perf_counter()
         rec.buffers_in = handle.buffers_in
         rec.t_packed, rec.t_called = handle.t_packed, handle.t_called
+        # a user's window function's: what it read, the tile it ran over
+        rec.fn_elems = getattr(handle, "fn_elems", 0)
+        rec.tile_elems = getattr(handle, "tile_elems", 0)
         self.launched_batches += 1
         return handle, t_sub
 
@@ -699,6 +705,7 @@ class WinSeqTPULogic(NodeLogic):
             self.stats.bytes_to_device += nbytes_in
         rec = self._launches.open(self._chunk_seq, nbytes_in,
                                   _time.perf_counter())
+        rec.rows = len(starts)
         if self.async_dispatch:
             if self._dispatcher is None:
                 self._dispatcher = _AsyncDispatcher(self)

@@ -30,12 +30,14 @@ not a scalar-thread machine, so the design is different:
   **argmax** runs the same table over (value, index) pairs in int32:
   each window's maximum and the first element at it (ops/argmax.py has
   the host's half: the ids at it).
-* **custom window functions** gather padded [B, W_pad] tiles and vmap
-  the user's JAX function over the batch (the analogue of the
-  reference's arbitrary ``__host__ __device__`` functor path).
+* **custom window functions** gather padded [rows, W_pad] tiles a
+  block of rows at a time and vmap the user's JAX function over each
+  (the analogue of the reference's arbitrary ``__host__ __device__``
+  functor path): :func:`_custom_program`.
 
-A builtin launch hands the device ONE host buffer, inside the jitted
-call (:func:`pack_launch`, :func:`_packed`): the padded value columns and
+Every launch but the opt-in Pallas kernels' hands the device ONE host
+buffer, inside the jitted call (:func:`pack_launch`, :func:`_packed`;
+:func:`_custom_program` reads its own layout): the padded value columns and
 the extents in one pooled int32 array, the float32 sections written
 through a float32 view of the same memory and cast back bit for bit by
 the program (argmax's one section is int32, written as it is and
@@ -77,21 +79,26 @@ def next_pow2(n: int) -> int:
 # what a launch hands the device: one packed buffer
 # ---------------------------------------------------------------------------
 
-def packed_len(n_cols: int, t_pad: int, b_pad: int) -> int:
+def packed_len(n_cols: int, t_pad: int, b_pad: int,
+               n_rows: int = 2) -> int:
     """int32 words of one packed launch: ``n_cols`` value sections of
-    ``t_pad``, then starts and ends of ``b_pad`` each."""
-    return n_cols * t_pad + 2 * b_pad
+    ``t_pad``, then ``n_rows`` sections of ``b_pad`` (starts and ends,
+    and behind them what else a launch carries a row)."""
+    return n_cols * t_pad + n_rows * b_pad
 
 
 def pack_launch(buf: np.ndarray, cols, fill, starts, ends,
-                t_pad: int, b_pad: int) -> np.ndarray:
+                t_pad: int, b_pad: int, rows=()) -> np.ndarray:
     """Lay one launch out in ``buf`` (int32, :func:`packed_len` long):
-    ``[cols[0] | cols[1] ... | starts | ends]``.  The value sections are
-    written through a float32 view of the same memory, each element once:
+    ``[cols[0] | cols[1] ... | starts | ends | rows[0] ...]``.  The value
+    sections are written through a float32 view of the same memory, each
+    element once:
     ``f32[:T] = v`` is the one float64 -> float32 conversion, the tail
     takes ``fill`` (the combine's neutral), padding extents are (0, 0).
     :func:`_packed` is the device's side of the same layout.  An int32
-    column is written as it is (argmax, ops/argmax.int32_values)."""
+    column is written as it is (argmax, ops/argmax.int32_values); a
+    section of ``rows`` (a user's function's window ids) is cast to
+    int32 and padded with 0 as the extents are."""
     f32 = buf.view(np.float32)
     o = 0
     for v in cols:
@@ -101,7 +108,7 @@ def pack_launch(buf: np.ndarray, cols, fill, starts, ends,
         sec[o + T:o + t_pad] = fill
         o += t_pad
     B = len(starts)
-    for x in (starts, ends):
+    for x in (starts, ends, *rows):
         buf[o:o + B] = x
         buf[o + B:o + b_pad] = 0
         o += b_pad
@@ -356,19 +363,74 @@ def _argmax_program(t_pad: int, b_pad: int):
     return _packed(run, 1, t_pad, b_pad)
 
 
+# A user's window function runs over its tile a block of rows at a time,
+# a block being about this many elements (64 MiB of float32): a launch of
+# 65,875 windows of up to 4,096 readings is 1 GiB of tile, and twice that
+# again in gather indices and sort space, where a block of 4,096 rows
+# holds a few hundred MiB whatever the launch's length
+_FN_BLOCK_ELEMS = 1 << 24
+
+
+def fn_rows(n_rows: int, w_pad: int):
+    """``(b_pad, block)`` of a launch of ``n_rows`` windows of up to
+    ``w_pad`` elements handed to a user's function.  ``b_pad``, the rows
+    the launch's buffer has room for, is a power of eight (4,096 at
+    least): the launches of a steady stream, whose row counts wander
+    round one value (63,750 or 65,875 a chunk in SABER's SG2 shape, on
+    either side of 2**16), share one program.  The function runs over
+    the filled blocks of ``block`` rows alone (:func:`_custom_program`),
+    so the rows beyond the launch's cost their ids and extents (12 bytes
+    a row) and never a row of the tile."""
+    b_pad = 4096
+    while b_pad < n_rows:
+        b_pad <<= 3
+    return b_pad, min(b_pad, max(1, _FN_BLOCK_ELEMS // w_pad))
+
+
 @functools.lru_cache(maxsize=None)
-def _custom_program(fn: Callable, w_pad: int, col_names: tuple):
+def _custom_program(fn: Callable, w_pad: int, col_names: tuple, t_pad: int,
+                    b_pad: int, block: int):
+    """A user's ``fn(gwid, cols, mask)`` over every window of one packed
+    launch (:func:`pack_launch`: the columns, then starts, ends and the
+    window ids, then the row count in the buffer's last word): each block
+    of ``block`` rows takes its [block, w_pad] tile of every column as one
+    slice of ``w_pad`` a row, with the mask of the window's own elements,
+    and vmaps the function over it, as the user wrote it.  A row's slice
+    is a dynamic slice, not ``w_pad`` gathered elements: on a TPU v5e a
+    block of 4,096 rows of 4,096 took 5.5 ms so against 120 ms (PERF.md
+    section 6), and the column's ``t_pad`` leaves ``w_pad`` behind the
+    last window's start, so no slice is moved to fit (:func:`fn_rows`'
+    caller).  As many blocks run as the launch fills (a loop whose count
+    is read from the buffer); the rows behind read 0."""
     jax, jnp = _jax()
+    n_cols = len(col_names)
+    n_blocks = b_pad // block
 
     @jax.jit
-    def run(gwids, starts, ends, valid, *cols):
-        T = cols[0].shape[0]
-        idx = starts[:, None] + jnp.arange(w_pad)[None, :]
-        mask = idx < ends[:, None]
-        idx = jnp.clip(idx, 0, T - 1)
-        win_cols = {name: c[idx] for name, c in zip(col_names, cols)}
-        out = jax.vmap(fn)(gwids, win_cols, mask)
-        return jnp.where(valid, out, 0)
+    def run(buf):
+        cols = [jax.lax.bitcast_convert_type(
+            buf[j * t_pad:(j + 1) * t_pad], jnp.float32)
+            for j in range(n_cols)]
+        rows = buf[n_cols * t_pad:n_cols * t_pad + 3 * b_pad]
+        blocks = rows.reshape(3, n_blocks, block)
+        n_rows = buf[-1]
+        lane = jnp.arange(w_pad, dtype=jnp.int32)[None, :]
+
+        def rows_of(c, starts):
+            return jax.vmap(
+                lambda s: jax.lax.dynamic_slice(c, (s,), (w_pad,)))(starts)
+
+        def one(b, out):
+            starts, ends, gwids = (blocks[k, b] for k in range(3))
+            mask = lane < (ends - starts)[:, None]
+            win = {name: rows_of(c, starts)
+                   for name, c in zip(col_names, cols)}
+            got = jax.vmap(fn)(gwids, win, mask)
+            return out.at[b].set(got.astype(out.dtype))
+
+        out = jnp.zeros((n_blocks, block), jnp.float32)
+        out = jax.lax.fori_loop(0, (n_rows + block - 1) // block, one, out)
+        return out.reshape(b_pad)
 
     return run
 
@@ -463,23 +525,27 @@ class DeviceBatchHandle:
     65,536 quotients take some 20 us.
 
     ``buffers_in`` is how many host arrays the launch handed the device
-    (1 on the packed paths; ``spans.Launch.buffers_in``).  ``t_packed``
+    (1 on the packed paths; ``spans.Launch.buffers_in``); ``fn_elems``
+    and ``tile_elems`` what a user's window function read and the tile
+    it ran over (0 on a builtin's launch; ``spans.Launch``).  ``t_packed``
     and ``t_called`` are the engine's two ``perf_counter`` stamps of the
     launch (``spans.Launch``): the host had finished preparing what it
     hands the runtime; the jitted call had returned, before the copy
     back is started here."""
 
     __slots__ = ("_dev", "_n", "_pair", "buffers_in", "t_packed",
-                 "t_called")
+                 "t_called", "fn_elems", "tile_elems")
 
     def __init__(self, dev_array, n_valid: int, t_packed: float,
-                 pair: bool = False, buffers_in: int = 1):
+                 pair: bool = False, buffers_in: int = 1, fn_elems: int = 0,
+                 tile_elems: int = 0):
         self.t_called = time.perf_counter()
         self.t_packed = t_packed
         self._dev = dev_array
         self._n = n_valid
         self._pair = pair
         self.buffers_in = buffers_in
+        self.fn_elems, self.tile_elems = fn_elems, tile_elems
         dev_array.copy_to_host_async()
 
     def ready(self) -> bool:
@@ -547,8 +613,10 @@ class WindowComputeEngine:
         # handful, so steady-state launches never hit a mid-stream XLA
         # compile
         T_pad = next_pow2(max(T, 2048))
+        if callable(self.kind):
+            return self._compute_fn(cols, starts, ends, gwids)
         B_pad = next_pow2(max(B, 2048))
-        if callable(self.kind) or (self.is_ffat and _use_pallas(
+        if (self.is_ffat and _use_pallas(
                 "WINDFLOW_PALLAS_FFAT", T_pad, B_pad)) or (
                 self.kind == "sum" and _use_pallas(
                     "WINDFLOW_PALLAS_WINSUM", T_pad, B_pad)):
@@ -599,12 +667,35 @@ class WindowComputeEngine:
         return DeviceBatchHandle(prog(buf), B, t_packed,
                                  self.kind in ("mean", "mean_panes"))
 
+    def _compute_fn(self, cols, starts, ends, gwids) -> DeviceBatchHandle:
+        """A user's window function: its columns (the set is the user's,
+        in name order), the extents and the window ids in ONE packed
+        buffer, and the row count in its last word (:func:`fn_rows`,
+        :func:`_custom_program`).  A column's section holds ``w_pad``
+        more than its elements, so that every window's row is a slice
+        of the section."""
+        B = len(starts)
+        T = len(next(iter(cols.values())))
+        w_pad = next_pow2(int((ends - starts).max()) if B else 1)
+        T_pad = next_pow2(max(T + w_pad, 2048))
+        B_pad, block = fn_rows(B, w_pad)
+        names = tuple(sorted(cols))
+        buf = self._padded.take(packed_len(len(names), T_pad, B_pad, 3) + 1,
+                                np.int32)
+        pack_launch(buf[:-1], [cols[c] for c in names], 0, starts, ends,
+                    T_pad, B_pad, rows=(gwids,))
+        buf[-1] = B
+        t_packed = time.perf_counter()
+        prog = _custom_program(self.kind, w_pad, names, T_pad, B_pad, block)
+        return DeviceBatchHandle(
+            prog(buf), B, t_packed, fn_elems=int((ends - starts).sum()),
+            tile_elems=-(-B // block) * block * w_pad)
+
     def _compute_unpacked(self, cols, starts, ends, gwids, T_pad: int,
                           B_pad: int) -> DeviceBatchHandle:
-        """The launches that keep their own columns: a user's window
-        function (the set of columns is the user's) and the two opt-in
-        Pallas kernels.  Host arrays go into the jitted call as they
-        are here too."""
+        """The launches that keep their own columns: the two opt-in
+        Pallas kernels.  Host arrays go into the jitted call as they are
+        here too."""
         B = len(starts)
         T = len(next(iter(cols.values())))
         se = np.zeros((2, B_pad), dtype=np.int32)
@@ -631,16 +722,6 @@ class WindowComputeEngine:
             def prog(*operands):
                 return window_sums_device(*operands)[:, 0]
             operands = (pad_col(cols[self.value_col]), se[0], se[1])
-        else:
-            valid = np.zeros(B_pad, dtype=bool)
-            valid[:B] = True
-            gwids_p = np.zeros(B_pad, dtype=np.int64)
-            gwids_p[:B] = gwids
-            w_pad = next_pow2(int((ends - starts).max()) if B else 1)
-            names = tuple(sorted(cols))
-            prog = _custom_program(self.kind, w_pad, names)
-            operands = (gwids_p, se[0], se[1], valid,
-                        *[pad_col(cols[c]) for c in names])
         t_packed = time.perf_counter()
         return DeviceBatchHandle(prog(*operands), B, t_packed,
                                  buffers_in=len(operands))

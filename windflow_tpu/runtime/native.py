@@ -630,10 +630,12 @@ class NativeWindowEngine:
 
     __slots__ = ("lib", "ptr", "_stats", "_stats_p", "engine_kind", "tb",
                  "dense", "plq_counters", "key_intern", "key_extern",
-                 "_staged", "copy_out_ns", "argmax")
+                 "_staged", "copy_out_ns", "argmax", "tuples")
 
+    # ``tuples``: a pane keeps its tuples, for a user's window function
+    # (docs/RUNTIME.md 5d)
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4,
-             "argmax": 5}
+             "argmax": 5, "tuples": 6}
     # what ``stats()`` returns, in order: nanoseconds creating key
     # states, finding and queueing fired windows, evicting; keys opened,
     # evicted, live now, live at their peak; windows fired; tuples
@@ -657,14 +659,16 @@ class NativeWindowEngine:
     # Behind it what an ``argmax`` engine clocks and counts
     # (``spans.ENGINE_ARGMAX``, the engine's ``out[26..29)``), and the
     # tuples of the calls the one-key walk took (``out[29]``,
-    # docs/RUNTIME.md 5b)
+    # docs/RUNTIME.md 5b) and the tuples a ``tuples`` engine's flushes
+    # copied into launch buffers (``out[30]``, docs/RUNTIME.md 5d)
     STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
              "keys_evicted", "keys_live", "keys_live_peak",
              "windows_fired", "folded_by_key", "folded_singly",
              "late_accepted", "anchors_moved", "inputs_ignored",
              "stream_time", "key_touches", "walked_ahead", "rings_spilled",
              "panes_staged", "windows_staged", "walked_in_lanes",
-             *ENGINE_CLOCKS, *ENGINE_ARGMAX, "walked_one_key")
+             *ENGINE_CLOCKS, *ENGINE_ARGMAX, "walked_one_key",
+             "tuples_staged")
     _COPY_OUT = STATS.index("copy_out_ns")
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
@@ -689,6 +693,7 @@ class NativeWindowEngine:
         self.engine_kind = {"count": "sum", "mean": "mean_panes"}.get(kind)
         self.tb, self.dense = is_tb, dense
         self.argmax = kind == "argmax"
+        self.tuples = kind == "tuples"
         # where ``flush`` copies a launch's pane partials to: buffers
         # that come back once the launch has read them (by refcount, no
         # release call).  A fresh array as large as a wide launch's (62 MB
@@ -845,8 +850,9 @@ class NativeWindowEngine:
         ready-window count.  An ``argmax`` engine keeps ids, which the
         fused lane does not walk: its slice is materialized, as the
         Python store does (the graph's lowering fuses no filter or map
-        into an argmax window)."""
-        if self.argmax:
+        into an argmax window).  A ``tuples`` engine keeps its tuples'
+        ids too, and is fed so as well."""
+        if self.argmax or self.tuples:
             from ..core.tuples import SynthChunk
             b = SynthChunk(start, n, n_keys, vmod, vscale,
                            voff).materialize()
@@ -885,7 +891,9 @@ class NativeWindowEngine:
         as numpy copies, or None when nothing is ready: ``cols`` holds
         the pane partials under ``value`` (f64) and, for the 'mean'
         kind only, the per-pane tuple counts in the same layout under
-        ``count``; ``engine_kind`` is the helper engine they need."""
+        ``count``; a ``tuples`` engine's ``value`` holds the windows' own
+        values, each key's span once (docs/RUNTIME.md 5d); ``engine_kind``
+        is the helper engine they need."""
         import numpy as np
         LL = ctypes.c_longlong
         PD = ctypes.POINTER(ctypes.c_double)

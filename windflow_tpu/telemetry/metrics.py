@@ -189,6 +189,8 @@ def render_openmetrics(apps: dict) -> str:
              "whose walk was split over the host lanes"),
             ("walked_one_key", "counter", "tuples of the engine's calls "
              "that carried one key, walked once with no probe"),
+            ("tuples_staged", "counter", "tuples a tuples engine's flush "
+             "copied into launch buffers for a user's window function"),
             ("cols_selected", "counter", "columns the selected batches "
              "the operator ingested carried"),
             ("cols_gathered", "counter", "columns of selected batches "

@@ -362,12 +362,17 @@ class Launch:
     hands the runtime; the jitted call has returned.  They cut
     ``dispatch`` into ``pack``, ``call`` and ``handoff`` (what is left:
     the handle starting the copy back, the way out of the engine); a
-    lane that does not take them (mesh, host) leaves them None."""
+    lane that does not take them (mesh, host) leaves them None.
+    ``rows`` is the windows the launch computed; ``fn_elems`` and
+    ``tile_elems`` are a user's window function's: the elements the
+    launch's windows hand it (the sum of their extents' lengths) and
+    those of the tile it is run over (rows by width, the padding with
+    them); 0 on every other launch."""
 
     __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "buffers_in",
-                 "t_submitted", "t_picked", "t_packed", "t_called",
-                 "t_dispatched", "t_ready_seen", "t_on_host", "t_emitted",
-                 "collected")
+                 "rows", "fn_elems", "tile_elems", "t_submitted",
+                 "t_picked", "t_packed", "t_called", "t_dispatched",
+                 "t_ready_seen", "t_on_host", "t_emitted", "collected")
 
     def __init__(self, seq: int, chunk_seq: int, bytes_in: int,
                  t_submitted: float):
@@ -376,6 +381,7 @@ class Launch:
         self.bytes_in = bytes_in
         self.bytes_out = 0
         self.buffers_in = 0
+        self.rows = self.fn_elems = self.tile_elems = 0
         self.t_submitted = t_submitted
         self.t_picked = self.t_dispatched = self.t_ready_seen = None
         self.t_packed = self.t_called = None
@@ -389,6 +395,9 @@ class Launch:
                 "Bytes_in": int(self.bytes_in),
                 "Bytes_out": int(self.bytes_out),
                 "Buffers_in": int(self.buffers_in),
+                "Rows": int(self.rows),
+                "Fn_elems": int(self.fn_elems),
+                "Tile_elems": int(self.tile_elems),
                 "Picked_s": round(self.t_picked, 6),
                 "Collected": self.collected,
                 **{k: round(v, 4) for k, v in self.stages_ms().items()
@@ -464,7 +473,9 @@ class LaunchRing:
         done = self.finished(t0, t1)
         rows = [r.stages_ms() for r in done]
         out = {"Operator": self.operator, "Launches": len(rows),
-               "Buffers_in": sum(r.buffers_in for r in done)}
+               "Buffers_in": sum(r.buffers_in for r in done),
+               "Fn_elems": sum(r.fn_elems for r in done),
+               "Tile_elems": sum(r.tile_elems for r in done)}
         for s in STAGES + DISPATCH_PARTS:
             vals = [r[s] for r in rows if r[s] is not None]
             out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
@@ -535,22 +546,23 @@ ENGINE_ARGMAX = {
 # staged: the pane partials it copied into launch buffers and the windows
 # they serve (docs/RUNTIME.md 5c); the tuples of its calls whose walk was
 # split over the host lanes (docs/RUNTIME.md 5b); the
-# :data:`ENGINE_CLOCKS` and :data:`ENGINE_ARGMAX`; and the tuples of its
-# calls that the one-key walk took (docs/RUNTIME.md 5b)
+# :data:`ENGINE_CLOCKS` and :data:`ENGINE_ARGMAX`; the tuples of its
+# calls that the one-key walk took (docs/RUNTIME.md 5b); and the tuples a
+# ``tuples`` engine's flush copied into launch buffers (docs/RUNTIME.md 5d)
 ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
                    "keys_live_peak", "windows_fired", "folded_by_key",
                    "folded_singly", "late_accepted", "anchors_moved",
                    "inputs_ignored", "key_touches", "walked_ahead",
                    "rings_spilled", "panes_staged", "windows_staged",
                    "walked_in_lanes", *ENGINE_CLOCKS, *ENGINE_ARGMAX,
-                   "walked_one_key")
+                   "walked_one_key", "tuples_staged")
 # those of them kept as a series (the last values noted in each 100 ms
 # bucket), so that what moved between two instants can be read
 SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
                    "anchors_moved", "inputs_ignored", "key_touches",
                    "walked_ahead", "walked_in_lanes", "panes_staged",
                    "windows_staged", *ENGINE_CLOCKS, *ENGINE_ARGMAX,
-                   "walked_one_key")
+                   "walked_one_key", "tuples_staged")
 
 
 # what a window operator counts of the selected batches it ingests
