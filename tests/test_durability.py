@@ -386,6 +386,54 @@ def test_aligner_final_barrier_unblocks_alignment():
     assert coord.acks == [1, 2]
 
 
+def test_aligner_counts_parked_barrier_once():
+    """A next-epoch barrier parked behind an open alignment and then
+    replayed left its channel once: ``epoch_barriers_in`` counts it
+    once, so the graph-wide ledger (channel gets less barriers) keeps
+    every consumed tuple."""
+    from windflow_tpu.durability.barrier import EpochAligner
+    from windflow_tpu.runtime.queues import EpochBarrier
+
+    class _Coord:
+        def __init__(self):
+            self.acks = []
+
+        def add_snapshot(self, epoch, states):
+            pass
+
+        def sink_ack(self, epoch, name):
+            self.acks.append(epoch)
+
+    class _Node:
+        name = "sink.0"
+        outlets = ()
+        faults = None
+        epoch_barriers_in = 0
+        epoch_barriers_out = 0
+
+        class logic:
+            pass
+
+        def _emit(self, item):
+            pass
+
+    node = _Node()
+    coord = _Coord()
+    al = EpochAligner(node, coord, n_producers=2)
+    seen = []
+
+    def process(cid, item):
+        seen.append((cid, item))
+
+    assert al.offer(0, EpochBarrier(1), process)
+    assert al.offer(0, EpochBarrier(2), process)   # parked: 1 still open
+    assert al.offer(1, EpochBarrier(1), process)   # cut 1, replay 2
+    assert al.offer(1, EpochBarrier(2), process)   # cut 2
+    assert coord.acks == [1, 2]
+    assert seen == []
+    assert node.epoch_barriers_in == 4
+
+
 # ---------------------------------------------------------------------------
 # end-to-end: clean run, exactly-once sinks, metrics/doctor surfaces
 # ---------------------------------------------------------------------------

@@ -204,6 +204,13 @@ class EpochAligner:
         """Dispatch one channel item.  Returns True when the aligner
         consumed it (a barrier, or an item held back during alignment);
         False means the caller processes it normally."""
+        if type(item) is EpochBarrier:
+            # counted once, as it leaves the channel: a parked
+            # next-epoch barrier replays through _dispatch, not here
+            self.node.epoch_barriers_in += 1
+        return self._dispatch(cid, item, process)
+
+    def _dispatch(self, cid, item, process) -> bool:
         if type(item) is not EpochBarrier:
             if self.waiting is not None and (cid in self.arrived
                                              or cid in self.finished):
@@ -214,7 +221,6 @@ class EpochAligner:
         return True
 
     def _on_barrier(self, cid, b: EpochBarrier, process) -> None:
-        self.node.epoch_barriers_in += 1
         if b.final:
             self.finished.add(cid)
             if self.waiting is not None:
@@ -248,7 +254,7 @@ class EpochAligner:
         # a nested completion lands mid-drain: its re-held items must
         # run before the remaining (later-arrived) replay items.  Only
         # the outermost frame drains -- a parked next-epoch barrier
-        # re-enters offer(), may complete the next alignment, and that
+        # re-enters _dispatch(), may complete the next alignment, and that
         # nested call just prepends.
         self._replay.extendleft(reversed(held))
         if self._draining:
@@ -257,7 +263,7 @@ class EpochAligner:
         try:
             while self._replay:
                 hcid, hitem = self._replay.popleft()
-                if not self.offer(hcid, hitem, process):
+                if not self._dispatch(hcid, hitem, process):
                     process(hcid, hitem)
         finally:
             self._draining = False

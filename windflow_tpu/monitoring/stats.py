@@ -91,10 +91,13 @@ from ..telemetry.histogram import LogHistogram
 # Rows (the windows a launch computed), rows and summaries Fn_elems and
 # Tile_elems (what a user's window function read, and the tile it ran
 # over).
+# 25 = Spans.Launches rows and summaries gain Fn_rows_dma (the rows of a
+# user's window function whose tile the Pallas kernel gathered,
+# docs/RUNTIME.md 5d).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 24
+SCHEMA_VERSION = 25
 
 
 @dataclass

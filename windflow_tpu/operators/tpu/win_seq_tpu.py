@@ -687,9 +687,11 @@ class WinSeqTPULogic(NodeLogic):
         rec.t_dispatched = _time.perf_counter()
         rec.buffers_in = handle.buffers_in
         rec.t_packed, rec.t_called = handle.t_packed, handle.t_called
-        # a user's window function's: what it read, the tile it ran over
+        # a user's window function's: what it read, the tile it ran over,
+        # the rows whose tile the Pallas kernel gathered
         rec.fn_elems = getattr(handle, "fn_elems", 0)
         rec.tile_elems = getattr(handle, "tile_elems", 0)
+        rec.fn_rows_dma = getattr(handle, "fn_rows_dma", 0)
         self.launched_batches += 1
         return handle, t_sub
 

@@ -64,6 +64,7 @@ from typing import Any, Callable, Dict
 import numpy as np
 
 from .backend import jax_modules as _jax
+from .pallas import row_gather
 
 BUILTIN_KINDS = ("sum", "count", "mean", "max", "min", "argmax")
 
@@ -389,27 +390,34 @@ def fn_rows(n_rows: int, w_pad: int):
 
 @functools.lru_cache(maxsize=None)
 def _custom_program(fn: Callable, w_pad: int, col_names: tuple, t_pad: int,
-                    b_pad: int, block: int):
+                    b_pad: int, block: int, dma: bool):
     """A user's ``fn(gwid, cols, mask)`` over every window of one packed
     launch (:func:`pack_launch`: the columns, then starts, ends and the
     window ids, then the row count in the buffer's last word): each block
-    of ``block`` rows takes its [block, w_pad] tile of every column as one
-    slice of ``w_pad`` a row, with the mask of the window's own elements,
-    and vmaps the function over it, as the user wrote it.  A row's slice
-    is a dynamic slice, not ``w_pad`` gathered elements: on a TPU v5e a
-    block of 4,096 rows of 4,096 took 5.5 ms so against 120 ms (PERF.md
-    section 6), and the column's ``t_pad`` leaves ``w_pad`` behind the
-    last window's start, so no slice is moved to fit (:func:`fn_rows`'
-    caller).  As many blocks run as the launch fills (a loop whose count
-    is read from the buffer); the rows behind read 0."""
+    of ``block`` rows takes its [block, w_pad] tile of every column, row
+    ``i`` the ``w_pad`` elements from its start, with the mask of the
+    window's own elements, and vmaps the function over it, as the user
+    wrote it.  With ``dma`` (``pallas.row_gather.engages``: the TPU, a
+    row of whole lane-rows) the Pallas kernel copies each row by DMA
+    (``ops/pallas/row_gather.py``); else a row is a dynamic slice, which
+    XLA's TPU backend runs as a loop of one row an iteration.  On a TPU
+    v5e a block of 4,096 rows of 4,096 took 120 ms by element gather;
+    alone, with a real launch's starts, 9.1 ms of device time by the slice
+    and 0.38 ms by the kernel (PERF.md section 6).  The column's
+    ``t_pad`` leaves ``w_pad`` behind the last window's start, so no row
+    is moved to fit (:func:`fn_rows`' caller); the kernel's view of a
+    column reaches one lane-row further, into the next section of the
+    buffer.  As many blocks run as the launch fills (a
+    loop whose count is read from the buffer); the rows behind read 0."""
     jax, jnp = _jax()
     n_cols = len(col_names)
     n_blocks = b_pad // block
+    reach = row_gather.LANES if dma else 0
 
     @jax.jit
     def run(buf):
         cols = [jax.lax.bitcast_convert_type(
-            buf[j * t_pad:(j + 1) * t_pad], jnp.float32)
+            buf[j * t_pad:(j + 1) * t_pad + reach], jnp.float32)
             for j in range(n_cols)]
         rows = buf[n_cols * t_pad:n_cols * t_pad + 3 * b_pad]
         blocks = rows.reshape(3, n_blocks, block)
@@ -417,6 +425,8 @@ def _custom_program(fn: Callable, w_pad: int, col_names: tuple, t_pad: int,
         lane = jnp.arange(w_pad, dtype=jnp.int32)[None, :]
 
         def rows_of(c, starts):
+            if dma:
+                return row_gather.row_tiles(c, starts, w_pad)
             return jax.vmap(
                 lambda s: jax.lax.dynamic_slice(c, (s,), (w_pad,)))(starts)
 
@@ -527,18 +537,20 @@ class DeviceBatchHandle:
     ``buffers_in`` is how many host arrays the launch handed the device
     (1 on the packed paths; ``spans.Launch.buffers_in``); ``fn_elems``
     and ``tile_elems`` what a user's window function read and the tile
-    it ran over (0 on a builtin's launch; ``spans.Launch``).  ``t_packed``
+    it ran over, ``fn_rows_dma`` the rows whose tile the Pallas kernel
+    gathered (all of them or none; 0 on a builtin's launch;
+    ``spans.Launch``).  ``t_packed``
     and ``t_called`` are the engine's two ``perf_counter`` stamps of the
     launch (``spans.Launch``): the host had finished preparing what it
     hands the runtime; the jitted call had returned, before the copy
     back is started here."""
 
     __slots__ = ("_dev", "_n", "_pair", "buffers_in", "t_packed",
-                 "t_called", "fn_elems", "tile_elems")
+                 "t_called", "fn_elems", "tile_elems", "fn_rows_dma")
 
     def __init__(self, dev_array, n_valid: int, t_packed: float,
                  pair: bool = False, buffers_in: int = 1, fn_elems: int = 0,
-                 tile_elems: int = 0):
+                 tile_elems: int = 0, fn_rows_dma: int = 0):
         self.t_called = time.perf_counter()
         self.t_packed = t_packed
         self._dev = dev_array
@@ -546,6 +558,7 @@ class DeviceBatchHandle:
         self._pair = pair
         self.buffers_in = buffers_in
         self.fn_elems, self.tile_elems = fn_elems, tile_elems
+        self.fn_rows_dma = fn_rows_dma
         dev_array.copy_to_host_async()
 
     def ready(self) -> bool:
@@ -686,10 +699,13 @@ class WindowComputeEngine:
                     T_pad, B_pad, rows=(gwids,))
         buf[-1] = B
         t_packed = time.perf_counter()
-        prog = _custom_program(self.kind, w_pad, names, T_pad, B_pad, block)
+        dma = row_gather.engages(_jax()[0].default_backend(), w_pad)
+        prog = _custom_program(self.kind, w_pad, names, T_pad, B_pad, block,
+                               dma)
         return DeviceBatchHandle(
             prog(buf), B, t_packed, fn_elems=int((ends - starts).sum()),
-            tile_elems=-(-B // block) * block * w_pad)
+            tile_elems=-(-B // block) * block * w_pad,
+            fn_rows_dma=B if dma else 0)
 
     def _compute_unpacked(self, cols, starts, ends, gwids, T_pad: int,
                           B_pad: int) -> DeviceBatchHandle:
