@@ -94,10 +94,13 @@ from ..telemetry.histogram import LogHistogram
 # 25 = Spans.Launches rows and summaries gain Fn_rows_dma (the rows of a
 # user's window function whose tile the Pallas kernel gathered,
 # docs/RUNTIME.md 5d).
+# 26 = Spans.Launches rows and summaries gain Fn_rows_sorted (the rows of
+# a user's window function whose every sort the Pallas sorting network
+# made, docs/RUNTIME.md 5d).
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.
-SCHEMA_VERSION = 25
+SCHEMA_VERSION = 26
 
 
 @dataclass

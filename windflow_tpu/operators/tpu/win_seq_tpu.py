@@ -619,6 +619,8 @@ class WinSeqTPULogic(NodeLogic):
             tr.end()
         now = rec.t_on_host = _time.perf_counter()
         rec.bytes_out = results.nbytes
+        # counted on the device: the rows the sorting network sorted
+        rec.fn_rows_sorted = getattr(handle, "fn_rows_sorted", 0)
         launch_ms = (now - t_sub) * 1e3
         if self.stats is not None:  # single-writer: dispatcher thread
             self.stats.bytes_from_device += results.nbytes

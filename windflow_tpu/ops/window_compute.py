@@ -33,7 +33,8 @@ not a scalar-thread machine, so the design is different:
 * **custom window functions** gather padded [rows, W_pad] tiles a
   block of rows at a time and vmap the user's JAX function over each
   (the analogue of the reference's arbitrary ``__host__ __device__``
-  functor path): :func:`_custom_program`.
+  functor path): :func:`_custom_program`; a sort in the function is
+  lowered to a Pallas sorting network on the TPU (:func:`_fn_sorts`).
 
 Every launch but the opt-in Pallas kernels' hands the device ONE host
 buffer, inside the jitted call (:func:`pack_launch`, :func:`_packed`;
@@ -64,7 +65,7 @@ from typing import Any, Callable, Dict
 import numpy as np
 
 from .backend import jax_modules as _jax
-from .pallas import row_gather
+from .pallas import row_gather, window_sort
 
 BUILTIN_KINDS = ("sum", "count", "mean", "max", "min", "argmax")
 
@@ -389,8 +390,24 @@ def fn_rows(n_rows: int, w_pad: int):
 
 
 @functools.lru_cache(maxsize=None)
+def _fn_sorts(fn: Callable, w_pad: int, col_names: tuple, block: int,
+              backend: str):
+    """The user's ``fn`` vmapped over a block, with each sort that
+    ``pallas.window_sort`` takes lowered to its kernel
+    (``window_sort.lower_sorts``: traced once on the block's shapes), or
+    None where the function has no such sort and runs as it is."""
+    jax, jnp = _jax()
+    tile = jax.ShapeDtypeStruct((block, w_pad), jnp.float32)
+    return window_sort.lower_sorts(
+        jax.vmap(fn), (jax.ShapeDtypeStruct((block,), jnp.int32),
+                       {name: tile for name in col_names},
+                       jax.ShapeDtypeStruct((block, w_pad), jnp.bool_)),
+        backend)
+
+
+@functools.lru_cache(maxsize=None)
 def _custom_program(fn: Callable, w_pad: int, col_names: tuple, t_pad: int,
-                    b_pad: int, block: int, dma: bool):
+                    b_pad: int, block: int, dma: bool, backend: str):
     """A user's ``fn(gwid, cols, mask)`` over every window of one packed
     launch (:func:`pack_launch`: the columns, then starts, ends and the
     window ids, then the row count in the buffer's last word): each block
@@ -408,11 +425,19 @@ def _custom_program(fn: Callable, w_pad: int, col_names: tuple, t_pad: int,
     is moved to fit (:func:`fn_rows`' caller); the kernel's view of a
     column reaches one lane-row further, into the next section of the
     buffer.  As many blocks run as the launch fills (a
-    loop whose count is read from the buffer); the rows behind read 0."""
+    loop whose count is read from the buffer); the rows behind read 0.
+
+    Where the function sorts a window and ``pallas.window_sort`` takes
+    the sort on ``backend`` (:func:`_fn_sorts`), the sort is its kernel
+    and the result carries one more word: the rows of the launch whose
+    every sort the kernel made (int32, bit for bit), counted on the
+    device since a block that holds -0.0, a NaN or a subnormal takes
+    ``lax.sort``.  Else the program is the function's as traced."""
     jax, jnp = _jax()
     n_cols = len(col_names)
     n_blocks = b_pad // block
     reach = row_gather.LANES if dma else 0
+    sorts = _fn_sorts(fn, w_pad, col_names, block, backend)
 
     @jax.jit
     def run(buf):
@@ -430,17 +455,34 @@ def _custom_program(fn: Callable, w_pad: int, col_names: tuple, t_pad: int,
             return jax.vmap(
                 lambda s: jax.lax.dynamic_slice(c, (s,), (w_pad,)))(starts)
 
-        def one(b, out):
+        def block_of(b):
             starts, ends, gwids = (blocks[k, b] for k in range(3))
             mask = lane < (ends - starts)[:, None]
             win = {name: rows_of(c, starts)
                    for name, c in zip(col_names, cols)}
-            got = jax.vmap(fn)(gwids, win, mask)
-            return out.at[b].set(got.astype(out.dtype))
+            return gwids, win, mask
 
         out = jnp.zeros((n_blocks, block), jnp.float32)
-        out = jax.lax.fori_loop(0, (n_rows + block - 1) // block, one, out)
-        return out.reshape(b_pad)
+        n_filled = (n_rows + block - 1) // block
+        if sorts is None:
+            def one(b, out):
+                got = jax.vmap(fn)(*block_of(b))
+                return out.at[b].set(got.astype(out.dtype))
+
+            return jax.lax.fori_loop(0, n_filled, one, out).reshape(b_pad)
+
+        def one(b, carry):
+            out, n_sorted = carry
+            got, taken = sorts(*block_of(b))
+            filled = jnp.minimum(n_rows - b * block, block)
+            return (out.at[b].set(got.astype(out.dtype)),
+                    n_sorted + jnp.where(taken, filled, 0))
+
+        out, n_sorted = jax.lax.fori_loop(0, n_filled, one,
+                                          (out, jnp.int32(0)))
+        return jnp.concatenate([
+            out.reshape(b_pad),
+            jax.lax.bitcast_convert_type(n_sorted, jnp.float32)[None]])
 
     return run
 
@@ -539,26 +581,33 @@ class DeviceBatchHandle:
     and ``tile_elems`` what a user's window function read and the tile
     it ran over, ``fn_rows_dma`` the rows whose tile the Pallas kernel
     gathered (all of them or none; 0 on a builtin's launch;
-    ``spans.Launch``).  ``t_packed``
+    ``spans.Launch``).  ``fn_rows_sorted`` is the rows whose every sort
+    the Pallas sorting network made, read off the result's last word by
+    :meth:`block` where the program carries it (``counted``), else 0.
+    ``t_packed``
     and ``t_called`` are the engine's two ``perf_counter`` stamps of the
     launch (``spans.Launch``): the host had finished preparing what it
     hands the runtime; the jitted call had returned, before the copy
     back is started here."""
 
-    __slots__ = ("_dev", "_n", "_pair", "buffers_in", "t_packed",
-                 "t_called", "fn_elems", "tile_elems", "fn_rows_dma")
+    __slots__ = ("_dev", "_n", "_pair", "_counted", "buffers_in",
+                 "t_packed", "t_called", "fn_elems", "tile_elems",
+                 "fn_rows_dma", "fn_rows_sorted")
 
     def __init__(self, dev_array, n_valid: int, t_packed: float,
                  pair: bool = False, buffers_in: int = 1, fn_elems: int = 0,
-                 tile_elems: int = 0, fn_rows_dma: int = 0):
+                 tile_elems: int = 0, fn_rows_dma: int = 0,
+                 counted: bool = False):
         self.t_called = time.perf_counter()
         self.t_packed = t_packed
         self._dev = dev_array
         self._n = n_valid
         self._pair = pair
+        self._counted = counted
         self.buffers_in = buffers_in
         self.fn_elems, self.tile_elems = fn_elems, tile_elems
         self.fn_rows_dma = fn_rows_dma
+        self.fn_rows_sorted = 0
         dev_array.copy_to_host_async()
 
     def ready(self) -> bool:
@@ -572,6 +621,8 @@ class DeviceBatchHandle:
 
     def block(self) -> np.ndarray:
         out = np.asarray(self._dev)
+        if self._counted:
+            self.fn_rows_sorted = int(out[-1:].view(np.int32)[0])
         if not self._pair:
             return out[: self._n]
         if out.dtype == np.int32:   # argmax: [maxima; offsets]
@@ -699,13 +750,16 @@ class WindowComputeEngine:
                     T_pad, B_pad, rows=(gwids,))
         buf[-1] = B
         t_packed = time.perf_counter()
-        dma = row_gather.engages(_jax()[0].default_backend(), w_pad)
+        backend = _jax()[0].default_backend()
+        dma = row_gather.engages(backend, w_pad)
         prog = _custom_program(self.kind, w_pad, names, T_pad, B_pad, block,
-                               dma)
+                               dma, backend)
         return DeviceBatchHandle(
             prog(buf), B, t_packed, fn_elems=int((ends - starts).sum()),
             tile_elems=-(-B // block) * block * w_pad,
-            fn_rows_dma=B if dma else 0)
+            fn_rows_dma=B if dma else 0,
+            counted=_fn_sorts(self.kind, w_pad, names, block,
+                              backend) is not None)
 
     def _compute_unpacked(self, cols, starts, ends, gwids, T_pad: int,
                           B_pad: int) -> DeviceBatchHandle:

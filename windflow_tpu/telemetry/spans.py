@@ -369,10 +369,15 @@ class Launch:
     those of the tile it is run over (rows by width, the padding with
     them); ``fn_rows_dma`` the launch's rows whose tile the Pallas kernel
     gathered (docs/RUNTIME.md 5d: all of them on the TPU, none on the
-    slice path); 0 on every other launch."""
+    slice path); ``fn_rows_sorted`` those whose every sort the Pallas
+    sorting network made, counted on the device and set as the result
+    reaches the host (all of a sorting function's rows on the TPU at its
+    widths but a block that holds -0.0, a NaN or a subnormal; none
+    elsewhere); 0 on every other launch."""
 
     __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "buffers_in",
                  "rows", "fn_elems", "tile_elems", "fn_rows_dma",
+                 "fn_rows_sorted",
                  "t_submitted",
                  "t_picked", "t_packed", "t_called", "t_dispatched",
                  "t_ready_seen", "t_on_host", "t_emitted", "collected")
@@ -385,6 +390,7 @@ class Launch:
         self.bytes_out = 0
         self.buffers_in = 0
         self.rows = self.fn_elems = self.tile_elems = self.fn_rows_dma = 0
+        self.fn_rows_sorted = 0
         self.t_submitted = t_submitted
         self.t_picked = self.t_dispatched = self.t_ready_seen = None
         self.t_packed = self.t_called = None
@@ -402,6 +408,7 @@ class Launch:
                 "Fn_elems": int(self.fn_elems),
                 "Tile_elems": int(self.tile_elems),
                 "Fn_rows_dma": int(self.fn_rows_dma),
+                "Fn_rows_sorted": int(self.fn_rows_sorted),
                 "Picked_s": round(self.t_picked, 6),
                 "Collected": self.collected,
                 **{k: round(v, 4) for k, v in self.stages_ms().items()
@@ -480,7 +487,8 @@ class LaunchRing:
                "Buffers_in": sum(r.buffers_in for r in done),
                "Fn_elems": sum(r.fn_elems for r in done),
                "Tile_elems": sum(r.tile_elems for r in done),
-               "Fn_rows_dma": sum(r.fn_rows_dma for r in done)}
+               "Fn_rows_dma": sum(r.fn_rows_dma for r in done),
+               "Fn_rows_sorted": sum(r.fn_rows_sorted for r in done)}
         for s in STAGES + DISPATCH_PARTS:
             vals = [r[s] for r in rows if r[s] is not None]
             out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
