@@ -384,6 +384,7 @@ def test_engine_deserialize_corruption_fuzz():
 # trusted.
 
 import base64  # noqa: E402
+import functools  # noqa: E402
 import hashlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -391,7 +392,8 @@ import os  # noqa: E402
 from test_fold_by_key import drive as drive_lane  # noqa: E402
 from test_fold_by_key import stream as churn_stream  # noqa: E402
 
-from windflow_tpu.runtime.native import NativeWindowEngine  # noqa: E402
+from windflow_tpu.runtime.native import (NativeWindowEngine,  # noqa: E402
+                                         get_lib)
 
 KS_GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "golden", "key_state_pr32.json")
@@ -854,28 +856,136 @@ ARGMAX_STATS = ("argmax_ns", "max_raised", "tie_ids")
     ("q5_ooo", [3908, 3908, 0, 2931, 6998, 58278, 1722, 5903, 2, 0, 59999,
                 6819, 5155, 0, 13208, 6998])])
 def test_the_first_nineteen_stats_keep_their_order_and_values(case, want):
-    """``_account_churn`` slices ``STATS`` by position: the new clocks
-    come behind the nineteen, whose counts on a fixed stream are the
-    ones the engine of the commit before read (PR 37's parent, by
-    position 3..18)."""
+    """The nineteen stats the engine had before its clocks came, read by
+    name, hold on a fixed stream the counts the engine without the clocks
+    read (by position 3..18); the clocks and an argmax engine's own are
+    declared in the order the tests name them."""
     from windflow_tpu.telemetry import spans
-    assert NativeWindowEngine.STATS[:19] == PARENT_STATS
-    assert NativeWindowEngine.STATS[19] == "walked_in_lanes"
-    assert NativeWindowEngine.STATS[20:26] == CLOCKS == tuple(
-        spans.ENGINE_CLOCKS)
-    # behind them an argmax engine's (PR 40): the engine's out[26..29)
-    assert NativeWindowEngine.STATS[26:29] == ARGMAX_STATS == tuple(
-        spans.ENGINE_ARGMAX)
-    # the one-key walk's tuples, out[29]; a tuples engine's staged
-    # tuples, out[30], the last
-    assert NativeWindowEngine.STATS[29:] == ("walked_one_key",
-                                             "tuples_staged")
-    assert spans.ENGINE_COUNTERS[-2:] == spans.SERIES_COUNTERS[-2:] \
-        == ("walked_one_key", "tuples_staged")
+    assert tuple(spans.ENGINE_CLOCKS) == CLOCKS
+    assert tuple(spans.ENGINE_ARGMAX) == ARGMAX_STATS
     eng = ks_moved_back()[0] if case == "moved_back" \
         else ks_q5("ooo", "count", 4096, n=60_000)[0]
-    assert list(eng.stats())[3:19] == want
+    slots = [NativeWindowEngine.STATS.index(n) for n in PARENT_STATS[3:]]
+    stats = list(eng.stats())
+    assert [stats[i] for i in slots] == want
     assert [eng.snapshot()[n] for n in PARENT_STATS[3:]] == want
+
+
+def stat_feed(eng, keys, ids, vals, chunk, ts=None):
+    """``keys`` in calls of ``chunk``, what fired staged after each call
+    but the last."""
+    ts = ids if ts is None else ts
+    for lo in range(0, len(keys), chunk):
+        hi = lo + chunk
+        eng.ingest(keys[lo:hi], ids[lo:hi], ts[lo:hi], vals[lo:hi])
+        if hi < len(keys):
+            while eng.flush(1 << 30) is not None:
+                pass
+    return eng
+
+
+@functools.lru_cache(maxsize=1)
+def stat_runs():
+    """Five engines after known streams, each read once: a Q5-shaped
+    count with its generator's delay and the lanes forced on
+    (``count``), a ring that left its key state (``moved_back``), one
+    key with late tuples (``one_key_late``), an argmax over three keys
+    with ties, and a user's function's ``tuples`` engine.  Run ->
+    (engine, ``stats()`` as a list, ``snapshot()``)."""
+    keys, ts, vals = q5_law(60_000, KS_DELAY)
+    count = NativeWindowEngine(KS_WIN, KS_SLIDE, True, KS_DELAY,
+                               kind="count")
+    get_lib().wfn_engine_lanes_forced(count.ptr, 1)
+    stat_feed(count, keys, ts, vals, 4096)
+    keys, ts, vals = one_key_stream("late", [20_000])
+    late = stat_feed(NativeWindowEngine(256, 128, True, 0, kind="count"),
+                     keys, ts, vals, 1000)
+    rng = np.random.RandomState(5)
+    keys = rng.randint(0, 3, 20_000).astype(np.int64)
+    ids = np.arange(20_000, dtype=np.int64)
+    vals = rng.randint(1, 3, 20_000).astype(np.float64)
+    argmax = stat_feed(NativeWindowEngine(256, 256, True, 0, kind="argmax"),
+                       keys, ids, vals, 1000)
+    tuples = stat_feed(NativeWindowEngine(40, 1, True, 5, kind="tuples"),
+                       keys, ids, vals, 500, ts=ids // 17)
+    engines = {"count": count, "moved_back": ks_moved_back()[0],
+               "one_key_late": late, "argmax": argmax, "tuples": tuples}
+    return {run: (eng, list(eng.stats()), eng.snapshot())
+            for run, eng in engines.items()}
+
+
+def inside_ingest(s, _eng):
+    return 0 < s["tuple_walk_ns"] + s["key_walk_ns"] + s["argmax_ns"] \
+        <= s["ingest_ns"]
+
+
+# each engine stat: the run that moves it, and what it holds there (a
+# count, or a test of the snapshot and the engine for a clock)
+STAT_WANT = {
+    "keys_opened": ("count", 3908), "keys_evicted": ("count", 979),
+    "keys_live": ("count", 2929), "keys_live_peak": ("count", 2931),
+    "windows_fired": ("count", 4353), "folded_by_key": ("count", 58278),
+    "folded_singly": ("count", 1722), "late_accepted": ("count", 5903),
+    "anchors_moved": ("moved_back", 3),
+    "inputs_ignored": ("one_key_late", 186),
+    "stream_time": ("count", 59999), "key_touches": ("count", 6819),
+    "walked_ahead": ("count", 5155), "rings_spilled": ("moved_back", 2),
+    "panes_staged": ("count", 4258), "windows_staged": ("count", 2129),
+    "walked_in_lanes": ("count", 16384),
+    "ingest_ns": ("count", lambda s, _e: s["ingest_ns"]
+                  > s["tuple_walk_ns"] + s["key_walk_ns"]),
+    "tuple_walk_ns": ("count", inside_ingest),
+    "key_walk_ns": ("count", inside_ingest),
+    "stage_ns": ("count", lambda s, _e: s["stage_ns"] > 0),
+    "panes_shifted": ("count", 3450),
+    "copy_out_ns": ("count", lambda s, e: s["copy_out_ns"]
+                    == e.copy_out_ns > 0),
+    "argmax_ns": ("argmax", lambda s, _e: s["argmax_ns"] > 0
+                  and inside_ingest(s, _e)),
+    "max_raised": ("argmax", 359), "tie_ids": ("argmax", 9347),
+    "walked_one_key": ("one_key_late", 19_000),
+    "tuples_staged": ("tuples", 20976),
+}
+
+
+def _stat_ids():
+    from windflow_tpu.telemetry import spans
+    return [s.name for s in spans.ENGINE_STATS]
+
+
+@pytest.mark.parametrize("name", _stat_ids())
+def test_each_engine_stat_holds_its_count_in_its_slot_and_on_the_metrics_page(
+        name):
+    """One case an entry of ``spans.ENGINE_STATS``: the entry's slot of
+    ``NativeWindowEngine.STATS`` in the buffer ``stats()`` refills holds
+    what ``snapshot()`` reads by name, and that is what the engine
+    counted on a known stream (0 on the runs that do not move it); a
+    count is on ``/metrics`` under its kind and help text, with that
+    value, and the stream time is not."""
+    from windflow_tpu.telemetry import spans
+    from windflow_tpu.telemetry.metrics import render_openmetrics
+    stat, = (s for s in spans.ENGINE_STATS if s.name == name)
+    run, want = STAT_WANT[name]
+    runs = stat_runs()
+    eng, stats, snap = runs[run]
+    assert stats[NativeWindowEngine.STATS.index(name)] == snap[name]
+    assert want(snap, eng) if callable(want) else snap[name] == want
+    if name in ("argmax_ns", "max_raised", "tie_ids", "tuples_staged"):
+        assert runs["count"][2][name] == 0
+    counted = spans.Counters("op")
+    counted.note(0, [snap[n] for n in spans.ENGINE_COUNTERS])
+    report = {"PipeGraph_name": "g", "Spans": {"Operators": [
+        {"Operator": "op", "Counters": dict(counted.values)}]}}
+    text = render_openmetrics({"a": {"report": report}})
+    metric = f"windflow_engine_{name}"
+    if not stat.kind:
+        assert name not in counted.values and metric not in text
+        return
+    assert f"# TYPE {metric} {stat.kind}\n# HELP {metric} {stat.help} " \
+        "(span layer)\n" in text
+    total = "_total" if stat.kind == "counter" else ""
+    assert f'{metric}{total}{{operator="op",app="a",graph="g"}} ' \
+        f"{snap[name]}\n" in text
 
 
 def test_each_clock_grows_only_in_the_call_it_times():
@@ -919,12 +1029,11 @@ def test_each_clock_grows_only_in_the_call_it_times():
     assert s["windows_staged"] > 1000 and s["panes_shifted"] > 0
     assert 0 < s["tuple_walk_ns"] + s["key_walk_ns"] <= s["ingest_ns"]
     assert s["ingest_ns"] + s["open_ns"] + s["trigger_ns"] <= wall
-    # the wrapper's own clock: an attribute, mirrored into the slot of
-    # the buffer behind the twenty-five the engine refills
-    assert NativeWindowEngine.STATS.index("copy_out_ns") == 25
-    assert eng.stats()[25] == eng.stats()[25] == eng.copy_out_ns \
+    # the wrapper's own clock: an attribute, mirrored into its slot of
+    # the buffer the engine refills
+    slot = NativeWindowEngine.STATS.index("copy_out_ns")
+    assert eng.stats()[slot] == eng.stats()[slot] == eng.copy_out_ns \
         == s["copy_out_ns"] > 0
-    assert list(eng.stats())[20:25] == [s[n] for n in CLOCKS[:5]]
     assert eng.flush(1 << 30) is None       # nothing ready: no clock read
     assert eng.snapshot()["stage_ns"] == s["stage_ns"]
     synth = NativeWindowEngine(64, 32, True, 0, kind="sum")

@@ -651,3 +651,35 @@ def test_the_engines_clocks_are_counters_and_no_spans(native):
     assert 0 < v["stage_ns"] and 0 < v["copy_out_ns"]
     assert v["stage_ns"] + v["copy_out_ns"] < flush
     assert v["panes_shifted"] > 0
+
+
+@pytest.mark.parametrize("name,taken,text", spans.LAUNCH_COUNTS,
+                         ids=[c[0] for c in spans.LAUNCH_COUNTS])
+def test_each_launch_count_reaches_its_record_row_and_summary(name, taken,
+                                                              text):
+    """One case an entry of ``spans.LAUNCH_COUNTS``: a count the handle
+    carries is taken off it at its moment and no other, and is summed
+    in the summary; the one the operator sets at submit is in the row
+    alone.  Every one is in the launch's row under its name
+    capitalized."""
+    import jax.numpy as jnp
+    from windflow_tpu.ops.window_compute import DeviceBatchHandle
+    ring = spans.LaunchRing("op")
+    rec = ring.open(1, 64, 10.0)
+    rec.t_picked, rec.t_dispatched = 10.001, 10.002
+    rec.t_ready_seen = rec.t_on_host = 10.003
+    rec.t_emitted = 10.004
+    assert text and getattr(rec, name) == 0
+    if taken == "submit":
+        setattr(rec, name, 7)
+        assert name not in spans.HANDLE_COUNTS
+    else:
+        handle = DeviceBatchHandle(jnp.zeros(4), 4, 10.0, **{name: 7})
+        handle.block()
+        rec.take(handle, "host" if taken == "dispatch" else "dispatch")
+        assert getattr(rec, name) == 0
+        rec.take(handle, taken)
+    assert getattr(rec, name) == 7
+    assert rec.as_row()[name.capitalize()] == 7
+    assert ring.summary().get(name.capitalize()) == (
+        None if taken == "submit" else 7)

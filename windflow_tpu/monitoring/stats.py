@@ -57,46 +57,14 @@ from ..telemetry.histogram import LogHistogram
 # 13 = Spans.Launches rows gain Collected (how many launches were
 # collected ready / waited / forced / flushed) and their Slowest row
 # its own Collected.
-# 14 = Spans.Operators rows of a window operator on the native lane gain
-# Counters (keys_opened, keys_evicted, keys_live, keys_live_peak,
-# windows_fired) and their Phases the engine's open / trigger / evict.
-# 15 = those Counters gain folded_by_key and folded_singly (tuples the
-# engine folded with their key's others of the call in one combine, and
-# one by one).
-# 16 = those Counters gain late_accepted, anchors_moved and
-# inputs_ignored (tuples accepted behind the engine's stream time, times
-# a live key's anchor moved back, tuples dropped behind a fired window).
-# 17 = those Counters gain key_touches, walked_ahead and rings_spilled
-# (keys the engine's calls visited, those of them in a call that ran
-# ahead of itself, pane rings that left their key state).
-# 18 = those Counters gain panes_staged and windows_staged (pane partials
-# the engine's flush copied into launch buffers, windows they serve).
-# 19 = Spans.Launches rows gain Buffers_in (host arrays the row's
-# launches handed the device, summed: one a launch where the engine packs
-# it into one buffer, docs/RUNTIME.md 5c) and their Slowest row its own.
-# 20 = those Counters gain the inside of fold and flush
-# (spans.ENGINE_CLOCKS: ingest_ns, tuple_walk_ns, key_walk_ns, stage_ns,
-# panes_shifted, copy_out_ns) and Spans.Launches rows the three parts of
-# dispatch (pack, call, handoff; None where the lane takes no stamps for
-# them), their Slowest row its own.
-# 21 = those Counters gain walked_in_lanes (tuples of the engine's calls
-# whose walk was split over the host lanes, docs/RUNTIME.md 5b).
-# 22 = those Counters gain spans.ENGINE_ARGMAX (argmax_ns, max_raised,
-# tie_ids: an argmax engine's own walks and what they kept, 0 elsewhere).
-# 23 = those Counters gain walked_one_key (tuples of the engine's calls
-# whose tuples all carried one key, walked once with no probe,
-# docs/RUNTIME.md 5b).
-# 24 = those Counters gain tuples_staged (tuples a tuples engine's flush
-# copied into launch buffers, docs/RUNTIME.md 5d); Spans.Launches rows gain
-# Rows (the windows a launch computed), rows and summaries Fn_elems and
-# Tile_elems (what a user's window function read, and the tile it ran
-# over).
-# 25 = Spans.Launches rows and summaries gain Fn_rows_dma (the rows of a
-# user's window function whose tile the Pallas kernel gathered,
-# docs/RUNTIME.md 5d).
-# 26 = Spans.Launches rows and summaries gain Fn_rows_sorted (the rows of
-# a user's window function whose every sort the Pallas sorting network
-# made, docs/RUNTIME.md 5d).
+# 14-26 = Spans.Operators rows of a window operator on the native lane
+# gain Counters (telemetry/spans.ENGINE_STATS, SELECTION_COUNTERS) and
+# their Phases the engine's open / trigger / evict; Spans.Launches rows
+# and summaries gain the counts of spans.LAUNCH_COUNTS and the three
+# parts of dispatch (pack, call, handoff; None where the lane takes no
+# stamps for them).  From 26 on, a name added to a by-name block
+# (Counters, a Launches row or summary) leaves the version as it is:
+# readers look names up.
 # Readers (doctor CLI, dashboard /explain, tests) must tolerate MISSING
 # blocks rather than dispatch on this number: older dumps carry no
 # version field at all, and every block is optional by contract.

@@ -35,6 +35,7 @@ from ...core.basic import (OrderingMode, Pattern, Role, RoutingMode,
 from ...core.tuples import BasicRecord, SynthChunk, TupleBatch
 from ...ops.window_compute import WindowComputeEngine
 from ...runtime.emitters import StandardEmitter
+from ...runtime.native import NativeWindowEngine, native_available
 from ...runtime.node import EOSMarker, NodeLogic
 from ...telemetry import spans
 from ..base import Operator, StageSpec
@@ -475,8 +476,6 @@ class WinSeqTPULogic(NodeLogic):
             # no library (no toolchain, WINDFLOW_NATIVE=0): the Python
             # store is the supported fallback, and runtime/native.py
             # has said why on stderr
-            from ...runtime.native import (NativeWindowEngine,
-                                           native_available)
             if native_available():
                 # renumbering = per-key arrival-order ids, which the
                 # engine implements natively (ids implicit, always on
@@ -619,8 +618,7 @@ class WinSeqTPULogic(NodeLogic):
             tr.end()
         now = rec.t_on_host = _time.perf_counter()
         rec.bytes_out = results.nbytes
-        # counted on the device: the rows the sorting network sorted
-        rec.fn_rows_sorted = getattr(handle, "fn_rows_sorted", 0)
+        rec.take(handle, "host")
         launch_ms = (now - t_sub) * 1e3
         if self.stats is not None:  # single-writer: dispatcher thread
             self.stats.bytes_from_device += results.nbytes
@@ -687,13 +685,8 @@ class WinSeqTPULogic(NodeLogic):
         finally:
             tr.end()
         rec.t_dispatched = _time.perf_counter()
-        rec.buffers_in = handle.buffers_in
         rec.t_packed, rec.t_called = handle.t_packed, handle.t_called
-        # a user's window function's: what it read, the tile it ran over,
-        # the rows whose tile the Pallas kernel gathered
-        rec.fn_elems = getattr(handle, "fn_elems", 0)
-        rec.tile_elems = getattr(handle, "tile_elems", 0)
-        rec.fn_rows_dma = getattr(handle, "fn_rows_dma", 0)
+        rec.take(handle, "dispatch")
         self.launched_batches += 1
         return handle, t_sub
 
@@ -847,10 +840,8 @@ class WinSeqTPULogic(NodeLogic):
             if s[i] != last[i]:
                 tr.account(name, s[i] - last[i])
         last[:] = s
-        # spans.ENGINE_COUNTERS: STATS without the churn clocks and the
-        # stream time
         self._counters.note(tr.stack[-1][2] if tr.stack else tr.last_ns,
-                            last[3:13] + last[14:])
+                            [last[i] for i in NativeWindowEngine.COUNTED])
 
     def _launch_due(self) -> bool:
         return ((_time.perf_counter() - self._last_launch_t) * 1e3

@@ -64,6 +64,7 @@ from typing import Any, Callable, Dict
 
 import numpy as np
 
+from ..telemetry.spans import HANDLE_COUNTS
 from .backend import jax_modules as _jax
 from .pallas import row_gather, window_sort
 
@@ -576,38 +577,31 @@ class DeviceBatchHandle:
     of an exact sum and an exact count can be checked bit for bit.
     65,536 quotients take some 20 us.
 
-    ``buffers_in`` is how many host arrays the launch handed the device
-    (1 on the packed paths; ``spans.Launch.buffers_in``); ``fn_elems``
-    and ``tile_elems`` what a user's window function read and the tile
-    it ran over, ``fn_rows_dma`` the rows whose tile the Pallas kernel
-    gathered (all of them or none; 0 on a builtin's launch;
-    ``spans.Launch``).  ``fn_rows_sorted`` is the rows whose every sort
-    the Pallas sorting network made, read off the result's last word by
-    :meth:`block` where the program carries it (``counted``), else 0.
-    ``t_packed``
-    and ``t_called`` are the engine's two ``perf_counter`` stamps of the
-    launch (``spans.Launch``): the host had finished preparing what it
-    hands the runtime; the jitted call had returned, before the copy
-    back is started here."""
+    The handle carries the launch's ``spans.HANDLE_COUNTS``, given as
+    keywords (0 where not given; ``buffers_in`` 1, as on the packed
+    paths), but for ``fn_rows_sorted``: :meth:`block` reads that off the
+    result's last word where the program carries it (``counted``).
+    ``t_packed`` and ``t_called`` are the engine's two ``perf_counter``
+    stamps of the launch (``spans.Launch``): the host had finished
+    preparing what it hands the runtime; the jitted call had returned,
+    before the copy back is started here."""
 
-    __slots__ = ("_dev", "_n", "_pair", "_counted", "buffers_in",
-                 "t_packed", "t_called", "fn_elems", "tile_elems",
-                 "fn_rows_dma", "fn_rows_sorted")
+    __slots__ = ("_dev", "_n", "_pair", "_counted", "t_packed", "t_called",
+                 *HANDLE_COUNTS)
 
     def __init__(self, dev_array, n_valid: int, t_packed: float,
-                 pair: bool = False, buffers_in: int = 1, fn_elems: int = 0,
-                 tile_elems: int = 0, fn_rows_dma: int = 0,
-                 counted: bool = False):
+                 pair: bool = False, counted: bool = False, **counts):
         self.t_called = time.perf_counter()
         self.t_packed = t_packed
         self._dev = dev_array
         self._n = n_valid
         self._pair = pair
         self._counted = counted
-        self.buffers_in = buffers_in
-        self.fn_elems, self.tile_elems = fn_elems, tile_elems
-        self.fn_rows_dma = fn_rows_dma
-        self.fn_rows_sorted = 0
+        counts = {"buffers_in": 1, **counts}
+        for name in HANDLE_COUNTS:
+            setattr(self, name, counts.pop(name, 0))
+        if counts:
+            raise TypeError(f"not a launch count: {sorted(counts)}")
         dev_array.copy_to_host_async()
 
     def ready(self) -> bool:

@@ -22,7 +22,7 @@ import time
 from typing import Any, Optional
 
 from ..ops.argmax import ArgmaxStaged
-from ..telemetry.spans import ENGINE_ARGMAX, ENGINE_CLOCKS
+from ..telemetry.spans import ENGINE_COUNTERS, ENGINE_STATS
 from .queues import CHANNEL_TIMEOUT
 
 _lib = None
@@ -637,39 +637,16 @@ class NativeWindowEngine:
     KINDS = {"sum": 0, "count": 1, "max": 2, "min": 3, "mean": 4,
              "argmax": 5, "tuples": 6}
     # what ``stats()`` returns, in order: nanoseconds creating key
-    # states, finding and queueing fired windows, evicting; keys opened,
-    # evicted, live now, live at their peak; windows fired; tuples
-    # folded with their key's others of the call in one combine, tuples
-    # folded one by one; tuples accepted whose stamp lay behind the
-    # stream time when they came, times a live key's anchor moved back
-    # (docs/RUNTIME.md 5a), tuples ignored; stream time; the keys the
-    # calls' per-key visit met, those of them in a call that ran ahead
-    # of itself (the table had outgrown the caches), the rings that
-    # left their key state (docs/RUNTIME.md 5a "A key state in one
-    # place"); the pane partials ``flush`` copied into launch buffers
-    # and the windows they serve (docs/RUNTIME.md 5c); the tuples of the
-    # calls whose walk was split over the host lanes (docs/RUNTIME.md
-    # 5b).  Behind these twenty the inside of ``fold`` and ``flush``
-    # (``spans.ENGINE_CLOCKS``, in the order of ``wfn_engine_stats``'
-    # ``out[20..25)``; tests/test_native_runtime.py names each index):
-    # the engine fills all but the last, ``copy_out_ns``, which is this
-    # wrapper's own (``flush`` keeps it in an attribute and mirrors it
-    # into its slot, 25, which ``wfn_engine_stats`` leaves alone, so that
-    # ``stats()`` hands it over with the rest and costs what it cost).
-    # Behind it what an ``argmax`` engine clocks and counts
-    # (``spans.ENGINE_ARGMAX``, the engine's ``out[26..29)``), and the
-    # tuples of the calls the one-key walk took (``out[29]``,
-    # docs/RUNTIME.md 5b) and the tuples a ``tuples`` engine's flushes
-    # copied into launch buffers (``out[30]``, docs/RUNTIME.md 5d)
-    STATS = ("open_ns", "trigger_ns", "evict_ns", "keys_opened",
-             "keys_evicted", "keys_live", "keys_live_peak",
-             "windows_fired", "folded_by_key", "folded_singly",
-             "late_accepted", "anchors_moved", "inputs_ignored",
-             "stream_time", "key_touches", "walked_ahead", "rings_spilled",
-             "panes_staged", "windows_staged", "walked_in_lanes",
-             *ENGINE_CLOCKS, *ENGINE_ARGMAX, "walked_one_key",
-             "tuples_staged")
+    # states, finding and queueing fired windows, evicting; then
+    # ``spans.ENGINE_STATS``, in the slots ``wfn_engine_stats`` fills but
+    # ``copy_out_ns``: ``flush`` keeps that clock in an attribute and
+    # mirrors it into its slot, which the engine leaves alone, so that
+    # ``stats()`` hands it over with the rest
+    STATS = ("open_ns", "trigger_ns", "evict_ns",
+             *(s.name for s in ENGINE_STATS))
     _COPY_OUT = STATS.index("copy_out_ns")
+    # the slots of ``spans.ENGINE_COUNTERS``, in their order
+    COUNTED = tuple(map(STATS.index, ENGINE_COUNTERS))
 
     def __init__(self, win_len: int, slide_len: int, is_tb: bool,
                  delay: int = 0, renumber: bool = False, kind: str = "sum",

@@ -150,64 +150,26 @@ def render_openmetrics(apps: dict) -> str:
             for op, s in sorted(secs.items()):
                 out.append(f"{metric}_total"
                            f"{_labels(operator=op, **lab)} {s:.6f}")
-    # the native window engine's counters (spans.ENGINE_COUNTERS): the
-    # same operator's rows (one a thread) carry the same values
+    # the native window engine's counters (spans.ENGINE_STATS) and the
+    # selection's: the same operator's rows (one a thread) carry the same
+    # values
     engines = [(lab, {row.get("Operator", ""): row["Counters"]
                       for row in (rep.get("Spans") or {}).get("Operators", [])
                       if "Counters" in row})
                for rep, lab in per_graph()]
-    for name, kind, text in (
-            ("keys_opened", "counter", "key states the window engine "
-             "created"),
-            ("keys_evicted", "counter", "key states the window engine "
-             "evicted"),
-            ("keys_live", "gauge", "key states the window engine holds"),
-            ("keys_live_peak", "gauge", "most key states the window "
-             "engine held at once"),
-            ("windows_fired", "counter", "windows the engine fired"),
-            ("folded_by_key", "counter", "tuples the engine folded with "
-             "their key's others of the call in one combine"),
-            ("folded_singly", "counter", "tuples the engine folded one "
-             "by one"),
-            ("late_accepted", "counter", "tuples the engine accepted "
-             "whose stamp lay behind its stream time when they came"),
-            ("anchors_moved", "counter", "times a live key's anchor moved "
-             "back for a tuple earlier than the key's first to arrive"),
-            ("inputs_ignored", "counter", "tuples the engine dropped "
-             "behind a window that had fired"),
-            ("key_touches", "counter", "keys the engine's calls visited "
-             "(a key once a call)"),
-            ("walked_ahead", "counter", "of those, the visits of a call "
-             "that ran ahead of itself (a table beyond the caches)"),
-            ("rings_spilled", "counter", "pane rings that left their key "
-             "state for a block of their own"),
-            ("panes_staged", "counter", "pane partials the engine's flush "
-             "copied into launch buffers"),
-            ("windows_staged", "counter", "windows the engine's flush "
-             "staged for a launch"),
-            ("walked_in_lanes", "counter", "tuples of the engine's calls "
-             "whose walk was split over the host lanes"),
-            ("walked_one_key", "counter", "tuples of the engine's calls "
-             "that carried one key, walked once with no probe"),
-            ("tuples_staged", "counter", "tuples a tuples engine's flush "
-             "copied into launch buffers for a user's window function"),
-            ("cols_selected", "counter", "columns the selected batches "
-             "the operator ingested carried"),
-            ("cols_gathered", "counter", "columns of selected batches "
-             "that were gathered on read"),
-            ("rows_by_selection", "counter", "rows the window store read "
-             "through a batch's selection"),
-            *((name, "counter", text)
-              for name, text in spans.ENGINE_CLOCKS.items()),
-            *((name, "counter", text)
-              for name, text in spans.ENGINE_ARGMAX.items())):
-        metric = f"windflow_engine_{name}"
-        family(metric, kind, text + " (span layer)")
+    # the clocks and the argmax engine's last, where they came in
+    stats = spans.ENGINE_STATS
+    for stat in (*(s for s in stats if s.kind and not s.group),
+                 *(spans.Stat(*kv) for kv in spans.SELECTION_COUNTERS.items()),
+                 *(s for s in stats if s.group)):
+        metric = f"windflow_engine_{stat.name}"
+        family(metric, stat.kind, stat.help + " (span layer)")
         for lab, seen in engines:
             for op, vals in sorted(seen.items()):
-                out.append(f"{metric}{'_total' if kind == 'counter' else ''}"
+                out.append(f"{metric}"
+                           f"{'_total' if stat.kind == 'counter' else ''}"
                            f"{_labels(operator=op, **lab)} "
-                           f"{int(vals.get(name, 0))}")
+                           f"{int(vals.get(stat.name, 0))}")
     family("windflow_queue_depth", "gauge",
            "tuples parked in the operator's inbound channels")
     for _op, reps, lab in per_op():

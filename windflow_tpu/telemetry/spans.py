@@ -29,15 +29,16 @@ never per event or per tuple.  They are always on; there is no switch.
   began.
 * :class:`LaunchRing` keeps one :class:`Launch` record per device
   launch: eight host stamps whose differences are the stages of a
-  launch's round trip and the parts of its ``dispatch``.
+  launch's round trip and the parts of its ``dispatch``, and what the
+  launch counted (:data:`LAUNCH_COUNTS`).
 * Work that native code timed on its own steady clock (the window
   engine's ``open``, ``trigger`` and ``evict``) is entered as a child of
   the span open round the call (:meth:`Track.account`), with no clock
-  read here; what the engine counts (:data:`ENGINE_COUNTERS`) is kept
-  per operator in :class:`Counters`, and with it what the engine's
-  other clocks read of the inside of ``fold`` and ``flush``
-  (:data:`ENGINE_CLOCKS`: kept as counters, never entered as spans, so
-  the two spans' self time means what it meant).
+  read here; what the engine counts (:data:`ENGINE_STATS`, the one
+  declaration of each count) is kept per operator in :class:`Counters`,
+  and with it what the engine's other clocks read of the inside of
+  ``fold`` and ``flush`` (:data:`ENGINE_CLOCKS`: kept as counters, never
+  entered as spans, so the two spans' self time means what it meant).
 """
 from __future__ import annotations
 
@@ -45,7 +46,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 _now = time.perf_counter_ns
 
@@ -347,38 +348,56 @@ def bind(graph: Optional["SpanGraph"]) -> Track:
 
 # -- launches --------------------------------------------------------------
 
+# What a launch counts, declared here once: :class:`Launch`'s attributes,
+# its row in the stats JSON (the name capitalized), the sums of
+# :meth:`LaunchRing.summary` and the attributes of the compute engine's
+# handle (ops/window_compute.DeviceBatchHandle) all read this.  Each is
+# (name, when the operator takes it, help): "submit" from the batch it
+# stages, "dispatch" off the handle once the engine has returned it,
+# "host" off the handle once the result is on the host.  A handle that
+# has no such attribute counts 0.  The summary sums what the handles
+# brought back.  A new count is one entry here and the handle's value.
+LAUNCH_COUNTS = (
+    ("buffers_in", "dispatch", "host arrays the launch handed the device "
+     "(1 where the engine packs the launch, docs/RUNTIME.md 5c; 0 on the "
+     "host lane)"),
+    ("rows", "submit", "the windows the launch computed"),
+    ("fn_elems", "dispatch", "the elements a user's window function read "
+     "(the sum of the windows' extents' lengths)"),
+    ("tile_elems", "dispatch", "the elements of the tile a user's window "
+     "function ran over (rows by width, the padding with them)"),
+    ("fn_rows_dma", "dispatch", "the rows whose tile the Pallas kernel "
+     "gathered (docs/RUNTIME.md 5d: all of them on the TPU, none on the "
+     "slice path)"),
+    ("fn_rows_sorted", "host", "the rows whose every sort the Pallas "
+     "sorting network made, counted on the device (all of a sorting "
+     "function's rows on the TPU at its widths but a block that holds "
+     "-0.0, a NaN or a subnormal; none elsewhere)"),
+)
+# what the compute engine's handle carries, and when each is taken
+HANDLE_COUNTS = tuple(n for n, taken, _ in LAUNCH_COUNTS if taken != "submit")
+_TAKEN = {when: tuple(n for n, taken, _ in LAUNCH_COUNTS if taken == when)
+          for when in ("dispatch", "host")}
+
+
 class Launch:
-    """One device launch: host stamps on ``time.perf_counter`` (seconds).
+    """One device launch: host stamps on ``time.perf_counter`` (seconds),
+    and the :data:`LAUNCH_COUNTS` (0 until taken).
     ``t_submitted`` is taken on the ingest thread before the hand-off,
     the others by whoever dispatches (the dispatcher thread, or the
     ingest thread on the inline lane).  ``queue_wait``, ``dispatch``,
     ``ready_wait``, ``block`` and ``emit`` are their differences.
     ``collected`` (one of :data:`COLLECTED`) says how the dispatcher
-    came to take the result, ``buffers_in`` how many host arrays the
-    launch handed the device (1 where the engine packs the launch,
-    docs/RUNTIME.md 5c; 0 on the host lane).  ``t_packed`` and
+    came to take the result.  ``t_packed`` and
     ``t_called`` are the compute engine's, taken inside ``dispatch`` and
     brought back by the handle: the host has finished preparing what it
     hands the runtime; the jitted call has returned.  They cut
     ``dispatch`` into ``pack``, ``call`` and ``handoff`` (what is left:
     the handle starting the copy back, the way out of the engine); a
-    lane that does not take them (mesh, host) leaves them None.
-    ``rows`` is the windows the launch computed; ``fn_elems`` and
-    ``tile_elems`` are a user's window function's: the elements the
-    launch's windows hand it (the sum of their extents' lengths) and
-    those of the tile it is run over (rows by width, the padding with
-    them); ``fn_rows_dma`` the launch's rows whose tile the Pallas kernel
-    gathered (docs/RUNTIME.md 5d: all of them on the TPU, none on the
-    slice path); ``fn_rows_sorted`` those whose every sort the Pallas
-    sorting network made, counted on the device and set as the result
-    reaches the host (all of a sorting function's rows on the TPU at its
-    widths but a block that holds -0.0, a NaN or a subnormal; none
-    elsewhere); 0 on every other launch."""
+    lane that does not take them (mesh, host) leaves them None."""
 
-    __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out", "buffers_in",
-                 "rows", "fn_elems", "tile_elems", "fn_rows_dma",
-                 "fn_rows_sorted",
-                 "t_submitted",
+    __slots__ = ("seq", "chunk_seq", "bytes_in", "bytes_out",
+                 *(n for n, _, _ in LAUNCH_COUNTS), "t_submitted",
                  "t_picked", "t_packed", "t_called", "t_dispatched",
                  "t_ready_seen", "t_on_host", "t_emitted", "collected")
 
@@ -388,14 +407,19 @@ class Launch:
         self.chunk_seq = chunk_seq
         self.bytes_in = bytes_in
         self.bytes_out = 0
-        self.buffers_in = 0
-        self.rows = self.fn_elems = self.tile_elems = self.fn_rows_dma = 0
-        self.fn_rows_sorted = 0
+        for name, _, _ in LAUNCH_COUNTS:
+            setattr(self, name, 0)
         self.t_submitted = t_submitted
         self.t_picked = self.t_dispatched = self.t_ready_seen = None
         self.t_packed = self.t_called = None
         self.t_on_host = self.t_emitted = None
         self.collected = None
+
+    def take(self, handle, when: str) -> None:
+        """The counts taken at ``when`` ("dispatch" or "host") off the
+        compute engine's handle."""
+        for name in _TAKEN[when]:
+            setattr(self, name, getattr(handle, name, 0))
 
     def as_row(self) -> dict:
         """An emitted launch for the stats JSON: which launch, the chunk
@@ -403,12 +427,8 @@ class Launch:
         return {"Seq": self.seq, "Chunk_seq": self.chunk_seq,
                 "Bytes_in": int(self.bytes_in),
                 "Bytes_out": int(self.bytes_out),
-                "Buffers_in": int(self.buffers_in),
-                "Rows": int(self.rows),
-                "Fn_elems": int(self.fn_elems),
-                "Tile_elems": int(self.tile_elems),
-                "Fn_rows_dma": int(self.fn_rows_dma),
-                "Fn_rows_sorted": int(self.fn_rows_sorted),
+                **{n.capitalize(): int(getattr(self, n))
+                   for n, _, _ in LAUNCH_COUNTS},
                 "Picked_s": round(self.t_picked, 6),
                 "Collected": self.collected,
                 **{k: round(v, 4) for k, v in self.stages_ms().items()
@@ -477,18 +497,15 @@ class LaunchRing:
                 t1: Optional[float] = None) -> dict:
         """Mean and longest of each stage and each part of ``dispatch``
         over :meth:`finished` (a part over the launches that took its
-        stamps), the host arrays those launches handed the device in all,
-        how many of them were collected in each way, and the launch whose
+        stamps), the :data:`HANDLE_COUNTS` of those launches summed, how
+        many of them were collected in each way, and the launch whose
         round trip (picked up to emitted) was the longest, whole: the one
         to look for in a trace or a log."""
         done = self.finished(t0, t1)
         rows = [r.stages_ms() for r in done]
         out = {"Operator": self.operator, "Launches": len(rows),
-               "Buffers_in": sum(r.buffers_in for r in done),
-               "Fn_elems": sum(r.fn_elems for r in done),
-               "Tile_elems": sum(r.tile_elems for r in done),
-               "Fn_rows_dma": sum(r.fn_rows_dma for r in done),
-               "Fn_rows_sorted": sum(r.fn_rows_sorted for r in done)}
+               **{n.capitalize(): sum(getattr(r, n) for r in done)
+                  for n in HANDLE_COUNTS}}
         for s in STAGES + DISPATCH_PARTS:
             vals = [r[s] for r in rows if r[s] is not None]
             out[s] = {"mean_ms": round(sum(vals) / len(vals), 4),
@@ -503,83 +520,115 @@ class LaunchRing:
 
 # -- counters --------------------------------------------------------------
 
-# The inside of ``fold`` and ``flush``, declared here once: the tail of
-# ``NativeWindowEngine.STATS``, of :data:`ENGINE_COUNTERS` and of the
-# series, the stats JSON's row and ``/metrics`` (by name and help text)
-# all read this.  Nanoseconds on the engine's steady clock, a call at a
-# time, unless said otherwise; kept as counters and never entered as
-# child spans, so ``fold`` and ``flush`` keep the self time they had.
-# THE ORDER IS THE ENGINE'S: ``wfn_engine_stats`` fills ``out[20..25)``
-# with the first five in this order (native/window_engine.cpp) and the
-# wrapper keeps the sixth behind them; tests/test_native_runtime.py
-# names each index, so a reordering here fails there and mislabels
-# nothing.  Each has a reader under benchmarks/metrics/ (PERF.md
-# section 3) and a family on ``/metrics``.
-ENGINE_CLOCKS = {
-    "ingest_ns": "nanoseconds inside the engine's batch calls, less "
-                 "their open and trigger",
-    "tuple_walk_ns": "of ingest_ns, the walks that cost by the tuple "
-                     "(gather, note_late, fold_singly)",
-    "key_walk_ns": "of ingest_ns, the walks that cost by the key "
-                   "(prepare of the keys a call did not open, settle)",
-    "stage_ns": "nanoseconds inside the engine's flush, less its evict "
-                "(staging the spans, retiring the rings, the rows)",
-    "panes_shifted": "ring elements the engine's retire() moved down "
-                     "after a staging",
-    "copy_out_ns": "nanoseconds copying a flush's columns out of the "
-                   "engine (perf_counter_ns round the copies, "
-                   "NativeWindowEngine.flush)",
-}
-# what an ``argmax`` window engine clocks and counts of its own
-# (native/window_engine.cpp "THE IDS AT A PANE'S MAXIMUM";
-# ``NativeWindowEngine.STATS`` behind ``copy_out_ns``): 0 on every other
-# kind.  Each has a reader under benchmarks/metrics/ and a family on
-# ``/metrics``.
-ENGINE_ARGMAX = {
-    "argmax_ns": "of ingest_ns, an argmax engine's own walks (each key's "
-                 "largest value of a call and the ids at it; the one-by-one "
-                 "fold), out of what would be tuple_walk_ns; where the "
-                 "one-key walk took the call, the one-by-one fold alone",
-    "max_raised": "times an argmax engine set or raised a pane's held "
-                  "maximum (and with it its ids)",
-    "tie_ids": "ids beyond the first at the maxima of the windows an "
-               "argmax engine staged",
-}
-# what the native window engine counts (runtime/native.py
-# ``NativeWindowEngine.STATS[3:13]`` and ``[14:]``): key states it
-# created and evicted since it was made, those live now and at their
-# peak, windows it fired, tuples it folded with their key's others of
-# the call in one combine and tuples it folded one by one, what disorder
-# it met: tuples it accepted whose stamp lay behind its stream time when
-# they came, times a live key's anchor moved back, tuples it ignored;
-# and where its key states were: the keys its calls' per-key visit met,
-# those of them in a call that ran ahead of itself (the table had
-# outgrown the caches), the rings that left their key state
-# (docs/RUNTIME.md 5a "A key state in one place"); and what its flush
-# staged: the pane partials it copied into launch buffers and the windows
-# they serve (docs/RUNTIME.md 5c); the tuples of its calls whose walk was
-# split over the host lanes (docs/RUNTIME.md 5b); the
-# :data:`ENGINE_CLOCKS` and :data:`ENGINE_ARGMAX`; the tuples of its
-# calls that the one-key walk took (docs/RUNTIME.md 5b); and the tuples a
-# ``tuples`` engine's flush copied into launch buffers (docs/RUNTIME.md 5d)
-ENGINE_COUNTERS = ("keys_opened", "keys_evicted", "keys_live",
-                   "keys_live_peak", "windows_fired", "folded_by_key",
-                   "folded_singly", "late_accepted", "anchors_moved",
-                   "inputs_ignored", "key_touches", "walked_ahead",
-                   "rings_spilled", "panes_staged", "windows_staged",
-                   "walked_in_lanes", *ENGINE_CLOCKS, *ENGINE_ARGMAX,
-                   "walked_one_key", "tuples_staged")
+class Stat(NamedTuple):
+    """One slot of the native window engine's stats vector behind its
+    three churn clocks (``NativeWindowEngine.STATS``): its name, its help
+    text, its kind on ``/metrics`` ("counter" or "gauge"; "" where it is
+    no count and stays out of :class:`Counters`), whether the series
+    keeps it (:data:`SERIES_COUNTERS`), and its group ("clock":
+    :data:`ENGINE_CLOCKS`; "argmax": :data:`ENGINE_ARGMAX`)."""
+
+    name: str
+    help: str
+    kind: str = "counter"
+    series: bool = True
+    group: str = ""
+
+
+# What the native window engine counts, declared here once: the stats
+# JSON's ``Counters`` row, the series, ``NativeWindowEngine.STATS`` and
+# ``/metrics`` (by name, kind and help text) all read this.  THE ORDER
+# IS THE ENGINE'S: ``wfn_engine_stats`` fills ``out[3..)`` in this order
+# (native/window_engine.cpp), but for ``copy_out_ns``, which the wrapper
+# keeps in its slot; tests/test_native_runtime.py checks each slot
+# against what the engine counted on a known stream.  Each ``_ns`` clock
+# is nanoseconds on the engine's steady clock, a call at a time (but
+# ``copy_out_ns``, the wrapper's ``perf_counter_ns``); the
+# clocks of ``fold`` and ``flush`` are counters and never child spans,
+# so those two spans keep the self time they had.  A new counter is one
+# entry here and its fill line in ``wfn_engine_stats``.
+ENGINE_STATS = (
+    Stat("keys_opened", "key states the window engine created",
+         series=False),
+    Stat("keys_evicted", "key states the window engine evicted",
+         series=False),
+    Stat("keys_live", "key states the window engine holds", "gauge",
+         series=False),
+    Stat("keys_live_peak", "most key states the window engine held at "
+         "once", "gauge", series=False),
+    Stat("windows_fired", "windows the engine fired", series=False),
+    Stat("folded_by_key", "tuples the engine folded with their key's "
+         "others of the call in one combine"),
+    Stat("folded_singly", "tuples the engine folded one by one"),
+    Stat("late_accepted", "tuples the engine accepted whose stamp lay "
+         "behind its stream time when they came"),
+    Stat("anchors_moved", "times a live key's anchor moved back for a "
+         "tuple earlier than the key's first to arrive"),
+    Stat("inputs_ignored", "tuples the engine dropped behind a window "
+         "that had fired"),
+    Stat("stream_time", "the largest stamp the engine has seen (-1 "
+         "before the first)", "", series=False),
+    Stat("key_touches", "keys the engine's calls visited (a key once a "
+         "call)"),
+    Stat("walked_ahead", "of those, the visits of a call that ran ahead "
+         "of itself (a table beyond the caches)"),
+    Stat("rings_spilled", "pane rings that left their key state for a "
+         "block of their own", series=False),
+    Stat("panes_staged", "pane partials the engine's flush copied into "
+         "launch buffers"),
+    Stat("windows_staged", "windows the engine's flush staged for a "
+         "launch"),
+    Stat("walked_in_lanes", "tuples of the engine's calls whose walk was "
+         "split over the host lanes"),
+    Stat("ingest_ns", "nanoseconds inside the engine's batch calls, less "
+         "their open and trigger", group="clock"),
+    Stat("tuple_walk_ns", "of ingest_ns, the walks that cost by the tuple "
+         "(gather, note_late, fold_singly)", group="clock"),
+    Stat("key_walk_ns", "of ingest_ns, the walks that cost by the key "
+         "(prepare of the keys a call did not open, settle)",
+         group="clock"),
+    Stat("stage_ns", "nanoseconds inside the engine's flush, less its "
+         "evict (staging the spans, retiring the rings, the rows)",
+         group="clock"),
+    Stat("panes_shifted", "ring elements the engine's retire() moved down "
+         "after a staging", group="clock"),
+    Stat("copy_out_ns", "nanoseconds copying a flush's columns out of the "
+         "engine (perf_counter_ns round the copies, "
+         "NativeWindowEngine.flush)", group="clock"),
+    Stat("argmax_ns", "of ingest_ns, an argmax engine's own walks (each "
+         "key's largest value of a call and the ids at it; the one-by-one "
+         "fold), out of what would be tuple_walk_ns; where the one-key "
+         "walk took the call, the one-by-one fold alone", group="argmax"),
+    Stat("max_raised", "times an argmax engine set or raised a pane's "
+         "held maximum (and with it its ids)", group="argmax"),
+    Stat("tie_ids", "ids beyond the first at the maxima of the windows an "
+         "argmax engine staged", group="argmax"),
+    Stat("walked_one_key", "tuples of the engine's calls that carried one "
+         "key, walked once with no probe"),
+    Stat("tuples_staged", "tuples a tuples engine's flush copied into "
+         "launch buffers for a user's window function"),
+)
+# the counts, in the engine's order: a ``Counters`` row's keys
+ENGINE_COUNTERS = tuple(s.name for s in ENGINE_STATS if s.kind)
 # those of them kept as a series (the last values noted in each 100 ms
 # bucket), so that what moved between two instants can be read
-SERIES_COUNTERS = ("folded_by_key", "folded_singly", "late_accepted",
-                   "anchors_moved", "inputs_ignored", "key_touches",
-                   "walked_ahead", "walked_in_lanes", "panes_staged",
-                   "windows_staged", *ENGINE_CLOCKS, *ENGINE_ARGMAX,
-                   "walked_one_key", "tuples_staged")
+SERIES_COUNTERS = tuple(s.name for s in ENGINE_STATS if s.series)
+# the inside of ``fold`` and ``flush``, and what an ``argmax`` engine
+# clocks and counts of its own (0 on every other kind), by help text
+ENGINE_CLOCKS = {s.name: s.help for s in ENGINE_STATS if s.group == "clock"}
+ENGINE_ARGMAX = {s.name: s.help for s in ENGINE_STATS
+                 if s.group == "argmax"}
 
-
-# what a window operator counts of the selected batches it ingests
-SELECTION_COUNTERS = ("cols_selected", "cols_gathered", "rows_by_selection")
+# what a window operator counts of the selected batches it ingests, by
+# help text
+SELECTION_COUNTERS = {
+    "cols_selected": "columns the selected batches the operator ingested "
+                     "carried",
+    "cols_gathered": "columns of selected batches that were gathered on "
+                     "read",
+    "rows_by_selection": "rows the window store read through a batch's "
+                         "selection",
+}
 
 
 class Counters:
@@ -589,21 +638,18 @@ class Counters:
     between two instants and the disorder met between them can be read
     afterwards.  Written by the operator's ingest thread alone."""
 
-    __slots__ = ("operator", "values", "live", "series", "cols_selected",
-                 "cols_gathered", "rows_by_selection")
+    __slots__ = ("operator", "values", "live", "series",
+                 *SELECTION_COUNTERS)
 
     def __init__(self, operator: str):
         self.operator = operator
         self.values: Dict[str, int] = dict.fromkeys(ENGINE_COUNTERS, 0)
         self.live: Dict[int, int] = {}      # bucket -> largest keys_live
         self.series: Dict[int, tuple] = {}  # bucket -> SERIES_COUNTERS
-        # the selected batches (core/tuples.py) the operator ingested:
-        # the columns they carried, those of them that had been gathered
-        # by the time the store had read the batch, and the rows the
-        # store was handed through a selection
-        self.cols_selected = 0
-        self.cols_gathered = 0
-        self.rows_by_selection = 0
+        # what the selected batches (core/tuples.py) the operator
+        # ingested carried (:data:`SELECTION_COUNTERS`)
+        for name in SELECTION_COUNTERS:
+            setattr(self, name, 0)
 
     def selected(self, carried: int, gathered: int, rows: int) -> None:
         """One selected batch ingested (the window operator, after its
